@@ -60,8 +60,8 @@ from cockroach_tpu.coldata.arrow import pack_layout
 from cockroach_tpu.coldata.batch import Batch, Column, Schema, concat_batches
 from cockroach_tpu.exec import stats
 from cockroach_tpu.exec.fused import (
-    RESULT_CAP, Unsupported, _Tracer, _pack_result, _unpack_result,
-    compile_via_vault,
+    RESULT_CAP, HBMExceeded, Unsupported, _Tracer, _pack_result,
+    _unpack_result, compile_via_vault, lower_program,
 )
 from cockroach_tpu.exec.operators import (
     FlowRestart, HashAggOp, JoinOp, Operator, ScanOp, ShrinkOp, SortOp, TopKOp,
@@ -509,11 +509,9 @@ class DistFusedRunner:
 
         return step
 
-    def _compile(self, pkey, scans, sharded, repart, args, layout, ops):
-        """Trace + lower + compile one program and publish it under
-        `pkey`. `args` may be committed global arrays (data-driven) or
-        sharded ShapeDtypeStructs (the AOT ladder)."""
-        box: dict = {}
+    def _lower(self, scans, sharded, repart, args, box):
+        """Trace + lower the sharded step program (`box` receives the
+        tracer's flag_ops / result_cap)."""
         step = self._make_step(scans, sharded, repart, box)
         in_specs = tuple(
             (P(self.axis), P(self.axis)) if id(sc) in sharded
@@ -521,14 +519,26 @@ class DistFusedRunner:
             for sc in scans)
         fn = shard_map(step, mesh=self.mesh, in_specs=in_specs,
                        out_specs=P(), check_rep=False)
+        return lower_program(fn, args)
+
+    def _compile(self, pkey, scans, sharded, repart, args, layout, ops):
+        """Trace + lower + compile one program and publish it under
+        `pkey`. `args` may be committed global arrays (data-driven) or
+        sharded ShapeDtypeStructs (the AOT ladder)."""
+        box: dict = {}
         extra = (mesh_key(self.mesh, self.axis),
                  tuple(layout[id(sc)] for sc in scans))
         with _tracing.child_span("dist.compile"), \
                 stats.timed("dist.compile"):
             try:
-                lowered = jax.jit(fn).lower(*args)
+                lowered = self._lower(scans, sharded, repart, args, box)
                 compiled = compile_via_vault(
                     lowered, tables=self._table_tags(), extra_key=extra)
+            except HBMExceeded as e:
+                # too large for this mesh's memory is a capacity error
+                # for the ladder above (shrink / single chip, counted
+                # there as resilience.*.dist), not a grammar verdict
+                raise MemoryError(str(e)) from e
             except Unsupported:
                 _PROGS[pkey] = None  # negative: skip re-trace next time
                 _trim_progs()
@@ -665,7 +675,13 @@ class DistFusedRunner:
     def batches(self):
         try:
             compiled, flag_ops, result_cap, args = self._prepare()
-        except Unsupported:
+        except Unsupported as e:
+            # outside what the distributed tracer runs (right/outer on
+            # the sharded spine, a repartition nested in a build, an
+            # empty scan): the streaming tree answers — say so
+            stats.add("dist.fallback_unsupported")
+            _tracing.record("dist.fallback", reason="unsupported",
+                            detail=str(e)[:80])
             yield from self.root.batches()
             return
 

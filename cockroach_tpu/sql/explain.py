@@ -118,7 +118,8 @@ def execute(sql: str, catalog: Catalog, capacity: int = 1 << 17,
 
 def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
                       mesh=None, ast=None,
-                      op_sink=None) -> Tuple[str, object, object]:
+                      op_sink=None,
+                      setting: str = "auto") -> Tuple[str, object, object]:
     """-> (kind, payload, output Schema or None) — the schema is the
     built operator tree's own, for exact result decoding. Pass `ast` to
     skip re-parsing (Session already parsed for dispatch). `op_sink` (a
@@ -149,7 +150,8 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
         qreg.set_phase_current("executing")
         sink = [] if op_sink is not None else None
         result, schema = run(plan, catalog, capacity, mesh=mesh,
-                             with_schema=True, op_sink=sink, sql=sql)
+                             with_schema=True, op_sink=sink, sql=sql,
+                             setting=setting)
         if op_sink is not None:
             op_sink.append({"plan": plan,
                             "op": sink[0] if sink else None})
@@ -168,8 +170,8 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
     placement = None
     try:
         placement = compile_plan(norm, catalog, capacity, sql=sql,
-                                 record=False, _normalized=True
-                                 ).placement
+                                 setting=setting, record=False,
+                                 _normalized=True).placement
     except Exception:
         pass  # placement is advisory; EXPLAIN still renders the plan
     if placement is not None:

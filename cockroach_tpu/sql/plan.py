@@ -1059,17 +1059,19 @@ def _walk_plan(p: Plan):
 
 def run(p: Plan, catalog: Catalog, capacity: int = 1 << 17, mesh=None,
         axis: str = "x", with_schema: bool = False, op_sink=None,
-        sql: Optional[str] = None):
+        sql: Optional[str] = None, setting: str = "auto"):
     """Execute a logical plan; `mesh` switches to distributed execution
     (the DistSQL on/off decision). `with_schema=True` also returns the
     operator tree's output Schema (result decoding needs the exact
     output types, and the tree was built anyway). `op_sink` (a list)
     receives the built operator tree — Session's prepared-statement
     cache re-collects it on warm re-execution. `sql` keys the placement
-    pass's per-fingerprint cache (measured-cost tier routing)."""
+    pass's per-fingerprint cache (measured-cost tier routing); `setting`
+    is the session's `vectorize` (auto lets the coster route)."""
     from cockroach_tpu.sql.plan_compile import compile_plan
 
-    compiled = compile_plan(p, catalog, capacity, sql=sql)
+    compiled = compile_plan(p, catalog, capacity, sql=sql,
+                            setting=setting)
     op = compiled.op
     if op_sink is not None:
         op_sink.append(op)

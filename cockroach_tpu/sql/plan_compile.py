@@ -201,7 +201,10 @@ def compile_plan(p: Plan, catalog: Catalog, capacity: int = 1 << 17,
     fp = fingerprint(sql) if sql else ""
     cache = default_placement_cache()
     cached: Optional[QueryPlacement] = None
-    if fp:
+    # a forced side (SET vectorize = tpu|cpu) neither reads nor seeds
+    # the per-fingerprint cache: that holds the coster's own decisions
+    routed = setting == "auto"
+    if fp and routed:
         if not record:
             cached = cache.peek(fp)
         elif not cache.should_replan(fp):
@@ -261,7 +264,7 @@ def compile_plan(p: Plan, catalog: Catalog, capacity: int = 1 << 17,
             oc.source = "measured"
         placement.ops.append(oc)
 
-    if fp and record and cached is None:
+    if fp and routed and record and cached is None:
         cache.store(fp, placement)
     return CompiledPlan(op=op, backend=backend, placement=placement,
                         runner=runner)

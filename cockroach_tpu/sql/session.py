@@ -788,7 +788,8 @@ class Session:
         # latency floor (0.0 -> the first statement refreshes it)
         self._ins_tick = 0
         self._ins_floor = 0.0
-        self.vars: Dict[str, object] = {"vectorize": "tpu",
+        # vectorize: auto (the coster routes, sql/cost.py) | tpu | cpu
+        self.vars: Dict[str, object] = {"vectorize": "auto",
                                         "admission_priority": "normal"}
         if db is None and isinstance(catalog, SessionCatalog):
             db = DB(catalog.store)
@@ -1189,10 +1190,13 @@ class Session:
         Statements in the serving queue's batchable class additionally
         carry a BatchSpec, the ticket into cross-session coalescing."""
         from cockroach_tpu.exec.operators import ScanOp, walk_operators
-        from cockroach_tpu.sql.plan import Scan as _Scan, _walk_plan
+        from cockroach_tpu.sql.plan import (
+            MVCCCatalog, Scan as _Scan, _walk_plan,
+        )
 
         op = sunk.get("op") if isinstance(sunk, dict) else None
-        if op is None or not isinstance(self.catalog, SessionCatalog):
+        if op is None or not isinstance(self.catalog,
+                                        (SessionCatalog, MVCCCatalog)):
             return
         for s in walk_operators(op):
             if isinstance(s, ScanOp) and s.cache_key is None:
@@ -1269,7 +1273,8 @@ class Session:
                     if payload is not None:
                         return "rows", payload, prep.schema
                 if prep.op is not None:
-                    return "rows", collect(prep.op), prep.schema
+                    return "rows", collect(
+                        prep.op, backend=self.vars["vectorize"]), prep.schema
                 # serving-only entry (stale plan over a resident table)
                 # whose batch submit declined: fall through to the cold
                 # parse path, which also re-stores a full entry
@@ -1310,12 +1315,14 @@ class Session:
                 # before the parse above
                 sink: List[object] = []
                 out = execute_with_plan(sql, catalog, self.capacity,
-                                        ast=ast, op_sink=sink)
+                                        ast=ast, op_sink=sink,
+                                        setting=self.vars["vectorize"])
                 if sink:
                     self._prepared_store(sql, sink[0], ast)
                 return out
             return execute_with_plan(sql, catalog, self.capacity,
-                                     ast=ast)
+                                     ast=ast,
+                                     setting=self.vars["vectorize"])
         if isinstance(ast, P.TxnControl):
             return self._txn_control(ast)
         if isinstance(ast, P.SetVar):
@@ -1815,6 +1822,8 @@ class Session:
         if ast.name not in self._VARS:
             raise BindError(f"unknown session variable {ast.name!r}")
         value = ast.value
+        if ast.name == "vectorize" and value not in ("auto", "tpu", "cpu"):
+            raise BindError("vectorize is one of auto, tpu, cpu")
         if ast.name not in ("pallas", "vectorize"):  # string-valued vars
             if value in ("on", "true"):
                 value = True

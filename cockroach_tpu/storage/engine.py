@@ -4,10 +4,11 @@ The reference's storage layer is Pebble (Go LSM) under MVCC semantics in
 pkg/storage; SURVEY.md §2.8 calls the C++ storage engine "the largest
 native-component obligation". This module compiles the engine on first use
 (g++ -O2 -shared, cached next to the source keyed by a source hash) and
-exposes it as the `NativeEngine` class. A pure-Python `PyEngine` with
-identical semantics backs environments without a toolchain and serves as
-the differential-testing model (the kvnemesis posture: two implementations,
-one history — pkg/kv/kvnemesis/validator.go:49).
+exposes it as the `NativeEngine` class — the default engine; a failed
+build raises with the compiler's stderr. A pure-Python `PyEngine` with
+identical semantics is the differential-testing model, chosen by name (the
+kvnemesis posture: two implementations, one history —
+pkg/kv/kvnemesis/validator.go:49).
 """
 
 from __future__ import annotations
@@ -34,32 +35,44 @@ _lib_err: Optional[str] = None
 _lib_lock = threading.Lock()
 
 
-def _build_lib() -> Optional[str]:
-    """Compile (or reuse) the shared library; returns its path or None."""
+def _build_lib() -> str:
+    """Compile (or reuse) the shared library from mvcc_engine.cpp — the
+    file git commits, keyed by its hash, so a stale build cannot load;
+    returns its path. A failed build raises with the compiler's words."""
     with open(_SRC, "rb") as f:
         digest = hashlib.sha256(f.read()).hexdigest()[:16]
     so_path = os.path.join(_NATIVE_DIR, f"mvcc_engine_{digest}.so")
     if os.path.exists(so_path):
         return so_path
+    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC,
+           "-o", so_path + ".tmp"]
     try:
-        subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC,
-             "-o", so_path + ".tmp"],
-            check=True, capture_output=True, timeout=120)
-        os.replace(so_path + ".tmp", so_path)
-        return so_path
-    except Exception:
-        return None
+        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+    except FileNotFoundError as e:
+        raise RuntimeError(f"no C++ compiler on PATH ({e})") from e
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"{' '.join(cmd)} exited {e.returncode}:\n"
+            f"{e.stderr.decode(errors='replace')[-4000:]}") from e
+    except subprocess.TimeoutExpired as e:
+        raise RuntimeError(f"{' '.join(cmd)} timed out") from e
+    os.replace(so_path + ".tmp", so_path)
+    return so_path
 
 
 def _load():
+    """The loaded library, or None when it cannot be built (the reason —
+    the compiler's stderr — is kept in _lib_err and NativeEngine() raises
+    it). Tests use the None to skip native-only cases; nothing in the
+    program turns it into a silent switch of engines."""
     global _lib, _lib_err
     with _lib_lock:
         if _lib is not None or _lib_err is not None:
             return _lib
-        path = _build_lib()
-        if path is None:
-            _lib_err = "g++ unavailable or compile failed"
+        try:
+            path = _build_lib()
+        except RuntimeError as e:
+            _lib_err = str(e)
             return None
         lib = ctypes.CDLL(path)
         u8p = ctypes.POINTER(ctypes.c_uint8)
@@ -766,7 +779,8 @@ class PyEngine(TableVersions):
 
 
 def open_engine(prefer_native: bool = True, **kw):
-    """NativeEngine when the toolchain allows, else the Python model."""
-    if prefer_native and _load() is not None:
-        return NativeEngine(**kw)
-    return PyEngine(**kw)
+    """The default engine is the native one, and a build failure RAISES
+    (NativeEngine: with the compiler's stderr) — a store some hundred
+    times slower is not a fallback anyone asked for. PyEngine, the
+    differential-testing model, is chosen by name."""
+    return NativeEngine(**kw) if prefer_native else PyEngine(**kw)

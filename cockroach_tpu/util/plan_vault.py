@@ -85,6 +85,15 @@ def _env_fingerprint() -> dict:
     }
 
 
+def _execution_devices(compiled) -> list:
+    """The devices `compiled` runs on, in device-assignment order.
+    serialize_executable does not keep them and deserialize_and_load
+    defaults to EVERY device of the backend, so a one-device program
+    loaded in a process that sees several fails at its first call
+    ("expected N shards"). The vault records them beside `env`."""
+    return list(compiled.runtime_executable().local_devices())
+
+
 class PlanVault:
     """Disk vault of serialized compiled executables, content-addressed
     by lowered-module digest + environment fingerprint."""
@@ -162,10 +171,21 @@ class PlanVault:
                 return None
             if hashlib.sha256(body).hexdigest() != header.get("sha256"):
                 raise ValueError("payload digest mismatch")
-            in_tree, out_tree, payload = pickle.loads(body)
+            import jax
             from jax.experimental import serialize_executable as _se
 
-            loaded = _se.deserialize_and_load(payload, in_tree, out_tree)
+            by_id = {d.id: d for d in jax.devices()}
+            ids = header.get("devices")
+            if not ids or any(i not in by_id for i in ids):
+                # compiled for devices this process does not have (or an
+                # artifact from before they were recorded): unusable
+                # here, not corrupt
+                self._miss(key, reason="devices_absent")
+                return None
+            in_tree, out_tree, payload = pickle.loads(body)
+            loaded = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in ids])
         except FileNotFoundError:
             self._miss(key, reason="absent")
             return None
@@ -203,9 +223,11 @@ class PlanVault:
         """Serialize `compiled` under `key` (atomic tmp+rename). Returns
         whether an artifact was written; False when the executable type
         doesn't serialize on this backend."""
-        try:
-            from jax.experimental import serialize_executable as _se
+        import jax
+        from jax.experimental import serialize_executable as _se
 
+        devices = _execution_devices(compiled)
+        try:
             payload, in_tree, out_tree = _se.serialize(compiled)
             # verify the round trip BEFORE persisting: an executable that
             # was itself a persistent-XLA-cache hit serializes without its
@@ -213,9 +235,13 @@ class PlanVault:
             # deserialize), so an unverified store would plant an artifact
             # that can never load. Refusing here keeps the invariant that
             # anything on disk serves.
-            _se.deserialize_and_load(payload, in_tree, out_tree)
+            _se.deserialize_and_load(payload, in_tree, out_tree,
+                                     execution_devices=devices)
             body = pickle.dumps((in_tree, out_tree, payload))
-        except Exception as e:  # noqa: BLE001 — backend-dependent support
+        except jax.errors.JaxRuntimeError as e:
+            # the BACKEND said no (executable type it cannot serialize,
+            # symbols it cannot find). Anything else — a wrong argument
+            # of ours, an unpicklable tree — is a bug and raises
             self._unsupported.inc()
             stats.add("compile.vault_unsupported")
             _tracing.record("compile.vault_unsupported",
@@ -225,6 +251,7 @@ class PlanVault:
             "magic": _MAGIC,
             "key": key,
             "env": _env_fingerprint(),
+            "devices": [d.id for d in devices],
             "tables": sorted(set(str(t) for t in tables if t)),
             "sha256": hashlib.sha256(body).hexdigest(),
             "nbytes": len(body),

@@ -68,12 +68,24 @@ _TRANSIENT_TOKENS = ("UNAVAILABLE", "ABORTED", "DATA_LOSS",
                      "transfer failed", "DEADLINE_EXCEEDED")
 
 
+class CompileRefused(RuntimeError):
+    """The device's compiler (XLA, Mosaic) or the lowering refused a
+    program: scoped vmem, an unsupported op, a kernel it cannot tile. A
+    defect of the program, not a capacity condition — no lower tier may
+    answer in its place, whatever words the message holds. Raised where
+    programs are lowered and compiled (exec/fused.py)."""
+
+
 def classify(exc: BaseException) -> str:
     """One verdict per exception: RETRYABLE / RESOURCE / TERMINAL."""
     from cockroach_tpu.util.cancel import QueryCancelled
     from cockroach_tpu.util.fault import InjectedFault
     from cockroach_tpu.util.mon import BudgetExceededError
 
+    if isinstance(exc, CompileRefused):
+        # before the token matchers: "Ran out of memory in memory space
+        # vmem" reads as an OOM, and must not step the ladder down
+        return TERMINAL
     if isinstance(exc, QueryCancelled):
         # checked before the token matchers: the cancellation reason may
         # mention "timeout", which must not read as a transient fault —

@@ -134,8 +134,9 @@ SCAN_IMAGE_CACHE_BUDGET = Settings.register(
 COMPILATION_CACHE_DIR = Settings.register(
     "sql.tpu.compilation_cache_dir",
     "",
-    "persistent XLA compilation cache directory (empty = disabled); "
-    "cold whole-query compiles are paid once per machine, not per process",
+    "persistent XLA compilation cache directory (empty = the checkout's "
+    ".jax_cache); the JAX_COMPILATION_CACHE_DIR environment variable "
+    "always wins over it (util/compile_cache.py)",
 )
 # Vector search (sql/plan.py VectorTopK): the ANN arm trades recall for
 # latency; exact is the default because it is loss-free and already one

@@ -21,8 +21,8 @@ produces the latency table for bench.py's JSON.
 
 from __future__ import annotations
 
+import os
 import shutil
-import tempfile
 import time
 from typing import Callable, Dict, Optional
 
@@ -70,46 +70,31 @@ def _first_exec_times(vault_dir: str = "") -> Dict[str, float]:
 
 
 def run(log: Optional[Callable[[str], None]] = None) -> dict:
-    """The bench.py "coldstart" block. Temporarily re-points the XLA
-    compilation cache and the plan vault at throwaway directories so the
-    three regimes are isolated from each other AND from the bench's own
-    warm caches; both settings are restored on exit."""
-    import jax
-    from jax.experimental.compilation_cache import (
-        compilation_cache as _xla_cc,
-    )
-
+    """The bench.py "coldstart" block. The cold and vault regimes run with
+    the persistent XLA cache switched off (util/compile_cache.py — the
+    cache directory itself is never re-pointed); the xla_warm regime uses
+    the process's own cache, populated by a first pass. The plan vault
+    lives in a fixed, emptied sub-directory of the checkout; its setting
+    is restored on exit."""
     from cockroach_tpu.util import plan_vault as pv
+    from cockroach_tpu.util.compile_cache import (
+        CHECKOUT, persistent_cache_disabled,
+    )
     from cockroach_tpu.util.settings import Settings
 
     log = log or (lambda m: None)
-    old_xla = jax.config.jax_compilation_cache_dir
     old_vault = Settings().get(pv.PLAN_VAULT_DIR)
-    scratch = tempfile.mkdtemp(prefix="coldstart_bench_")
-    xla_dir = scratch + "/xla"
-    vault_dir = scratch + "/vault"
-
-    def _repoint_xla_cache(directory):
-        # the cache object latches at the first compile; reset, or the
-        # dir change is silently ignored for the rest of the process
-        jax.config.update("jax_compilation_cache_dir", directory)
-        _xla_cc.reset_cache()
+    vault_dir = os.path.join(CHECKOUT, ".jax_cache", "coldstart_vault")
+    shutil.rmtree(vault_dir, ignore_errors=True)
+    os.makedirs(vault_dir)
 
     try:
         # -- regime 1: cold (no caches anywhere)
-        _repoint_xla_cache(None)
-        cold = _first_exec_times()
+        with persistent_cache_disabled():
+            cold = _first_exec_times()
         log(f"coldstart: cold {({k: round(v, 3) for k, v in cold.items()})}")
 
         # -- regime 2: persistent XLA cache, warm (populate, re-measure)
-        _repoint_xla_cache(xla_dir)
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", 0)
-        except Exception:  # noqa: BLE001 — older jax knob names
-            pass
         _first_exec_times()  # populate
         xla_warm = _first_exec_times()
         log(f"coldstart: xla_warm "
@@ -118,9 +103,9 @@ def run(log: Optional[Callable[[str], None]] = None) -> dict:
         # -- regime 3: plan vault, warm (populate, re-measure). The XLA
         # cache must be OFF while populating: a cache-hit executable
         # doesn't re-serialize (store would refuse, see plan_vault.py).
-        _repoint_xla_cache(None)
-        _first_exec_times(vault_dir)  # populate
-        vault_warm = _first_exec_times(vault_dir)
+        with persistent_cache_disabled():
+            _first_exec_times(vault_dir)  # populate
+            vault_warm = _first_exec_times(vault_dir)
         log(f"coldstart: vault_warm "
             f"{({k: round(v, 3) for k, v in vault_warm.items()})}")
 
@@ -134,6 +119,5 @@ def run(log: Optional[Callable[[str], None]] = None) -> dict:
             } for name in QUERIES
         }}
     finally:
-        _repoint_xla_cache(old_xla)
         Settings().set(pv.PLAN_VAULT_DIR, old_vault)
-        shutil.rmtree(scratch, ignore_errors=True)
+        shutil.rmtree(vault_dir, ignore_errors=True)

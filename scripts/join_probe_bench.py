@@ -17,11 +17,6 @@ import cockroach_tpu  # noqa: F401
 from cockroach_tpu.coldata.batch import Batch, Column
 from cockroach_tpu.ops.join import hash_join_prepared, prepare_build
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(os.path.dirname(__file__), "..",
-                               ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 n = 1 << int(sys.argv[1] if len(sys.argv) > 1 else 22)
 mode = sys.argv[2] if len(sys.argv) > 2 else "unique"

@@ -17,11 +17,6 @@ from cockroach_tpu.exec import collect
 from cockroach_tpu.workload import tpch_queries as Q
 from cockroach_tpu.workload.tpch import TPCH
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(os.path.dirname(__file__), "..",
-                               ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 from cockroach_tpu.util.settings import Settings, WORKMEM
 

@@ -77,3 +77,28 @@ def test_flow_backend_respects_est_rows():
     assert flow_backend(big) == "tpu"
     unknown = ScanOp(schema, chunks, 8)
     assert flow_backend(unknown) == "tpu"  # no estimate: accelerator
+
+
+def test_set_vectorize_forces_a_side_and_rejects_nonsense():
+    """SET vectorize reaches the placement pass (cold) and the prepared
+    re-collect (warm); the default stays the coster's `auto`."""
+    import pytest
+
+    from cockroach_tpu.sql.bind import BindError
+
+    s = _session()
+    s.execute("create table t (id int primary key, v int)")
+    s.execute("insert into t values " + ", ".join(
+        f"({i}, {i})" for i in range(50)))
+    assert s.vars["vectorize"] == "auto"
+    s.execute("set vectorize = tpu")
+    for _ in ("cold", "warm"):
+        st = stats.enable()
+        try:
+            s.execute("select sum(v) from t")
+            assert st.stage("route.tpu").events == 1
+            assert "route.cpu" not in st.stages
+        finally:
+            stats.disable()
+    with pytest.raises(BindError):
+        s.execute("set vectorize = sometimes")

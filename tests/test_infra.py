@@ -313,3 +313,83 @@ def test_range_repartition_local_on_mesh():
         assert ((mine >= lo) & (mine < hi)).all(), d
     # conservation
     assert osel.sum() == sel.sum()
+
+
+# ----------------------------------------- the one compile-cache resolver --
+
+def test_compile_cache_env_var_wins_and_sets_no_directory(monkeypatch,
+                                                          tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set JAX reads the variable itself:
+    the resolver reports it and sets NO directory — not the setting's,
+    not a caller's default."""
+    import jax
+
+    from cockroach_tpu.util import compile_cache as cc
+    from cockroach_tpu.util.settings import COMPILATION_CACHE_DIR, Settings
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(cc, "_resolved", cc._resolved)  # restored after
+    x = str(tmp_path / "x")
+    monkeypatch.setenv(cc.ENV_VAR, x)
+    Settings().set(COMPILATION_CACHE_DIR, "/from/the/setting")
+    try:
+        assert cc.resolve(default="/a/default") == x
+        assert cc.enable_persistent_cache(default="/a/default") == x
+        assert cc.enable_persistent_cache() == x
+    finally:
+        Settings().set(COMPILATION_CACHE_DIR, "")
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_checkout_dot_jax_cache(monkeypatch):
+    import os
+
+    import jax
+
+    from cockroach_tpu.util import compile_cache as cc
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv(cc.ENV_VAR, raising=False)
+    monkeypatch.setattr(cc, "_resolved", None)  # as at first import
+    try:
+        assert cc.resolve() == os.path.join(checkout, ".jax_cache")
+        # a later call without a default leaves a caller's choice alone
+        assert cc.enable_persistent_cache(default=before) == before
+        assert cc.enable_persistent_cache() == before
+    finally:
+        cc.enable_persistent_cache(default=before)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_only_the_resolver_touches_the_cache_directory():
+    """No module but util/compile_cache.py updates
+    jax_compilation_cache_dir, and no cache path is made from a temporary
+    name, a pid or the time."""
+    import os
+    import re
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    setter = re.compile(r"update\(\s*[\"']jax_compilation_cache_dir")
+    offenders = []
+    files = [os.path.join(root, "bench.py"),
+             os.path.join(root, "chip_smoke.py"),
+             os.path.join(root, "__graft_entry__.py")]
+    for sub in ("cockroach_tpu", "scripts", "tests"):
+        for d, _dirs, names in os.walk(os.path.join(root, sub)):
+            files += [os.path.join(d, n) for n in names
+                      if n.endswith(".py")]
+    resolver = os.path.join(root, "cockroach_tpu", "util",
+                            "compile_cache.py")
+    for path in files:
+        if path == resolver or not os.path.exists(path):
+            continue
+        with open(path) as f:
+            if setter.search(f.read()):
+                offenders.append(os.path.relpath(path, root))
+    assert not offenders, offenders
+    with open(resolver) as f:
+        src = f.read()
+    assert len(setter.findall(src)) == 1
+    for word in ("mkdtemp", "getpid", "time.time", "tempfile"):
+        assert word not in src, word

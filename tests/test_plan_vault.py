@@ -33,23 +33,15 @@ def vault_dir(tmp_path):
     # symbols on CPU PjRt, so the vault (correctly) refuses to store it —
     # which would make these round-trip tests depend on whether a prior
     # run already warmed .jax_cache_cpu. Fresh compiles serialize fine.
-    import jax
-    from jax.experimental.compilation_cache import (
-        compilation_cache as xla_cc,
-    )
+    from cockroach_tpu.util.compile_cache import persistent_cache_disabled
 
-    old_cache = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    xla_cc.reset_cache()  # the cache object latches at the first compile;
-    # without a reset the dir change above is silently ignored
     d = str(tmp_path / "vault")
-    Settings().set(pv.PLAN_VAULT_DIR, d)
-    try:
-        yield d
-    finally:
-        Settings().set(pv.PLAN_VAULT_DIR, "")
-        jax.config.update("jax_compilation_cache_dir", old_cache)
-        xla_cc.reset_cache()
+    with persistent_cache_disabled():
+        Settings().set(pv.PLAN_VAULT_DIR, d)
+        try:
+            yield d
+        finally:
+            Settings().set(pv.PLAN_VAULT_DIR, "")
 
 
 def _session(rows: int = 400, capacity: int = 256):
@@ -315,3 +307,57 @@ def test_serving_prewarm_shape_job_round_trip(vault_dir):
                             [1, 2, 4]) == 3
     sd2 = st2.as_dict()
     assert sd2.get("compile.vault_hit", {}).get("events", 0) >= 3, sd2
+
+
+# ------------------------------------------- devices + compile refusals --
+
+
+def test_one_device_artifact_loads_in_eight_device_process(vault_dir):
+    """serialize_executable drops the device assignment and
+    deserialize_and_load defaults to EVERY device of the backend: the
+    vault records the devices in the artifact header, so a program
+    compiled for one device loads — and runs — as a one-device program
+    in this eight-device process (and on a four-chip host)."""
+    import jax
+    import jax.numpy as jnp
+
+    assert len(jax.devices()) == 8
+    dev = jax.devices()[3]
+    x = jax.device_put(jnp.arange(16, dtype=jnp.int64), dev)
+    lowered = jax.jit(lambda a: a * 3 + 1).lower(x)
+    vault = pv.plan_vault()
+    key = vault.key_for(lowered.as_text())
+    assert vault.store(key, lowered.compile(), tables=("t",))
+    (entry,) = [e for e in vault.entries() if e["key"] == key]
+    loaded = vault.load(key)
+    assert loaded is not None
+    out = loaded(x)
+    assert out.devices() == {dev}
+    np.testing.assert_array_equal(np.asarray(out), np.arange(16) * 3 + 1)
+
+
+def test_compile_refusal_is_terminal_device_loss_steps_down():
+    """A compile-time refusal is the program's defect (TERMINAL, whatever
+    OOM words it holds); losing a device, or running out of memory while
+    EXECUTING, still steps the ladder down."""
+    from cockroach_tpu.exec import fused
+    from cockroach_tpu.parallel.mesh import DeviceLost
+    from cockroach_tpu.util import retry
+
+    vmem = RuntimeError(
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space vmem. Used 17.5M of 16.0M vmem.")
+    assert retry.classify(vmem) == retry.RESOURCE  # raised at execution
+    refused = fused._refusal("compile", vmem)
+    assert isinstance(refused, retry.CompileRefused)
+    assert "memory space vmem" in str(refused)  # the compiler's words
+    assert retry.classify(refused) == retry.TERMINAL
+    mosaic = fused._refusal("lowering", NotImplementedError(
+        "Mosaic failed to compile TPU kernel: out of memory"))
+    assert retry.classify(mosaic) == retry.TERMINAL
+    hbm = fused._refusal("compile", RuntimeError(
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space hbm. Used 20.1G of 15.75G hbm."))
+    assert isinstance(hbm, fused.HBMExceeded)  # -> streaming, counted
+    assert retry.classify(DeviceLost("chip 2 gone")) == retry.RESOURCE
+    assert retry.classify(MemoryError()) == retry.RESOURCE

@@ -198,3 +198,34 @@ def test_slow_query_interval_rate_limits_per_fingerprint():
     assert len(slow) == 2, slow
     assert "select a from t" in str(slow[0]["sql"])
     assert "select b from t" in str(slow[1]["sql"])
+
+
+def test_mvcc_catalog_statement_is_prepared_and_warm():
+    """TPCH.mvcc_load's read-only MVCCCatalog — what a bulk-loaded server
+    serves — has MVCC-versioned scan keys like SessionCatalog, so its
+    statements are prepared too: the second execution is one dispatch,
+    no re-plan, no compile; a write rotates the key and re-primes."""
+    from cockroach_tpu.coldata.batch import Field, INT, Schema
+    from cockroach_tpu.sql.plan import MVCCCatalog
+
+    store = MVCCStore(engine=PyEngine(), clock=HLC(ManualClock(1000)))
+    n = 300
+    store.ingest_table(7, np.arange(n, dtype=np.int64),
+                       {"a": np.arange(n, dtype=np.int64) % 5,
+                        "b": np.arange(n, dtype=np.int64)})
+    cat = MVCCCatalog(store, {"t": (7, Schema([Field("a", INT),
+                                                Field("b", INT)]))},
+                      rows={"t": n})
+    sess = Session(cat, capacity=128)
+    sess.execute(Q)
+    st = stats.enable()
+    _k, payload, _s = sess.execute(Q)
+    ev = {k: v["events"] for k, v in st.as_dict().items()}
+    assert ev.get("sql.prepared_hit") == 1, ev
+    assert ev.get("fused.exec") == 1 and "fused.compile" not in ev, ev
+    want = [int((np.arange(n)[np.arange(n) % 5 == g]).sum())
+            for g in range(5)]
+    assert [int(x) for x in payload["sb"]] == want
+    store.put(7, n, [0, 1000])  # rotates the version key
+    _k, payload, _s = sess.execute(Q)
+    assert int(payload["sb"][0]) == want[0] + 1000

@@ -355,3 +355,95 @@ def test_cache_insert_fault_degrades_to_miss():
     assert cache.get(("k",)) is None
     assert cache.put(("k",), "value", 100) is True
     assert cache.get(("k",)) == "value"
+
+
+# ------------------------------ compile refusals vs too-large-for-HBM ----
+
+
+class _RefusingLowered:
+    """Stands in for a jax Lowered whose backend compile fails."""
+
+    def __init__(self, msg):
+        self.msg = msg
+
+    def compile(self, *_a, **_k):
+        raise RuntimeError(self.msg)
+
+
+def _session_with_rows():
+    from cockroach_tpu.sql.session import Session, SessionCatalog
+    from cockroach_tpu.storage.engine import PyEngine
+    from cockroach_tpu.storage.mvcc import MVCCStore
+    from cockroach_tpu.util.hlc import HLC, ManualClock
+
+    store = MVCCStore(PyEngine(), HLC(ManualClock(1000)))
+    s = Session(SessionCatalog(store), capacity=64)
+    s.execute("create table t (k int primary key, v int)")
+    s.execute("insert into t values "
+              + ", ".join(f"({i}, {i % 7})" for i in range(100)))
+    s.execute("set vectorize = tpu")
+    return s
+
+
+def _compiler_says(monkeypatch, msg):
+    from cockroach_tpu.exec import fused
+
+    real = fused.FusedRunner._compile_lowered
+    monkeypatch.setattr(
+        fused.FusedRunner, "_compile_lowered",
+        staticmethod(lambda _lowered: real(_RefusingLowered(msg))))
+
+
+@pytest.mark.parametrize("msg", [
+    "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+    "memory in memory space vmem. Used 17.5M of 16.0M vmem.",
+    "INTERNAL: Mosaic failed to compile TPU kernel: unsupported shape",
+])
+def test_compile_refusal_fails_the_statement(monkeypatch, msg):
+    """vmem / Mosaic: no lower tier answers in the program's place, and
+    the client gets the compiler's words."""
+    from cockroach_tpu.util.retry import CompileRefused
+
+    s = _session_with_rows()
+    _compiler_says(monkeypatch, msg)
+    st = stats.enable()
+    try:
+        with pytest.raises(CompileRefused) as ei:
+            s.execute("select k, v from t where v > 3 order by k limit 5")
+    finally:
+        stats.disable()
+    assert msg[:60] in str(ei.value)
+    assert not [n for n in st.stages
+                if n.startswith(("resilience.degrade", "fused.fallback",
+                                 "fused.stream_hbm"))], st.stages
+
+
+def test_lowering_refusal_is_a_compile_refusal():
+    import jax.numpy as jnp
+
+    from cockroach_tpu.exec import fused
+    from cockroach_tpu.util.retry import CompileRefused
+
+    def prog(_x):
+        raise NotImplementedError("Mosaic lowering: vmem out of memory")
+
+    with pytest.raises(CompileRefused):
+        fused.lower_program(prog, (jnp.zeros(4),))
+
+
+def test_hbm_step_to_streaming_is_counted_by_name(monkeypatch):
+    """Too large for HBM is what the streaming tier is for: the statement
+    answers, and says so under ONE name."""
+    s = _session_with_rows()
+    _compiler_says(
+        monkeypatch,
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space hbm. Used 20.1G of 15.75G hbm.")
+    st = stats.enable()
+    try:
+        _k, payload, _s = s.execute(
+            "select k, v from t where v > 3 order by k limit 5")
+    finally:
+        stats.disable()
+    assert [int(x) for x in payload["k"]] == [4, 5, 6, 11, 12]
+    assert st.stage(stats.STREAM_HBM).events == 1
