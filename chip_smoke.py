@@ -224,8 +224,8 @@ def check_counters(seen: dict, what: str, want_tier=None,
         check(not name.startswith(("fused.fallback", "fused.stream_hbm",
                                    "dist.fallback")),
               f"{what}: {name} counted (the streaming tree answered)")
-    check(events(st, "scan.resident_fallback") == 0,
-          f"{what}: scan.resident_fallback counted")
+    for name in ("scan.resident_fallback", "compile.vault_store_error"):
+        check(events(st, name) == 0, f"{what}: {name} counted")
     if want_tier is not None:
         check(seen["tier"] == want_tier,
               f"{what}: root span tier {seen['tier']!r}, "
@@ -337,10 +337,10 @@ def _leaves(tree):
 
 # ------------------------------------------------------------ the phases --
 
-def phase_device(jax, rehearse: bool) -> None:
+def phase_device(jax, rehearse: bool) -> float:
     """The two constants sql/cost.py hard-codes, measured: one round trip
     of a trivial jitted program with its readback, and host->device
-    bandwidth for one large buffer."""
+    bandwidth for one large buffer. -> the round trip's median seconds."""
     import jax.numpy as jnp
 
     f = jax.jit(lambda x: x + 1)
@@ -369,6 +369,7 @@ def phase_device(jax, rehearse: bool) -> None:
           "h2d_bytes": nbytes,
           "cost_py_DISPATCH_FLOOR_S": cost.DISPATCH_FLOOR_S,
           "cost_py_H2D_GBPS": cost.H2D_GBPS})
+    return statistics.median(trips)
 
 
 def make_store():
@@ -383,23 +384,40 @@ def make_store():
     return store
 
 
-def force_device(client, explain_sql=None) -> None:
-    """SET vectorize = tpu on this connection. The coster (sql/cost.py)
-    still holds a 107 ms dispatch floor that was measured on another
-    attachment, which sends any scan under ~2.5M rows to the host
-    backend; the smoke is about the device path, so it says which side
-    `auto` would have chosen and then forces the device."""
-    if explain_sql is not None:
-        rows, code = client.query("explain " + explain_sql)
-        check(code is None, f"explain: sqlstate {code}")
-        emit({"phase": "coster", "sql": " ".join(explain_sql.split())[:60],
-              "auto_would_choose": [r[0] for r in rows
-                                    if r[0].startswith("engine:")]})
-    rows, code = client.query("set vectorize = tpu")
-    check(code is None, f"set vectorize: sqlstate {code}")
+def place(ask, sql: str, coster_stale: bool) -> bool:
+    """Leave `sql` to the coster (vectorize = auto, what a default
+    session runs) unless it would route it to the host backend. It does
+    that for any scan under ~2.5M rows while sql/cost.py holds a dispatch
+    floor two orders above the round trip phase_device measures; only
+    then (`coster_stale`) is the device forced — a stop-gap that goes
+    with ROADMAP S1/D3. Once the constants follow the measurement a host
+    route fails the run instead. `ask(text)` sends one statement and
+    returns its lines. -> whether the device was forced."""
+    ask("set vectorize = auto")
+    engine = [ln for ln in ask("explain " + sql)
+              if ln.startswith("engine:")]
+    check(bool(engine), f"explain printed no engine line for {sql[:40]!r}")
+    forced = any(ln.startswith("engine: cpu") for ln in engine)
+    emit({"phase": "coster", "sql": " ".join(sql.split())[:60],
+          "auto_would_choose": engine, "forced_to_device": forced})
+    if forced:
+        check(coster_stale,
+              f"auto routes {sql[:40]!r} to the host although cost.py's "
+              f"dispatch floor is within 10x of the measured round trip: "
+              f"{engine}")
+        ask("set vectorize = tpu")
+    return forced
 
 
-def phase_tpch(obs, store, gen, device, with_q3: bool = True) -> None:
+def wire_ask(client):
+    def ask(text: str):
+        rows, code = client.query(text)
+        check(code is None, f"{text[:40]!r}: sqlstate {code}")
+        return [r[0] for r in rows]
+    return ask
+
+
+def phase_tpch(obs, store, gen, device, coster_stale: bool) -> None:
     from cockroach_tpu.sql.pgwire import PgServer
     from cockroach_tpu.workload import tpch_queries as Q
     from cockroach_tpu.workload.servebench import WireClient
@@ -414,11 +432,10 @@ def phase_tpch(obs, store, gen, device, with_q3: bool = True) -> None:
     pg = PgServer(catalog, capacity=CAPACITY).start()
     try:
         client = WireClient(pg.addr, timeout=1100.0)
-        force_device(client, Q1_SQL)
-        stmts = [("q1", Q1_SQL, check_q1), ("q6", Q6_SQL, check_q6)]
-        if with_q3:
-            stmts.append(("q3", Q3_SQL, check_q3))
-        for name, sql, verify in stmts:
+        for name, sql, verify in (("q1", Q1_SQL, check_q1),
+                                  ("q6", Q6_SQL, check_q6),
+                                  ("q3", Q3_SQL, check_q3)):
+            forced = place(wire_ask(client), sql, coster_stale)
             for run in ("cold", "warm"):
                 with obs.statement() as seen:
                     rows, code = client.query(sql)
@@ -435,6 +452,7 @@ def phase_tpch(obs, store, gen, device, with_q3: bool = True) -> None:
                           f"compiles, {seen['cache_loads']} cache loads")
                 emit(statement_line(seen, phase=name, run=run,
                                     rows=len(rows), tpu_custom_call=custom,
+                                    forced_to_device=forced,
                                     matches_oracle=True))
         client.close()
     finally:
@@ -442,7 +460,7 @@ def phase_tpch(obs, store, gen, device, with_q3: bool = True) -> None:
 
 
 def phase_ycsb_and_write(obs, store, seed: int, n_rows: int,
-                         device) -> None:
+                         coster_stale: bool) -> None:
     """YCSB-E's statement (a short range scan from a key) through
     Parse/Bind/Execute, then an acknowledged INSERT read back on a second
     connection and through an aggregate. DDL/DML need the SessionCatalog
@@ -478,8 +496,6 @@ def phase_ycsb_and_write(obs, store, seed: int, n_rows: int,
         check(code is None, f"analyze usertable: sqlstate {code}")
         emit({"phase": "load", "what": "usertable", "rows": n_rows,
               "seconds": time.perf_counter() - t0})
-        force_device(a, "select field0 from usertable")
-        force_device(b)
 
         # -- YCSB-E: select ... where key >= $1 order by key limit 50
         scan_sql = ("select ycsb_key, " + ", ".join(fields)
@@ -489,6 +505,8 @@ def phase_ycsb_and_write(obs, store, seed: int, n_rows: int,
         # and the key is baked into the program, so every new start key
         # is another cold run (70-85 s on the chip, CHANGES.md PR 22)
         start = int(rng.integers(0, int(pks[-1]) - 3 * YCSB_SCAN_LEN))
+        forced = place(wire_ask(a), scan_sql.replace("$1", str(start)),
+                       coster_stale)
         for run in ("cold", "warm"):
             with obs.statement() as seen:
                 rows, code = a.query_extended(scan_sql, (start,))
@@ -506,6 +524,7 @@ def phase_ycsb_and_write(obs, store, seed: int, n_rows: int,
                       f"ycsb_e warm compiled ({seen['compiles']})")
             emit(statement_line(seen, phase="ycsb_e", run=run,
                                 start_key=start, rows=len(rows),
+                                forced_to_device=forced,
                                 matches_reference=True))
 
         # -- write path: INSERT on a, read back on b, then an aggregate
@@ -517,6 +536,7 @@ def phase_ycsb_and_write(obs, store, seed: int, n_rows: int,
             m = (f1 >= 1 << 37) & (f1 < 1 << 39) & (f2 < 1 << 39)
             return int(f0[m].sum()), int(m.sum())
 
+        agg_forced = place(wire_ask(b), agg_sql, coster_stale)
         with obs.statement() as seen:
             rows, code = b.query(agg_sql)
         check(code is None, f"agg before: sqlstate {code}")
@@ -524,7 +544,8 @@ def phase_ycsb_and_write(obs, store, seed: int, n_rows: int,
         check((int(rows[0][0]), int(rows[0][1])) == before,
               f"agg before insert: {rows} != {before}")
         check_counters(seen, "agg before")
-        emit(statement_line(seen, phase="write.agg_before"))
+        emit(statement_line(seen, phase="write.agg_before",
+                            forced_to_device=agg_forced))
 
         base = int(pks[-1]) + 1
         new = rng.integers(1 << 37, 1 << 38, (4, YCSB_FIELDS),
@@ -541,10 +562,11 @@ def phase_ycsb_and_write(obs, store, seed: int, n_rows: int,
 
         # a plain filter, not the scan statement: that one compiles for
         # over a minute per start key, and this phase is about the write
+        back_sql = ("select ycsb_key, " + ", ".join(fields)
+                    + " from usertable where ycsb_key >= %d" % base)
+        forced = place(wire_ask(b), back_sql, coster_stale)
         with obs.statement() as seen:
-            rows, code = b.query(
-                "select ycsb_key, " + ", ".join(fields)
-                + " from usertable where ycsb_key >= %d" % base)
+            rows, code = b.query(back_sql)
         check(code is None, f"read back: sqlstate {code}")
         got = sorted(tuple(int(v) for v in r) for r in rows)
         want = [tuple([base + i] + new[i].tolist())
@@ -553,8 +575,10 @@ def phase_ycsb_and_write(obs, store, seed: int, n_rows: int,
                            f"{got} != {want}")
         check_counters(seen, "read back")
         emit(statement_line(seen, phase="write.read_back", rows=len(rows),
+                            forced_to_device=forced,
                             matches_reference=True))
 
+        agg_forced = place(wire_ask(b), agg_sql, coster_stale)
         with obs.statement() as seen:
             rows, code = b.query(agg_sql)
         check(code is None, f"agg after: sqlstate {code}")
@@ -567,6 +591,7 @@ def phase_ycsb_and_write(obs, store, seed: int, n_rows: int,
               f"{rows} != {after}")
         check_counters(seen, "agg after")
         emit(statement_line(seen, phase="write.agg_after",
+                            forced_to_device=agg_forced,
                             sees_inserted_rows=True))
         a.close()
         b.close()
@@ -574,7 +599,7 @@ def phase_ycsb_and_write(obs, store, seed: int, n_rows: int,
         pg.close()
 
 
-def phase_four_chips(obs, store, gen) -> None:
+def phase_four_chips(obs, store, gen, coster_stale: bool) -> None:
     """DistSQL across chips: Q3's text through run_sql(mesh=make_mesh(4))
     with the BY_HASH repartition forced, against the same text on one
     chip in this process and against the oracle."""
@@ -642,7 +667,8 @@ def phase_four_chips(obs, store, gen) -> None:
     from cockroach_tpu.sql.session import Session
 
     sess = Session(catalog, capacity=CAPACITY)
-    sess.execute("set vectorize = tpu")  # see force_device
+    forced = place(lambda text: sess.execute(text)[1] or [], Q3_SQL,
+                   coster_stale)
     for run in ("cold", "warm"):
         with obs.statement() as seen:
             _kind, res, _schema = sess.execute(Q3_SQL)
@@ -650,6 +676,7 @@ def phase_four_chips(obs, store, gen) -> None:
         check_counters(seen, f"q3 one chip {run}", want_tier="fused",
                        want_stage="fused.exec")
         emit(statement_line(seen, phase="q3_one_chip", run=run,
+                            forced_to_device=forced,
                             rows=len(results["one"])))
     check(results["one"] == results["dist"] == want,
           "q3: four chips, one chip and the oracle do not agree")
@@ -666,8 +693,6 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse-sf", type=float, default=None,
                     metavar="SF", help="CPU rehearsal at this tiny TPC-H "
                     "scale; the last line still names the real platform")
-    ap.add_argument("--skip-q3", action="store_true",
-                    help="leave Q3 out of the one-chip run")
     args = ap.parse_args(argv)
     rehearse = args.rehearse_sf is not None
 
@@ -706,14 +731,20 @@ def main(argv=None) -> int:
     gen = TPCH(sf=sf, seed=args.seed)
     try:
         store = make_store()
+        # a tiny rehearsal scale belongs on the host by any coster, so a
+        # rehearsal may force the device; a chip run may only where the
+        # coster's floor is far off the round trip measured here
         if args.chips == 4:
-            phase_four_chips(obs, store, gen)
+            phase_four_chips(obs, store, gen, coster_stale=rehearse)
         else:
-            phase_device(jax, rehearse)
-            phase_tpch(obs, store, gen, dev, with_q3=not args.skip_q3)
+            from cockroach_tpu.sql import cost
+
+            trip = phase_device(jax, rehearse)
+            stale = rehearse or cost.DISPATCH_FLOOR_S > 10 * trip
+            phase_tpch(obs, store, gen, dev, stale)
             phase_ycsb_and_write(
                 obs, store, args.seed,
-                max(2000, int(YCSB_ROWS * min(1.0, sf))), dev)
+                max(2000, int(YCSB_ROWS * min(1.0, sf))), stale)
     except (SmokeFailure, AssertionError) as e:
         emit({"phase": "failed", "error": f"{type(e).__name__}: {e}"[:2000],
               "seconds": time.perf_counter() - t_all})

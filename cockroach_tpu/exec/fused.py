@@ -51,7 +51,7 @@ from cockroach_tpu.exec import stats
 from cockroach_tpu.util import cancel as _cancel
 from cockroach_tpu.util import retry as _retry
 from cockroach_tpu.util import tracing as _tracing
-from cockroach_tpu.util.fault import maybe_fail
+from cockroach_tpu.util.fault import InjectedFault, maybe_fail
 from cockroach_tpu.exec.operators import (
     DistinctOp, FlowRestart, HashAggOp, JoinOp, LimitOp, MapOp, Operator,
     ScanOp, ShrinkOp, SortOp, TopKOp, WindowOp, _pow2_at_least,
@@ -93,11 +93,15 @@ TPU_COMPILE_OPTIONS = {"xla_tpu_scoped_vmem_limit_kib": 65536}
 
 
 def _refusal(stage: str, e: Exception) -> Exception:
-    """What a failed lower/compile means. Too large for HBM is a fact
-    about this run's volume (-> HBMExceeded: stream instead, counted);
-    anything else the compiler says — scoped vmem, Mosaic, a lowering
-    rule — is a defect of the program and reaches the client in the
-    compiler's own words (-> CompileRefused, TERMINAL)."""
+    """What a failed lower/compile means. A transient backend fault
+    (UNAVAILABLE, DEADLINE_EXCEEDED, a failed transfer) is returned as it
+    came, so with_retry("fused.compile") retries it in place. Too large
+    for HBM is a fact about this run's volume (-> HBMExceeded: stream
+    instead, counted); anything else the compiler says — scoped vmem,
+    Mosaic, a lowering rule — is a defect of the program and reaches the
+    client in the compiler's own words (-> CompileRefused, TERMINAL)."""
+    if _retry.classify(e) == _retry.RETRYABLE:
+        return e
     msg = str(e)
     low = msg.lower()
     if _is_oom(e) and "vmem" not in low and "mosaic" not in low:
@@ -107,18 +111,26 @@ def _refusal(stage: str, e: Exception) -> Exception:
         f"{stage} refused: {type(e).__name__}: {msg}")
 
 
+# What tracer code raises on purpose while a program is lowered: the
+# fusion grammar's verdicts, a restart, an injected fault, cancellation,
+# a budget trip (BudgetExceededError is a MemoryError).
+_PROGRAM_VERDICTS = (Unsupported, FlowRestart, InjectedFault,
+                     _cancel.QueryCancelled, MemoryError)
+
+
 def lower_program(fn, args):
     """jax.jit(fn).lower(*args) for a whole-query program. The program's
-    own verdicts — every exception this package defines (Unsupported,
-    injected faults, cancellation, budget trips) and MemoryError — pass
-    through; anything else is JAX, XLA or Mosaic refusing to lower it."""
+    own verdicts (_PROGRAM_VERDICTS) pass through; anything else is JAX,
+    XLA or Mosaic refusing to lower it (see _refusal)."""
     try:
         return jax.jit(fn).lower(*args)
-    except Exception as e:  # noqa: BLE001 — sorted just below
-        if (type(e).__module__.startswith("cockroach_tpu.")
-                or isinstance(e, MemoryError)):
+    except _PROGRAM_VERDICTS:
+        raise
+    except Exception as e:  # noqa: BLE001 — sorted in _refusal
+        refused = _refusal("lowering", e)
+        if refused is e:
             raise
-        raise _refusal("lowering", e) from e
+        raise refused from e
 
 
 CHUNKABLE_JOINS = ("inner", "left", "semi", "anti")
@@ -850,14 +862,18 @@ class FusedRunner:
     def _compile_lowered(lowered):
         """One backend compile: with TPU_COMPILE_OPTIONS on a TPU (the CPU
         backend knows no such option), never a second attempt without
-        them. A refusal raises (see _refusal) — it is not retried and not
-        served by a lower tier."""
+        them. A refusal raises (see _refusal) and is served by no lower
+        tier; only a transient backend fault comes out as it was, for
+        with_retry to try again."""
         options = (TPU_COMPILE_OPTIONS
                    if jax.devices()[0].platform == "tpu" else None)
         try:
             return lowered.compile(options)
         except Exception as e:  # noqa: BLE001 — classified in _refusal
-            raise _refusal("compile", e) from e
+            refused = _refusal("compile", e)
+            if refused is e:
+                raise
+            raise refused from e
 
     def _vault_compile(self, lowered):
         return compile_via_vault(

@@ -203,7 +203,7 @@ def compile_plan(p: Plan, catalog: Catalog, capacity: int = 1 << 17,
     cached: Optional[QueryPlacement] = None
     # a forced side (SET vectorize = tpu|cpu) neither reads nor seeds
     # the per-fingerprint cache: that holds the coster's own decisions
-    routed = setting == "auto"
+    routed = setting not in ("tpu", "cpu")
     if fp and routed:
         if not record:
             cached = cache.peek(fp)

@@ -788,7 +788,8 @@ class Session:
         # latency floor (0.0 -> the first statement refreshes it)
         self._ins_tick = 0
         self._ins_floor = 0.0
-        # vectorize: auto (the coster routes, sql/cost.py) | tpu | cpu
+        # vectorize: tpu | cpu force a backend; any other value (auto)
+        # leaves the route to the coster (sql/cost.py)
         self.vars: Dict[str, object] = {"vectorize": "auto",
                                         "admission_priority": "normal"}
         if db is None and isinstance(catalog, SessionCatalog):
@@ -1822,8 +1823,6 @@ class Session:
         if ast.name not in self._VARS:
             raise BindError(f"unknown session variable {ast.name!r}")
         value = ast.value
-        if ast.name == "vectorize" and value not in ("auto", "tpu", "cpu"):
-            raise BindError("vectorize is one of auto, tpu, cpu")
         if ast.name not in ("pallas", "vectorize"):  # string-valued vars
             if value in ("on", "true"):
                 value = True

@@ -119,6 +119,11 @@ class PlanVault:
             "plan_vault_serialize_unsupported_total",
             "executables the backend refused to serialize (persistent "
             "XLA cache remains the fallback)")
+        self._store_errors = reg.counter(
+            "plan_vault_store_errors_total",
+            "stores that failed in OUR code, not in the backend (a wrong "
+            "argument, an unpicklable tree): a defect to fix; the query "
+            "keeps the program it compiled")
         self._evicted = reg.counter(
             "plan_vault_evicted_total",
             "artifacts evicted by the size quota (LRU) or stray-file GC")
@@ -221,13 +226,17 @@ class PlanVault:
 
     def store(self, key: str, compiled, tables: Iterable[str] = ()) -> bool:
         """Serialize `compiled` under `key` (atomic tmp+rename). Returns
-        whether an artifact was written; False when the executable type
-        doesn't serialize on this backend."""
+        whether an artifact was written. Never raises — the caller holds
+        a compiled program that serves with or without an artifact — but
+        the two ways to fail keep separate names: the backend refusing
+        this executable type (`compile.vault_unsupported`, expected on
+        some backends) and a fault of this code
+        (`compile.vault_store_error`, never expected)."""
         import jax
         from jax.experimental import serialize_executable as _se
 
-        devices = _execution_devices(compiled)
         try:
+            devices = _execution_devices(compiled)
             payload, in_tree, out_tree = _se.serialize(compiled)
             # verify the round trip BEFORE persisting: an executable that
             # was itself a persistent-XLA-cache hit serializes without its
@@ -240,12 +249,26 @@ class PlanVault:
             body = pickle.dumps((in_tree, out_tree, payload))
         except jax.errors.JaxRuntimeError as e:
             # the BACKEND said no (executable type it cannot serialize,
-            # symbols it cannot find). Anything else — a wrong argument
-            # of ours, an unpicklable tree — is a bug and raises
+            # symbols it cannot find)
             self._unsupported.inc()
             stats.add("compile.vault_unsupported")
             _tracing.record("compile.vault_unsupported",
                             detail=str(e)[:80])
+            return False
+        except Exception as e:  # noqa: BLE001 — counted under its own name
+            # our call was wrong (an argument serialize_executable does
+            # not take, an unpicklable tree)
+            import traceback
+
+            from cockroach_tpu.util.log import Channel, get_logger
+
+            self._store_errors.inc()
+            stats.add("compile.vault_store_error")
+            _tracing.record("compile.vault_store_error",
+                            detail=f"{type(e).__name__}: {e}"[:120])
+            get_logger().error(
+                Channel.OPS, "plan vault store failed in our own call "
+                "(artifact not written): {}", traceback.format_exc())
             return False
         header = {
             "magic": _MAGIC,

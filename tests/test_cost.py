@@ -79,26 +79,23 @@ def test_flow_backend_respects_est_rows():
     assert flow_backend(unknown) == "tpu"  # no estimate: accelerator
 
 
-def test_set_vectorize_forces_a_side_and_rejects_nonsense():
-    """SET vectorize reaches the placement pass (cold) and the prepared
-    re-collect (warm); the default stays the coster's `auto`."""
-    import pytest
-
-    from cockroach_tpu.sql.bind import BindError
-
+def test_set_vectorize_forces_a_side_else_the_coster_routes():
+    """SET vectorize = tpu reaches the placement pass (cold) and the
+    prepared re-collect (warm); the default, and any value that names no
+    backend (`on`, as clients of the reference send), is the coster."""
     s = _session()
     s.execute("create table t (id int primary key, v int)")
     s.execute("insert into t values " + ", ".join(
         f"({i}, {i})" for i in range(50)))
     assert s.vars["vectorize"] == "auto"
-    s.execute("set vectorize = tpu")
-    for _ in ("cold", "warm"):
-        st = stats.enable()
-        try:
-            s.execute("select sum(v) from t")
-            assert st.stage("route.tpu").events == 1
-            assert "route.cpu" not in st.stages
-        finally:
-            stats.disable()
-    with pytest.raises(BindError):
-        s.execute("set vectorize = sometimes")
+    for value, want, other in (("tpu", "route.tpu", "route.cpu"),
+                               ("on", "route.cpu", "route.tpu")):
+        s.execute(f"set vectorize = {value}")
+        for _ in ("cold", "warm"):
+            st = stats.enable()
+            try:
+                s.execute("select sum(v) from t")
+                assert st.stage(want).events == 1
+                assert other not in st.stages
+            finally:
+                stats.disable()

@@ -336,6 +336,42 @@ def test_one_device_artifact_loads_in_eight_device_process(vault_dir):
     np.testing.assert_array_equal(np.asarray(out), np.arange(16) * 3 + 1)
 
 
+@pytest.mark.parametrize("error, counter", [
+    ("backend", "compile.vault_unsupported"),
+    ("ours", "compile.vault_store_error"),
+])
+def test_store_failures_keep_separate_names(vault_dir, monkeypatch,
+                                            error, counter):
+    """"The backend cannot serialize this" and "our call was wrong" are
+    two counters; neither takes the compiled program from the query."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import serialize_executable as se
+
+    def refuse(_compiled):
+        if error == "backend":
+            raise jax.errors.JaxRuntimeError(
+                "UNIMPLEMENTED: executable type does not serialize")
+        raise TypeError("serialize() got an unexpected keyword argument")
+
+    monkeypatch.setattr(se, "serialize", refuse)
+    x = jnp.arange(4, dtype=jnp.int64)
+    lowered = jax.jit(lambda a: a + 1).lower(x)
+    compiled = lowered.compile()
+    vault = pv.plan_vault()
+    st = stats.enable()
+    try:
+        assert vault.store(vault.key_for(lowered.as_text()),
+                           compiled) is False
+    finally:
+        stats.disable()
+    assert st.stage(counter).events == 1
+    assert len([n for n in st.stages
+                if n.startswith("compile.vault_")]) == 1, st.stages
+    assert not vault.entries()
+    np.testing.assert_array_equal(np.asarray(compiled(x)), [1, 2, 3, 4])
+
+
 def test_compile_refusal_is_terminal_device_loss_steps_down():
     """A compile-time refusal is the program's defect (TERMINAL, whatever
     OOM words it holds); losing a device, or running out of memory while

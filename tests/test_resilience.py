@@ -431,6 +431,71 @@ def test_lowering_refusal_is_a_compile_refusal():
         fused.lower_program(prog, (jnp.zeros(4),))
 
 
+def test_transient_compile_fault_is_retried_in_place(monkeypatch):
+    """UNAVAILABLE / DEADLINE_EXCEEDED out of the backend's compile is a
+    transient fault, not a refusal: with_retry("fused.compile") retries
+    it and the fused tier answers."""
+    from cockroach_tpu.exec import fused
+
+    s = _session_with_rows()
+    real = fused.FusedRunner._compile_lowered
+    failures = ["UNAVAILABLE: connection to the compile service reset",
+                "DEADLINE_EXCEEDED: compile rpc timed out"]
+
+    def flaky(lowered):
+        if failures:
+            return real(_RefusingLowered(failures.pop()))
+        return real(lowered)
+
+    monkeypatch.setattr(fused.FusedRunner, "_compile_lowered",
+                        staticmethod(flaky))
+    st = stats.enable()
+    try:
+        _k, payload, _s = s.execute(
+            "select k, v from t where v > 3 order by k limit 5")
+    finally:
+        stats.disable()
+    assert [int(x) for x in payload["k"]] == [4, 5, 6, 11, 12]
+    assert st.stage("resilience.retry.fused.compile").events == 2
+    assert st.stage("fused.exec").events == 1
+    assert not [n for n in st.stages
+                if n.startswith(("resilience.degrade", "fused.fallback",
+                                 "fused.stream_hbm"))], st.stages
+
+
+def test_lowering_passes_the_programs_own_verdicts_by_type():
+    """What tracer code raises on purpose passes through lower_program
+    unchanged, chosen by exception type; a builtin it did not mean to
+    raise is a refusal that names it."""
+    import jax.numpy as jnp
+
+    from cockroach_tpu.exec import fused
+    from cockroach_tpu.util.cancel import QueryCancelled
+    from cockroach_tpu.util.mon import BudgetExceededError
+    from cockroach_tpu.util.retry import CompileRefused
+
+    def raising(exc):
+        def prog(_x):
+            raise exc
+        return prog
+
+    x = (jnp.zeros(4),)
+    for exc in (fused.Unsupported("outside the grammar"),
+                InjectedFault("fused.compile"),
+                QueryCancelled("statement timeout"),
+                BudgetExceededError("workmem", 2, 1, 1),
+                MemoryError("host")):
+        with pytest.raises(type(exc)) as ei:
+            fused.lower_program(raising(exc), x)
+        assert ei.value is exc
+    transient = RuntimeError("UNAVAILABLE: transfer failed")
+    with pytest.raises(RuntimeError) as ei:
+        fused.lower_program(raising(transient), x)
+    assert ei.value is transient
+    with pytest.raises(CompileRefused, match="KeyError"):
+        fused.lower_program(raising(KeyError("l_orderkey")), x)
+
+
 def test_hbm_step_to_streaming_is_counted_by_name(monkeypatch):
     """Too large for HBM is what the streaming tier is for: the statement
     answers, and says so under ONE name."""

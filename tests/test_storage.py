@@ -162,3 +162,32 @@ def test_ycsb_e_mix_and_topk():
     for c in st.scan_chunks(ycsb.TABLE_ID, ycsb.N_FIELDS, 1 << 12):
         all_f0.extend(c["f0"].tolist())
     assert res["field0"].tolist() == sorted(all_f0, reverse=True)[:10]
+
+
+@pytest.mark.parametrize("toolchain, says", [
+    ("absent", "no C++ compiler on PATH"),
+    ("refuses", "mvcc_engine.cpp:1:1: error: the compiler's own words"),
+])
+def test_failed_native_build_raises_and_hands_back_no_python_engine(
+        tmp_path, monkeypatch, toolchain, says):
+    """With no built library, and g++ off the PATH or failing, the default
+    store RAISES with the reason (the compiler's stderr): it never comes
+    up on PyEngine in silence. PyEngine stays available by name."""
+    from cockroach_tpu.storage import engine
+
+    bindir = tmp_path / "bin"
+    bindir.mkdir()
+    if toolchain == "refuses":
+        gxx = bindir / "g++"
+        gxx.write_text("#!/bin/sh\n"
+                       f"echo \"{says}\" >&2\nexit 1\n")
+        gxx.chmod(0o755)
+    monkeypatch.setenv("PATH", str(bindir))
+    monkeypatch.setattr(engine, "_NATIVE_DIR", str(tmp_path))  # no .so
+    monkeypatch.setattr(engine, "_lib", None)
+    monkeypatch.setattr(engine, "_lib_err", None)
+    for build in (MVCCStore, NativeEngine, open_engine):
+        with pytest.raises(RuntimeError) as ei:
+            build()
+        assert says in str(ei.value)
+    assert isinstance(MVCCStore(engine=PyEngine()).engine, PyEngine)
