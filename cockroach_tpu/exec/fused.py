@@ -928,12 +928,11 @@ class FusedRunner:
             args, chunks = hit
             self._exec_cache.move_to_end(vkey)
             stats.add("prime.skipped")
-            _tracing.record("prime.skipped", scans=len(scans))
         else:
             stacked: Dict[int, Tuple] = {}
             chunks = {}
-            with _tracing.child_span("fused.prime", scans=len(scans)), \
-                    stats.timed("fused.prime"):
+            with stats.timed("fused.prime"):
+                _tracing.set_tag(scans=len(scans))
                 for sc in scans:
                     try:
                         st = sc.stacked_image()
@@ -976,8 +975,7 @@ class FusedRunner:
                 maybe_fail("fused.compile")
                 return self._vault_compile(lower_program(prog, args))
 
-            with _tracing.child_span("fused.compile"), \
-                    stats.timed("fused.compile"):
+            with stats.timed("fused.compile"):
                 # trace + compile eagerly so Unsupported surfaces here
                 # (before any batch is yielded) and flag_ops is known.
                 # HBMExceeded is an Unsupported: negative-cached, streamed
@@ -1034,8 +1032,8 @@ class FusedRunner:
                     maybe_fail("fused.compile")
                     return self._vault_compile(lower_program(prog, sds))
 
-                with _tracing.child_span("fused.aot_compile", step=step), \
-                        stats.timed("fused.aot_compile"):
+                with stats.timed("fused.aot_compile"):
+                    _tracing.set_tag(step=step)
                     try:
                         compiled = _retry.with_retry(
                             build, name="fused.compile")
@@ -1061,7 +1059,8 @@ class FusedRunner:
         first = not self._served_once
         t_first = _time.perf_counter()
         try:
-            (prog, flag_ops, result_cap), args = self._prepare()
+            with stats.timed("fused.prepare"):
+                (prog, flag_ops, result_cap), args = self._prepare()
         except Unsupported as e:
             # this run's volume (or shape) is outside the fusion grammar:
             # delegate wholesale to the streaming runtime
@@ -1079,15 +1078,20 @@ class FusedRunner:
         def dispatch():
             _cancel.checkpoint()
             maybe_fail("fused.exec")
+            # fused.exec = dispatch + wait: until prog() returns the host
+            # is enqueueing; after that it waits for the device, which may
+            # first finish another session's program
+            with stats.timed("fused.dispatch"):
+                out = prog(*args)
             # block: without the sync the dispatch returns immediately
             # and the device execution time was mis-billed to
             # fused.readback (16.3s "readback" for a 1.2MB buffer in
             # BENCH_r05); readback now measures only the transfer
-            return jax.block_until_ready(prog(*args))
+            with stats.timed("fused.wait"):
+                return jax.block_until_ready(out)
 
         try:
-            with _tracing.child_span("fused.exec"), \
-                    stats.timed("fused.exec"):
+            with stats.timed("fused.exec"):
                 buf = _retry.with_retry(dispatch, name="fused.exec")
             with stats.timed("fused.readback", bytes=buf.nbytes):
                 host = np.asarray(buf)
@@ -1111,8 +1115,9 @@ class FusedRunner:
                 yield from self.root.batches()
                 return
             raise
-        batch, flags, result_ovf = _unpack_result(host, self.schema,
-                                                   result_cap)
+        with stats.timed("fused.unpack"):
+            batch, flags, result_ovf = _unpack_result(host, self.schema,
+                                                      result_cap)
         # deferred overflow checks come FIRST: a restart discards output
         for fop, fl in zip(flag_ops, flags):
             if fl:
@@ -1133,7 +1138,6 @@ class FusedRunner:
                 "execution (prime + compile-or-vault-load + dispatch)"
             ).observe(dt)
             stats.add("fused.first_execution")
-            _tracing.record("first_execution", seconds=round(dt, 4))
         yield batch
 
 
@@ -1244,8 +1248,8 @@ class ServingScanRunner:
             if prog is not None:
                 return prog
             lane = jax.ShapeDtypeStruct((bucket,), self._keys.dtype)
-            with _tracing.child_span("serving.compile", bucket=bucket), \
-                    stats.timed("serving.compile"):
+            with stats.timed("serving.compile"):
+                _tracing.set_tag(bucket=bucket)
                 lowered = jax.jit(self._fn).lower(
                     lane, lane, lane,
                     self._keys, self._cols, self._vals)
@@ -1401,8 +1405,8 @@ class ResidentServingRunner:
             keys_s = jax.ShapeDtypeStruct((cap,), jnp.int64)
             cols_s = jax.ShapeDtypeStruct((len(self._slots), cap),
                                           jnp.int64)
-            with _tracing.child_span("serving.compile", bucket=bucket), \
-                    stats.timed("serving.compile"):
+            with stats.timed("serving.compile"):
+                _tracing.set_tag(bucket=bucket)
                 lowered = jax.jit(self._fn).lower(
                     lane, lane, lane, scalar, keys_s, cols_s, keys_s)
                 prog = compile_via_vault(
@@ -1640,8 +1644,8 @@ class ServingAggRunner:
             if prog is not None:
                 return prog
             lane = jax.ShapeDtypeStruct((bucket,), jnp.int64)
-            with _tracing.child_span("serving.compile", bucket=bucket), \
-                    stats.timed("serving.compile"):
+            with stats.timed("serving.compile"):
+                _tracing.set_tag(bucket=bucket)
                 lowered = jax.jit(self._fn).lower(
                     lane, lane, self._keys, self._cols, self._vals)
                 prog = compile_via_vault(
@@ -1792,8 +1796,8 @@ class ServingTopKRunner:
             if prog is not None:
                 return prog
             lane = jax.ShapeDtypeStruct((bucket,), jnp.int64)
-            with _tracing.child_span("serving.compile", bucket=bucket), \
-                    stats.timed("serving.compile"):
+            with stats.timed("serving.compile"):
+                _tracing.set_tag(bucket=bucket)
                 lowered = jax.jit(self._fn).lower(
                     lane, lane, lane, self._keys, self._cols,
                     self._vals, self._ovals, self._ovalid)
@@ -1909,8 +1913,8 @@ class ServingVectorRunner:
             if prog is not None:
                 return prog
             qs = jax.ShapeDtypeStruct((bucket, self.dim), jnp.float32)
-            with _tracing.child_span("serving.compile", bucket=bucket), \
-                    stats.timed("serving.compile"):
+            with stats.timed("serving.compile"):
+                _tracing.set_tag(bucket=bucket)
                 lowered = jax.jit(self._fn).lower(
                     qs, self._cols, self._vals, self._vecs,
                     self._vvalid)

@@ -421,9 +421,9 @@ class ScanOp(Operator):
                            + [jnp.int32(0)] * pad)
             return bufs, ms
 
-        with _tracing.child_span("scan.stack", chunks=n_real), \
-                stats.timed("scan.stack",
-                            bytes=sum(b.nbytes for b, _ in items)):
+        with stats.timed("scan.stack",
+                         bytes=sum(b.nbytes for b, _ in items)):
+            _tracing.set_tag(chunks=n_real)
             bufs, ms = _retry.with_retry(stack, name="scan.stack")
         st = (bufs, ms)
         if self.cache_key is not None:

@@ -15,6 +15,13 @@ dispatch time, forced syncs (readbacks), and row/byte counts. For true
 on-device kernel attribution use jax.profiler traces around a flow run
 (the XLA-trace analog of the reference's goexectrace, SURVEY.md §5.1).
 
+`timed(name)` is the one way to open a stage, and a stage is a span is
+an annotation: besides feeding the collections it attaches a child span
+to the thread's active trace (util/tracing.py) and opens the program's
+profiler annotation of that name, so the timeline EXPLAIN ANALYZE and
+/_status/traces render, the stage table, and a profile of the node all
+come from one call site.
+
 Zero overhead when disabled (module flag checked per call site).
 """
 
@@ -22,9 +29,13 @@ from __future__ import annotations
 
 import threading
 import time
-from contextlib import contextmanager
-from dataclasses import dataclass, field
+from contextlib import contextmanager, nullcontext
+from dataclasses import dataclass
 from typing import Dict, Optional
+
+from cockroach_tpu.util import tracing as _tracing
+
+_tracer = _tracing.tracer()
 
 
 @dataclass
@@ -63,8 +74,10 @@ class StatsCollection:
 
     def add(self, name: str, seconds: float = 0.0, rows: int = 0,
             bytes: int = 0, events: int = 1) -> None:
-        s = self.stage(name)
-        with self._mu:
+        with self._mu:  # stage(), inlined: one lock an event
+            s = self.stages.get(name)
+            if s is None:
+                s = self.stages[name] = ComponentStats(name)
             s.events += events
             s.seconds += seconds
             s.rows += rows
@@ -141,10 +154,6 @@ def query_stats():
         _tls.col = prev
 
 
-def query_active() -> Optional[StatsCollection]:
-    return getattr(_tls, "col", None)
-
-
 def add(name: str, **kw) -> None:
     a = _active
     if a is not None:
@@ -154,22 +163,75 @@ def add(name: str, **kw) -> None:
         q.add(name, **kw)
 
 
-@contextmanager
+def stage_seconds(col: Optional[StatsCollection], name: str) -> float:
+    """Seconds `col` has under stage `name` (0.0 when it has none)."""
+    s = col.stages.get(name) if col is not None else None
+    return s.seconds if s is not None else 0.0
+
+
+class _Stage:
+    """One open stage (what `timed` returns when anything listens):
+    the profiler annotation, the child span when the thread has an
+    active trace, one clock read on either side, and on exit one add()
+    to each collection, also when the body raised."""
+
+    __slots__ = ("name", "rows", "bytes", "a", "q", "traced", "span",
+                 "ann", "t0")
+
+    def __init__(self, name, rows, bytes, a, q, traced):
+        self.name = name
+        self.rows = rows
+        self.bytes = bytes
+        self.a = a
+        self.q = q
+        self.traced = traced
+
+    def __enter__(self):
+        self.ann = _tracing.annotation(self.name)
+        self.ann.__enter__()
+        if self.traced:
+            self.span = _tracer.start_span(self.name)
+            self.t0 = self.span.start
+        else:
+            self.span = None
+            self.t0 = time.perf_counter()
+        return self.span
+
+    def __exit__(self, *exc):
+        end = time.perf_counter()
+        dt = end - self.t0
+        span = self.span
+        if span is not None:
+            span.end = end
+            if self.rows:
+                span.tags["rows"] = self.rows
+            if self.bytes:
+                span.tags["bytes"] = self.bytes
+            _tracer.finish_span(span)
+        self.ann.__exit__(*exc)
+        a, q = self.a, self.q
+        if a is not None:
+            a.add(self.name, seconds=dt, rows=self.rows, bytes=self.bytes)
+        if q is not None and q is not a:
+            q.add(self.name, seconds=dt, rows=self.rows, bytes=self.bytes)
+        return False
+
+
+_NOT_LISTENING = nullcontext()
+
+
 def timed(name: str, rows: int = 0, bytes: int = 0):
+    """Open stage `name` (a context manager). Call it as `stats.timed`,
+    looked up on the module at call time, with `name, rows, bytes` only
+    (tags go on the span with tracing.set_tag): a harness that wraps
+    this function from outside relies on both. With no collection on
+    and no trace active on this thread it is a no-op of one branch."""
     a = _active
     q = getattr(_tls, "col", None)
-    if a is None and q is None:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        if a is not None:
-            a.add(name, seconds=dt, rows=rows, bytes=bytes)
-        if q is not None and q is not a:
-            q.add(name, seconds=dt, rows=rows, bytes=bytes)
+    traced = _tracer.current() is not None
+    if a is None and q is None and not traced:
+        return _NOT_LISTENING
+    return _Stage(name, rows, bytes, a, q, traced)
 
 
 # ------------------------------------------------- per-operator breakdown
@@ -183,11 +245,16 @@ _EXEC_PREFIXES = ("scan", "agg", "join", "sort", "fused", "serving",
                   "dist", "vector", "spill", "sql")
 _NON_EXEC_STAGES = ("compile", "vault", "image_build", "prime",
                     "prewarm")
+# stages that split or surround one already counted (fused.exec holds
+# fused.dispatch and fused.wait), or that are host work of the session
+_NOT_EXEC = frozenset(("fused.dispatch", "fused.wait", "fused.prepare",
+                       "fused.unpack", "sql.lookup", "sql.parse",
+                       "sql.bind", "sql.plan"))
 
 
 def _is_exec_stage(name: str) -> bool:
     head = name.split(".", 1)[0]
-    if head not in _EXEC_PREFIXES:
+    if head not in _EXEC_PREFIXES or name in _NOT_EXEC:
         return False
     return not any(t in name for t in _NON_EXEC_STAGES)
 

@@ -528,8 +528,7 @@ class DistFusedRunner:
         box: dict = {}
         extra = (mesh_key(self.mesh, self.axis),
                  tuple(layout[id(sc)] for sc in scans))
-        with _tracing.child_span("dist.compile"), \
-                stats.timed("dist.compile"):
+        with stats.timed("dist.compile"):
             try:
                 lowered = self._lower(scans, sharded, repart, args, box)
                 compiled = compile_via_vault(
@@ -693,7 +692,7 @@ class DistFusedRunner:
             # fused.exec): readback below measures only the transfer
             return jax.block_until_ready(compiled(*args))
 
-        with _tracing.child_span("dist.exec"), stats.timed("dist.exec"):
+        with stats.timed("dist.exec"):
             buf = _retry.with_retry(dispatch, name="dist.a2a")
         with stats.timed("dist.readback", bytes=buf.nbytes):
             host = np.asarray(buf)

@@ -112,10 +112,16 @@ class PrewarmService:
             if self._stop.is_set():
                 return
             try:
-                self.registry.adopt_and_run()
+                with stats.timed("prewarm.poll"):
+                    self.registry.adopt_and_run()
             except Exception as e:  # noqa: BLE001 — the warm-up loop
-                # must outlive any one bad job
-                _tracing.record("prewarm.loop_error", detail=str(e)[:120])
+                # must outlive any one bad job (this thread never has a
+                # span to record on: the log is where an operator looks)
+                from cockroach_tpu.util import log as _log
+
+                _log.get_logger().warning(
+                    _log.Channel.OPS,
+                    f"prewarm loop: {type(e).__name__}: {str(e)[:120]}")
 
     def run_pending(self, max_jobs: int = 16) -> List[int]:
         """Synchronously adopt+run runnable prewarm jobs — the

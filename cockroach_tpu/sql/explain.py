@@ -145,7 +145,8 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
 
     qreg = default_query_registry()
     qreg.set_phase_current("compiling")
-    plan = Binder(catalog).bind(stmt)
+    with stats.timed("sql.bind"):
+        plan = Binder(catalog).bind(stmt)
     if not is_explain:
         qreg.set_phase_current("executing")
         sink = [] if op_sink is not None else None

@@ -61,22 +61,27 @@ class Baseline:
     """Streaming latency model for one fingerprint. __slots__ + plain
     init: one EWMA update runs per statement on the warm path."""
 
-    __slots__ = ("count", "mean", "var")
+    __slots__ = ("count", "mean", "var", "wait")
 
     def __init__(self, count: int = 0, mean: float = 0.0,
-                 var: float = 0.0):
+                 var: float = 0.0, wait: float = 0.0):
         self.count = count
         self.mean = mean
         self.var = var
+        # EWMA of the statement's fused.wait (blocked on the device):
+        # what the tracer splits a slow statement's excess against
+        self.wait = wait
 
-    def observe(self, x: float) -> None:
+    def observe(self, x: float, wait: float = 0.0) -> None:
         if self.count == 0:
             self.mean = x
+            self.wait = wait
         else:
             d = x - self.mean
             self.mean += _EWMA_ALPHA * d
             self.var = ((1 - _EWMA_ALPHA)
                         * (self.var + _EWMA_ALPHA * d * d))
+            self.wait += _EWMA_ALPHA * (wait - self.wait)
         self.count += 1
 
     def is_slow(self, x: float, sigma: float, min_samples: int) -> bool:
@@ -143,11 +148,13 @@ class InsightsRegistry:
     def observe(self, sql: str, elapsed_s: float, session_id: int = 0,
                 query_id: int = 0, shed: bool = False,
                 degraded: bool = False, batch_fallback: bool = False,
-                error: bool = False) -> Optional[Insight]:
+                error: bool = False,
+                wait_s: float = 0.0) -> Optional[Insight]:
         """Record one execution; returns the Insight if it was anomalous.
         Error executions (including sheds) do NOT feed the baseline —
         a failed statement's latency says nothing about the
-        fingerprint's healthy profile."""
+        fingerprint's healthy profile. `wait_s` is the execution's
+        `fused.wait` seconds, kept as the baseline's `wait`."""
         fp = _fp(sql)
         st = self._st
         if not (shed or degraded or batch_fallback or error):
@@ -158,12 +165,14 @@ class InsightsRegistry:
                 with self._mu:
                     base = self._baselines.get(fp)
                     if base is None:
-                        self._baselines[fp] = Baseline(1, elapsed_s)
+                        self._baselines[fp] = Baseline(1, elapsed_s,
+                                                       wait=wait_s)
                     else:  # Baseline.observe, inlined
                         d = elapsed_s - base.mean
                         base.mean += _EWMA_ALPHA * d
                         base.var = ((1 - _EWMA_ALPHA)
                                     * (base.var + _EWMA_ALPHA * d * d))
+                        base.wait += _EWMA_ALPHA * (wait_s - base.wait)
                         base.count += 1
                 return None
         kinds = []
@@ -188,7 +197,7 @@ class InsightsRegistry:
                 kinds.append("slow")
             mean = base.mean
             if not error:
-                base.observe(elapsed_s)
+                base.observe(elapsed_s, wait_s)
             if not kinds:
                 return None
             ins = Insight(fp, tuple(kinds), elapsed_s, mean, session_id,

@@ -11,6 +11,14 @@ read correct values.
 
 Threaded accept loop (reader-per-connection, the serveImpl goroutine
 analog); the Stopper owns shutdown.
+
+The statement timeline starts here: once a Query or Execute message is
+complete in the buffer the connection opens the root span
+`wire.statement` (util/tracing.statement_span) and every layer below
+attaches its stages to it (exec/stats.timed): wire.decode,
+session.execute, wire.render, wire.encode, wire.flush. The wait for the
+client's next message is outside every span. Parse and Bind are stages
+of their own, `wire.parse` and `wire.bind`.
 """
 
 from __future__ import annotations
@@ -24,6 +32,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from cockroach_tpu.exec import stats
+from cockroach_tpu.util import tracing
 from cockroach_tpu.util.log import Channel, get_logger
 
 _log = get_logger()
@@ -34,6 +44,9 @@ class AdminShutdownError(Exception):
     (pgcode 57P01 admin_shutdown, what the reference sends on drain)."""
 
     pgcode = "57P01"
+
+# a Sync message, whole (type, length 4, no body)
+_SYNC = b"S\x00\x00\x00\x04"
 
 # type OIDs (pg catalog)
 OID_INT8 = 20
@@ -143,7 +156,8 @@ class _Conn:
         if self._out:
             msg = b"".join(self._out)
             self._out.clear()
-            self.sock.sendall(msg)
+            with stats.timed("wire.flush", bytes=len(msg)):
+                self.sock.sendall(msg)
 
     # -- protocol ---------------------------------------------------------
 
@@ -220,15 +234,28 @@ class _Conn:
                 continue  # discard until Sync
             try:
                 if t == b"Q":
-                    self.simple_query(body.rstrip(b"\x00").decode())
+                    with tracing.statement_span("wire.statement",
+                                                protocol="simple"):
+                        self.simple_query(body.rstrip(b"\x00").decode())
                 elif t == b"P":
-                    self._msg_parse(body)
+                    with stats.timed("wire.parse"):
+                        self._msg_parse(body)
                 elif t == b"B":
-                    self._msg_bind(body)
+                    with stats.timed("wire.bind"):
+                        self._msg_bind(body)
                 elif t == b"D":
                     self._msg_describe(body)
                 elif t == b"E":
-                    self._msg_execute(body)
+                    with tracing.statement_span("wire.statement",
+                                                protocol="extended"):
+                        self._msg_execute(body)
+                        if self.buf.startswith(_SYNC):
+                            # the Sync a pipelining client sent behind
+                            # its Execute is here already: answer it
+                            # inside the statement's trace, so that the
+                            # flush is one of its stages
+                            self.buf = self.buf[len(_SYNC):]
+                            self._ready()
                 elif t == b"C":
                     self._msg_close(body)
                 elif t == b"H":  # Flush: push buffered responses now
@@ -458,18 +485,22 @@ class _Conn:
         if word not in ("SELECT", "EXPLAIN", "SHOW", "VALUES"):
             self._send(b"n")  # NoData
             return
-        out = self._exec_portal(name)
-        kind_s, payload, schema = out
-        if kind_s == "rows":
-            names, _rows = self._render(payload, schema)
-            self._row_desc(names)
-        elif kind_s == "explain":
-            self._row_desc([("info", OID_TEXT)])
-        else:
-            self._send(b"n")  # NoData
+        # the portal runs here, so its timeline is rooted here; the
+        # Execute that follows only renders, encodes and flushes
+        with tracing.statement_span("wire.statement",
+                                    protocol="extended-describe"):
+            kind_s, payload, schema = self._exec_portal(name)
+            if kind_s == "rows":
+                names, _rows = self._render(payload, schema)
+                self._row_desc(names)
+            elif kind_s == "explain":
+                self._row_desc([("info", OID_TEXT)])
+            else:
+                self._send(b"n")  # NoData
 
     def _msg_execute(self, body: bytes):
-        name, off = self._cstr(body, 0)
+        with stats.timed("wire.decode"):
+            name, off = self._cstr(body, 0)
         kind_s, payload, schema = self._exec_portal(name)
         if kind_s == "ok":
             self._complete(str(payload))
@@ -489,8 +520,9 @@ class _Conn:
             self._complete(f"CHANGEFEED {n}")
         else:
             _names, rows = self._render(payload, schema)
-            self._data_rows(rows)
-            self._complete(f"SELECT {len(rows)}")
+            with stats.timed("wire.encode", rows=len(rows)):
+                self._data_rows(rows)
+                self._complete(f"SELECT {len(rows)}")
         self._portals[name]["result"] = None  # re-Execute re-runs
 
     def _msg_close(self, body: bytes):
@@ -514,9 +546,10 @@ class _Conn:
     def simple_query(self, sql: str):
         from cockroach_tpu.cli import split_statements
 
-        stmts, rest = split_statements(sql)
-        if rest.strip():
-            stmts.append(rest)
+        with stats.timed("wire.decode"):
+            stmts, rest = split_statements(sql)
+            if rest.strip():
+                stmts.append(rest)
         for stmt in stmts:
             try:
                 self._run_one(stmt)
@@ -556,34 +589,36 @@ class _Conn:
             self._complete(f"CHANGEFEED {n}")
             return
         names, rows = self._render(payload, schema)
-        self._row_desc(names)
-        self._data_rows(rows)
-        self._complete(f"SELECT {len(rows)}")
+        with stats.timed("wire.encode", rows=len(rows)):
+            self._row_desc(names)
+            self._data_rows(rows)
+            self._complete(f"SELECT {len(rows)}")
 
     def _render(self, result: dict, schema
                 ) -> Tuple[List[Tuple[str, int]], List[List[Optional[str]]]]:
         from cockroach_tpu.cli import decode_column
 
-        names = [n for n in result if not n.endswith("__valid")]
-        descs: List[Tuple[str, int]] = []
-        cols = []
-        for n in names:
-            vals = result[n]
-            valid = result.get(n + "__valid")
-            ty = None
-            d = None
-            if schema is not None:
-                try:
-                    ty = schema.field(n).type
-                    d = schema.dictionary(n)
-                except KeyError:
-                    pass
-            oid = _oid_for(ty) if ty is not None else (
-                OID_FLOAT4 if np.issubdtype(np.asarray(vals).dtype,
-                                            np.floating) else OID_INT8)
-            descs.append((n, oid))
-            cols.append(decode_column(vals, valid, ty, d))
-        rows = list(zip(*cols)) if cols else []
+        with stats.timed("wire.render"):
+            names = [n for n in result if not n.endswith("__valid")]
+            descs: List[Tuple[str, int]] = []
+            cols = []
+            for n in names:
+                vals = result[n]
+                valid = result.get(n + "__valid")
+                ty = None
+                d = None
+                if schema is not None:
+                    try:
+                        ty = schema.field(n).type
+                        d = schema.dictionary(n)
+                    except KeyError:
+                        pass
+                oid = _oid_for(ty) if ty is not None else (
+                    OID_FLOAT4 if np.issubdtype(np.asarray(vals).dtype,
+                                                np.floating) else OID_INT8)
+                descs.append((n, oid))
+                cols.append(decode_column(vals, valid, ty, d))
+            rows = list(zip(*cols)) if cols else []
         return descs, rows
 
     def _row_desc(self, fields: List[Tuple[str, int]]):

@@ -1068,16 +1068,16 @@ def run(p: Plan, catalog: Catalog, capacity: int = 1 << 17, mesh=None,
     cache re-collects it on warm re-execution. `sql` keys the placement
     pass's per-fingerprint cache (measured-cost tier routing); `setting`
     is the session's `vectorize` (auto lets the coster route)."""
+    from cockroach_tpu.exec import collect, stats
     from cockroach_tpu.sql.plan_compile import compile_plan
 
-    compiled = compile_plan(p, catalog, capacity, sql=sql,
-                            setting=setting)
+    with stats.timed("sql.plan"):
+        compiled = compile_plan(p, catalog, capacity, sql=sql,
+                                setting=setting)
     op = compiled.op
     if op_sink is not None:
         op_sink.append(op)
     if mesh is None:
-        from cockroach_tpu.exec import collect
-
         result = collect(op, backend=compiled.backend)
     else:
         from cockroach_tpu.parallel.dist_flow import collect_distributed

@@ -64,6 +64,10 @@ STATEMENT_TIMEOUT = Settings.register(
     "aborts with SQLSTATE 57014 query_canceled; 0 disables",
 )
 
+# executions of a fingerprint before its baseline is handed to the
+# statement's trace as its usual time (insights' min_samples default)
+_USUAL_MIN_SAMPLES = 5
+
 # slow-query rate-limit state: fingerprint -> last log time (monotonic).
 # Process-wide, like the log channel it protects.
 _slow_log_mu = threading.Lock()
@@ -891,37 +895,45 @@ class Session:
         from cockroach_tpu.util import cancel as _cancel
         from cockroach_tpu.util import tracing
 
-        head = sql.strip().split(None, 1)[0].lower() if sql.strip() else ""
-        t0 = _time.perf_counter()
-        timeout = self._statement_timeout()
-        # a statement headed for the serving queue skips per-statement
-        # admission — the batch LEADER acquires one slot for the whole
-        # coalesced batch (sql/serving.py), so the coalescing depth is
-        # not capped at the slot count. The probe (a dict get, no side
-        # effects) runs first so the statement registers directly in
-        # its final phase — the warm path pays ONE registry write.
         from cockroach_tpu.sql import serving as _serving
 
-        serving_path = head == "select" and _serving.probe(self, sql)
+        head = sql.strip().split(None, 1)[0].lower() if sql.strip() else ""
+        t0 = _time.perf_counter()
         qreg = self._qreg
-        # the registry entry doubles as the statement's CancelContext
-        ctx = qentry = qreg.register(
-            self, sql, timeout if timeout > 0 else None,
-            phase=(_registry.PHASE_SERVING if serving_path
-                   else _registry.PHASE_QUEUED),
-            track=not serving_path, start_pc=t0)
-        qid = qentry.query_id
-        with self._cancel_mu:
-            self._active_cancel = ctx
-        queue = None
+        queue = qentry = None
+        qid = 0
+        serving_path = False
         try:
             with tracing.query_span("session.execute", sql=sql[:60]), \
-                    _cancel.active(ctx), _stats.query_stats() as qcol:
+                    _stats.query_stats() as qcol:
                 try:
-                    if not serving_path:
-                        queue = self._admit(head)
-                        qentry.phase = _registry.PHASE_EXECUTING
-                    kind, payload, schema = self._execute(sql)
+                    with _stats.timed("session.admit"):
+                        timeout = self._statement_timeout()
+                        # a statement headed for the serving queue skips
+                        # per-statement admission — the batch LEADER
+                        # acquires one slot for the whole coalesced batch
+                        # (sql/serving.py), so the coalescing depth is
+                        # not capped at the slot count. The probe (a dict
+                        # get, no side effects) runs first so the
+                        # statement registers directly in its final
+                        # phase — the warm path pays ONE registry write.
+                        serving_path = (head == "select"
+                                        and _serving.probe(self, sql))
+                        # the registry entry doubles as the statement's
+                        # CancelContext
+                        ctx = qentry = qreg.register(
+                            self, sql, timeout if timeout > 0 else None,
+                            phase=(_registry.PHASE_SERVING if serving_path
+                                   else _registry.PHASE_QUEUED),
+                            track=not serving_path, start_pc=t0)
+                        qid = qentry.query_id
+                        with self._cancel_mu:
+                            self._active_cancel = ctx
+                        if not serving_path:
+                            queue = self._admit(head, ctx)
+                            qentry.phase = _registry.PHASE_EXECUTING
+                    with _cancel.active(ctx):
+                        kind, payload, schema = self._execute(sql)
                 except Exception as e:
                     elapsed = _time.perf_counter() - t0
                     default_sqlstats().record(
@@ -949,23 +961,26 @@ class Session:
                     if mapped is not None:
                         raise mapped from e
                     raise
-                rows = 0
-                if kind == "rows" and payload:
-                    first = next(iter(payload.values()), None)
-                    rows = len(first) if first is not None else 0
-                elapsed = _time.perf_counter() - t0
-                default_sqlstats().record(
-                    sql, elapsed, rows=rows,
-                    session_id=self.session_id,
-                    device_s=_stats.device_seconds(qcol),
-                    bytes_scanned=_stats.bytes_scanned(qcol),
-                    op_device=_stats.operator_device(qcol))
-                self._maybe_log_slow(sql, elapsed, rows=rows)
-                self._observe_insight(sql, elapsed, qid,
-                                      _stats.degradations_seen(qcol))
+                with _stats.timed("session.account"):
+                    rows = 0
+                    if kind == "rows" and payload:
+                        first = next(iter(payload.values()), None)
+                        rows = len(first) if first is not None else 0
+                    elapsed = _time.perf_counter() - t0
+                    default_sqlstats().record(
+                        sql, elapsed, rows=rows,
+                        session_id=self.session_id,
+                        device_s=_stats.device_seconds(qcol),
+                        bytes_scanned=_stats.bytes_scanned(qcol),
+                        op_device=_stats.operator_device(qcol))
+                    self._maybe_log_slow(sql, elapsed, rows=rows)
+                    self._observe_insight(
+                        sql, elapsed, qid, _stats.degradations_seen(qcol),
+                        _stats.stage_seconds(qcol, "fused.wait"))
             return kind, payload, schema
         finally:
-            qreg.deregister(self, qentry, not serving_path)
+            if qentry is not None:
+                qreg.deregister(self, qentry, not serving_path)
             if queue is not None:
                 queue.release()
             with self._cancel_mu:
@@ -1053,13 +1068,15 @@ class Session:
             with self._cancel_mu:
                 self._active_cancel = None
 
-    def _admit(self, head: str):
+    def _admit(self, head: str, ctx):
         """Session-layer admission: gate work statements through the
         shared WorkQueue (reference: sql admission queues above the KV
         work queues). Returns the queue holding ONE slot — released in
         execute()'s finally, so a shed, cancel, or execution error can
         never leak a slot — or None when admission is off / the
-        statement is exempt."""
+        statement is exempt. `ctx` is the statement's CancelContext: a
+        queued statement polls it while it waits."""
+        from cockroach_tpu.util import cancel as _cancel
         from cockroach_tpu.util.admission import (
             SESSION_QUEUE_TIMEOUT, session_queue,
         )
@@ -1068,9 +1085,10 @@ class Session:
         if queue is None or head in self._CONTROL_HEADS:
             return None
         try:
-            queue.acquire(
-                priority=self._admission_priority(),
-                timeout=float(Settings().get(SESSION_QUEUE_TIMEOUT)))
+            with _cancel.active(ctx):
+                queue.acquire(
+                    priority=self._admission_priority(),
+                    timeout=float(Settings().get(SESSION_QUEUE_TIMEOUT)))
         except TimeoutError as e:
             # 53300 too_many_connections: the canonical "server is at
             # capacity, back off" class — overload degrades into shed
@@ -1082,21 +1100,30 @@ class Session:
         return queue
 
     def _observe_insight(self, sql: str, elapsed: float, qid: int,
-                         degraded: bool) -> None:
+                         degraded: bool, wait_s: float = 0.0) -> None:
         """Healthy-statement insights seam. Full observe() runs for
         degraded or at/above-floor executions (those can flag) and for
         a 1-in-8 baseline sample of sub-floor ones; the other 7/8 of
         warm sub-floor statements — which can never flag and whose
         EWMA contribution a sample preserves — pay only this guard.
-        The floor is re-read from settings on each sampled tick."""
+        The floor is re-read from settings on each sampled tick.
+
+        First the fingerprint's usual time, as it stood BEFORE this
+        execution, goes to the statement's trace (tracing.note_usual):
+        the tracer keeps the tree of a statement that took twice that.
+        `wait_s` is this execution's `fused.wait`."""
+        from cockroach_tpu.sql.insights import default_insights
+        from cockroach_tpu.util import tracing
+
+        ins = default_insights()
+        base = ins.baseline(sql)
+        if base is not None and base.count >= _USUAL_MIN_SAMPLES:
+            tracing.note_usual(base.mean, base.wait)
         tick = self._ins_tick = (self._ins_tick + 1) & 7
         if degraded or tick == 0 or elapsed >= self._ins_floor:
-            from cockroach_tpu.sql.insights import default_insights
-
-            ins = default_insights()
             self._ins_floor = ins.min_latency_floor()
             ins.observe(sql, elapsed, session_id=self.session_id,
-                        query_id=qid, degraded=degraded)
+                        query_id=qid, degraded=degraded, wait_s=wait_s)
 
     def _maybe_log_slow(self, sql: str, elapsed: float, rows: int = 0,
                         error: bool = False) -> None:
@@ -1256,16 +1283,17 @@ class Session:
             svc.forget()
 
     def _execute(self, sql: str) -> Tuple[str, object, object]:
+        from cockroach_tpu.exec import collect, stats
+
         # warm-path short-circuit BEFORE the parse: a prepared hit needs
         # no ast at all (only SELECTs are ever stored, and the entry
         # already validated against the tables' MVCC versions), so the
         # serving path's per-statement cost is a dict probe + dispatch
         # instead of a full tokenize/parse
         if self._txn is None and not self._txn_aborted:
-            prep = self._prepared_lookup(sql)
+            with stats.timed("sql.lookup"):
+                prep = self._prepared_lookup(sql)
             if prep is not None:
-                from cockroach_tpu.exec import collect, stats
-
                 stats.add("sql.prepared_hit")
                 if prep.bspec is not None:
                     from cockroach_tpu.sql import serving as _serving
@@ -1279,7 +1307,8 @@ class Session:
                 # serving-only entry (stale plan over a resident table)
                 # whose batch submit declined: fall through to the cold
                 # parse path, which also re-stores a full entry
-        ast = P.parse(sql)
+        with stats.timed("sql.parse"):
+            ast = P.parse(sql)
         if isinstance(ast, (P.CreateTable, P.DropTable, P.CreateIndex,
                             P.AlterTable, P.SetVar, P.AnalyzeStmt)):
             # schema, settings, or stats changes can change plans

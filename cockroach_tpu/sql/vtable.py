@@ -195,7 +195,11 @@ def _rows_inflight_traces(base=None) -> List[dict]:
                           else int(r["parent_id"])),
             "node_id": int(r["node_id"]) if r.get("node_id") is not None
             else local,
+            "start_ms": (None if r.get("start_ms") is None
+                         else float(r["start_ms"])),
             "elapsed_ms": float(r["elapsed_ms"]),
+            # 1: a span of a kept slow statement's finished tree
+            "finished": int(bool(r.get("finished", False))),
             "events": int(r["events"]),
         })
     return rows
@@ -276,8 +280,9 @@ TABLES: Dict[str, Tuple[List[Tuple[str, object, bool]], object]] = {
     "node_inflight_traces": (
         [("name", STRING, False), ("trace_id", INT, False),
          ("span_id", INT, False), ("parent_id", INT, True),
-         ("node_id", INT, False),
-         ("elapsed_ms", FLOAT, False), ("events", INT, False)],
+         ("node_id", INT, False), ("start_ms", FLOAT, True),
+         ("elapsed_ms", FLOAT, False), ("finished", INT, False),
+         ("events", INT, False)],
         _rows_inflight_traces),
     "cluster_execution_insights": (
         [("fingerprint", STRING, False), ("kinds", STRING, False),

@@ -152,14 +152,18 @@ class MVCCStore:
         sorted engine run — the AddSSTable ingest path
         (batcheval/cmd_add_sstable.go), used by workload loads and
         RESTORE. ~100x faster than per-row put()."""
+        from cockroach_tpu.exec import stats
+        from cockroach_tpu.storage import resident as _resident
+
         ts = ts or self.clock.now()
         pks = np.asarray(pks, dtype=np.int64)
         col_list = list(cols.values())
-        self.engine.ingest(table_id, pks, col_list, ts)
-        from cockroach_tpu.storage import resident as _resident
-
-        _resident.on_ingest(self, table_id, pks, col_list, ts)
-        self._invalidate_scan_cache(table_id)
+        with stats.timed("storage.ingest", rows=len(pks),
+                         bytes=pks.nbytes + sum(
+                             getattr(c, "nbytes", 0) for c in col_list)):
+            self.engine.ingest(table_id, pks, col_list, ts)
+            _resident.on_ingest(self, table_id, pks, col_list, ts)
+            self._invalidate_scan_cache(table_id)
         return ts
 
     # -- scan path ---------------------------------------------------------
@@ -227,8 +231,8 @@ class MVCCStore:
             maybe_fail("scan.resident")
             return rt.scan_columns(ts, start_pk, end_pk)
 
-        with _tracing.child_span("scan.resident", table=rt.table_id), \
-                stats.timed("scan.resident"):
+        with stats.timed("scan.resident"):
+            _tracing.set_tag(table=rt.table_id)
             pks, vals = with_retry(materialize, name="scan.resident")
         n = int(pks.shape[0])
         stats.add("scan.resident_rows", rows=n)

@@ -387,8 +387,7 @@ class ResidentTable:
                                 dtype=np.int64)
         jnp = _jnp()
         out_cap = _mf.pow2_at_least(self.n + d)
-        with _tracing.child_span("scan.resident_fold", rows=d), \
-                stats.timed("scan.resident_fold", rows=d):
+        with stats.timed("scan.resident_fold", rows=d):
             self._pk, self._ts, self._seq, self._tomb, self._vals = \
                 _mf.fold_versions(
                     (self._pk, self._ts, self._seq, self._tomb,

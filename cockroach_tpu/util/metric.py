@@ -195,7 +195,53 @@ class Registry:
         return "\n".join(lines) + "\n"
 
 
+class _GcHistogram(Histogram):
+    """observe() runs inside a gc callback, and a collection can start
+    in a thread that is inside this histogram's own snapshot()/export()
+    (they allocate under the lock): the lock must be re-entrant."""
+
+    def __init__(self, name: str, help_: str = ""):
+        super().__init__(name, help_)
+        self._mu = threading.RLock()
+
+
+def watch_gc(registry: Registry) -> None:
+    """Count the interpreter's stop-the-world pauses where they happen:
+    every collection's duration into histogram `runtime_gc_pause_seconds`
+    and every full (oldest-generation) collection in counter
+    `runtime_gc_full_total` — the analog of the reference's
+    sys.gc.pause.ns / sys.gc.count (pkg/server/status/runtime.go). The
+    hook holds the two metrics directly: it must not take the registry's
+    lock, which the interrupted thread may hold."""
+    import gc
+    import time
+
+    pause = registry._get(
+        "runtime_gc_pause_seconds",
+        lambda: _GcHistogram(
+            "runtime_gc_pause_seconds",
+            "duration of each garbage collection of the Python runtime "
+            "(every thread waits: the collector holds the interpreter "
+            "lock)"),
+        Histogram)
+    full = registry.counter(
+        "runtime_gc_full_total",
+        "full (oldest-generation) collections of the Python runtime")
+    started = [0.0]
+
+    def on_gc(phase, info):
+        if phase == "start":
+            started[0] = time.perf_counter()
+        else:
+            pause.observe(time.perf_counter() - started[0])
+            if info["generation"] == 2:
+                full.inc()
+
+    gc.callbacks.append(on_gc)
+
+
 _default = Registry()
+watch_gc(_default)
 
 
 def default_registry() -> Registry:

@@ -218,6 +218,9 @@ def check_registry_overhead() -> int:
         def min_latency_floor(self):
             return 1.0
 
+        def baseline(self, sql):
+            return None
+
     real = (registry_mod.default_query_registry,
             insights_mod.default_insights)
     noops = (lambda: _NoopRegistry(), lambda: _NoopInsights())
