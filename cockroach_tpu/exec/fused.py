@@ -55,6 +55,7 @@ from cockroach_tpu.util.fault import InjectedFault, maybe_fail
 from cockroach_tpu.exec.operators import (
     DistinctOp, FlowRestart, HashAggOp, JoinOp, LimitOp, MapOp, Operator,
     ScanOp, ShrinkOp, SortOp, TopKOp, WindowOp, _pow2_at_least,
+    child_operators, walk_operators,
 )
 from cockroach_tpu.ops.agg import (
     _identity as _agg_identity, dense_aggregate, dense_merge,
@@ -62,7 +63,10 @@ from cockroach_tpu.ops.agg import (
 )
 from cockroach_tpu.ops.sort import _sortable_int
 from cockroach_tpu.ops.vector import distance_fn
-from cockroach_tpu.ops.join import hash_join, hash_join_prepared, prepare_build
+from cockroach_tpu.ops.join import (
+    effective_build_mode, hash_join_prepared, prepare_build,
+)
+from cockroach_tpu.ops.sortjoin import carries, probe_unique_compact
 from cockroach_tpu.ops.sort import sort_batch, top_k_batch
 
 
@@ -213,11 +217,29 @@ class _Stream:
         self.flag_ops = flag_ops
 
 
+def _build_mode(op: JoinOp) -> str:
+    return effective_build_mode(op.build_mode, op.build.schema.names(),
+                                op.build_on)
+
+
+def _shared_ops(root: Operator) -> set:
+    """ids of the operators under `root` that more than one parent reads
+    (plan-level CSE, sql/plan.build)."""
+    seen, shared = set(), set()
+    for node in walk_operators(root):
+        for c in child_operators(node):
+            (shared if id(c) in seen else seen).add(id(c))
+    return shared
+
+
 class _Tracer:
     """Builds the traced program for one config; lives for one trace."""
 
-    def __init__(self, stacked: Dict[int, Tuple[jnp.ndarray, jnp.ndarray]]):
+    def __init__(self, stacked: Dict[int, Tuple[jnp.ndarray, jnp.ndarray]],
+                 root: Operator):
         self.stacked = stacked  # id(scan) -> (bufs (N,B), ms (N,))
+        # operators more than one parent reads (see _mat_memo)
+        self._shared = _shared_ops(root)
         self.flag_ops: List[Operator] = []
         self.flags: List[jnp.ndarray] = []
         # shared-subtree memo: a deduped operator (plan-level CSE,
@@ -251,10 +273,7 @@ class _Tracer:
             if (build.capacity * self._row_bytes(op.build.schema)
                     > op.workmem):
                 raise Unsupported("join build exceeds workmem")
-            from cockroach_tpu.ops.join import effective_build_mode
-            mode = effective_build_mode(op.build_mode,
-                                        op.build.schema.names(),
-                                        op.build_on)
+            mode = _build_mode(op)
             bt = prepare_build(build, tuple(op.build_on), mode=mode)
             out_cap = s.cap * op.expansion
             probe_on, build_on = tuple(op.probe_on), tuple(op.build_on)
@@ -328,26 +347,17 @@ class _Tracer:
         if isinstance(op, DistinctOp):
             return self._mat(op._agg)
         if isinstance(op, JoinOp):
-            probe = self._mat(op.probe)
-            build = self._mat(op.build)
-            if (build.capacity * self._row_bytes(op.build.schema)
-                    > op.workmem):
-                raise Unsupported("join build exceeds workmem")
-            from cockroach_tpu.ops.join import effective_build_mode
-            out_cap = probe.capacity * op.expansion
-            res = hash_join(probe, build, tuple(op.probe_on),
-                            tuple(op.build_on), how=op.how,
-                            out_capacity=out_cap,
-                            mode=effective_build_mode(
-                                op.build_mode, op.build.schema.names(),
-                                op.build_on))
-            self.flag_ops.append(op)
-            self.flags.append(res.overflow)
-            return res.batch
+            return self._mat_join(op)[0]
         if isinstance(op, HashAggOp):
             return self._mat_agg(op)
         if isinstance(op, ShrinkOp):
-            out, flag = op.shrink_traceable(self._mat(op.child))
+            if self._compactable(op.child):
+                m, compacted = self._mat_join(op.child, op)
+                if compacted:
+                    return m
+            else:
+                m = self._mat(op.child)
+            out, flag = op.shrink_traceable(m)
             self.flag_ops.append(op)
             self.flags.append(flag)
             return out
@@ -387,6 +397,47 @@ class _Tracer:
             return m.with_sel(keep)
         raise Unsupported(f"operator {type(op).__name__}")
 
+    def _compactable(self, op: Operator) -> bool:
+        """May a ShrinkOp directly above `op` lower with it as one step
+        (_mat_join)? An inner or semi join on the unique path that
+        nothing else reads: the compacted batch has no probe lane layout
+        left for another parent, and the other join types emit (or count)
+        unmatched lanes."""
+        return (isinstance(op, JoinOp) and op.how in ("inner", "semi")
+                and op.grace_level == 0
+                and id(op) not in self._shared
+                and _build_mode(op) == "unique")
+
+    def _mat_join(self, op: JoinOp,
+                  shrink: Optional[ShrinkOp] = None) -> Tuple[Batch, bool]:
+        """-> (batch, compacted). With `shrink` (the ShrinkOp above a
+        _compactable join) and a build the carry join takes, the pair is
+        ONE step that compacts the matched probe lanes in key order
+        (ops/sortjoin.probe_unique_compact): the join never restores the
+        probe order that the Shrink would discard one operator later.
+        The join's fallback flag and the Shrink's overflow flag keep
+        their operators and their order, so the restart ladder is the
+        two-step path's."""
+        probe = self._mat(op.probe)
+        build = self._mat(op.build)
+        if (build.capacity * self._row_bytes(op.build.schema)
+                > op.workmem):
+            raise Unsupported("join build exceeds workmem")
+        probe_on, build_on = tuple(op.probe_on), tuple(op.build_on)
+        bt = prepare_build(build, build_on, mode=_build_mode(op))
+        if shrink is not None and carries(bt, probe.capacity, op.how):
+            res = probe_unique_compact(probe, bt, probe_on, op.how,
+                                       shrink.capacity)
+            stats.add("fused.join_compact")
+            self.flag_ops.extend([op, shrink])
+            self.flags.extend([res.fallback, res.overflow])
+            return res.batch, True
+        res = hash_join_prepared(probe, bt, probe_on, build_on, how=op.how,
+                                 out_capacity=probe.capacity * op.expansion)
+        self.flag_ops.append(op)
+        self.flags.append(res.overflow)
+        return res.batch, False
+
     def _try_groupjoin(self, op: HashAggOp) -> Optional[Batch]:
         """Aggregate-over-join collapse (ops/groupjoin.py): when the
         GROUP BY keys on the join column (+ build columns a unique build
@@ -399,7 +450,6 @@ class _Tracer:
         from cockroach_tpu.ops.groupjoin import (
             GJ_FUNCS, group_join_aggregate,
         )
-        from cockroach_tpu.ops.join import effective_build_mode
 
         child = op.child
         if isinstance(child, ShrinkOp):
@@ -413,9 +463,7 @@ class _Tracer:
             return None
         if len(child.probe_on) != 1 or len(child.build_on) != 1:
             return None
-        if effective_build_mode(child.build_mode,
-                                child.build.schema.names(),
-                                child.build_on) != "unique":
+        if _build_mode(child) != "unique":
             return None
         pon, bon = child.probe_on[0], child.build_on[0]
         gb = list(op.group_by)
@@ -460,7 +508,6 @@ class _Tracer:
 
         # the collapse materializes the probe side whole: respect the
         # operator budget (the streaming fold remains the bounded path)
-        from cockroach_tpu.exec.operators import walk_operators
 
         est_rows = 0
         for sub in walk_operators(child.probe):
@@ -517,7 +564,6 @@ class _Tracer:
                 if not (dt == jnp.bool_
                         or jnp.issubdtype(dt, jnp.integer)):
                     return None
-        from cockroach_tpu.exec.operators import walk_operators
 
         est_rows = 0
         for sub in walk_operators(op.child):
@@ -827,7 +873,6 @@ class FusedRunner:
         return tuple(out)
 
     def _collect_key(self, op, chunks, out):
-        from cockroach_tpu.exec.operators import child_operators
 
         if isinstance(op, ScanOp):
             # chunk counts enter the key pow2-bucketed (stacked_image pads
@@ -880,7 +925,6 @@ class FusedRunner:
             lowered, tables=self._table_tags())
 
     def _table_tags(self):
-        from cockroach_tpu.exec.operators import walk_operators
 
         return tuple(sorted({sc.table for sc in walk_operators(self.root)
                              if isinstance(sc, ScanOp)
@@ -894,7 +938,7 @@ class FusedRunner:
         schema = self.schema
 
         def prog(*stacked_args):
-            t = _Tracer(dict(zip(scan_ids, stacked_args)))
+            t = _Tracer(dict(zip(scan_ids, stacked_args)), self.root)
             out = t._mat(self.root)
             tracer_box["flag_ops"] = list(t.flag_ops)
             # the packed window never exceeds the result's own static
@@ -914,7 +958,6 @@ class FusedRunner:
             return self._prepare_locked()
 
     def _prepare_locked(self):
-        from cockroach_tpu.exec.operators import walk_operators
 
         scans = [n for n in walk_operators(self.root)
                  if isinstance(n, ScanOp)]
@@ -998,7 +1041,6 @@ class FusedRunner:
         vault, so both this process's first execution and a restarted
         node's are warm. Returns the number of program configs now
         resident (0 when the plan is outside the fusion grammar)."""
-        from cockroach_tpu.exec.operators import walk_operators
 
         with self._mu:
             try:

@@ -1721,7 +1721,17 @@ class ShrinkOp(Operator):
     4K-lane batch collapses those operators' sort/gather costs. The
     optimistic-capacity + deferred-flag posture matches the engine's
     join-expansion and hash-collision retries (disk_spiller.go:208's
-    optimistic/general pairing)."""
+    optimistic/general pairing).
+
+    What it costs: a stable (pred, i32) argsort of the child's full
+    capacity plus a (C, W) row gather of the child's columns. Over a
+    unique-build inner or semi join that nothing else reads, the fused
+    runner lowers the pair as ONE step (fused._Tracer._mat_join,
+    sortjoin.probe_unique_compact): the join compacts its matches in key
+    order, its resort to probe order never runs, and the row gather packs
+    the probe's columns only. The capacity, widen() and the overflow
+    flag are this operator's either way; the lane order of a shrunk
+    batch is no contract."""
 
     START_CAPACITY = 1 << 12
     GROWTH = 16

@@ -26,18 +26,34 @@ key + one i32 iota) and moves whole rows exactly once:
      flow driver to restart the join in the general many-to-many mode
      (ops/join.py) — the same optimistic-fast-path/general-slow-path
      pairing as the reference's disk spiller (disk_spiller.go:208);
-  5. resort by each lane's DESTINATION index (probe lanes -> their own
-     probe position), carrying (matched-build-row << 1 | match) as one
-     i32 — lanes [0:lcap] land in probe order, probe columns never move;
+  5. back out of the sorted (key) domain, in one of two forms. The
+     consumer decides which, and the plan shows the consumer:
+     a. RESORT by each lane's DESTINATION index (probe lanes -> their own
+        probe position), carrying (matched-build-row << 1 | match) as one
+        i32 — lanes [0:lcap] land in probe order, probe columns never
+        move. For every consumer that reads the probe's lane layout;
+     b. COMPACT the matched probe lanes where they stand, in key order
+        (probe_unique_compact): one single-operand u32 sort of lcap +
+        rcap lanes puts the matches first (_first_matches), and C-row
+        gathers fetch their probe lane index and payload and (step 6)
+        their probe rows. For an inner or semi join whose only reader
+        is a ShrinkOp of capacity C (exec/fused._Tracer._mat_join): a
+        selective join keeps a sliver of its probe, and the second
+        full-width sort of (a) would restore an order that the Shrink
+        discards one operator later (Q3 at SF1: 37.6 of 211 ms a
+        statement, PERF.md section 6, PR 26);
   6. ONE (lcap, W) row gather pulls each matched build row's columns
      from the build side's pre-packed row matrix (rowmat.pack_rows at
-     prepare time) — a row gather costs the same as a 1-D gather.
+     prepare time) — a row gather costs the same as a 1-D gather. (The
+     payload-carry build of round 5 needs none: its columns ride the
+     sorts. Form (b) gathers C rows of the PROBE's columns instead.)
 
 Unique-build covers every FK->PK join TPC-H runs (the build side of
 every flagship-query join is its primary key). Output capacity == probe
 capacity: each probe row has at most one match, so there is no
 expansion, no overflow, and downstream operators keep the probe's lane
-layout.
+layout. (Form 5b trades that layout for capacity C and an overflow flag,
+which are the ShrinkOp's own.)
 """
 
 from __future__ import annotations
@@ -210,24 +226,41 @@ def _run_build_broadcast(newrun, is_build, perm):
     return low > 0, low - 1
 
 
-def _probe_carry(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
-                 how: str, p_packed, p_range):
-    """Payload-carry probe: build columns ride the two sorts as one
-    bit-packed u64 operand; NO row-matrix gather happens. Applies to
-    int-keyed unique builds for inner/left/semi/anti without
-    matched-build tracking."""
-    from cockroach_tpu.ops import bitpack
-    from cockroach_tpu.ops.join import JoinResult
+class _CarrySorted(NamedTuple):
+    """The carry join after its key sort and run broadcast, still in the
+    sorted (key) domain: what both last steps (resort to probe order /
+    compact the matches) start from."""
 
-    build = ub.batch
-    lcap, rcap = probe.capacity, build.capacity
+    s_packed: jnp.ndarray      # sorted packed keys, build ++ probe
+    s_val: jnp.ndarray         # build lanes: payload; probe lanes: lane idx
+    is_build: jnp.ndarray
+    match_sorted: jnp.ndarray  # probe lane whose run starts with a build
+    bpay: jnp.ndarray          # uint64: the run's build payload
+    fallback: jnp.ndarray
+
+
+def _carry_sort(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
+                payload: bool = True) -> _CarrySorted:
+    """Steps 1-4 of the carry join: probe key packing, key sort, run
+    detection, the duplicate / range / wide-payload `fallback` flag and
+    the split-cummax broadcast of each run's build payload (ONE copy,
+    whatever step 5 does with it). `payload=False` (a compacting
+    semi join: nothing of the build is emitted) sorts a u32 lane index
+    beside the key instead of the u64 payload; `bpay` is then unused and
+    XLA drops its half of the broadcast."""
+    lcap, rcap = probe.capacity, ub.batch.capacity
     n = lcap + rcap
+    p_packed, p_range = _pack_keys(
+        probe, probe_on, 1, ub.seed, ub.key_kind,
+        narrow=(ub.packed.dtype == jnp.uint32))
     packed = jnp.concatenate([ub.packed, p_packed])
     # value operand: build lanes carry the packed payload, probe lanes
     # their own lane index (the destination for the resort)
-    val = jnp.concatenate([ub.payv,
-                           jnp.arange(lcap, dtype=jnp.uint32)
-                           .astype(jnp.uint64)])
+    lane = jnp.arange(lcap, dtype=jnp.uint32)
+    if payload:
+        val = jnp.concatenate([ub.payv, lane.astype(jnp.uint64)])
+    else:
+        val = jnp.concatenate([jnp.zeros((rcap,), jnp.uint32), lane])
     s_packed, s_val = jax.lax.sort((packed, val), num_keys=1)
 
     one = s_packed.dtype.type(1)  # u32 (narrow carry keys) or u64
@@ -246,8 +279,9 @@ def _probe_carry(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
     runid = jnp.cumsum(newrun.astype(jnp.int32)).astype(jnp.int64)
     M31 = np.uint64(0x7FFFFFFF)
     M32 = np.int64(0xFFFFFFFF)
-    lo31 = (s_val & M31).astype(jnp.int64)
-    hi31 = (s_val >> np.uint64(31)).astype(jnp.int64)
+    pay = s_val.astype(jnp.uint64)
+    lo31 = (pay & M31).astype(jnp.int64)
+    hi31 = (pay >> np.uint64(31)).astype(jnp.int64)
     m1 = jax.lax.cummax((runid << np.int64(32))
                         | jnp.where(is_build, lo31 + 1, 0))
     m2 = jax.lax.cummax((runid << np.int64(32))
@@ -257,13 +291,41 @@ def _probe_carry(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
     bpay = (jax.lax.bitcast_convert_type(low1 - 1, jnp.uint64)
             & M31) | (jax.lax.bitcast_convert_type(m2 & M32, jnp.uint64)
                       << np.uint64(31))
-    match_sorted = ~is_build & has_b
+    return _CarrySorted(s_packed, s_val, is_build, ~is_build & has_b,
+                        bpay, fallback)
+
+
+def _synth_build_keys(bcols, probe: Batch, ub: UniqueBuild,
+                      probe_on: Sequence[str], match) -> None:
+    """The build key equals the probe key on every matched lane: the
+    carry payload holds non-key columns only."""
+    for pn, bn in zip(probe_on, ub.build_on):
+        bdt = ub.batch.col(bn).values.dtype
+        v = jnp.where(match, probe.col(pn).values.astype(bdt),
+                      jnp.zeros((), bdt))
+        bcols[bn] = Column(v, match)
+
+
+def _probe_carry(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
+                 how: str):
+    """Payload-carry probe: build columns ride the two sorts as one
+    bit-packed u64 operand; NO row-matrix gather happens. Applies to
+    int-keyed unique builds for inner/left/semi/anti without
+    matched-build tracking."""
+    from cockroach_tpu.ops import bitpack
+    from cockroach_tpu.ops.join import JoinResult
+
+    build = ub.batch
+    lcap = probe.capacity
+    cs = _carry_sort(probe, ub, probe_on)
+    fallback = cs.fallback
 
     # resort by destination: probe lanes -> their own probe position,
     # build lanes -> past the probe span; payload rides as (bpay<<1|match)
-    dest = jnp.where(is_build, jnp.int32(lcap) + pos,
-                     s_val.astype(jnp.int32))
-    res = (bpay << np.uint64(1)) | match_sorted.astype(jnp.uint64)
+    pos = jnp.arange(lcap + build.capacity, dtype=jnp.int32)
+    dest = jnp.where(cs.is_build, jnp.int32(lcap) + pos,
+                     cs.s_val.astype(jnp.int32))
+    res = (cs.bpay << np.uint64(1)) | cs.match_sorted.astype(jnp.uint64)
     _d, o_res = jax.lax.sort((dest, res), num_keys=1)
     o_match = (o_res[:lcap] & np.uint64(1)) != 0
     o_bpay = o_res[:lcap] >> np.uint64(1)
@@ -279,17 +341,102 @@ def _probe_carry(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
                           None)
     bcols = bitpack.unpack_lanes(o_bpay, ub.pay_plan, build,
                                  valid_and=match)
-    for pn, bn in zip(probe_on, ub.build_on):
-        # the build key equals the probe key on every matched lane
-        bdt = build.col(bn).values.dtype
-        v = jnp.where(match, probe.col(pn).values.astype(bdt),
-                      jnp.zeros((), bdt))
-        bcols[bn] = Column(v, match)
+    _synth_build_keys(bcols, probe, ub, probe_on, match)
     cols = dict(probe.columns)
     cols.update(bcols)
     sel = probe.sel if how == "left" else (probe.sel & match)
     return JoinResult(Batch(cols, sel, jnp.sum(sel).astype(jnp.int32)),
                       fallback, None)
+
+
+class CompactJoin(NamedTuple):
+    batch: Batch                # capacity C, matched rows first
+    fallback: jnp.ndarray       # as JoinResult.overflow of probe_unique
+    overflow: jnp.ndarray       # bool scalar: more matches than C
+
+
+def carries(ub: UniqueBuild, probe_capacity: int, how: str,
+            track_build: bool = False) -> bool:
+    """Does probe_unique take the payload-carry path for this probe?"""
+    return (ub.pay_plan is not None
+            and how in ("inner", "left", "semi", "anti")
+            and not track_build
+            and probe_capacity + ub.batch.capacity < (1 << 30))
+
+
+_TOP32 = np.uint32(1 << 31)
+
+
+def _first_matches(match, carry, C: int):
+    """-> (C,) int32: `carry` (uint32 under 2^31) of the first C lanes
+    where `match`, in lane order, then of unmatched lanes. ONE sort of a
+    single u32 operand: the miss bit rides above each lane's carry, so
+    no key needs a value operand beside it and none needs the stability
+    operand XLA adds to a `(pred, i32)` argsort. On v5e at 8,650,752
+    lanes that argsort costs 24.6 ms and this sort 8.1 (PERF.md section
+    6, PR 26)."""
+    key = jax.lax.sort(jnp.where(match, carry, carry | _TOP32),
+                       is_stable=False)
+    n = key.shape[0]
+    key = key[:C] if n >= C else jnp.concatenate(
+        [key, jnp.full((C - n,), _TOP32)])
+    return (key & ~_TOP32).astype(jnp.int32)
+
+
+def probe_unique_compact(probe: Batch, ub: UniqueBuild,
+                         probe_on: Sequence[str], how: str,
+                         capacity: int) -> CompactJoin:
+    """probe_unique followed by a compaction to `capacity` lanes (what a
+    ShrinkOp over the join computes), as ONE step that never restores
+    probe order: the matched probe lanes are known in the sorted domain,
+    so the destination resort is replaced by the compaction's own sort
+    there (_first_matches), one C-row gather of each match's probe lane
+    index and broadcast payload, and one C-row gather of the probe's
+    columns. A semi join emits nothing of the build: its compaction
+    carries the probe lane index itself and gathers probe rows only.
+    Inner and semi joins over a carry-eligible build (`carries`) only;
+    the lane order of the result is the key order, which no consumer of
+    a compacted batch may rely on."""
+    from cockroach_tpu.coldata.batch import mask_padding
+    from cockroach_tpu.ops import bitpack
+
+    if how not in ("inner", "semi") or not carries(ub, probe.capacity, how):
+        raise ValueError(f"no compacting probe for a {how} join of this "
+                         f"build")
+    C = capacity
+    cs = _carry_sort(probe, ub, probe_on, payload=(how == "inner"))
+    # a sentinel probe lane (dead lane or NULL key: top bit) pairs with
+    # the same-index build sentinel and is no match: the key-liveness
+    # guard of the resorting form, taken in the sorted domain
+    kdt = cs.s_packed.dtype
+    top = kdt.type(1 << (kdt.itemsize * 8 - 1))
+    match = cs.match_sorted & ((cs.s_packed & top) == kdt.type(0))
+    n_match = jnp.sum(match).astype(jnp.int32)
+    length = jnp.minimum(n_match, C).astype(jnp.int32)
+    sel = jnp.arange(C) < length
+    lane_sorted = cs.s_val.astype(jnp.uint32)  # probe lane index < 2^30
+    if how == "semi":
+        lane = _first_matches(match, lane_sorted, C)
+    else:
+        # one (C, 3) row gather: three 1-D gathers cost three times it
+        kidx = _first_matches(
+            match, jnp.arange(match.shape[0], dtype=jnp.uint32), C)
+        got = jnp.stack(
+            [lane_sorted, cs.bpay.astype(jnp.uint32),
+             (cs.bpay >> np.uint64(32)).astype(jnp.uint32)],
+            axis=1)[kidx]
+        lane = got[:, 0].astype(jnp.int32)
+        bpay = got[:, 1].astype(jnp.uint64) | (
+            got[:, 2].astype(jnp.uint64) << np.uint64(32))
+    out = probe.gather(lane, sel=sel, length=length)
+    cols = dict(out.columns)
+    if how == "inner":
+        bcols = bitpack.unpack_lanes(bpay, ub.pay_plan, ub.batch,
+                                     valid_and=sel)
+        _synth_build_keys(bcols, out, ub, probe_on, sel)
+        cols.update(bcols)
+    return CompactJoin(Batch(mask_padding(cols, sel), sel, length),
+                       cs.fallback, n_match > C)
 
 
 def probe_unique(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
@@ -302,14 +449,8 @@ def probe_unique(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
     from cockroach_tpu.ops.join import JoinResult
 
     build = ub.batch
-    if (ub.pay_plan is not None
-            and how in ("inner", "left", "semi", "anti")
-            and not track_build
-            and probe.capacity + build.capacity < (1 << 30)):
-        p_packed, p_range = _pack_keys(
-            probe, probe_on, 1, ub.seed, ub.key_kind,
-            narrow=(ub.packed.dtype == jnp.uint32))
-        return _probe_carry(probe, ub, probe_on, how, p_packed, p_range)
+    if carries(ub, probe.capacity, how, track_build):
+        return _probe_carry(probe, ub, probe_on, how)
     if ub.mat is None:
         # carry-prepared build reached a path that needs the row matrix
         # (matched-build tracking, right/outer): build it here — inside

@@ -172,9 +172,9 @@ class _DistTracer(_Tracer):
     slice; large join builds co-partition; aggregations and top-Ks merge
     across the mesh axis before finalizing."""
 
-    def __init__(self, stacked, axis: str, n_dev: int,
+    def __init__(self, stacked, root: Operator, axis: str, n_dev: int,
                  sharded_scans: set, repart_ops: dict):
-        super().__init__(stacked)
+        super().__init__(stacked, root)
         self.axis = axis
         self.n_dev = n_dev
         self.sharded_scans = sharded_scans   # id(scan) of chunk-sharded
@@ -194,6 +194,10 @@ class _DistTracer(_Tracer):
         return None  # same two-stage reasoning as _try_groupjoin
 
     # -- distribution-aware joins -----------------------------------------
+
+    def _compactable(self, op: Operator) -> bool:
+        # a co-partitioned join routes both sides first (_mat below)
+        return id(op) not in self.repart_ops and super()._compactable(op)
 
     def _stream(self, op: Operator):
         if isinstance(op, JoinOp) and id(op) in self.repart_ops:
@@ -498,7 +502,7 @@ class DistFusedRunner:
 
         def step(*stacked_args):
             local = dict(zip([id(s) for s in scans], stacked_args))
-            t = _DistTracer(local, axis, n_dev, sharded, repart)
+            t = _DistTracer(local, root, axis, n_dev, sharded, repart)
             out = t._mat(root)
             box["flag_ops"] = list(t.flag_ops)
             box["result_cap"] = min(RESULT_CAP, out.capacity)

@@ -195,3 +195,212 @@ def test_fused_respects_workmem_fallback():
     srt = SortOp(scan, [SortKey("k")], workmem=64)  # 64 bytes: force spill
     res = collect(srt)
     np.testing.assert_array_equal(res["k"], np.arange(n))
+
+
+# -- a selective join under a Shrink lowers as one step --------------------
+
+def _sql_catalog():
+    from cockroach_tpu.sql import TPCHCatalog
+
+    gen = TPCH(sf=0.01)
+    return gen, TPCHCatalog(gen)
+
+
+def _join_compacts(fn):
+    """fn() under a fresh stats collection -> (result, events of counter
+    `fused.join_compact`: one per Join+Shrink pair per traced program)."""
+    from cockroach_tpu.exec import stats
+
+    col = stats.enable()
+    try:
+        out = fn()
+    finally:
+        stats.disable()
+    st = col.stages.get("fused.join_compact")
+    return out, (st.events if st is not None else 0)
+
+
+def test_q3_sql_compacts_both_joins_and_matches_oracle():
+    """The served Q3 text through Session: oracle rows; both of its
+    joins sit under a Shrink and lower with it; Q1 has neither."""
+    from cockroach_tpu.sql.session import Session
+    from tests.test_sql import Q1_SQL, Q3_SQL
+
+    gen, cat = _sql_catalog()
+    sess = Session(cat, capacity=1 << 14)
+    sess.execute("set vectorize = tpu")
+    (_k, got, _s), n = _join_compacts(lambda: sess.execute(Q3_SQL))
+    rows = [(int(got["l_orderkey"][i]), int(got["revenue"][i]),
+             int(got["o_orderdate"][i]))
+            for i in range(len(got["l_orderkey"]))]
+    assert rows == Q.q3_oracle(gen)
+    assert n == 2
+    (_k, got, _s), n = _join_compacts(lambda: sess.execute(Q1_SQL))
+    assert len(got["l_returnflag"]) == len(Q.q1_oracle(gen))
+    assert n == 0
+
+
+def _sorts(jaxpr, out):
+    """(lanes, dtype of the first operand, operands) of every sort
+    equation."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            aval = eqn.invars[0].aval
+            out.append((aval.shape[0], str(aval.dtype), len(eqn.invars)))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _sorts(inner, out)
+    return out
+
+
+@pytest.mark.parametrize("compact", [True, False])
+def test_q3_program_sorts_per_join(compact, monkeypatch):
+    """Per join of Q3's fused program: exactly two sorts at lcap + rcap
+    lanes, the key sort and the compaction's single-operand sort, none
+    by destination and no argsort at lcap; with the one-step lowering
+    switched off (the parent's program): key sort and resort at lcap +
+    rcap, and the Shrink's `(pred, i32)` argsort at lcap."""
+    import jax
+
+    from cockroach_tpu.exec.operators import ScanOp, walk_operators
+    from cockroach_tpu.sql.bind import plan_sql
+    from cockroach_tpu.sql.plan_compile import compile_plan
+    from tests.test_sql import Q3_SQL
+
+    _gen, cat = _sql_catalog()
+    joins = []
+    if compact:
+        real = fused.probe_unique_compact
+
+        def recording(probe, ub, *a):
+            joins.append((probe.capacity, ub.batch.capacity))
+            return real(probe, ub, *a)
+
+        monkeypatch.setattr(fused, "probe_unique_compact", recording)
+    else:
+        real = fused.hash_join_prepared
+
+        def recording(probe, bt, *a, **kw):
+            joins.append((probe.capacity, bt.batch.capacity))
+            return real(probe, bt, *a, **kw)
+
+        monkeypatch.setattr(fused, "hash_join_prepared", recording)
+        monkeypatch.setattr(fused._Tracer, "_compactable",
+                            lambda self, op: False)
+    cp = compile_plan(plan_sql(Q3_SQL, cat), cat, 1 << 14, sql=Q3_SQL,
+                      setting="tpu")
+    _prog, args = cp.runner._prepare()
+    joins.clear()
+    scans = [n for n in walk_operators(cp.op) if isinstance(n, ScanOp)]
+    prog, _box = cp.runner._make_prog([id(s) for s in scans])
+    sorts = _sorts(jax.make_jaxpr(prog)(*args).jaxpr, [])
+    assert len(joins) == 2
+    for lcap, rcap in joins:
+        n = lcap + rcap
+        at_n = sorted(s[1:] for s in sorts if s[0] == n)
+        if compact:
+            assert at_n == [("uint32", 1), ("uint32", 2)]
+            assert (lcap, "bool", 2) not in sorts
+        else:
+            assert at_n == [("int32", 2), ("uint32", 2)]
+            assert sorts.count((lcap, "bool", 2)) == 1
+
+
+def _shrunk_join(how, capacity=512, second_parent=False):
+    """-> (root, probe keys, matched mask): a ShrinkOp over a JoinOp of
+    256 probe rows against 64 unique build keys; with `second_parent`
+    the join is also read by an aggregate that semi-joins back in."""
+    from cockroach_tpu.exec.operators import ShrinkOp
+
+    rng = np.random.default_rng(21)
+    pk = rng.integers(0, 400, 256)
+    bk = rng.permutation(400)[:64]
+    probe = _int_scan({"fk": pk, "v": np.arange(256)}, 64)
+    build = _int_scan({"k": bk, "d": bk * 7}, 64)
+    join = JoinOp(probe, build, ["fk"], ["k"], how=how)
+    root = ShrinkOp(join, capacity)
+    if second_parent:
+        counted = HashAggOp(join, ["fk"],
+                            [AggSpec("count_star", None, "n")])
+        root = JoinOp(root, counted, ["fk"], ["fk"], how="semi")
+        assert fused._shared_ops(root) == {id(join)}
+    return root, pk, np.isin(pk, bk)
+
+
+def _matched_v(res):
+    return sorted(res["v"].tolist())
+
+
+def test_left_join_under_shrink_takes_two_steps():
+    root, _pk, _hit = _shrunk_join("left")
+    res, n = _join_compacts(lambda: collect(root, fuse=True))
+    assert n == 0
+    assert _matched_v(res) == list(range(256))
+
+
+def test_inner_join_under_shrink_compacts():
+    root, _pk, hit = _shrunk_join("inner")
+    res, n = _join_compacts(lambda: collect(root, fuse=True))
+    assert n == 1
+    assert _matched_v(res) == np.nonzero(hit)[0].tolist()
+    assert (res["d"] == res["fk"] * 7).all()
+    assert (res["k"] == res["fk"]).all()
+
+
+def test_compacted_join_overflow_widens_the_shrink():
+    """More matches than the Shrink's capacity: its flag restarts the
+    flow, widen() grows it 16x and the rerun answers right."""
+    from cockroach_tpu.exec.operators import ShrinkOp
+
+    root, _pk, hit = _shrunk_join("semi", capacity=16)
+    res, n = _join_compacts(lambda: collect(root, fuse=True))
+    assert root.capacity == 16 * ShrinkOp.GROWTH
+    assert n == 2  # one per traced program
+    assert _matched_v(res) == np.nonzero(hit)[0].tolist()
+
+
+def test_join_read_by_two_parents_takes_two_steps():
+    """A join another parent also reads keeps its probe lane layout
+    (one materialization through _mat_memo, no compaction)."""
+    root, _pk, hit = _shrunk_join("semi", second_parent=True)
+    res, n = _join_compacts(lambda: collect(root, fuse=True))
+    assert n == 0
+    assert _matched_v(res) == np.nonzero(hit)[0].tolist()
+
+
+@pytest.mark.parametrize("path", ["fused", "dist"])
+@pytest.mark.parametrize("qn", [9, 18])
+def test_shrunk_joins_match_oracles(qn, path):
+    """Q9 and Q18 Shrink over selective joins (sql/plan.insert_shrinks):
+    the one-step lowering fires on the single-chip tracer and inside
+    shard_map (_DistTracer), and the answers are the oracles'."""
+    import jax
+
+    gen = TPCH(sf=0.01)
+    flow = Q.QUERIES[qn](gen, 1 << 12)
+    if path == "dist":
+        if len(jax.devices()) < 8:
+            pytest.skip("needs the 8-device CPU mesh")
+        from cockroach_tpu.parallel import make_mesh
+        from cockroach_tpu.parallel.dist_flow import collect_distributed
+
+        res, n = _join_compacts(
+            lambda: collect_distributed(flow, make_mesh(8)))
+    else:
+        res, n = _join_compacts(lambda: collect(flow, fuse=True))
+    assert n >= 1
+    if qn == 9:
+        nnames = gen.schema("nation").dicts["n_name"]
+        got = {(str(nnames[int(nm)]), int(y)): int(v)
+               for nm, y, v in zip(res["n_name"], res["o_year"],
+                                   res["sum_profit"])}
+        assert got == Q.q9_oracle(gen)
+    else:
+        got = [(int(cn), int(ck), int(ok), int(od), int(tp), int(q))
+               for cn, ck, ok, od, tp, q in zip(
+                   res["c_name"], res["c_custkey"], res["o_orderkey"],
+                   res["o_orderdate"], res["o_totalprice"],
+                   res["sum_qty"])]
+        assert got == Q.q18_oracle(gen)

@@ -252,6 +252,10 @@ def test_explain_analyze_q3_renders_span_tree():
     assert "exec" in text
     assert "resilience: tier=" in text
     assert "retries=" in text and "degradations=" in text
+    # the stage table counts the Join+Shrink pairs lowered as one step
+    compacts = [ln.split() for ln in lines
+                if ln.startswith("fused.join_compact")]
+    assert compacts and compacts[0][-2:] == ["2", "ev"]
 
 
 def test_explain_analyze_trace_shows_retry_on_armed_fault():

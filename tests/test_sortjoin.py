@@ -31,14 +31,18 @@ def _batch(cols, sel=None):
 
 def _rows(res, names):
     """Set-of-tuples view of selected rows (None for NULL)."""
-    sel = np.asarray(res.batch.sel)
+    return _batch_rows(res.batch, names)
+
+
+def _batch_rows(batch, names):
+    sel = np.asarray(batch.sel)
     out = []
     for i in range(len(sel)):
         if not sel[i]:
             continue
         row = []
         for n in names:
-            c = res.batch.col(n)
+            c = batch.col(n)
             valid = (np.asarray(c.validity)[i]
                      if c.validity is not None else True)
             row.append(int(np.asarray(c.values)[i]) if valid else None)
@@ -201,3 +205,142 @@ def test_streaming_joinop_unique_fallback_to_expand(how):
         assert [r[0] for r in rows] == [2, 2]
     elif how == "anti":
         assert [r[0] for r in rows] == [1, 5]
+
+
+# -- the compacting probe (a ShrinkOp directly above the join) -------------
+
+def _compact_cases():
+    rng = np.random.default_rng(11)
+    n, m = 300, 64
+    keys = rng.permutation(200)[:m].astype(np.int64)
+    plain_probe = {"pk": rng.integers(0, 200, n).astype(np.int64),
+                   "pv": np.arange(n, dtype=np.int64),
+                   "pw": np.arange(n, dtype=np.int64) * 3}
+    plain_build = {"bk": keys, "bv": np.arange(m, dtype=np.int64) * 10}
+    big = np.int64(1) << np.int64(62)
+    return {
+        # name: (probe cols, probe sel, build cols, build sel, C,
+        #        fallback, overflow)
+        "plain": (plain_probe, None, plain_build, None, 512, False, False),
+        "dead_lanes": (plain_probe, rng.random(n) > 0.3, plain_build,
+                       rng.random(m) > 0.3, 512, False, False),
+        "null_keys": (
+            dict(plain_probe, pk=(plain_probe["pk"], rng.random(n) > 0.2)),
+            rng.random(n) > 0.1,
+            {"bk": (keys, rng.random(m) > 0.2),
+             "bv": (plain_build["bv"], rng.random(m) > 0.5)},
+            None, 512, False, False),
+        "negative_keys": (
+            {"pk": np.array([-5, 0, 7, -5], dtype=np.int64),
+             "pv": np.arange(4, dtype=np.int64)}, None,
+            {"bk": np.array([-5, 7, 9], dtype=np.int64),
+             "bv": np.array([1, 2, 3], dtype=np.int64)}, None,
+            8, True, False),
+        "out_of_range_keys": (
+            {"pk": np.array([1, big], dtype=np.int64),
+             "pv": np.arange(2, dtype=np.int64)}, None,
+            {"bk": np.array([1, 5], dtype=np.int64),
+             "bv": np.array([10, 50], dtype=np.int64)}, None,
+            8, True, False),
+        "duplicate_build_keys": (
+            {"pk": np.array([1, 2, 3], dtype=np.int64),
+             "pv": np.arange(3, dtype=np.int64)}, None,
+            {"bk": np.array([2, 2, 3], dtype=np.int64),
+             "bv": np.array([7, 8, 9], dtype=np.int64)}, None,
+            8, True, False),
+        "more_matches_than_c": (plain_probe, None, plain_build, None, 16,
+                                False, True),
+        "c_over_all_lanes": (plain_probe, None, plain_build, None, 1024,
+                             False, False),
+        "zero_matches": (
+            plain_probe, None,
+            {"bk": keys + 1000, "bv": plain_build["bv"]}, None,
+            64, False, False),
+        "payload_over_31_bits": (
+            plain_probe, None,
+            {"bk": keys,
+             "bv": (rng.integers(0, 1 << 40, m).astype(np.int64),
+                    rng.random(m) > 0.2),
+             "bw": rng.integers(-(1 << 15), 1 << 15, m).astype(np.int64)},
+            rng.random(m) > 0.1, 512, False, False),
+        "payload_over_62_bits": (
+            plain_probe, None,
+            {"bk": keys,
+             "bv": rng.integers(0, 1 << 40, m).astype(np.int64),
+             "bw": rng.integers(0, 1 << 40, m).astype(np.int64)}, None,
+            512, True, False),
+    }
+
+
+_COMPACT_CASES = _compact_cases()
+
+
+def _two_step(probe, build, how, capacity):
+    """probe_unique, then the ShrinkOp's own compaction."""
+    from cockroach_tpu.exec.operators import ShrinkOp
+    from tests.test_exec import _source
+
+    res = hash_join(probe, build, ("pk",), ("bk",), how=how, mode="unique")
+    shrink = ShrinkOp(_source({"x": np.zeros(1, np.int64)}, capacity=1),
+                      capacity)
+    out, overflow = shrink.shrink_traceable(res.batch)
+    return out, bool(res.overflow), bool(overflow)
+
+
+@pytest.mark.parametrize("how", ["inner", "semi"])
+@pytest.mark.parametrize("case", sorted(_COMPACT_CASES))
+def test_compacting_probe_matches_probe_then_shrink(case, how):
+    """probe_unique_compact == probe_unique + ShrinkOp.shrink_traceable:
+    the same row multiset, the same fallback and overflow flags."""
+    from cockroach_tpu.ops.join import prepare_build
+    from cockroach_tpu.ops.sortjoin import carries, probe_unique_compact
+
+    pcols, psel, bcols, bsel, C, fallback, overflow = _COMPACT_CASES[case]
+    probe, build = _batch(pcols, psel), _batch(bcols, bsel)
+    ub = prepare_build(build, ("bk",), mode="unique")
+    assert carries(ub, probe.capacity, how)
+    got = probe_unique_compact(probe, ub, ("pk",), how, C)
+    want, want_fallback, want_overflow = _two_step(probe, build, how, C)
+    assert (bool(got.fallback), bool(got.overflow)) == (fallback, overflow)
+    assert (want_fallback, want_overflow) == (fallback, overflow)
+    assert got.batch.capacity == C == want.capacity
+    assert sorted(got.batch.columns) == sorted(want.columns)
+    if fallback:
+        return  # the restart ladder reruns the join in its next mode
+    assert int(got.batch.length) == int(want.length)
+    names = sorted(want.columns)
+    if not overflow:
+        assert _batch_rows(got.batch, names) == _batch_rows(want, names)
+        return
+    # first restart: ShrinkOp.widen() grows the capacity 16x
+    from cockroach_tpu.exec.operators import ShrinkOp
+    wide = C * ShrinkOp.GROWTH
+    got = probe_unique_compact(probe, ub, ("pk",), how, wide)
+    want, _f, want_overflow = _two_step(probe, build, how, wide)
+    assert not bool(got.overflow) and not want_overflow
+    assert _batch_rows(got.batch, names) == _batch_rows(want, names)
+
+
+@pytest.mark.parametrize("how", ["left", "anti", "right", "outer"])
+def test_compacting_probe_refuses_other_join_types(how):
+    from cockroach_tpu.ops.join import prepare_build
+    from cockroach_tpu.ops.sortjoin import probe_unique_compact
+
+    pcols, _ps, bcols, _bs, C, _f, _o = _COMPACT_CASES["plain"]
+    ub = prepare_build(_batch(bcols), ("bk",), mode="unique")
+    with pytest.raises(ValueError):
+        probe_unique_compact(_batch(pcols), ub, ("pk",), how, C)
+
+
+def test_compacting_probe_needs_a_carry_build():
+    """A row-matrix build (unique-mat, or a hash-kind key) has no payload
+    to broadcast: the tracer sees `carries` false and takes two steps."""
+    from cockroach_tpu.ops.join import prepare_build
+    from cockroach_tpu.ops.sortjoin import carries, probe_unique_compact
+
+    pcols, _ps, bcols, _bs, C, _f, _o = _COMPACT_CASES["plain"]
+    probe = _batch(pcols)
+    ub = prepare_build(_batch(bcols), ("bk",), mode="unique-mat")
+    assert not carries(ub, probe.capacity, "inner")
+    with pytest.raises(ValueError):
+        probe_unique_compact(probe, ub, ("pk",), "inner", C)
