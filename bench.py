@@ -583,7 +583,7 @@ def _multichip_child() -> None:
                 "rows_per_sec": round(n_line / warm),
                 "warm_s": round(warm, 4),
                 "cold_s": round(t_cold, 2),
-                "repartition_bytes": by(col, "dist.a2a_capacity"),
+                "repartition_bytes": by(col, "dist.a2a"),
                 "ingest_shard_bytes": by(col, "dist.ingest_shard"),
                 "ingest_replicate_bytes":
                     by(col, "dist.ingest_replicate"),

@@ -600,12 +600,11 @@ def phase_ycsb_and_write(obs, store, seed: int, n_rows: int,
 
 
 def phase_four_chips(obs, store, gen, coster_stale: bool) -> None:
-    """DistSQL across chips: Q3's text through run_sql(mesh=make_mesh(4))
-    with the BY_HASH repartition forced, against the same text on one
-    chip in this process and against the oracle."""
+    """DistSQL across chips: Q3's text through a Session with `SET
+    distsql = always` over a catalog whose mesh is make_mesh(4), against
+    the same text on one chip in this process and against the oracle."""
     from cockroach_tpu.parallel import dist_flow, ingest, make_mesh
-    from cockroach_tpu.sql import run_sql
-    from cockroach_tpu.util.settings import Settings
+    from cockroach_tpu.sql.session import Session
     from cockroach_tpu.workload import tpch_queries as Q
 
     t0 = time.perf_counter()
@@ -614,15 +613,15 @@ def phase_four_chips(obs, store, gen, coster_stale: bool) -> None:
           "lineitem_rows": gen.num_rows("lineitem"),
           "seconds": time.perf_counter() - t0,
           "engine": type(store.engine).__name__})
-    mesh = make_mesh(4)
-    # force the all_to_all path for the big join (as
-    # __graft_entry__._dryrun_impl does — broadcast alone proves no ICI):
-    # a limit of customer's padded rows keeps the inner customer build
-    # broadcast and repartitions lineitem x (orders x customer); a lower
-    # one nests a repartition inside a build, which the runner declines
-    limit = -(-gen.num_rows("customer") // CAPACITY) * CAPACITY
-    Settings().set(dist_flow.BROADCAST_LIMIT, limit)
+    catalog.with_mesh(make_mesh(4))
+    # nothing is forced: at SF1 the default sql.distsql.broadcast_limit_rows
+    # equals customer's padded rows, so customer stays MIRROR and
+    # lineitem x (orders x customer) goes BY_HASH through the all_to_all
+    # (checked on the compiled text below); at a rehearsal's scale every
+    # build is under the limit and no exchange is compiled
     want = Q.q3_oracle(gen)
+    dist = Session(catalog, capacity=CAPACITY)
+    dist.execute("set distsql = always")
 
     def rows_of(res):
         return [(int(res["l_orderkey"][i]), int(res["revenue"][i]),
@@ -632,11 +631,12 @@ def phase_four_chips(obs, store, gen, coster_stale: bool) -> None:
     results = {}
     for run in ("cold", "warm"):
         with obs.statement() as seen:
-            res = run_sql(Q3_SQL, catalog, CAPACITY, mesh=mesh)
+            _kind, res, _schema = dist.execute(Q3_SQL)
         results["dist"] = rows_of(res)
         check(results["dist"] == want, f"q3 on four chips ({run}) != "
               f"oracle: {results['dist'][:3]} != {want[:3]}")
-        check_counters(seen, f"q3 dist {run}", want_stage="dist.exec")
+        check_counters(seen, f"q3 dist {run}", want_tier="dist",
+                       want_stage="dist.exec")
         if run == "warm":
             check(seen["compiles"] == 0 and seen["cache_loads"] == 0,
                   f"q3 dist warm compiled ({seen['compiles']})")
@@ -644,9 +644,13 @@ def phase_four_chips(obs, store, gen, coster_stale: bool) -> None:
                             rows=len(results["dist"]),
                             matches_oracle=True))
 
-    progs = [e[0] for e in dist_flow._PROGS.values() if e is not None]
+    progs = [e for e in dist_flow._PROGS.values() if e is not None]
     check(bool(progs), "no distributed program was compiled")
-    check(any("all-to-all" in p.as_text() for p in progs),
+    by_hash = any(e.a2a_bytes for e in progs)
+    check(by_hash or coster_stale,
+          "no join of Q3 at SF1 went BY_HASH: nothing crossed the ICI")
+    check(not by_hash
+          or any("all-to-all" in e.compiled.as_text() for e in progs),
           "no all-to-all in the compiled distributed program")
     shard_devs = {}
     for img in ingest._CACHE.values():
@@ -660,11 +664,9 @@ def phase_four_chips(obs, store, gen, coster_stale: bool) -> None:
     for d in li:
         check(len(set(d)) == 4, f"lineitem shards sit on {d}, "
                                 f"not on four distinct devices")
-    emit({"phase": "q3_dist4.placement", "all_to_all": True,
+    emit({"phase": "q3_dist4.placement", "all_to_all": by_hash,
           "lineitem_shard_devices": li[0],
           "sharded_images": len(shard_devs)})
-
-    from cockroach_tpu.sql.session import Session
 
     sess = Session(catalog, capacity=CAPACITY)
     forced = place(lambda text: sess.execute(text)[1] or [], Q3_SQL,

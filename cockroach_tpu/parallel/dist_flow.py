@@ -38,9 +38,19 @@ stepping down to single-chip fused/streaming execution.
 The runner reuses the single-chip fusion grammar (exec/fused.py _Tracer)
 for everything except the distribution decisions, so the distributed and
 local executors cannot drift semantically — one kernel library, two
-placements. Anything outside the grammar falls back to single-chip
-execution (the reference plans local flows when distribution is off,
-distsql_physical_planner.go).
+placements. Anything outside the grammar (`Unsupported`) goes to the
+single-chip ladder (the reference plans local flows when distribution is
+off, distsql_physical_planner.go) or, for a `strict` caller
+(`distsql = always`), comes out as the error it is.
+
+Reached from a served statement through its session (`SET distsql = on |
+always` and the node's mesh, Catalog.mesh; sql/session.py), or from
+`run_sql(mesh=)`. Stages, in the one seam (exec/stats.timed = stage =
+span = annotation), under the `flow.dist` span: `dist.prepare` (cold:
+`dist.prime` per scan, `dist.ingest` per image, `dist.compile`),
+`dist.exec` = `dist.dispatch` + `dist.wait`, `dist.readback`,
+`dist.unpack`; `dist.a2a` counts one event a dispatch, with the bytes
+one device sends through the exchanges.
 """
 
 from __future__ import annotations
@@ -48,7 +58,7 @@ from __future__ import annotations
 import threading
 from collections import OrderedDict
 from dataclasses import is_dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -71,11 +81,13 @@ from cockroach_tpu.ops.agg import hash_aggregate
 from cockroach_tpu.parallel import ingest
 from cockroach_tpu.parallel.mesh import mesh_key, shrink_mesh
 from cockroach_tpu.parallel.repartition import (
-    hash_repartition_local, shard_map, _batch_pspecs,
+    exchange_bytes, hash_repartition_local, shard_map, _batch_pspecs,
 )
+from cockroach_tpu.util import cancel as _cancel
 from cockroach_tpu.util import retry as _retry
 from cockroach_tpu.util import tracing as _tracing
 from cockroach_tpu.util.fault import maybe_fail
+from cockroach_tpu.util.metric import default_registry
 from cockroach_tpu.util.settings import Settings
 
 BROADCAST_LIMIT = Settings.register(
@@ -101,7 +113,18 @@ def _all_gather_batch(b: Batch, axis: str) -> Batch:
 # Negative entries (None) pin configs the tracer rejected so the
 # streaming fallback is taken without re-tracing.
 
-_PROGS: "OrderedDict[tuple, Optional[tuple]]" = OrderedDict()
+class _Program(NamedTuple):
+    """One compiled distributed program and what its trace found out."""
+
+    compiled: object
+    flag_idx: tuple        # walk positions of the deferred-flag operators
+    flag_types: tuple      # their type names (drift check on a hit)
+    result_cap: int
+    a2a_bytes: int         # what one device sends through the exchanges
+    #                        of ONE dispatch (stage dist.a2a)
+
+
+_PROGS: "OrderedDict[tuple, Optional[_Program]]" = OrderedDict()
 _PROGS_CAP = 32
 _PROG_MU = threading.RLock()
 _MISS = object()
@@ -179,6 +202,15 @@ class _DistTracer(_Tracer):
         self.n_dev = n_dev
         self.sharded_scans = sharded_scans   # id(scan) of chunk-sharded
         self.repart_ops = repart_ops         # id(join) -> bucket caps
+        # (side, id(join)) -> bytes one device sends through that side's
+        # exchange in one dispatch; keyed, because a streamed probe's
+        # chain is traced twice (chunk 0, then the scan body)
+        self.a2a: Dict[tuple, int] = {}
+
+    def _note_exchange(self, side: str, op: JoinOp, batch: Batch,
+                       bucket: int, times: int = 1) -> None:
+        self.a2a[(side, id(op))] = times * exchange_bytes(
+            batch, self.n_dev, bucket)
 
     def _try_groupjoin(self, op):
         """The single-chip aggregate-over-join collapse (exec/fused.py)
@@ -212,6 +244,7 @@ class _DistTracer(_Tracer):
 
             p_bucket, b_bucket = self.repart_ops[id(op)]
             build_local = self._mat(op.build)
+            self._note_exchange("build", op, build_local, b_bucket)
             build_part, b_ovf = hash_repartition_local(
                 build_local, tuple(op.build_on), self.axis, self.n_dev,
                 b_bucket, seed=1)
@@ -222,9 +255,12 @@ class _DistTracer(_Tracer):
             probe_on, build_on = tuple(op.probe_on), tuple(op.build_on)
             how = op.how
             out_cap = (self.n_dev * p_bucket) * op.expansion
+            # every local chunk of the probe's scan is routed on its own
+            n_chunks = int(self.stacked[id(s.scan)][0].shape[0])
 
             def fn(item, f=s.fn):
                 b, fl = f(item)
+                self._note_exchange("probe", op, b, p_bucket, n_chunks)
                 routed, p_ovf = hash_repartition_local(
                     b, probe_on, self.axis, self.n_dev, p_bucket, seed=1)
                 res = hash_join_prepared(routed, bt, probe_on, build_on,
@@ -284,6 +320,7 @@ class _DistTracer(_Tracer):
             _p_bucket, b_bucket = self.repart_ops[id(op)]
             probe_local = self._mat(op.probe)
             build_local = self._mat(op.build)
+            self._note_exchange("build", op, build_local, b_bucket)
             build_part, b_ovf = hash_repartition_local(
                 build_local, tuple(op.build_on), self.axis, self.n_dev,
                 b_bucket, seed=1)
@@ -293,6 +330,7 @@ class _DistTracer(_Tracer):
                                    op.build_on))
             p_bucket = _pow2_at_least(
                 max(64, probe_local.capacity // self.n_dev * 2))
+            self._note_exchange("probe", op, probe_local, p_bucket)
             probe_part, p_ovf = hash_repartition_local(
                 probe_local, tuple(op.probe_on), self.axis, self.n_dev,
                 p_bucket, seed=1)
@@ -394,6 +432,42 @@ class DistFusedRunner:
         spine(self.root)
         return sharded, repart
 
+    def describe(self, chunks: Dict[int, int]) -> List[str]:
+        """EXPLAIN's distribution lines for `chunks` ({id(scan): chunk
+        count}): how many shards, each scan's placement, and each join's
+        router — BY_HASH (both sides through an all_to_all) or MIRROR
+        (the build replicated, the join local)."""
+        try:
+            sharded, repart = self._classify(chunks)
+        except Unsupported as e:
+            return [f"distribution: local (outside the distributed "
+                    f"grammar: {e})"]
+        lines = [f"distribution: full ({self.n_dev} shards, mesh axis "
+                 f"{self.axis!r})"]
+        for op in walk_operators(self.root):
+            if isinstance(op, ScanOp):
+                role = "sharded" if id(op) in sharded else "replicated"
+                lines.append(f"  scan {getattr(op, 'table', None) or '?'}: "
+                             f"{role} ({chunks[id(op)]} chunks of "
+                             f"{op.capacity} rows)")
+            elif isinstance(op, JoinOp):
+                keys = ", ".join(f"{a}={b}" for a, b in
+                                 zip(op.probe_on, op.build_on))
+                if id(op) in repart:
+                    p_bucket, b_bucket = repart[id(op)]
+                    how = (f"BY_HASH (all_to_all of both sides; buckets "
+                           f"of {p_bucket} probe and {b_bucket} build "
+                           f"rows a shard)")
+                elif any(isinstance(n, ScanOp) and id(n) in sharded
+                         for n in walk_operators(op.probe)):
+                    how = (f"MIRROR (build of "
+                           f"{self._subtree_rows(op.build, chunks)} rows "
+                           f"replicated, local join)")
+                else:
+                    how = "replicated (every shard joins it whole)"
+                lines.append(f"  {op.how} join on {keys}: {how}")
+        return lines
+
     def _subtree_rows(self, op, chunks) -> int:
         total = 0
         for sc in walk_operators(op):
@@ -434,19 +508,23 @@ class DistFusedRunner:
                     self._warm = False
                 continue
             self._warm = False
-            rs = ingest.resident_source(sc)
-            if rs is not None:
-                cnt = -(-rs[2].count // sc.capacity)
-                if cnt == 0:
+            # no image of this scan on the mesh yet: resolve its source
+            # on the host (the scan walk and the pack), fused.prime's twin
+            with stats.timed("dist.prime"):
+                _tracing.set_tag(table=getattr(sc, "table", None))
+                rs = ingest.resident_source(sc)
+                if rs is not None:
+                    cnt = -(-rs[2].count // sc.capacity)
+                    if cnt == 0:
+                        raise Unsupported("empty scan")
+                    sources[id(sc)] = ("resident", rs)
+                    chunks[id(sc)] = cnt
+                    continue
+                items = ingest.host_pack(sc)
+                if not items:
                     raise Unsupported("empty scan")
-                sources[id(sc)] = ("resident", rs)
-                chunks[id(sc)] = cnt
-                continue
-            items = ingest.host_pack(sc)
-            if not items:
-                raise Unsupported("empty scan")
-            sources[id(sc)] = ("host", items)
-            chunks[id(sc)] = len(items)
+                sources[id(sc)] = ("host", items)
+                chunks[id(sc)] = len(items)
         return scans, sources, chunks
 
     def _materialize(self, scans, sources, chunks):
@@ -462,9 +540,14 @@ class DistFusedRunner:
                 images[id(sc)] = src[1]
                 continue
             self._warm = False
-            img = ingest.build(sc, self.mesh, self.axis, role, src)
-            if img is None:
-                raise Unsupported("empty scan")
+            with stats.timed("dist.ingest"):
+                img = ingest.build(sc, self.mesh, self.axis, role, src)
+                if img is None:
+                    raise Unsupported("empty scan")
+                _tracing.set_tag(table=getattr(sc, "table", None),
+                                 role=role, bytes=img.nbytes)
+            # the stage's bytes are known only once the image is built
+            stats.add("dist.ingest", bytes=img.nbytes, events=0)
             images[id(sc)] = img
         return sharded, repart, images
 
@@ -506,6 +589,7 @@ class DistFusedRunner:
             out = t._mat(root)
             box["flag_ops"] = list(t.flag_ops)
             box["result_cap"] = min(RESULT_CAP, out.capacity)
+            box["a2a_bytes"] = sum(t.a2a.values())
             flags = tuple(
                 lax.psum(f.astype(jnp.int32), axis) > 0
                 for f in t.flags)
@@ -546,22 +630,11 @@ class DistFusedRunner:
                 _PROGS[pkey] = None  # negative: skip re-trace next time
                 _trim_progs()
                 raise
-        if repart:
-            # a2a capacity estimate (bytes that COULD cross ICI per
-            # dispatch) for the bench scaling block; row widths from the
-            # packed layout, both sides, all-pairs exchange
-            est = 0
-            for op in ops:
-                if id(op) in repart:
-                    p_b, b_b = repart[id(op)]
-                    pw = pack_layout(op.probe.schema, 1)[1]
-                    bw = pack_layout(op.build.schema, 1)[1]
-                    est += self.n_dev * self.n_dev * (p_b * pw + b_b * bw)
-            stats.add("dist.a2a_capacity", bytes=est)
         pos = {id(op): i for i, op in enumerate(ops)}
         flag_idx = tuple(pos[id(f)] for f in box["flag_ops"])
         flag_types = tuple(type(f).__name__ for f in box["flag_ops"])
-        entry = (compiled, flag_idx, flag_types, box["result_cap"])
+        entry = _Program(compiled, flag_idx, flag_types, box["result_cap"],
+                         box["a2a_bytes"])
         _PROGS[pkey] = entry
         _trim_progs()
         return entry
@@ -583,9 +656,8 @@ class DistFusedRunner:
         if entry is None:
             raise Unsupported("cached unsupported config")
         if entry is not _MISS:
-            _, flag_idx, flag_types, _ = entry
             if any(i >= len(ops) or type(ops[i]).__name__ != t
-                   for i, t in zip(flag_idx, flag_types)):
+                   for i, t in zip(entry.flag_idx, entry.flag_types)):
                 entry = _MISS  # tree drifted under the fingerprint
         if entry is _MISS:
             self._warm = False
@@ -599,11 +671,10 @@ class DistFusedRunner:
                 # warm distributed execution: cached placement + cached
                 # executable — the whole prepare was pointer chasing
                 stats.add("dist.prime_skipped")
-        compiled, flag_idx, _flag_types, result_cap = entry
-        flag_ops = [ops[i] for i in flag_idx]
+        flag_ops = [ops[i] for i in entry.flag_idx]
         args = tuple((images[id(sc)].bufs, images[id(sc)].ms)
                      for sc in scans)
-        return compiled, flag_ops, result_cap, args
+        return entry, flag_ops, args
 
     # -------------------------------------------------------------- aot --
 
@@ -676,38 +747,48 @@ class DistFusedRunner:
     # ------------------------------------------------------------- run --
 
     def batches(self):
-        try:
-            compiled, flag_ops, result_cap, args = self._prepare()
-        except Unsupported as e:
-            # outside what the distributed tracer runs (right/outer on
-            # the sharded spine, a repartition nested in a build, an
-            # empty scan): the streaming tree answers — say so
-            stats.add("dist.fallback_unsupported")
-            _tracing.record("dist.fallback", reason="unsupported",
-                            detail=str(e)[:80])
-            yield from self.root.batches()
-            return
+        """One dispatch of the whole query. Raises `Unsupported` for a
+        plan, or an answer, the distributed runner does not take (right/
+        outer on the sharded spine, a repartition nested in a build, an
+        empty scan, more rows than the packed result window): the caller
+        decides what answers instead (collect_distributed)."""
+        with stats.timed("dist.prepare"):
+            prog, flag_ops, args = self._prepare()
+        compiled, a2a_bytes = prog.compiled, prog.a2a_bytes
 
         def dispatch():
+            _cancel.checkpoint()
             # the a2a collectives live inside the compiled program; this
             # host-side seam stands in for an ICI transfer fault
             maybe_fail("dist.a2a")
-            # block inside the exec timer (same attribution contract as
-            # fused.exec): readback below measures only the transfer
-            return jax.block_until_ready(compiled(*args))
+            # dist.exec = dispatch + wait, fused.exec's two halves: until
+            # the program call returns the host is enqueueing; after that
+            # it waits for the mesh (readback below is the transfer only)
+            with stats.timed("dist.dispatch"):
+                out = compiled(*args)
+            with stats.timed("dist.wait"):
+                out = jax.block_until_ready(out)
+            # one event a dispatch; the bytes are the traced shapes'
+            stats.add("dist.a2a", bytes=a2a_bytes)
+            default_registry().counter(
+                "sql_distsql_exchange_bytes_total",
+                "bytes one device sent through the BY_HASH exchanges of "
+                "the distributed programs it dispatched").inc(a2a_bytes)
+            return out
 
         with stats.timed("dist.exec"):
+            _tracing.set_tag(shards=self.n_dev)
             buf = _retry.with_retry(dispatch, name="dist.a2a")
         with stats.timed("dist.readback", bytes=buf.nbytes):
             host = np.asarray(buf)
-        batch, flags, result_ovf = _unpack_result(host, self.schema,
-                                                  result_cap)
+        with stats.timed("dist.unpack"):
+            batch, flags, result_ovf = _unpack_result(host, self.schema,
+                                                      prog.result_cap)
         for fop, fl in zip(flag_ops, flags):
             if fl:
                 raise FlowRestart(fop)
         if result_ovf:
-            yield from self.root.batches()
-            return
+            raise Unsupported("result exceeds the packed window")
         yield batch
 
 
@@ -749,8 +830,6 @@ def _run_dist(runner: DistFusedRunner, reset, consume,
                 if restarts == max_restarts:
                     raise
                 restarts += 1
-                from cockroach_tpu.util.metric import default_registry
-
                 default_registry().counter(
                     "sql_flow_restarts_total",
                     "deferred-flag flow restarts").inc()
@@ -773,7 +852,7 @@ def _run_dist(runner: DistFusedRunner, reset, consume,
 
 def collect_distributed(root: Operator, mesh: Mesh, axis: str = "x",
                         max_restarts: int = 8, shrink: bool = True,
-                        placement=None):
+                        placement=None, strict: bool = False):
     """Run a query tree distributed over `mesh`; returns host columns
     (the distributed analog of exec.collect). TOP rungs of the
     degradation ladder: a non-terminal failure (device loss, sharding
@@ -781,9 +860,11 @@ def collect_distributed(root: Operator, mesh: Mesh, axis: str = "x",
     surviving pow2 sub-mesh (honoring the failure's `survivors` when it
     names them, parallel/mesh.DeviceLost) — and only when no smaller
     mesh remains steps down to single-chip exec.collect, which carries
-    the remaining rungs (fused -> streaming -> forced spill)."""
+    the remaining rungs (fused -> streaming -> forced spill). A plan
+    outside the distributed grammar (`Unsupported`) goes to the same
+    single-chip ladder, counted as `dist.fallback_unsupported`; with
+    `strict` (`SET distsql = always`) it raises instead."""
     from cockroach_tpu.util import circuit as _circuit
-    from cockroach_tpu.util.metric import default_registry
 
     if placement is not None:
         # the placement pass (sql/plan_compile.py) decided tiers for the
@@ -822,8 +903,21 @@ def collect_distributed(root: Operator, mesh: Mesh, axis: str = "x",
                 done = True
                 br.success()
                 _tracing.tag_root(tier="dist")
+                default_registry().counter(
+                    "sql_distsql_queries_total",
+                    "statements that finished on the distributed "
+                    "tier").inc()
             except FlowRestart:
                 raise  # widening exhausted: single-chip would overflow too
+            except Unsupported as e:
+                # a verdict on the plan, not a fault of the tier: the
+                # breaker stays as it was
+                stats.add("dist.fallback_unsupported")
+                _tracing.record("dist.fallback", reason="unsupported",
+                                detail=str(e)[:80])
+                if strict:
+                    raise
+                break
             except Exception as e:  # noqa: BLE001 — classifier decides
                 if _retry.classify(e) == _retry.TERMINAL:
                     raise

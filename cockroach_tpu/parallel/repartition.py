@@ -24,6 +24,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import inspect as _inspect
@@ -71,8 +72,6 @@ def axis_devices(mesh: Mesh, axis: str):
     every device holding the d-th block of a P(axis)-sharded array (one
     device per row on a flat mesh; the replica set across the other axes
     on a multi-axis mesh)."""
-    import numpy as np
-
     ax = tuple(mesh.axis_names).index(axis)
     grid = np.moveaxis(mesh.devices, ax, 0)
     return grid.reshape(grid.shape[0], -1)
@@ -88,8 +87,6 @@ def put_sharded_blocks(blocks, mesh: Mesh, axis: str):
     `blocks` is a length-n_dev list of equal-shape numpy arrays; returns
     (global jax.Array, per-device single-shard arrays for incremental
     reassembly via `reassemble_sharded`)."""
-    import numpy as np
-
     grid = axis_devices(mesh, axis)
     n_dev = grid.shape[0]
     assert len(blocks) == n_dev, (len(blocks), n_dev)
@@ -158,6 +155,21 @@ def range_repartition_local(batch: Batch, key_name: str,
     dest = jnp.searchsorted(boundaries.astype(jnp.int64), vals,
                             side="right").astype(jnp.int32)
     return _route_and_exchange(batch, dest, axis_name, n_dev, bucket_cap)
+
+
+def exchange_bytes(batch: Batch, n_dev: int, bucket_cap: int) -> int:
+    """Bytes ONE device sends over the axis in one `_route_and_exchange`
+    of `batch`, from its static shapes: of the n_dev buckets of
+    bucket_cap rows it fills, one stays at home; a row is every column's
+    values, its validity lane where it has one, and the selection
+    lane."""
+    row = jnp.dtype(jnp.bool_).itemsize
+    for c in batch.columns.values():
+        row += c.values.dtype.itemsize * int(
+            np.prod(c.values.shape[1:], dtype=np.int64))
+        if c.validity is not None:
+            row += c.validity.dtype.itemsize
+    return (n_dev - 1) * bucket_cap * row
 
 
 def _route_and_exchange(batch: Batch, dest: jnp.ndarray, axis_name: str,

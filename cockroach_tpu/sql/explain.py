@@ -103,6 +103,22 @@ def render_plan(p: Plan, catalog: Catalog) -> List[str]:
     return lines
 
 
+def _distribution_lines(op, mesh, catalog: Catalog) -> List[str]:
+    """How the distributed runner would place this tree on `mesh`
+    (DistFusedRunner.describe), from the catalog's row counts: no scan
+    is walked and nothing moves for an EXPLAIN."""
+    from cockroach_tpu.exec.operators import ScanOp, walk_operators
+    from cockroach_tpu.parallel.dist_flow import DistFusedRunner
+
+    chunks = {}
+    for sc in walk_operators(op):
+        if isinstance(sc, ScanOp):
+            # an index feed names no table: one chunk
+            rows = int(catalog.table_rows(sc.table)) if sc.table else 0
+            chunks[id(sc)] = max(1, -(-rows // sc.capacity))
+    return DistFusedRunner(op, mesh).describe(chunks)
+
+
 def execute(sql: str, catalog: Catalog, capacity: int = 1 << 17,
             mesh=None) -> Tuple[str, object]:
     """-> ("rows", columns-dict) | ("explain", [lines]).
@@ -119,12 +135,15 @@ def execute(sql: str, catalog: Catalog, capacity: int = 1 << 17,
 def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
                       mesh=None, ast=None,
                       op_sink=None,
-                      setting: str = "auto") -> Tuple[str, object, object]:
+                      setting: str = "auto",
+                      strict: bool = False) -> Tuple[str, object, object]:
     """-> (kind, payload, output Schema or None) — the schema is the
     built operator tree's own, for exact result decoding. Pass `ast` to
     skip re-parsing (Session already parsed for dispatch). `op_sink` (a
     list) receives {"plan": bound plan, "op": built operator tree} for
-    non-EXPLAIN statements — Session's prepared-statement cache."""
+    non-EXPLAIN statements — Session's prepared-statement cache. With a
+    `mesh` the statement runs distributed (sql/plan.run) and EXPLAIN
+    shows the distribution; `strict` is `distsql = always`."""
     from cockroach_tpu.exec import stats
     from cockroach_tpu.sql.plan import run
     from cockroach_tpu.util.tracing import tracer
@@ -152,7 +171,7 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
         sink = [] if op_sink is not None else None
         result, schema = run(plan, catalog, capacity, mesh=mesh,
                              with_schema=True, op_sink=sink, sql=sql,
-                             setting=setting)
+                             setting=setting, strict=strict)
         if op_sink is not None:
             op_sink.append({"plan": plan,
                             "op": sink[0] if sink else None})
@@ -168,11 +187,12 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
     from cockroach_tpu.sql.cost import crossover_rows, est_tpu_seconds
     from cockroach_tpu.sql.plan_compile import compile_plan
 
-    placement = None
+    placement = explained = None
     try:
-        placement = compile_plan(norm, catalog, capacity, sql=sql,
+        explained = compile_plan(norm, catalog, capacity, sql=sql,
                                  setting=setting, record=False,
-                                 _normalized=True).placement
+                                 _normalized=True)
+        placement = explained.placement
     except Exception:
         pass  # placement is advisory; EXPLAIN still renders the plan
     if placement is not None:
@@ -201,6 +221,8 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
         lines.append(f"engine: {engine} (est {est} scan rows, "
                      f"crossover ~{crossover_rows()} rows; tpu dispatch "
                      f"floor {1000 * est_tpu_seconds(0):.0f}ms)")
+    if mesh is not None and explained is not None:
+        lines.extend(_distribution_lines(explained.op, mesh, catalog))
     if analyze:
         from cockroach_tpu.util.tracing import summarize
 
@@ -208,7 +230,8 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
         try:
             with tracer().span("query", sql=sql[:60]) as sp:
                 t0 = time.perf_counter()
-                res = run(norm, catalog, capacity, mesh=mesh, sql=sql)
+                res = run(norm, catalog, capacity, mesh=mesh, sql=sql,
+                          strict=strict)
                 elapsed = time.perf_counter() - t0
             n = len(next(iter(res.values()))) if res else 0
             lines.append("")

@@ -41,6 +41,16 @@ from cockroach_tpu.ops.sort import SortKey
 class Catalog:
     """Resolve a table name to (Schema, chunks_thunk)."""
 
+    # the device mesh of the node that serves this catalog
+    # (parallel/mesh.make_mesh), or None: a node with one device. It
+    # belongs to the node, not to a call: a session whose `distsql` is
+    # not `off` distributes its SELECTs over it (sql/session.py)
+    mesh = None
+
+    def with_mesh(self, mesh) -> "Catalog":
+        self.mesh = mesh
+        return self
+
     def table_schema(self, name: str) -> Schema:
         raise NotImplementedError
 
@@ -158,8 +168,9 @@ class MVCCCatalog(Catalog):
     def __init__(self, store, tables: Dict[str, Tuple[int, Schema]],
                  rows: Optional[Dict[str, int]] = None,
                  pks: Optional[Dict[str, Tuple[str, ...]]] = None,
-                 stats: Optional[Dict[str, object]] = None):
+                 stats: Optional[Dict[str, object]] = None, mesh=None):
         self.store = store
+        self.mesh = mesh
         self.tables = dict(tables)
         self.rows = dict(rows or {})
         self.pks = dict(pks or {})
@@ -1059,9 +1070,13 @@ def _walk_plan(p: Plan):
 
 def run(p: Plan, catalog: Catalog, capacity: int = 1 << 17, mesh=None,
         axis: str = "x", with_schema: bool = False, op_sink=None,
-        sql: Optional[str] = None, setting: str = "auto"):
+        sql: Optional[str] = None, setting: str = "auto",
+        strict: bool = False):
     """Execute a logical plan; `mesh` switches to distributed execution
-    (the DistSQL on/off decision). `with_schema=True` also returns the
+    (the DistSQL on/off decision: a session's `distsql` variable and its
+    catalog's mesh, or a caller's own), and `strict` makes a plan the
+    distributed runner declines an error instead of a single-chip run
+    (`distsql = always`). `with_schema=True` also returns the
     operator tree's output Schema (result decoding needs the exact
     output types, and the tree was built anyway). `op_sink` (a list)
     receives the built operator tree — Session's prepared-statement
@@ -1083,5 +1098,6 @@ def run(p: Plan, catalog: Catalog, capacity: int = 1 << 17, mesh=None,
         from cockroach_tpu.parallel.dist_flow import collect_distributed
 
         result = collect_distributed(op, mesh, axis,
-                                     placement=compiled.placement)
+                                     placement=compiled.placement,
+                                     strict=strict)
     return (result, op.schema) if with_schema else result

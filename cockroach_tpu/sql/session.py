@@ -90,8 +90,10 @@ def map_execution_error(e: BaseException) -> Optional[SQLError]:
     (reference: pgerror codes on colexecerror panics). Memory-budget trips
     become 53200 out_of_memory; exhausted restart/retry budgets become
     40001 serialization_failure — the statement is safe for the CLIENT to
-    retry. Anything else keeps its Python identity (BindError et al. are
-    already user-facing)."""
+    retry; a statement `distsql = always` cannot distribute becomes 0A000.
+    Anything else keeps its Python identity (BindError et al. are already
+    user-facing)."""
+    from cockroach_tpu.exec.fused import Unsupported
     from cockroach_tpu.exec.operators import FlowRestart
     from cockroach_tpu.util.cancel import QueryCancelled
     from cockroach_tpu.util.mon import BudgetExceededError
@@ -109,6 +111,13 @@ def map_execution_error(e: BaseException) -> Optional[SQLError]:
             f"restart statement: flow restart budget exhausted ({e})")
     if isinstance(e, RetriesExhausted):
         return SQLError("40001", f"restart statement: {e}")
+    if isinstance(e, Unsupported):
+        # the single-chip ladder answers an Unsupported itself; the one
+        # that comes out is the distributed runner's under
+        # `distsql = always` (0A000 feature_not_supported)
+        return SQLError(
+            "0A000", f"distsql = always: the distributed runner does not "
+            f"take this statement ({e})")
     return None
 
 
@@ -265,8 +274,9 @@ class SessionCatalog(Catalog):
     Reads (desc lookups, scans) stay lock-free: a dict get is atomic and
     scans read the MVCC engine, which has its own lock."""
 
-    def __init__(self, store: MVCCStore):
+    def __init__(self, store: MVCCStore, mesh=None):
         self.store = store
+        self.mesh = mesh  # the node's device mesh (Catalog.mesh)
         # RLock: create() calls _next_id() and save() under the lock
         self._mu = threading.RLock()
         self._descs: Dict[str, TableDescriptor] = {}
@@ -740,18 +750,21 @@ class _Prepared:
     (MVCC-write-versioned — the invalidation check), the capacity those
     keys were computed at (entries are shared across sessions, which may
     differ in capacity; the plan's own chunking governs, not the
-    reader's), and the batchable-statement spec when the statement is in
-    the serving queue's coalescible class (sql/serving.py)."""
+    reader's), the batchable-statement spec when the statement is in
+    the serving queue's coalescible class (sql/serving.py), and whether
+    a distributing session made it (`distsql`; the cache is shared by a
+    catalog's sessions, and an entry serves only its own kind)."""
 
-    __slots__ = ("op", "schema", "vkeys", "capacity", "bspec")
+    __slots__ = ("op", "schema", "vkeys", "capacity", "bspec", "dist")
 
     def __init__(self, op, schema, vkeys: Dict[str, tuple],
-                 capacity: int, bspec=None):
+                 capacity: int, bspec=None, dist: bool = False):
         self.op = op
         self.schema = schema
         self.vkeys = vkeys
         self.capacity = capacity
         self.bspec = bspec
+        self.dist = dist
 
 
 _session_ids = itertools.count(1)
@@ -767,6 +780,8 @@ class Session:
         "admission_slots": "sql.tpu.admission_slots",
         "workmem": "sql.distsql.temp_storage.workmem",
         "vectorize": None,
+        # distsql: off | on | always (_distsql)
+        "distsql": None,
         # per-statement deadline in seconds: session-local, defaulting
         # to the sql.defaults.statement_timeout cluster setting
         "statement_timeout": None,
@@ -795,6 +810,7 @@ class Session:
         # vectorize: tpu | cpu force a backend; any other value (auto)
         # leaves the route to the coster (sql/cost.py)
         self.vars: Dict[str, object] = {"vectorize": "auto",
+                                        "distsql": "off",
                                         "admission_priority": "normal"}
         if db is None and isinstance(catalog, SessionCatalog):
             db = DB(catalog.store)
@@ -976,7 +992,8 @@ class Session:
                     self._maybe_log_slow(sql, elapsed, rows=rows)
                     self._observe_insight(
                         sql, elapsed, qid, _stats.degradations_seen(qcol),
-                        _stats.stage_seconds(qcol, "fused.wait"))
+                        _stats.stage_seconds(qcol, "fused.wait")
+                        + _stats.stage_seconds(qcol, "dist.wait"))
             return kind, payload, schema
         finally:
             if qentry is not None:
@@ -1111,7 +1128,8 @@ class Session:
         First the fingerprint's usual time, as it stood BEFORE this
         execution, goes to the statement's trace (tracing.note_usual):
         the tracer keeps the tree of a statement that took twice that.
-        `wait_s` is this execution's `fused.wait`."""
+        `wait_s` is this execution's `fused.wait` (`dist.wait` on the
+        distributed tier)."""
         from cockroach_tpu.sql.insights import default_insights
         from cockroach_tpu.util import tracing
 
@@ -1211,12 +1229,15 @@ class Session:
             return False
         return k is not None and "resident-serving" in k
 
-    def _prepared_store(self, sql: str, sunk, ast=None) -> None:
+    def _prepared_store(self, sql: str, sunk, ast=None,
+                        dist: bool = False) -> None:
         """Cache the built operator tree when it is safely re-runnable:
         every scan carries a versioned cache key (rules out IndexScan
         ops and non-MVCC catalogs, whose inputs we cannot re-validate).
         Statements in the serving queue's batchable class additionally
-        carry a BatchSpec, the ticket into cross-session coalescing."""
+        carry a BatchSpec, the ticket into cross-session coalescing —
+        unless a distributing session (`dist`) made the entry: the
+        serving queue is a single-chip path."""
         from cockroach_tpu.exec.operators import ScanOp, walk_operators
         from cockroach_tpu.sql.plan import (
             MVCCCatalog, Scan as _Scan, _walk_plan,
@@ -1240,7 +1261,7 @@ class Session:
                 return
             vkeys[t] = k
         bspec = None
-        if ast is not None:
+        if ast is not None and not dist:
             from cockroach_tpu.sql import serving as _serving
 
             try:
@@ -1250,7 +1271,7 @@ class Session:
                 bspec = None   # block the prepared path
         with self._prepared_mu:
             self._prepared[sql] = _Prepared(op, op.schema, vkeys,
-                                            self.capacity, bspec)
+                                            self.capacity, bspec, dist)
             self._prepared.move_to_end(sql)
             while len(self._prepared) > self.PREPARED_CACHE_ENTRIES:
                 self._prepared.popitem(last=False)
@@ -1294,6 +1315,14 @@ class Session:
             with stats.timed("sql.lookup"):
                 prep = self._prepared_lookup(sql)
             if prep is not None:
+                mesh, strict = self._distsql()
+                if prep.dist != (mesh is not None):
+                    # made under another value of `distsql` (by a session
+                    # of the same catalog; this one's SET cleared its
+                    # own): never served here; the cold path stores this
+                    # kind
+                    prep = None
+            if prep is not None:
                 stats.add("sql.prepared_hit")
                 if prep.bspec is not None:
                     from cockroach_tpu.sql import serving as _serving
@@ -1301,6 +1330,13 @@ class Session:
                     payload = _serving.maybe_submit(self, prep, sql=sql)
                     if payload is not None:
                         return "rows", payload, prep.schema
+                if prep.op is not None and mesh is not None:
+                    from cockroach_tpu.parallel.dist_flow import (
+                        collect_distributed,
+                    )
+
+                    return "rows", collect_distributed(
+                        prep.op, mesh, strict=strict), prep.schema
                 if prep.op is not None:
                     return "rows", collect(
                         prep.op, backend=self.vars["vectorize"]), prep.schema
@@ -1334,25 +1370,37 @@ class Session:
             from cockroach_tpu.sql.explain import execute_with_plan
 
             catalog = self.catalog
+            mesh, strict = self._distsql()
             if self._txn is not None and isinstance(catalog,
                                                     SessionCatalog):
                 # read-your-writes: SELECTs inside an open transaction
                 # must see its buffered mutations (conn_executor routes
-                # statement execution through the txn's kv.Txn)
+                # statement execution through the txn's kv.Txn), which
+                # are the gateway's: such a read is never distributed
+                if mesh is not None and strict:
+                    raise SQLError(
+                        "0A000", "distsql = always: a SELECT inside an "
+                        "open transaction reads the transaction's "
+                        "buffered writes on the gateway and cannot be "
+                        "distributed")
+                mesh = None
                 catalog = _TxnReadCatalog(catalog, self._txn)
             if isinstance(ast, P.SelectStmt) and self._txn is None:
                 # cold path only: warm prepared hits short-circuited
                 # before the parse above
                 sink: List[object] = []
                 out = execute_with_plan(sql, catalog, self.capacity,
-                                        ast=ast, op_sink=sink,
-                                        setting=self.vars["vectorize"])
+                                        mesh=mesh, ast=ast, op_sink=sink,
+                                        setting=self.vars["vectorize"],
+                                        strict=strict)
                 if sink:
-                    self._prepared_store(sql, sink[0], ast)
+                    self._prepared_store(sql, sink[0], ast,
+                                         dist=mesh is not None)
                 return out
             return execute_with_plan(sql, catalog, self.capacity,
-                                     ast=ast,
-                                     setting=self.vars["vectorize"])
+                                     mesh=mesh, ast=ast,
+                                     setting=self.vars["vectorize"],
+                                     strict=strict)
         if isinstance(ast, P.TxnControl):
             return self._txn_control(ast)
         if isinstance(ast, P.SetVar):
@@ -1848,11 +1896,40 @@ class Session:
 
         return Settings().get(key)
 
+    _DISTSQL_MODES = ("off", "on", "always")
+
+    def _distsql(self):
+        """-> (mesh, strict): the mesh this session's SELECTs are
+        distributed over, or None for the single-chip ladder, and whether
+        a plan the distributed runner declines is an error (`always`).
+        `distsql`: `off` (the default) never
+        distributes; `on` distributes whenever the node has a mesh
+        (Catalog.mesh) and the distributed grammar takes the plan, and
+        runs the single-chip ladder otherwise; `always` makes a
+        statement that cannot be distributed (no mesh, a plan outside
+        the grammar) an error, never a silent single-chip run.
+        Upstream's default is `auto`; there is none here until a
+        measurement says when several chips beat one (ROADMAP U1)."""
+        mode = self.vars["distsql"]
+        if mode == "off":
+            return None, False
+        mesh = self.catalog.mesh
+        if mesh is None and mode == "always":
+            raise SQLError(
+                "0A000", "distsql = always: this node has no device mesh "
+                "to distribute over (Catalog.mesh)")
+        return mesh, mode == "always"
+
     def _set_var(self, ast: P.SetVar):
         if ast.name not in self._VARS:
             raise BindError(f"unknown session variable {ast.name!r}")
         value = ast.value
-        if ast.name not in ("pallas", "vectorize"):  # string-valued vars
+        if ast.name == "distsql":
+            if value not in self._DISTSQL_MODES:
+                raise SQLError(
+                    "22023", f"distsql takes one of "
+                    f"{', '.join(self._DISTSQL_MODES)}, not {value!r}")
+        elif ast.name not in ("pallas", "vectorize"):  # string-valued vars
             if value in ("on", "true"):
                 value = True
             elif value in ("off", "false"):

@@ -25,9 +25,10 @@ SLOW_FACTOR times its fingerprint's usual time (sql/insights.py's
 baseline, handed over by the session with `note_usual`). Its tree then
 goes to a ring of FINISHED_RING trees served beside the inflight spans
 (`Tracer.inflight_summaries`), and its excess over the usual time is
-split between `sql_slow_stmt_wait_seconds` (spent in `fused.wait` over
-that stage's usual time: the device, or the queue before it) and
-`sql_slow_stmt_host_seconds` (the rest: the host).
+split between `sql_slow_stmt_wait_seconds` (spent in `fused.wait`, or
+`dist.wait` on the distributed tier, over that stage's usual time: the
+device, or the queue before it) and `sql_slow_stmt_host_seconds` (the
+rest: the host).
 """
 
 from __future__ import annotations
@@ -59,9 +60,11 @@ ANNOTATION_PREFIX = "crdb."
 # statement that repeats to 0.03% would flag its own jitter at 3 sigma
 SLOW_FACTOR = 2.0
 FINISHED_RING = 64  # slow statements' finished trees kept
-# what of a statement is not the host's: the device call and its readback
-_DEVICE_STAGES = frozenset(("fused.exec", "fused.readback"))
-_WAIT_STAGE = "fused.wait"
+# what of a statement is not the host's: the device call and its
+# readback, of the single-chip runner or of the distributed one
+_DEVICE_STAGES = frozenset(("fused.exec", "fused.readback",
+                            "dist.exec", "dist.readback"))
+_WAIT_STAGES = frozenset(("fused.wait", "dist.wait"))
 
 _dropped_counter = None
 
@@ -221,8 +224,9 @@ class Tracer:
         self._slow_wait = reg.histogram(
             "sql_slow_stmt_wait_seconds",
             "of each slow statement's excess over its fingerprint's "
-            "usual time, the part spent in fused.wait over that "
-            "stage's usual time (the device, or the queue before it)")
+            "usual time, the part spent in fused.wait (dist.wait) over "
+            "that stage's usual time (the device, or the queue before "
+            "it)")
         self._slow_host = reg.histogram(
             "sql_slow_stmt_host_seconds",
             "of each slow statement's excess over its fingerprint's "
@@ -361,7 +365,7 @@ class Tracer:
         if usual is None or dur < SLOW_FACTOR * usual[0]:
             return
         excess = dur - usual[0]
-        waited = _covered(root, (_WAIT_STAGE,))
+        waited = _covered(root, _WAIT_STAGES)
         wait_x = min(max(waited - usual[1], 0.0), excess)
         self._slow_wait.observe(wait_x)
         self._slow_host.observe(excess - wait_x)

@@ -152,9 +152,9 @@ def main():
         from cockroach_tpu.parallel import dist_flow, ingest
 
         mesh = Mesh(np.array(topo.devices[:4]), ("x",))
-        limit = (-(-gen.num_rows("customer") // chip_smoke.CAPACITY)
-                 * chip_smoke.CAPACITY)
-        Settings().set(dist_flow.BROADCAST_LIMIT, limit)
+        # nothing forced: the default broadcast limit is customer's
+        # padded rows at SF1, as in chip_smoke.py --chips 4 and in the
+        # benchmark's tpch-sf1-mesh4 cell (setting `auto`, as they run)
         cp = compile_plan(plan_sql(chip_smoke.Q3_SQL, catalog), catalog,
                           chip_smoke.CAPACITY, sql=chip_smoke.Q3_SQL,
                           setting="tpu")
