@@ -14,8 +14,8 @@ shard_map'd XLA program runs the ENTIRE query on every device —
 - P3 BY_HASH repartition: larger build sides are co-partitioned by join-
   key hash with ONE `lax.all_to_all` per side, and every probe chunk is
   routed the same way before its local join (colflow/routers.go:442
-  HashRouter -> outbox/inbox over gRPC becomes bucket-sort -> a2a over
-  ICI);
+  HashRouter -> outbox/inbox over gRPC becomes destination sort ->
+  bucket slices -> a2a over ICI);
 - P9 two-stage aggregation: per-device partial fold -> all_gather ->
   replicated merge -> finalize (partial aggregators on data nodes, final
   on the gateway);

@@ -2,8 +2,11 @@
 
 Reference mapping (SURVEY.md §2.9):
 - P3 BY_HASH repartition (colflow/routers.go:442 HashRouter -> outbox ->
-  gRPC FlowStream -> inbox) ==> `hash_repartition_local`: on-chip bucket
-  sort by destination + ONE `lax.all_to_all` per batch round over ICI.
+  gRPC FlowStream -> inbox) ==> `hash_repartition_local`: one stable
+  on-chip sort by destination that carries every lane as an operand, each
+  destination's bucket cut out of the sorted lanes as ONE slice (no
+  gather, no scatter), then `lax.all_to_all` over ICI, once per lane per
+  batch round.
 - P4 MIRROR broadcast ==> `all_gather` of the small side (used by
   `distributed_aggregate`'s merge phase).
 - Two-stage distributed aggregation (partial aggregators on data nodes +
@@ -129,8 +132,8 @@ def hash_repartition_local(batch: Batch, key_names: Sequence[str],
                            bucket_cap: int, seed: int = 0
                            ) -> Tuple[Batch, jnp.ndarray]:
     """Runs INSIDE shard_map. Routes each selected row to device
-    `hash(keys) % n_dev` via bucket-sort + one all_to_all (the BY_HASH
-    router, P3).
+    `hash(keys) % n_dev`: destination sort, bucket slices, all_to_all
+    (the BY_HASH router, P3).
 
     Returns (received batch of capacity n_dev*bucket_cap, overflow flag).
     Overflow (some bucket exceeded bucket_cap) must be psum-checked by the
@@ -175,42 +178,48 @@ def exchange_bytes(batch: Batch, n_dev: int, bucket_cap: int) -> int:
 def _route_and_exchange(batch: Batch, dest: jnp.ndarray, axis_name: str,
                         n_dev: int, bucket_cap: int
                         ) -> Tuple[Batch, jnp.ndarray]:
-    """Shared router tail: bucket-sort rows by destination, pad each
-    bucket to bucket_cap, one all_to_all over ICI."""
-    cap = batch.capacity
-    dest = jnp.where(batch.sel, dest, n_dev)          # dead rows drop
+    """Shared router tail: ONE stable sort by destination carries every
+    lane along as an operand; each destination's rows are then a
+    contiguous run, cut out as one slice of bucket_cap rows; an
+    all_to_all over ICI for each lane. No gather and no scatter: on a
+    v5e the sort costs a fourteenth of gathering the lanes by an argsort
+    and a seventieth of placing them element by element (PERF.md
+    section 6, PR 28)."""
+    dest = jnp.where(batch.sel, dest, n_dev)          # dead rows sort last
 
-    order = jnp.argsort(dest)                          # stable: groups rows
-    sorted_dest = dest[order]
-    # rank of each sorted row within its destination group
-    starts = jnp.searchsorted(sorted_dest, jnp.arange(n_dev + 1)).astype(jnp.int32)
-    rank = jnp.arange(cap, dtype=jnp.int32) - starts[jnp.minimum(sorted_dest, n_dev)]
-
-    fits = (sorted_dest < n_dev) & (rank < bucket_cap)
-    overflow = jnp.any((sorted_dest < n_dev) & (rank >= bucket_cap))
-    slot = jnp.where(fits, sorted_dest * bucket_cap + rank, n_dev * bucket_cap)
-
-    out_size = n_dev * bucket_cap
-
-    def scatter(vals):
-        out = jnp.zeros((out_size,), vals.dtype)
-        return out.at[slot].set(vals[order], mode="drop")
-
-    cols = {}
-    for n, c in batch.columns.items():
-        v = scatter(c.values)
-        validity = None if c.validity is None else scatter(c.validity)
-        cols[n] = Column(v, validity)
-    sel = jnp.zeros((out_size,), jnp.bool_).at[slot].set(
-        jnp.ones((cap,), jnp.bool_), mode="drop")
+    # every column's values, and its validity where it has one (a tuple:
+    # a dict would come back in sorted order, not the batch's)
+    lanes, columns = jax.tree_util.tree_flatten(
+        tuple(batch.columns.values()))
+    sorted_dest, *lanes = lax.sort((dest, *lanes), num_keys=1,
+                                   is_stable=True)
+    starts = jnp.searchsorted(sorted_dest, jnp.arange(n_dev + 1)
+                              ).astype(jnp.int32)
+    count = starts[1:] - starts[:-1]                   # rows per destination
+    overflow = jnp.any(count > bucket_cap)
+    # row j of bucket d is the j-th row of d's run: live while the run
+    # lasts, zero after it (an overflowing run keeps its first bucket_cap)
+    live = (jnp.arange(bucket_cap, dtype=jnp.int32)[None, :]
+            < jnp.minimum(count, bucket_cap)[:, None]).reshape(-1)
 
     # exchange: chunk d of my buffer -> device d (ICI all-to-all)
     a2a = lambda x: lax.all_to_all(x, axis_name, split_axis=0,
                                    concat_axis=0, tiled=True)
-    cols = {n: Column(a2a(c.values),
-                      None if c.validity is None else a2a(c.validity))
-            for n, c in cols.items()}
-    sel = a2a(sel)
+
+    def send(lane):
+        # bucket_cap rows of padding: a run that starts near the end is
+        # still cut at its own start (dynamic_slice clamps a start whose
+        # slice would not fit, and would send another destination's rows)
+        lane = jnp.concatenate(
+            [lane, jnp.zeros((bucket_cap,), lane.dtype)])
+        out = jnp.concatenate(
+            [lax.dynamic_slice(lane, (starts[d],), (bucket_cap,))
+             for d in range(n_dev)])
+        return a2a(jnp.where(live, out, jnp.zeros((), lane.dtype)))
+
+    cols = dict(zip(batch.columns, jax.tree_util.tree_unflatten(
+        columns, [send(v) for v in lanes])))
+    sel = a2a(live)
     out = Batch(cols, sel, jnp.sum(sel).astype(jnp.int32))
     return out, overflow
 

@@ -6,6 +6,7 @@ chips. Every path here is exactly what runs on a TPU slice.
 """
 
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 
@@ -164,3 +165,153 @@ def test_host_mesh_errors_are_actionable():
         host_mesh(per_host=0)
     with pytest.raises(ValueError, match="needs"):
         host_mesh(per_host=1 << 20)
+
+
+# ------------------------------------------------- the router, bit for bit --
+
+def _numpy_router(cols, sel, dest, n_dev, bucket_cap):
+    """The plain router: walk each device's rows in order, append a live
+    row to its destination's list while the bucket has room; device d
+    then receives bucket d of every source, in source order, each padded
+    with zeros to bucket_cap."""
+    per_dev = len(sel) // n_dev
+    recv = {n: [[np.zeros(bucket_cap, v.dtype) for _ in range(n_dev)]
+                for _ in range(n_dev)] for n, v in cols.items()}
+    recv_sel = np.zeros((n_dev, n_dev, bucket_cap), bool)
+    overflow = np.zeros(n_dev, bool)
+    for src in range(n_dev):
+        filled = [0] * n_dev
+        for i in range(src * per_dev, (src + 1) * per_dev):
+            if not sel[i]:
+                continue
+            d = int(dest[i])
+            if filled[d] == bucket_cap:
+                overflow[src] = True
+                continue
+            for n, v in cols.items():
+                recv[n][d][src][filled[d]] = v[i]
+            recv_sel[d, src, filled[d]] = True
+            filled[d] += 1
+    return ({n: np.concatenate([np.concatenate(r) for r in v])
+             for n, v in recv.items()}, recv_sel.reshape(-1), overflow)
+
+
+def _router_case(name):
+    """(columns, sel, dest-or-None, bucket_cap) of 4 devices x 64 rows;
+    a column is (values, validity or None). dest None: BY_RANGE on "v"
+    with an empty range."""
+    n_dev, per_dev = 4, 64
+    n = n_dev * per_dev
+    rng = np.random.default_rng(28)
+    v = rng.integers(-1 << 62, 1 << 62, n, dtype=np.int64)
+    cols = {"v": (v, None)}
+    sel = rng.random(n) > 0.25
+    dest = rng.integers(0, n_dev, n).astype(np.int32)
+    bucket_cap = 32
+    if name == "one_destination_overflows":
+        dest[:] = 2
+    elif name == "run_starts_past_cap_minus_bucket":
+        # device rows 0..39 go to 0, the rest to 3: 3's run starts at
+        # row 40 (less the dead rows), past 64 - 48
+        sel[:] = True
+        dest[:] = np.where(np.arange(n) % per_dev < 40, 0, 3)
+        bucket_cap = 48
+    elif name == "all_dead":
+        sel[:] = False
+    elif name == "validity_lane":
+        cols["w"] = (rng.integers(0, 1 << 31, n).astype(np.int32),
+                     rng.random(n) > 0.5)
+    elif name == "bool_and_int64_high_words":
+        cols["b"] = (rng.random(n) > 0.5, None)
+        # equal low words: only the high words tell the rows apart
+        cols["h"] = ((np.arange(n, dtype=np.int64) << 32) | 7, None)
+    elif name == "empty_range":
+        cols["v"] = (rng.integers(0, 400, n).astype(np.int64), None)
+        dest = None
+    else:
+        assert name == "uniform", name
+    return cols, sel, dest, bucket_cap
+
+
+@pytest.mark.parametrize("case", [
+    "uniform", "one_destination_overflows",
+    "run_starts_past_cap_minus_bucket", "all_dead", "validity_lane",
+    "bool_and_int64_high_words", "empty_range"])
+def test_router_matches_numpy_router_bit_for_bit(case):
+    from jax.sharding import PartitionSpec as P
+    from cockroach_tpu.parallel.repartition import (
+        _batch_pspecs, _route_and_exchange, range_repartition_local,
+        shard_map,
+    )
+
+    n_dev = 4
+    mesh = make_mesh(n_dev)
+    cols, sel, dest, bucket_cap = _router_case(case)
+    batch = make_batch(cols, sel=sel)
+    # device 2 owns [200, 200): nothing
+    bounds = np.array([100, 200, 200], np.int64)
+    if dest is None:
+        dest = np.searchsorted(bounds, cols["v"][0], side="right")
+
+    def local(b, d):
+        if case == "empty_range":
+            out, ovf = range_repartition_local(
+                b, "v", jnp.asarray(bounds), "x", n_dev, bucket_cap)
+        else:
+            out, ovf = _route_and_exchange(b, d, "x", n_dev, bucket_cap)
+        return (out.columns, out.sel), ovf[None]
+
+    (got, got_sel), got_ovf = jax.jit(shard_map(
+        local, mesh=mesh, in_specs=(_batch_pspecs(batch, "x"), P("x")),
+        out_specs=(P("x"), P("x")), check_rep=False))(
+            batch, jnp.asarray(dest, dtype=jnp.int32))
+
+    flat = {n: v for n, (v, _) in cols.items()}
+    flat.update({n + "__valid": val for n, (_, val) in cols.items()
+                 if val is not None})
+    want, want_sel, want_ovf = _numpy_router(flat, sel, dest, n_dev,
+                                             bucket_cap)
+    assert np.array_equal(np.asarray(got_ovf), want_ovf)
+    assert want_ovf.any() == (case == "one_destination_overflows")
+    assert np.array_equal(np.asarray(got_sel), want_sel)
+    for n, (_, val) in cols.items():
+        values = np.asarray(got[n].values)
+        assert values.dtype == want[n].dtype
+        assert np.array_equal(values, want[n]), n
+        assert (got[n].validity is None) == (val is None)
+        if val is not None:
+            assert np.array_equal(np.asarray(got[n].validity),
+                                  want[n + "__valid"]), n
+
+
+@pytest.mark.parametrize("router", ["by_hash", "by_range"])
+def test_router_lowers_to_all_to_all_and_no_scatter(router):
+    """Everything in this program stems from the router: it exchanges
+    with all_to_all, and no scatter places rows in buckets one element
+    at a time (PERF.md section 6, PR 27 and PR 28)."""
+    from jax.sharding import PartitionSpec as P
+    from cockroach_tpu.parallel.repartition import (
+        _batch_pspecs, hash_repartition_local, range_repartition_local,
+        shard_map,
+    )
+
+    n_dev = 4
+    mesh = make_mesh(n_dev)
+    n = n_dev * 64
+    batch = make_batch({
+        "k": (np.arange(n, dtype=np.int64), None),
+        "w": (np.arange(n, dtype=np.int32), np.arange(n) % 3 == 0)})
+
+    def local(b):
+        if router == "by_hash":
+            out, ovf = hash_repartition_local(b, ("k",), "x", n_dev, 32)
+        else:
+            out, ovf = range_repartition_local(
+                b, "k", jnp.asarray([64, 128, 192]), "x", n_dev, 32)
+        return (out.columns, out.sel), ovf[None]
+
+    text = jax.jit(shard_map(
+        local, mesh=mesh, in_specs=(_batch_pspecs(batch, "x"),),
+        out_specs=(P("x"), P("x")), check_rep=False)).lower(batch).as_text()
+    assert "all_to_all" in text
+    assert "scatter" not in text

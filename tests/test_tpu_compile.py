@@ -141,7 +141,10 @@ def test_sortjoin_compiles_at_1m_x_1m(one_chip):
 def test_four_device_repartition_has_all_to_all(topo):
     """parallel/repartition.py's BY_HASH router under shard_map on a
     four-device Mesh of the described chips: the compiler must put an
-    all-to-all over ICI in, and the per-device program must fit."""
+    all-to-all over ICI in, the per-device program must fit, and the
+    buckets are cut out of the destination-sorted lanes: a scatter would
+    place them one element at a time (144 ms a 64-bit column at
+    4,194,304 lanes; PERF.md section 6, PR 27)."""
     from jax import lax
 
     from cockroach_tpu.coldata.batch import Batch, Column
@@ -169,4 +172,6 @@ def test_four_device_repartition_has_all_to_all(topo):
         jax.ShapeDtypeStruct((n_dev * local,), jnp.int64, sharding=rows),
         jax.ShapeDtypeStruct((n_dev * local,), jnp.int64, sharding=rows),
         jax.ShapeDtypeStruct((n_dev * local,), jnp.bool_, sharding=rows))
-    assert "all-to-all" in compiled.as_text()
+    text = compiled.as_text()
+    assert "all-to-all" in text
+    assert " scatter(" not in text
