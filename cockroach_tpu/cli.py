@@ -4,7 +4,6 @@ commands `cockroach sql|demo|workload|...`, pkg/workload generators).
     python -m cockroach_tpu sql [--sf X] [-e SQL ...]
     python -m cockroach_tpu demo [-e SQL ...]
     python -m cockroach_tpu workload tpch|ycsb [...]
-    python -m cockroach_tpu bench
 
 `sql` opens an interactive shell over the TPC-H catalog (generated
 data); `demo` boots an in-process 3-node replicated cluster, loads a
@@ -348,14 +347,6 @@ def cmd_debug(args):
     print(f"wrote {out}")
 
 
-def cmd_bench(_args):
-    import runpy
-    import os
-
-    runpy.run_path(os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench.py"), run_name="__main__")
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(
         prog="cockroach_tpu",
@@ -391,9 +382,6 @@ def main(argv=None):
     st.add_argument("--http-port", type=int, default=8080)
     st.add_argument("--capacity", type=int, default=1 << 14)
     st.set_defaults(fn=cmd_start)
-
-    bp = sub.add_parser("bench", help="run the benchmark driver")
-    bp.set_defaults(fn=cmd_bench)
 
     dz = sub.add_parser("debug",
                         help="diagnostics: `debug zip` collects a "

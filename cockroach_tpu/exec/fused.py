@@ -38,6 +38,8 @@ reruns — recompiling the program at the wider capacity.
 
 from __future__ import annotations
 
+import functools
+import operator
 import threading
 from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -222,6 +224,12 @@ def _build_mode(op: JoinOp) -> str:
                                 op.build_on)
 
 
+def _or_flags(*flags):
+    """OR of the flags that are there; a None adds no operation."""
+    return functools.reduce(operator.or_,
+                            [f for f in flags if f is not None])
+
+
 def _shared_ops(root: Operator) -> set:
     """ids of the operators under `root` that more than one parent reads
     (plan-level CSE, sql/plan.build)."""
@@ -269,30 +277,58 @@ class _Tracer:
             s = self._stream(op.probe)
             if s is None:
                 return None
-            build = self._mat(op.build)
-            if (build.capacity * self._row_bytes(op.build.schema)
-                    > op.workmem):
-                raise Unsupported("join build exceeds workmem")
+            build, b_ovf = self._join_build(op)
             mode = _build_mode(op)
             bt = prepare_build(build, tuple(op.build_on), mode=mode)
-            out_cap = s.cap * op.expansion
+            n_chunks = int(self.stacked[id(s.scan)][0].shape[0])
+            p_cap, route = self._join_probe(op, s.cap, n_chunks)
+            out_cap = p_cap * op.expansion
             probe_on, build_on = tuple(op.probe_on), tuple(op.build_on)
             how = op.how
 
             def fn(item, f=s.fn):
                 b, fl = f(item)
+                b, p_ovf = route(b)
                 res = hash_join_prepared(b, bt, probe_on, build_on,
                                          how=how, out_capacity=out_cap)
-                return res.batch, fl + (res.overflow,)
+                return res.batch, fl + (_or_flags(b_ovf, p_ovf,
+                                                  res.overflow),)
 
             if mode == "unique":
                 # one output lane per probe row for every chunkable type
-                cap = s.cap
+                cap = p_cap
             else:
-                cap = {"inner": out_cap, "left": out_cap + s.cap,
-                       "semi": s.cap, "anti": s.cap}[op.how]
+                cap = {"inner": out_cap, "left": out_cap + p_cap,
+                       "semi": p_cap, "anti": p_cap}[op.how]
             return _Stream(s.scan, fn, cap, s.flag_ops + [op])
         return None
+
+    # -- how a join's sides reach it ----------------------------------------
+    #
+    # The ONE lowering of a join (the branch above: build once, probe a
+    # chunk; _mat_join: both sides whole) takes its inputs through these
+    # two hooks. Here a side is what its subtree materializes; the
+    # distributed tracer (parallel/dist_flow.py) sends a co-partitioned
+    # join's sides through the BY_HASH exchange first and hands back the
+    # router's overflow flag, which the lowering ORs into the join's own.
+    # A None flag adds no operation to the program.
+
+    def _join_build(self, op: JoinOp) -> Tuple[Batch, Optional[jnp.ndarray]]:
+        """-> (the build side as the join sees it, its exchange's overflow
+        flag or None)."""
+        build = self._mat(op.build)
+        if (build.capacity * self._row_bytes(op.build.schema)
+                > op.workmem):
+            raise Unsupported("join build exceeds workmem")
+        return build, None
+
+    def _join_probe(self, op: JoinOp, cap: int,
+                    chunks: Optional[int] = None) -> Tuple[int, Callable]:
+        """How probe batches of `cap` lanes reach the join: `chunks` of
+        them, one at a time (the streamed form), or the whole side as one
+        (None). -> (lanes of a batch as the join sees it, route), where
+        route(batch) -> (batch, overflow flag or None)."""
+        return cap, lambda batch: (batch, None)
 
     def _items(self, scan: ScanOp) -> List[Tuple]:
         bufs, ms = self.stacked[id(scan)]
@@ -419,23 +455,23 @@ class _Tracer:
         their operators and their order, so the restart ladder is the
         two-step path's."""
         probe = self._mat(op.probe)
-        build = self._mat(op.build)
-        if (build.capacity * self._row_bytes(op.build.schema)
-                > op.workmem):
-            raise Unsupported("join build exceeds workmem")
+        build, b_ovf = self._join_build(op)
         probe_on, build_on = tuple(op.probe_on), tuple(op.build_on)
         bt = prepare_build(build, build_on, mode=_build_mode(op))
+        _, route = self._join_probe(op, probe.capacity)
+        probe, p_ovf = route(probe)
         if shrink is not None and carries(bt, probe.capacity, op.how):
             res = probe_unique_compact(probe, bt, probe_on, op.how,
                                        shrink.capacity)
             stats.add("fused.join_compact")
             self.flag_ops.extend([op, shrink])
-            self.flags.extend([res.fallback, res.overflow])
+            self.flags.extend([_or_flags(b_ovf, p_ovf, res.fallback),
+                               res.overflow])
             return res.batch, True
         res = hash_join_prepared(probe, bt, probe_on, build_on, how=op.how,
                                  out_capacity=probe.capacity * op.expansion)
         self.flag_ops.append(op)
-        self.flags.append(res.overflow)
+        self.flags.append(_or_flags(b_ovf, p_ovf, res.overflow))
         return res.batch, False
 
     def _try_groupjoin(self, op: HashAggOp) -> Optional[Batch]:
@@ -443,10 +479,8 @@ class _Tracer:
         GROUP BY keys on the join column (+ build columns a unique build
         makes functionally dependent on it), ONE sort joins AND groups —
         no destination resort, no row gather, no separate aggregation
-        sort. The r4 engine ran Q3 at 0.19x numpy; this path measures
-        1.09x (scripts/exp_groupjoin.py). Returns None when the pattern
-        or dtypes don't fit; deferred flags rerun wider configs or the
-        general path."""
+        sort. Returns None when the pattern or dtypes don't fit; deferred
+        flags rerun wider configs or the general path."""
         from cockroach_tpu.ops.groupjoin import (
             GJ_FUNCS, group_join_aggregate,
         )
@@ -1097,7 +1131,7 @@ class FusedRunner:
 
         # first-ever execution of this runner is the cold-start number the
         # plan vault exists to shrink: give it its own metric/span so the
-        # coldstart bench and the /_status dashboards can see it directly
+        # /_status dashboards can see it directly
         first = not self._served_once
         t_first = _time.perf_counter()
         try:
@@ -1126,9 +1160,8 @@ class FusedRunner:
             with stats.timed("fused.dispatch"):
                 out = prog(*args)
             # block: without the sync the dispatch returns immediately
-            # and the device execution time was mis-billed to
-            # fused.readback (16.3s "readback" for a 1.2MB buffer in
-            # BENCH_r05); readback now measures only the transfer
+            # and the device's execution time is billed to
+            # fused.readback; readback measures only the transfer
             with stats.timed("fused.wait"):
                 return jax.block_until_ready(out)
 

@@ -4,8 +4,8 @@ Reference: the Pebble block cache (pkg/storage) keeps hot table blocks in
 RAM across statements; here the analog is the packed+stacked device image
 of a table's chunks (the input format of fused whole-query programs). The
 per-operator resident pin (ScanOp.resident) dies with its flow — every
-fresh plan build re-packed and re-transferred the same table (BENCH_r05:
-Q1/Q3/Q9/Q18 each re-uploaded the 472 MB lineitem image). This cache keys
+fresh plan build re-packed and re-transferred the same table (each of
+Q1/Q3/Q9/Q18 uploaded lineitem's whole image again). This cache keys
 the image on table *content* identity — (source, table, write version,
 capacity, column subset) as produced by Catalog.scan_cache_key — so any
 ScanOp over the same snapshot borrows the one HBM copy.

@@ -90,9 +90,8 @@ class StatsCollection:
         return "\n".join(s.line() for s in stages)
 
     def as_dict(self) -> Dict[str, Dict[str, float]]:
-        """JSON-ready stage table (bench.py embeds this in BENCH_*.json so
-        host-side stage trajectories are trackable across PRs, not just in
-        the human-readable stderr tail)."""
+        """JSON-ready stage table (chip_smoke.py and the scripts/check_*
+        gates print it)."""
         with self._mu:
             return {
                 s.name: {"seconds": round(s.seconds, 4),

@@ -6,8 +6,7 @@ moving the build side's PAYLOAD to matched probe lanes — the round-4
 engine did it with a (rows, W) row-matrix gather (~30 ms per 4M rows,
 latency-bound). If the payload columns fit in 63 bits they can instead
 ride the join's existing sorts as the value operand: the sort moves them
-at sequential-bandwidth cost and no gather ever happens (round-5 design,
-validated in scripts/exp_groupjoin.py: Q3 0.19x -> 1.09x numpy).
+at sequential-bandwidth cost and no gather ever happens.
 
 Packing is DYNAMIC: per-column [lo, hi] are computed on device (cheap
 reductions), widths are ceil(log2(span+1)) plus a validity bit for

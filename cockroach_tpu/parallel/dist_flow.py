@@ -81,7 +81,8 @@ from cockroach_tpu.ops.agg import hash_aggregate
 from cockroach_tpu.parallel import ingest
 from cockroach_tpu.parallel.mesh import mesh_key, shrink_mesh
 from cockroach_tpu.parallel.repartition import (
-    exchange_bytes, hash_repartition_local, shard_map, _batch_pspecs,
+    exchange_bucket, exchange_bytes, hash_repartition_local, shard_map,
+    _batch_pspecs,
 )
 from cockroach_tpu.util import cancel as _cancel
 from cockroach_tpu.util import retry as _retry
@@ -225,57 +226,49 @@ class _DistTracer(_Tracer):
     def _try_int_agg(self, op):
         return None  # same two-stage reasoning as _try_groupjoin
 
-    # -- distribution-aware joins -----------------------------------------
+    # -- how a co-partitioned join's sides reach it ---------------------------
+    #
+    # The join itself is lowered in exec/fused.py, once for each form
+    # (_Tracer._stream: build once, probe a chunk; _Tracer._mat_join: both
+    # sides whole). These two hooks only send a side of a BY_HASH join
+    # through the exchange first and hand back the router's overflow flag,
+    # which the lowering ORs into the join's own.
 
     def _compactable(self, op: Operator) -> bool:
-        # a co-partitioned join routes both sides first (_mat below)
+        # The one line in which the mesh's join differs: a co-partitioned
+        # join under a Shrink keeps its probe-order resort and the Shrink
+        # its sort. Not a limit of the lowering (the exchange is over
+        # before _mat_join looks at the Shrink) but the program the mesh
+        # cell was measured on; taking this method away is ROADMAP U1 (2),
+        # a perf_opt with its own claim on tpch-sf1-mesh4.q3-1stream.
         return id(op) not in self.repart_ops and super()._compactable(op)
 
-    def _stream(self, op: Operator):
-        if isinstance(op, JoinOp) and id(op) in self.repart_ops:
-            s = super()._stream(op.probe)
-            if s is None:
-                return None
-            from cockroach_tpu.ops.join import (
-                hash_join_prepared, prepare_build,
-            )
+    def _join_build(self, op: JoinOp):
+        if id(op) not in self.repart_ops:
+            return super()._join_build(op)
+        # a routed partition is not held to op.workmem (ROADMAP D3)
+        bucket = self.repart_ops[id(op)][1]
+        local = self._mat(op.build)
+        self._note_exchange("build", op, local, bucket)
+        return hash_repartition_local(local, tuple(op.build_on), self.axis,
+                                      self.n_dev, bucket, seed=1)
 
-            from cockroach_tpu.ops.join import effective_build_mode
+    def _join_probe(self, op: JoinOp, cap: int, chunks: Optional[int] = None):
+        if id(op) not in self.repart_ops:
+            return super()._join_probe(op, cap, chunks)
+        # every local chunk of a streamed probe is routed on its own, in
+        # the bucket _classify sized from the chain; a whole side in one
+        # sized from its lanes
+        bucket = (self.repart_ops[id(op)][0] if chunks
+                  else exchange_bucket(cap, self.n_dev))
+        probe_on = tuple(op.probe_on)
 
-            p_bucket, b_bucket = self.repart_ops[id(op)]
-            build_local = self._mat(op.build)
-            self._note_exchange("build", op, build_local, b_bucket)
-            build_part, b_ovf = hash_repartition_local(
-                build_local, tuple(op.build_on), self.axis, self.n_dev,
-                b_bucket, seed=1)
-            mode = effective_build_mode(op.build_mode,
-                                        op.build.schema.names(),
-                                        op.build_on)
-            bt = prepare_build(build_part, tuple(op.build_on), mode=mode)
-            probe_on, build_on = tuple(op.probe_on), tuple(op.build_on)
-            how = op.how
-            out_cap = (self.n_dev * p_bucket) * op.expansion
-            # every local chunk of the probe's scan is routed on its own
-            n_chunks = int(self.stacked[id(s.scan)][0].shape[0])
+        def route(batch):
+            self._note_exchange("probe", op, batch, bucket, chunks or 1)
+            return hash_repartition_local(batch, probe_on, self.axis,
+                                          self.n_dev, bucket, seed=1)
 
-            def fn(item, f=s.fn):
-                b, fl = f(item)
-                self._note_exchange("probe", op, b, p_bucket, n_chunks)
-                routed, p_ovf = hash_repartition_local(
-                    b, probe_on, self.axis, self.n_dev, p_bucket, seed=1)
-                res = hash_join_prepared(routed, bt, probe_on, build_on,
-                                         how=how, out_capacity=out_cap)
-                return res.batch, fl + (b_ovf | p_ovf | res.overflow,)
-
-            if mode == "unique":
-                cap = self.n_dev * p_bucket
-            else:
-                cap = {"inner": out_cap,
-                       "left": out_cap + self.n_dev * p_bucket,
-                       "semi": self.n_dev * p_bucket,
-                       "anti": self.n_dev * p_bucket}[op.how]
-            return type(s)(s.scan, fn, cap, s.flag_ops + [op])
-        return super()._stream(op)
+        return self.n_dev * bucket, route
 
     # -- two-stage aggregation ---------------------------------------------
 
@@ -310,37 +303,7 @@ class _DistTracer(_Tracer):
         return any(isinstance(n, ScanOp) and id(n) in self.sharded_scans
                    for n in walk_operators(op))
 
-    def _mat(self, op: Operator) -> Batch:
-        if isinstance(op, JoinOp) and id(op) in self.repart_ops:
-            from cockroach_tpu.ops.join import hash_join_prepared, \
-                prepare_build
-
-            from cockroach_tpu.ops.join import effective_build_mode
-
-            _p_bucket, b_bucket = self.repart_ops[id(op)]
-            probe_local = self._mat(op.probe)
-            build_local = self._mat(op.build)
-            self._note_exchange("build", op, build_local, b_bucket)
-            build_part, b_ovf = hash_repartition_local(
-                build_local, tuple(op.build_on), self.axis, self.n_dev,
-                b_bucket, seed=1)
-            bt = prepare_build(build_part, tuple(op.build_on),
-                               mode=effective_build_mode(
-                                   op.build_mode, op.build.schema.names(),
-                                   op.build_on))
-            p_bucket = _pow2_at_least(
-                max(64, probe_local.capacity // self.n_dev * 2))
-            self._note_exchange("probe", op, probe_local, p_bucket)
-            probe_part, p_ovf = hash_repartition_local(
-                probe_local, tuple(op.probe_on), self.axis, self.n_dev,
-                p_bucket, seed=1)
-            out_cap = probe_part.capacity * op.expansion
-            res = hash_join_prepared(probe_part, bt, tuple(op.probe_on),
-                                     tuple(op.build_on), how=op.how,
-                                     out_capacity=out_cap)
-            self.flag_ops.append(op)
-            self.flags.append(b_ovf | p_ovf | res.overflow)
-            return res.batch
+    def _mat_inner(self, op: Operator) -> Batch:
         if isinstance(op, TopKOp):
             keys, k, schema = tuple(op.keys), op.k, op.child.schema
             from cockroach_tpu.ops.sort import top_k_batch
@@ -373,7 +336,7 @@ class _DistTracer(_Tracer):
 
             m = _all_gather_batch(self._mat(op.child), self.axis)
             return sort_batch(m, tuple(op.keys), op.child.schema)
-        return super()._mat(op)
+        return super()._mat_inner(op)
 
 
 class DistFusedRunner:
@@ -415,15 +378,13 @@ class DistFusedRunner:
                     if in_build:
                         raise Unsupported(
                             "repartitioned join nested inside a build")
-                    local_rows = max(1, rows // self.n_dev)
-                    b_bucket = _pow2_at_least(
-                        max(64, local_rows // self.n_dev * 2))
-                    # probe chunk cap flows from the chain; bucket sized
-                    # for a uniform spread with 2x skew headroom
-                    p_cap = self._chain_cap(op.probe)
-                    p_bucket = _pow2_at_least(
-                        max(64, p_cap // self.n_dev * 2))
-                    repart[id(op)] = (p_bucket, b_bucket)
+                    # a shard holds its share of the build's rows; a
+                    # probe chunk's lanes flow from the chain
+                    repart[id(op)] = (
+                        exchange_bucket(self._chain_cap(op.probe),
+                                        self.n_dev),
+                        exchange_bucket(max(1, rows // self.n_dev),
+                                        self.n_dev))
                     spine(op.build, in_build=True)
                 return  # small build: scans stay replicated (broadcast)
             for c in _children(op):

@@ -160,6 +160,14 @@ def range_repartition_local(batch: Batch, key_name: str,
     return _route_and_exchange(batch, dest, axis_name, n_dev, bucket_cap)
 
 
+def exchange_bucket(rows: int, n_dev: int) -> int:
+    """Rows of one destination's bucket when a device sends `rows` lanes
+    through `hash_repartition_local`: an even spread with twice the room
+    for skew, a power of two, 64 at least. A fuller bucket raises the
+    router's overflow flag."""
+    return 1 << (max(64, rows // n_dev * 2) - 1).bit_length()
+
+
 def exchange_bytes(batch: Batch, n_dev: int, bucket_cap: int) -> int:
     """Bytes ONE device sends over the axis in one `_route_and_exchange`
     of `batch`, from its static shapes: of the n_dev buckets of

@@ -127,10 +127,9 @@ class Histogram:
         return out
 
     def snapshot(self) -> Dict[str, object]:
-        """Consistent point-in-time view for bench JSON and the
-        node_metrics virtual table: count/sum/mean plus CUMULATIVE
-        bucket counts keyed by upper bound (the same semantics the
-        Prometheus export emits)."""
+        """Consistent point-in-time view for the node_metrics virtual
+        table: count/sum/mean plus CUMULATIVE bucket counts keyed by
+        upper bound (the same semantics the Prometheus export emits)."""
         with self._mu:
             counts = list(self._counts)
             total = self._sum

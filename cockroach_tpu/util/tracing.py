@@ -502,7 +502,7 @@ def child_span(name: str, **tags):
 
 
 def summarize(span: Optional[Span]) -> Optional[Dict[str, object]]:
-    """Compact per-query trace digest for BENCH JSON / EXPLAIN ANALYZE:
+    """Compact per-query trace digest for EXPLAIN ANALYZE:
     stage durations, retry count, tier reached, event volume."""
     if span is None:
         return None

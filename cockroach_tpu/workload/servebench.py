@@ -1,16 +1,15 @@
 """Concurrent serving benchmark + the shared harness pieces behind it.
 
-This module owns the fixtures that both `scripts/chaos.py --concurrent`
-and `bench.py`'s serving block drive: a minimal pgwire client, the
-three-table serving catalog (YCSB-ish kv, a lineitem-shaped table for
+This module owns the fixtures that `scripts/chaos.py --concurrent`,
+`scripts/check_race.py` and `chip_smoke.py` drive: a minimal pgwire
+client, the three-table serving catalog (YCSB-ish kv, a lineitem-shaped table for
 TPC-H trickle aggregates, a small vector table), the fixed read-query
 pool whose answers are insert-independent, and `run()` — N wire-client
 threads hammering the pool with cross-session continuous batching
 (sql/serving.py) on or off.
 
 `compare()` runs both modes back to back and reports the
-batched-vs-unbatched speedup — the number the PR gate and the README
-table cite. Every read is verified bit-exact against a serial
+batched-vs-unbatched speedup. Every read is verified bit-exact against a serial
 fault-free reference over the same wire path, so a throughput win can
 never hide a correctness regression.
 """

@@ -921,7 +921,7 @@ QUERIES = {1: q1, 2: q2, 3: q3, 4: q4, 5: q5, 6: q6, 9: q9, 10: q10,
            12: q12, 14: q14, 15: q15, 16: q16, 17: q17, 18: q18, 19: q19}
 
 # logical-plan constructors (uniform gen -> Plan signature) — what the
-# placement pass and bench.py's placement block compile directly
+# placement pass compiles directly
 PLANS = {
     1: q1_plan,
     2: lambda gen: q2_plan(),
@@ -942,9 +942,9 @@ PLANS = {
 
 
 def q3_oracle_columnar(gen: TPCH):
-    """Vectorized numpy Q3 — single-thread CPU columnar baseline for
-    bench.py (searchsorted joins + bincount aggregation; the same shape a
-    CPU vectorized engine executes)."""
+    """Vectorized numpy Q3 — a single-thread CPU columnar baseline
+    (searchsorted joins + bincount aggregation; the same shape a CPU
+    vectorized engine executes)."""
     c, o, l = gen.table("customer"), gen.table("orders"), gen.table("lineitem")
     seg = gen.schema("customer").dicts["c_mktsegment"]
     code = int(np.nonzero(seg == "BUILDING")[0][0])
@@ -1022,9 +1022,9 @@ def q18_oracle_columnar(gen: TPCH, threshold: int = 300):
 
 
 def q1_oracle_columnar(gen: TPCH, chunks=None):
-    """Vectorized numpy Q1 — the single-thread CPU columnar baseline
-    bench.py times (exact int64 sums; bincount-free because charge sums
-    exceed float64's exact-integer range at SF>=1)."""
+    """Vectorized numpy Q1 — a single-thread CPU columnar baseline
+    (exact int64 sums; bincount-free because charge sums exceed
+    float64's exact-integer range at SF>=1)."""
     if chunks is None:
         chunks = [gen.table("lineitem")]
     acc: Dict[tuple, list] = {}

@@ -7,7 +7,7 @@ is 10 int64 fields — the fixed-width codec the native scanner decodes
 column-major (storage/mvcc.py), which is also how strings ride device
 lanes (dictionary codes).
 
-Two measurement modes (bench.py):
+Two measurement modes:
   - `run_e`: the classic operational mix — per-op MVCC range scans on the
     CPU engine (the reference path being matched: storage.MVCCScanToCols
     per Scan request);
@@ -146,7 +146,7 @@ class ScanTopKBatcher:
     kernel per op — the B-host-dispatch baseline; `run` pads each group
     of ops to a pow2 bucket and executes it as ONE `vmap`'d dispatch.
     Both paths trace the SAME kernel, so their per-op results are
-    bit-identical — asserted by bench.py and scripts/check_warm_dispatch.
+    bit-identical — asserted by scripts/check_warm_dispatch.py.
     """
 
     def __init__(self, values: np.ndarray, pks: np.ndarray, k: int = 10,

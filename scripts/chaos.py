@@ -285,8 +285,8 @@ def run_cluster_chaos(queries=(1, 3, 18), sf=0.01, capacity=1 << 13,
 # ------------------------------------------- concurrent serving nemesis
 #
 # The fixtures (wire client, serving catalog, query pool) live in
-# cockroach_tpu/workload/servebench.py so bench.py and the smoke gates
-# drive the SAME tables and queries this nemesis does; the aliases keep
+# cockroach_tpu/workload/servebench.py so the smoke gates drive the
+# SAME tables and queries this nemesis does; the aliases keep
 # this module's internal names stable.
 
 

@@ -8,6 +8,7 @@ single-program path and the streaming operator tree, and every query must
 produce identical results through both.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -240,72 +241,137 @@ def test_q3_sql_compacts_both_joins_and_matches_oracle():
     assert n == 0
 
 
-def _sorts(jaxpr, out):
-    """(lanes, dtype of the first operand, operands) of every sort
-    equation."""
+def _eqns(jaxpr):
+    """Every equation of `jaxpr`, those of its sub-jaxprs included."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "sort":
-            aval = eqn.invars[0].aval
-            out.append((aval.shape[0], str(aval.dtype), len(eqn.invars)))
+        yield eqn
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else (v,)):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    _sorts(inner, out)
-    return out
+                    yield from _eqns(inner)
 
 
-@pytest.mark.parametrize("compact", [True, False])
-def test_q3_program_sorts_per_join(compact, monkeypatch):
+def _sorts(jaxpr):
+    """(lanes, dtype of the first operand, operands) of every sort
+    equation."""
+    return [(eqn.invars[0].aval.shape[0], str(eqn.invars[0].aval.dtype),
+             len(eqn.invars))
+            for eqn in _eqns(jaxpr) if eqn.primitive.name == "sort"]
+
+
+def _mesh_jaxpr(root, n_dev, limit):
+    """-> (jaxpr, flag_ops) of `root`'s distributed program over `n_dev`
+    virtual devices, with the broadcast limit at `limit` rows (what
+    DistFusedRunner._lower traces, without compiling it)."""
+    from jax.sharding import PartitionSpec as P
+
+    from cockroach_tpu.parallel import dist_flow, make_mesh
+    from cockroach_tpu.parallel.repartition import shard_map
+    from cockroach_tpu.util.settings import Settings
+
+    s = Settings()
+    old = s.get(dist_flow.BROADCAST_LIMIT)
+    s.set(dist_flow.BROADCAST_LIMIT, limit)
+    try:
+        runner = dist_flow.DistFusedRunner(root, make_mesh(n_dev))
+        scans, sources, chunks = runner._prime()
+        sharded, repart, images = runner._materialize(scans, sources,
+                                                      chunks)
+        assert len(repart) == 1
+        box = {}
+        step = runner._make_step(scans, sharded, repart, box)
+        specs = tuple((P("x"), P("x")) if id(sc) in sharded
+                      else (P(), P()) for sc in scans)
+        fn = shard_map(step, mesh=runner.mesh, in_specs=specs,
+                       out_specs=P(), check_rep=False)
+        jaxpr = jax.make_jaxpr(fn)(*(
+            (images[id(sc)].bufs, images[id(sc)].ms) for sc in scans))
+        return jaxpr, box["flag_ops"]
+    finally:
+        s.set(dist_flow.BROADCAST_LIMIT, old)
+
+
+@pytest.mark.parametrize("program", ["compact", "two_step", "mesh"])
+def test_q3_program_sorts_per_join(program, monkeypatch):
     """Per join of Q3's fused program: exactly two sorts at lcap + rcap
     lanes, the key sort and the compaction's single-operand sort, none
     by destination and no argsort at lcap; with the one-step lowering
-    switched off (the parent's program): key sort and resort at lcap +
-    rcap, and the Shrink's `(pred, i32)` argsort at lcap."""
-    import jax
-
+    switched off (PR 25's program): key sort and resort at lcap + rcap,
+    and the Shrink's `(pred, i32)` argsort at lcap. On a four-shard mesh
+    (the mesh cell's program at this scale) the local semi join compacts
+    and the BY_HASH inner join takes the two steps
+    (_DistTracer._compactable), behind the router's two destination
+    sorts, which carry a side's lanes as operands."""
     from cockroach_tpu.exec.operators import ScanOp, walk_operators
     from cockroach_tpu.sql.bind import plan_sql
     from cockroach_tpu.sql.plan_compile import compile_plan
     from tests.test_sql import Q3_SQL
 
     _gen, cat = _sql_catalog()
-    joins = []
-    if compact:
-        real = fused.probe_unique_compact
+    compacted, two_step = [], []  # (lcap, rcap) of the joins lowered so
+    real_compact, real_join = (fused.probe_unique_compact,
+                               fused.hash_join_prepared)
 
-        def recording(probe, ub, *a):
-            joins.append((probe.capacity, ub.batch.capacity))
-            return real(probe, ub, *a)
+    def compact(probe, ub, *a):
+        compacted.append((probe.capacity, ub.batch.capacity))
+        return real_compact(probe, ub, *a)
 
-        monkeypatch.setattr(fused, "probe_unique_compact", recording)
-    else:
-        real = fused.hash_join_prepared
+    def join(probe, bt, *a, **kw):
+        two_step.append((probe.capacity, bt.batch.capacity))
+        return real_join(probe, bt, *a, **kw)
 
-        def recording(probe, bt, *a, **kw):
-            joins.append((probe.capacity, bt.batch.capacity))
-            return real(probe, bt, *a, **kw)
-
-        monkeypatch.setattr(fused, "hash_join_prepared", recording)
+    monkeypatch.setattr(fused, "probe_unique_compact", compact)
+    monkeypatch.setattr(fused, "hash_join_prepared", join)
+    if program == "two_step":
         monkeypatch.setattr(fused._Tracer, "_compactable",
                             lambda self, op: False)
     cp = compile_plan(plan_sql(Q3_SQL, cat), cat, 1 << 14, sql=Q3_SQL,
                       setting="tpu")
-    _prog, args = cp.runner._prepare()
-    joins.clear()
-    scans = [n for n in walk_operators(cp.op) if isinstance(n, ScanOp)]
-    prog, _box = cp.runner._make_prog([id(s) for s in scans])
-    sorts = _sorts(jax.make_jaxpr(prog)(*args).jaxpr, [])
-    assert len(joins) == 2
-    for lcap, rcap in joins:
-        n = lcap + rcap
-        at_n = sorted(s[1:] for s in sorts if s[0] == n)
-        if compact:
-            assert at_n == [("uint32", 1), ("uint32", 2)]
-            assert (lcap, "bool", 2) not in sorts
-        else:
-            assert at_n == [("int32", 2), ("uint32", 2)]
-            assert sorts.count((lcap, "bool", 2)) == 1
+    if program == "mesh":
+        if len(jax.devices()) < 4:
+            pytest.skip("needs four virtual CPU devices")
+        # lineitem's four chunks shard one to a device; orders + customer
+        # (two chunks) are over the limit, so the inner join goes BY_HASH
+        jaxpr, _flag_ops = _mesh_jaxpr(cp.op, 4, 1 << 14)
+    else:
+        _prog, args = cp.runner._prepare()
+        compacted.clear()
+        two_step.clear()
+        scans = [n for n in walk_operators(cp.op) if isinstance(n, ScanOp)]
+        prog, _box = cp.runner._make_prog([id(s) for s in scans])
+        jaxpr = jax.make_jaxpr(prog)(*args)
+    sorts = _sorts(jaxpr.jaxpr)
+    if program == "mesh":
+        # the semi join orders x customer is local to a shard (16,384
+        # lanes a side) and compacts: key sort and compaction sort
+        assert compacted == [(16384, 16384)]
+        assert sorts.count((32768, "uint32", 2)) == 1
+        assert sorts.count((32768, "uint32", 1)) == 1
+        # the router: one stable sort by destination a side, carrying the
+        # side's four column lanes as operands: a shard's 16,384 lineitem
+        # lanes into buckets of 8,192, its 4,096 shrunk orders lanes into
+        # buckets of 4,096
+        assert sorts.count((16384, "int32", 5)) == 1
+        assert sorts.count((4096, "int32", 5)) == 1
+        # so the inner join sees 4 x 8,192 probe and 4 x 4,096 build
+        # lanes, and takes the two steps: key sort, resort to probe order,
+        # and the Shrink's argsort over the probe lanes
+        assert two_step == [(32768, 16384)]
+        assert sorts.count((49152, "uint32", 2)) == 1
+        assert sorts.count((49152, "int32", 2)) == 1
+        assert sorts.count((32768, "bool", 2)) == 1
+        return
+    assert (len(compacted), len(two_step)) == {
+        "compact": (2, 0), "two_step": (0, 2)}[program]
+    for lcap, rcap in compacted:
+        at_n = sorted(s[1:] for s in sorts if s[0] == lcap + rcap)
+        assert at_n == [("uint32", 1), ("uint32", 2)]
+        assert (lcap, "bool", 2) not in sorts
+    for lcap, rcap in two_step:
+        at_n = sorted(s[1:] for s in sorts if s[0] == lcap + rcap)
+        assert at_n == [("int32", 2), ("uint32", 2)]
+        assert sorts.count((lcap, "bool", 2)) == 1
 
 
 def _shrunk_join(how, capacity=512, second_parent=False):
@@ -376,8 +442,6 @@ def test_shrunk_joins_match_oracles(qn, path):
     """Q9 and Q18 Shrink over selective joins (sql/plan.insert_shrinks):
     the one-step lowering fires on the single-chip tracer and inside
     shard_map (_DistTracer), and the answers are the oracles'."""
-    import jax
-
     gen = TPCH(sf=0.01)
     flow = Q.QUERIES[qn](gen, 1 << 12)
     if path == "dist":

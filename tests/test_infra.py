@@ -372,8 +372,7 @@ def test_only_the_resolver_touches_the_cache_directory():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     setter = re.compile(r"update\(\s*[\"']jax_compilation_cache_dir")
     offenders = []
-    files = [os.path.join(root, "bench.py"),
-             os.path.join(root, "chip_smoke.py"),
+    files = [os.path.join(root, "chip_smoke.py"),
              os.path.join(root, "__graft_entry__.py")]
     for sub in ("cockroach_tpu", "scripts", "tests"):
         for d, _dirs, names in os.walk(os.path.join(root, sub)):
