@@ -230,6 +230,20 @@ def _or_flags(*flags):
                             [f for f in flags if f is not None])
 
 
+def _join_flags(guard, b_ovf, p_ovf, own) -> tuple:
+    """A join's deferred flags: its own, with its exchanges' ORed in; or,
+    where the exchanges have a restart target of their own (`guard`,
+    _Tracer._route_guard), theirs first and then its own."""
+    if guard is None:
+        return (_or_flags(b_ovf, p_ovf, own),)
+    return (_or_flags(b_ovf, p_ovf), own)
+
+
+def _flag_targets(guard, op: JoinOp) -> list:
+    """The operators behind _join_flags' flags, in its order."""
+    return [op] if guard is None else [guard, op]
+
+
 def _shared_ops(root: Operator) -> set:
     """ids of the operators under `root` that more than one parent reads
     (plan-level CSE, sql/plan.build)."""
@@ -282,6 +296,7 @@ class _Tracer:
             bt = prepare_build(build, tuple(op.build_on), mode=mode)
             n_chunks = int(self.stacked[id(s.scan)][0].shape[0])
             p_cap, route = self._join_probe(op, s.cap, n_chunks)
+            guard = self._route_guard(op)
             out_cap = p_cap * op.expansion
             probe_on, build_on = tuple(op.probe_on), tuple(op.build_on)
             how = op.how
@@ -291,8 +306,8 @@ class _Tracer:
                 b, p_ovf = route(b)
                 res = hash_join_prepared(b, bt, probe_on, build_on,
                                          how=how, out_capacity=out_cap)
-                return res.batch, fl + (_or_flags(b_ovf, p_ovf,
-                                                  res.overflow),)
+                return res.batch, fl + _join_flags(guard, b_ovf, p_ovf,
+                                                   res.overflow)
 
             if mode == "unique":
                 # one output lane per probe row for every chunkable type
@@ -300,7 +315,8 @@ class _Tracer:
             else:
                 cap = {"inner": out_cap, "left": out_cap + p_cap,
                        "semi": p_cap, "anti": p_cap}[op.how]
-            return _Stream(s.scan, fn, cap, s.flag_ops + [op])
+            return _Stream(s.scan, fn, cap,
+                           s.flag_ops + _flag_targets(guard, op))
         return None
 
     # -- how a join's sides reach it ----------------------------------------
@@ -310,7 +326,8 @@ class _Tracer:
     # two hooks. Here a side is what its subtree materializes; the
     # distributed tracer (parallel/dist_flow.py) sends a co-partitioned
     # join's sides through the BY_HASH exchange first and hands back the
-    # router's overflow flag, which the lowering ORs into the join's own.
+    # router's overflow flag, which the lowering ORs into the join's own,
+    # or keeps apart where _route_guard names a restart target for it.
     # A None flag adds no operation to the program.
 
     def _join_build(self, op: JoinOp) -> Tuple[Batch, Optional[jnp.ndarray]]:
@@ -329,6 +346,12 @@ class _Tracer:
         (None). -> (lanes of a batch as the join sees it, route), where
         route(batch) -> (batch, overflow flag or None)."""
         return cap, lambda batch: (batch, None)
+
+    def _route_guard(self, op: JoinOp):
+        """The FlowRestart target that answers the flags `op`'s hooks hand
+        back, where they have one of their own; None: they are ORed into
+        the join's flag and answered as the join's."""
+        return None
 
     def _items(self, scan: ScanOp) -> List[Tuple]:
         bufs, ms = self.stacked[id(scan)]
@@ -460,18 +483,19 @@ class _Tracer:
         bt = prepare_build(build, build_on, mode=_build_mode(op))
         _, route = self._join_probe(op, probe.capacity)
         probe, p_ovf = route(probe)
+        guard = self._route_guard(op)
+        self.flag_ops.extend(_flag_targets(guard, op))
         if shrink is not None and carries(bt, probe.capacity, op.how):
             res = probe_unique_compact(probe, bt, probe_on, op.how,
                                        shrink.capacity)
             stats.add("fused.join_compact")
-            self.flag_ops.extend([op, shrink])
-            self.flags.extend([_or_flags(b_ovf, p_ovf, res.fallback),
-                               res.overflow])
+            self.flag_ops.append(shrink)
+            self.flags.extend(_join_flags(guard, b_ovf, p_ovf, res.fallback)
+                              + (res.overflow,))
             return res.batch, True
         res = hash_join_prepared(probe, bt, probe_on, build_on, how=op.how,
                                  out_capacity=probe.capacity * op.expansion)
-        self.flag_ops.append(op)
-        self.flags.append(_or_flags(b_ovf, p_ovf, res.overflow))
+        self.flags.extend(_join_flags(guard, b_ovf, p_ovf, res.overflow))
         return res.batch, False
 
     def _try_groupjoin(self, op: HashAggOp) -> Optional[Batch]:

@@ -15,20 +15,34 @@ shard_map'd XLA program runs the ENTIRE query on every device —
   key hash with ONE `lax.all_to_all` per side, and every probe chunk is
   routed the same way before its local join (colflow/routers.go:442
   HashRouter -> outbox/inbox over gRPC becomes destination sort ->
-  bucket slices -> a2a over ICI);
+  bucket slices -> a2a over ICI). A bucket is a static shape, sized by
+  `repartition.exchange_bucket` from the rows a shard is expected to
+  SEND: its share of the planner's estimate for that side of the join
+  (`est_rows`, stamped on the operators by sql/plan_compile.py), or,
+  where the tree carries none (built by hand), from the side's lanes;
+  never more than the lanes give (`_classify`, `side_bucket`). The
+  join behind the exchange runs at n_dev x (probe + build bucket)
+  lanes, so the estimate is what keeps it near the rows there are;
 - P9 two-stage aggregation: per-device partial fold -> all_gather ->
   replicated merge -> finalize (partial aggregators on data nodes, final
   on the gateway);
 - deferred overflow/collision flags are psum-reduced across the axis and
   answered by the same FlowRestart widen/re-seed retry as single-chip.
+  A full bucket drops no row silently: the router raises its flag.
+  From lanes it is ORed into the join's; from an estimate it is a flag
+  of its own whose target (`_BucketGuard`) sends that join back to the
+  lanes' buckets in ONE restart (`sql_distsql_bucket_restarts_total`):
+  a low estimate is slower once, never wrong.
 
 Warm path: compiled programs live in a process-wide cache keyed by
 (plan fingerprint, config key) where the config key carries the mesh
-identity, the broadcast limit, and every scan's (role, pow2 bucket) —
-the distributed analog of exec/fused.py's exec cache. A warm re-run of
-a distributed query is ONE dispatch: cached ingest-sharded images (per-
-shard-refreshed against their resident MVCC source when the table took
-writes), cached executable, no trace, no transfer.
+identity, the broadcast limit, every scan's (role, pow2 bucket) and
+the bucket pair an estimate gave each BY_HASH join (the fingerprint
+skips `est_rows`; the power of two keeps drifting statistics on one
+program) — the distributed analog of exec/fused.py's exec cache. A warm
+re-run of a distributed query is ONE dispatch: cached ingest-sharded
+images (per-shard-refreshed against their resident MVCC source when the
+table took writes), cached executable, no trace, no transfer.
 
 Degradation ladder (top rung of exec/operators.collect's): a device
 loss or sharding failure first SHRINKS THE MESH — recompile on the
@@ -50,7 +64,9 @@ span = annotation), under the `flow.dist` span: `dist.prepare` (cold:
 `dist.prime` per scan, `dist.ingest` per image, `dist.compile`),
 `dist.exec` = `dist.dispatch` + `dist.wait`, `dist.readback`,
 `dist.unpack`; `dist.a2a` counts one event a dispatch, with the bytes
-one device sends through the exchanges.
+one device sends through the exchanges (empty bucket lanes included);
+`dist.compile` carries one `dist.bucket` event a routed side: the
+estimate, the bucket traced, the bucket the lanes give.
 """
 
 from __future__ import annotations
@@ -70,8 +86,8 @@ from cockroach_tpu.coldata.arrow import pack_layout
 from cockroach_tpu.coldata.batch import Batch, Column, Schema, concat_batches
 from cockroach_tpu.exec import stats
 from cockroach_tpu.exec.fused import (
-    RESULT_CAP, HBMExceeded, Unsupported, _Tracer, _pack_result,
-    _unpack_result, compile_via_vault, lower_program,
+    RESULT_CAP, HBMExceeded, Unsupported, _ModeBumpGuard, _Tracer,
+    _pack_result, _unpack_result, compile_via_vault, lower_program,
 )
 from cockroach_tpu.exec.operators import (
     FlowRestart, HashAggOp, JoinOp, Operator, ScanOp, ShrinkOp, SortOp, TopKOp,
@@ -123,6 +139,74 @@ class _Program(NamedTuple):
     result_cap: int
     a2a_bytes: int         # what one device sends through the exchanges
     #                        of ONE dispatch (stage dist.a2a)
+
+    def flag_ops(self, ops: list) -> Optional[list]:
+        """The restart targets of this program's flags over `ops` (a
+        tree's walk); None if the tree drifted under the fingerprint."""
+        out = []
+        for i, t in zip(self.flag_idx, self.flag_types):
+            if i >= len(ops):
+                return None
+            op = ops[i]
+            if t == _BucketGuard.__name__ and isinstance(op, JoinOp):
+                op = _BucketGuard(op)
+            if type(op).__name__ != t:
+                return None
+            out.append(op)
+        return out
+
+
+class _Exchange(NamedTuple):
+    """The buckets (rows a destination) of one BY_HASH join, as
+    DistFusedRunner._classify sized them. `probe` and `build` come from
+    lanes: one chunk of a streamed probe, and a shard's share of the
+    build subtree's scan rows. `probe_est` and `build_est` are what
+    `exchange_bucket` gives a shard's share of the planner's estimate of
+    the rows each WHOLE side sends, None where the operator carries no
+    estimate or a full bucket has sent the join back to its lanes
+    (_BucketGuard). `side_bucket` resolves the pair that is used."""
+
+    probe: int
+    build: int
+    probe_est: Optional[int] = None
+    build_est: Optional[int] = None
+
+    @property
+    def estimated(self) -> bool:
+        return self.probe_est is not None or self.build_est is not None
+
+
+def side_bucket(by_lanes: int, by_est: Optional[int], n_dev: int,
+                parts: int = 1) -> int:
+    """The bucket one side of a BY_HASH join is routed in: the one its
+    estimate gives (spread evenly over the `parts` batches a shard sends
+    the side in), never more than the one its lanes give; without an
+    estimate, the lanes'."""
+    if by_est is None:
+        return by_lanes
+    return min(by_lanes, max(exchange_bucket(0, n_dev), by_est // parts))
+
+
+class _BucketGuard(_ModeBumpGuard):
+    """FlowRestart target of a BY_HASH join's router flags where its
+    buckets were sized from the planner's estimate: a full bucket means
+    the estimate was low, and widen() sends the join back to the buckets
+    its lanes give, in one step: the program every tree without an
+    estimate runs. The attribute rides the config key through
+    _classify (no estimate: no bucket pair in the key)."""
+
+    ATTR = "_lanes_buckets"
+
+    def __init__(self, op: JoinOp):
+        super().__init__(op, self.ATTR)
+
+    def widen(self):
+        super().widen()
+        default_registry().counter(
+            "sql_distsql_bucket_restarts_total",
+            "flow restarts that sent a BY_HASH join from buckets sized by "
+            "the planner's row estimate back to the buckets its lanes "
+            "give (the estimate was low: a bucket filled)").inc()
 
 
 _PROGS: "OrderedDict[tuple, Optional[_Program]]" = OrderedDict()
@@ -202,16 +286,24 @@ class _DistTracer(_Tracer):
         self.axis = axis
         self.n_dev = n_dev
         self.sharded_scans = sharded_scans   # id(scan) of chunk-sharded
-        self.repart_ops = repart_ops         # id(join) -> bucket caps
+        self.repart_ops = repart_ops         # id(join) -> _Exchange
         # (side, id(join)) -> bytes one device sends through that side's
         # exchange in one dispatch; keyed, because a streamed probe's
         # chain is traced twice (chunk 0, then the scan body)
         self.a2a: Dict[tuple, int] = {}
+        # (side, id(join)) -> (bucket, the bucket its lanes give)
+        self.buckets: Dict[tuple, Tuple[int, int]] = {}
 
     def _note_exchange(self, side: str, op: JoinOp, batch: Batch,
                        bucket: int, times: int = 1) -> None:
         self.a2a[(side, id(op))] = times * exchange_bytes(
             batch, self.n_dev, bucket)
+
+    def _bucket(self, side: str, op: JoinOp, by_lanes: int,
+                by_est: Optional[int], parts: int = 1) -> int:
+        bucket = side_bucket(by_lanes, by_est, self.n_dev, parts)
+        self.buckets[(side, id(op))] = (bucket, by_lanes)
+        return bucket
 
     def _try_groupjoin(self, op):
         """The single-chip aggregate-over-join collapse (exec/fused.py)
@@ -230,9 +322,15 @@ class _DistTracer(_Tracer):
     #
     # The join itself is lowered in exec/fused.py, once for each form
     # (_Tracer._stream: build once, probe a chunk; _Tracer._mat_join: both
-    # sides whole). These two hooks only send a side of a BY_HASH join
-    # through the exchange first and hand back the router's overflow flag,
-    # which the lowering ORs into the join's own.
+    # sides whole). These hooks only send a side of a BY_HASH join through
+    # the exchange first and hand back the router's overflow flag. Where
+    # the buckets come from the join's lanes the lowering ORs it into the
+    # join's own; where they come from the planner's estimate it is a flag
+    # of its own, answered by _BucketGuard.
+
+    def _route_guard(self, op: JoinOp):
+        x = self.repart_ops.get(id(op))
+        return _BucketGuard(op) if x is not None and x.estimated else None
 
     def _compactable(self, op: Operator) -> bool:
         # The one line in which the mesh's join differs: a co-partitioned
@@ -247,7 +345,8 @@ class _DistTracer(_Tracer):
         if id(op) not in self.repart_ops:
             return super()._join_build(op)
         # a routed partition is not held to op.workmem (ROADMAP D3)
-        bucket = self.repart_ops[id(op)][1]
+        x = self.repart_ops[id(op)]
+        bucket = self._bucket("build", op, x.build, x.build_est)
         local = self._mat(op.build)
         self._note_exchange("build", op, local, bucket)
         return hash_repartition_local(local, tuple(op.build_on), self.axis,
@@ -257,10 +356,14 @@ class _DistTracer(_Tracer):
         if id(op) not in self.repart_ops:
             return super()._join_probe(op, cap, chunks)
         # every local chunk of a streamed probe is routed on its own, in
-        # the bucket _classify sized from the chain; a whole side in one
-        # sized from its lanes
-        bucket = (self.repart_ops[id(op)][0] if chunks
-                  else exchange_bucket(cap, self.n_dev))
+        # the bucket _classify sized from the chain's lanes, or in its
+        # share of the estimate's; a whole side in one sized from its
+        # lanes, or in the estimate's
+        x = self.repart_ops[id(op)]
+        bucket = self._bucket(
+            "probe", op,
+            x.probe if chunks else exchange_bucket(cap, self.n_dev),
+            x.probe_est, chunks or 1)
         probe_on = tuple(op.probe_on)
 
         def route(batch):
@@ -378,13 +481,7 @@ class DistFusedRunner:
                     if in_build:
                         raise Unsupported(
                             "repartitioned join nested inside a build")
-                    # a shard holds its share of the build's rows; a
-                    # probe chunk's lanes flow from the chain
-                    repart[id(op)] = (
-                        exchange_bucket(self._chain_cap(op.probe),
-                                        self.n_dev),
-                        exchange_bucket(max(1, rows // self.n_dev),
-                                        self.n_dev))
+                    repart[id(op)] = self._exchange(op, rows)
                     spine(op.build, in_build=True)
                 return  # small build: scans stay replicated (broadcast)
             for c in _children(op):
@@ -392,6 +489,28 @@ class DistFusedRunner:
 
         spine(self.root)
         return sharded, repart
+
+    def _exchange(self, op: JoinOp, build_rows: int) -> _Exchange:
+        """Size the buckets of a BY_HASH join whose build subtree scans
+        `build_rows` rows. From lanes: a shard holds its share of the
+        build's rows, and a probe chunk's lanes flow from the chain. From
+        the planner's estimate of the rows a side SENDS (`est_rows`,
+        stamped by sql/plan_compile.py; range shards of a table loaded in
+        key order are even to a chunk, so a shard's share is est_rows //
+        n_dev), unless a full bucket has already sent this join back to
+        its lanes. An estimate that sizes the build no smaller than its
+        lanes do is no estimate."""
+        n = self.n_dev
+        probe = exchange_bucket(self._chain_cap(op.probe), n)
+        build = exchange_bucket(max(1, build_rows // n), n)
+        if getattr(op, _BucketGuard.ATTR, 0):
+            return _Exchange(probe, build)
+        p_est, b_est = (
+            None if rows is None else exchange_bucket(int(rows) // n, n)
+            for rows in (_est_rows(op.probe), _est_rows(op.build)))
+        if b_est is not None and b_est >= build:
+            b_est = None
+        return _Exchange(probe, build, p_est, b_est)
 
     def describe(self, chunks: Dict[int, int]) -> List[str]:
         """EXPLAIN's distribution lines for `chunks` ({id(scan): chunk
@@ -412,13 +531,19 @@ class DistFusedRunner:
                              f"{role} ({chunks[id(op)]} chunks of "
                              f"{op.capacity} rows)")
             elif isinstance(op, JoinOp):
-                keys = ", ".join(f"{a}={b}" for a, b in
-                                 zip(op.probe_on, op.build_on))
                 if id(op) in repart:
-                    p_bucket, b_bucket = repart[id(op)]
+                    x = repart[id(op)]
+                    # an estimated probe bucket is the whole side's (a
+                    # streamed chunk takes its share of it), and no side's
+                    # passes what its lanes give (side_bucket)
+                    p, b = [
+                        f"{lanes} {side}" if est is None else
+                        f"{est} {side} (estimated {_est_rows(sub)} rows)"
+                        for side, lanes, est, sub in (
+                            ("probe", x.probe, x.probe_est, op.probe),
+                            ("build", x.build, x.build_est, op.build))]
                     how = (f"BY_HASH (all_to_all of both sides; buckets "
-                           f"of {p_bucket} probe and {b_bucket} build "
-                           f"rows a shard)")
+                           f"of {p} and {b} rows a shard)")
                 elif any(isinstance(n, ScanOp) and id(n) in sharded
                          for n in walk_operators(op.probe)):
                     how = (f"MIRROR (build of "
@@ -426,7 +551,7 @@ class DistFusedRunner:
                            f"replicated, local join)")
                 else:
                     how = "replicated (every shard joins it whole)"
-                lines.append(f"  {op.how} join on {keys}: {how}")
+                lines.append(f"  {op.how} join on {_join_keys(op)}: {how}")
         return lines
 
     def _subtree_rows(self, op, chunks) -> int:
@@ -514,9 +639,16 @@ class DistFusedRunner:
 
     # ---------------------------------------------------------- compile --
 
-    def _config_key(self, layout: Dict[int, Tuple[str, int]]):
+    def _config_key(self, layout: Dict[int, Tuple[str, int]],
+                    repart: Dict[int, _Exchange]):
         """Shape identity of one compiled program: mesh, broadcast limit,
-        and per-op pow2 buckets. `layout` maps scan id -> (role, bucket)."""
+        and per-op pow2 buckets. `layout` maps scan id -> (role, bucket);
+        `repart` is _classify's. A BY_HASH join whose buckets come from
+        the planner's estimate adds the pair the estimate gives (every
+        bucket of its program follows from the pair and the layout): two
+        states of the statistics share a program until they round to
+        different powers of two. Without an estimate, or sent back to its
+        lanes, it adds nothing."""
         out: list = [("mesh",) + mesh_key(self.mesh, self.axis),
                      ("bl", int(Settings().get(BROADCAST_LIMIT)))]
         for op in walk_operators(self.root):
@@ -524,10 +656,13 @@ class DistFusedRunner:
                 role, bucket = layout[id(op)]
                 out.append(("scan", role, int(bucket), op.capacity))
             elif isinstance(op, (JoinOp, HashAggOp)):
+                x = repart.get(id(op))
                 out.append((type(op).__name__, op.expansion, op.workmem,
                             getattr(op, "seed", 0),
                             getattr(op, "build_mode", ""),
-                            getattr(op, "_range_dense", None)))
+                            getattr(op, "_range_dense", None))
+                           + ((x.probe_est, x.build_est)
+                              if x is not None and x.estimated else ()))
             elif isinstance(op, SortOp):
                 out.append(("sort", op.workmem))
             elif isinstance(op, ShrinkOp):
@@ -551,6 +686,7 @@ class DistFusedRunner:
             box["flag_ops"] = list(t.flag_ops)
             box["result_cap"] = min(RESULT_CAP, out.capacity)
             box["a2a_bytes"] = sum(t.a2a.values())
+            box["buckets"] = dict(t.buckets)
             flags = tuple(
                 lax.psum(f.astype(jnp.int32), axis) > 0
                 for f in t.flags)
@@ -591,14 +727,32 @@ class DistFusedRunner:
                 _PROGS[pkey] = None  # negative: skip re-trace next time
                 _trim_progs()
                 raise
+            self._record_buckets(repart, box["buckets"])
         pos = {id(op): i for i, op in enumerate(ops)}
-        flag_idx = tuple(pos[id(f)] for f in box["flag_ops"])
+        flag_idx = tuple(
+            pos[id(f.op if isinstance(f, _BucketGuard) else f)]
+            for f in box["flag_ops"])
         flag_types = tuple(type(f).__name__ for f in box["flag_ops"])
         entry = _Program(compiled, flag_idx, flag_types, box["result_cap"],
                          box["a2a_bytes"])
         _PROGS[pkey] = entry
         _trim_progs()
         return entry
+
+    def _record_buckets(self, repart, buckets) -> None:
+        """One `dist.bucket` event a routed side on the `dist.compile`
+        span: the planner's estimate (None: the operator carries none),
+        the bucket the program was traced with, and the one the side's
+        lanes give (the same two after a _BucketGuard restart)."""
+        for op in walk_operators(self.root):
+            if id(op) not in repart:
+                continue
+            for side, sub in (("probe", op.probe), ("build", op.build)):
+                if (side, id(op)) in buckets:
+                    bucket, by_lanes = buckets[(side, id(op))]
+                    _tracing.record("dist.bucket", join=_join_keys(op),
+                                    side=side, est_rows=_est_rows(sub),
+                                    bucket=bucket, lanes_bucket=by_lanes)
 
     # ---------------------------------------------------------- prepare --
 
@@ -611,28 +765,27 @@ class DistFusedRunner:
         sharded, repart, images = self._materialize(scans, sources, chunks)
         layout = {id(sc): (images[id(sc)].role, images[id(sc)].bucket)
                   for sc in scans}
-        pkey = (_plan_fingerprint(self.root), self._config_key(layout))
+        pkey = (_plan_fingerprint(self.root),
+                self._config_key(layout, repart))
         ops = list(walk_operators(self.root))
         entry = _PROGS.get(pkey, _MISS)
         if entry is None:
             raise Unsupported("cached unsupported config")
-        if entry is not _MISS:
-            if any(i >= len(ops) or type(ops[i]).__name__ != t
-                   for i, t in zip(entry.flag_idx, entry.flag_types)):
-                entry = _MISS  # tree drifted under the fingerprint
-        if entry is _MISS:
+        # None: the tree drifted under the fingerprint
+        flag_ops = None if entry is _MISS else entry.flag_ops(ops)
+        if flag_ops is None:
             self._warm = False
             args = tuple((images[id(sc)].bufs, images[id(sc)].ms)
                          for sc in scans)
             entry = self._compile(pkey, scans, sharded, repart, args,
                                   layout, ops)
+            flag_ops = entry.flag_ops(ops)
         else:
             _PROGS.move_to_end(pkey)
             if self._warm:
                 # warm distributed execution: cached placement + cached
                 # executable — the whole prepare was pointer chasing
                 stats.add("dist.prime_skipped")
-        flag_ops = [ops[i] for i in entry.flag_idx]
         args = tuple((images[id(sc)].bufs, images[id(sc)].ms)
                      for sc in scans)
         return entry, flag_ops, args
@@ -657,7 +810,7 @@ class DistFusedRunner:
             ops = list(walk_operators(self.root))
             layout = {id(sc): (images[id(sc)].role, images[id(sc)].bucket)
                       for sc in scans}
-            pkey = (fp, self._config_key(layout))
+            pkey = (fp, self._config_key(layout, repart))
             if _PROGS.get(pkey, _MISS) is _MISS:
                 args = tuple((images[id(sc)].bufs, images[id(sc)].ms)
                              for sc in scans)
@@ -694,7 +847,7 @@ class DistFusedRunner:
                                              jnp.uint8, sharding=sh),
                         jax.ShapeDtypeStruct((rows,), jnp.int32,
                                              sharding=sh)))
-                pkey2 = (fp, self._config_key(layout2))
+                pkey2 = (fp, self._config_key(layout2, repart2))
                 if _PROGS.get(pkey2, _MISS) is not _MISS:
                     continue
                 try:
@@ -756,6 +909,17 @@ class DistFusedRunner:
 def _trim_progs() -> None:
     while len(_PROGS) > _PROGS_CAP:
         _PROGS.popitem(last=False)
+
+
+def _est_rows(op: Operator) -> Optional[int]:
+    """The planner's estimate of the rows `op` puts out, where
+    sql/plan_compile.py (or, for a scan, plan.build) stamped one."""
+    rows = getattr(op, "est_rows", None)
+    return None if rows is None else int(rows)
+
+
+def _join_keys(op: JoinOp) -> str:
+    return ", ".join(f"{a}={b}" for a, b in zip(op.probe_on, op.build_on))
 
 
 def _children(op):

@@ -161,10 +161,15 @@ def range_repartition_local(batch: Batch, key_name: str,
 
 
 def exchange_bucket(rows: int, n_dev: int) -> int:
-    """Rows of one destination's bucket when a device sends `rows` lanes
+    """Rows of one destination's bucket when a device sends `rows` rows
     through `hash_repartition_local`: an even spread with twice the room
-    for skew, a power of two, 64 at least. A fuller bucket raises the
-    router's overflow flag."""
+    for skew, a power of two, 64 at least. `rows` is the caller's to
+    choose: the rows it expects the side to SEND (the planner's estimate,
+    a shard's share of it; parallel/dist_flow.py) or, where nothing
+    better is known, the side's lanes, which no count of rows can pass.
+    A fuller bucket drops nothing silently: it raises the router's
+    overflow flag, and the flow restarts (from an estimate, on the
+    bucket the lanes give; dist_flow._BucketGuard)."""
     return 1 << (max(64, rows // n_dev * 2) - 1).bit_length()
 
 

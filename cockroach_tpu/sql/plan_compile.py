@@ -135,6 +135,18 @@ def _est_scan_rows(op: Operator) -> Optional[int]:
     return est if known else None
 
 
+def _stamp_estimate(op: Optional[Operator], rows: float) -> None:
+    """Leave the planner's output-row estimate of a plan node on its
+    operator as `est_rows`: what the distributed runner sizes a BY_HASH
+    join's buckets from (parallel/dist_flow.py: the rows a side sends).
+    A ScanOp keeps the table's row count that build() stamped: the
+    device-or-host routing (_est_scan_rows, operators.flow_backend) sums
+    the scans' figures and reads no other operator's."""
+    inner = _unwrap(op) if op is not None else None
+    if inner is not None and not isinstance(inner, ScanOp):
+        inner.est_rows = int(rows)
+
+
 def _wrap_mixed(root: Operator):
     """Root didn't fuse: find host-only operators (the row engine's
     RowMapOp) whose child subtree DOES fuse, and wrap that subtree in
@@ -250,6 +262,8 @@ def compile_plan(p: Plan, catalog: Catalog, capacity: int = 1 << 17,
             rows = estimate_cardinality(node, catalog)
         except Exception:
             rows = 0.0
+        else:
+            _stamp_estimate(nop, rows)
         oc = OpCost(name=name, detail=_describe(node),
                     est_rows=rows,
                     device_s=rows / TPU_ROWS_PER_S,

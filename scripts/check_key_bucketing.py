@@ -174,7 +174,7 @@ def dist_keys_by_mesh():
                                else 1)
                       for op in walk_operators(plan)
                       if isinstance(op, ScanOp)}
-            sharded, _repart = runner._classify(chunks)
+            sharded, repart = runner._classify(chunks)
             layout = {}
             for op in walk_operators(plan):
                 if not isinstance(op, ScanOp):
@@ -185,7 +185,7 @@ def dist_keys_by_mesh():
                         SHARDED, _pow2_at_least(max(1, -(-n // n_dev))))
                 else:
                     layout[id(op)] = (REPLICATED, _pow2_at_least(n))
-            keys.add(runner._config_key(layout))
+            keys.add(runner._config_key(layout, repart))
         yield n_dev, len(keys)
 
 

@@ -172,8 +172,11 @@ def main():
 
         sds = scan_shapes(scans, gen, lead)
         t0 = time.perf_counter()
-        lowered = runner._lower(scans, sharded, repart, sds, {})
+        box = {}
+        lowered = runner._lower(scans, sharded, repart, sds, box)
         report("q3_dist4", lowered, time.perf_counter() - t0,
+               # (bucket traced, the bucket the side's lanes give)
+               buckets={side: b for (side, _), b in box["buckets"].items()},
                sharded=sorted(sc.table for sc in scans
                               if id(sc) in sharded),
                roles={sc.table: (ingest.SHARDED if id(sc) in sharded

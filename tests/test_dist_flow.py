@@ -13,7 +13,7 @@ from cockroach_tpu.exec.operators import HashAggOp, JoinOp, ShrinkOp
 from cockroach_tpu.ops.agg import AggSpec
 from cockroach_tpu.parallel import make_mesh
 from cockroach_tpu.parallel.dist_flow import (
-    BROADCAST_LIMIT, DistFusedRunner, collect_distributed,
+    BROADCAST_LIMIT, DistFusedRunner, _Exchange, collect_distributed,
 )
 from cockroach_tpu.parallel.repartition import exchange_bucket
 from cockroach_tpu.util.settings import Settings
@@ -118,8 +118,8 @@ def _classified_by_hand(monkeypatch, join):
     it: both aggregates merge across the mesh, so the root joins two
     replicated sides on every device."""
     n_dev = 4
-    repart = {id(join): (exchange_bucket(256, n_dev),
-                         exchange_bucket(512 // n_dev, n_dev))}
+    repart = {id(join): _Exchange(exchange_bucket(256, n_dev),
+                                  exchange_bucket(512 // n_dev, n_dev))}
     monkeypatch.setattr(
         DistFusedRunner, "_classify",
         lambda self, chunks: ({id(join.probe), id(join.build)}, repart))
@@ -148,3 +148,178 @@ def test_join_two_parents_read_answers_as_one_chip(monkeypatch):
     names = ("fk", "sv", "k", "n")
     rows = lambda res: sorted(zip(*(res[c].tolist() for c in names)))
     assert rows(dist) == rows(local) and len(rows(dist)) > 400
+
+
+# -- buckets from the planner's estimate (ISSUE 30) --------------------------
+
+@pytest.mark.parametrize("by_lanes, by_est, parts, want", [
+    (1 << 20, None, 1, 1 << 20),      # no estimate: the lanes' bucket
+    (1 << 20, 1 << 19, 1, 1 << 19),   # an estimate: its bucket
+    (1 << 18, 1 << 19, 1, 1 << 18),   # never more than the lanes give
+    (1 << 16, 1 << 19, 16, 1 << 15),  # a streamed chunk takes its share
+    (1 << 16, 1 << 19, 64, 1 << 13),
+    (1 << 16, 1 << 9, 64, 64),        # exchange_bucket's floor
+    (1 << 16, None, 64, 1 << 16),
+])
+def test_side_bucket_rule(by_lanes, by_est, parts, want):
+    from cockroach_tpu.parallel.dist_flow import side_bucket
+
+    assert side_bucket(by_lanes, by_est, 4, parts) == want
+
+
+def _estimated_join(probe_est=None, build_est=None):
+    """-> (runner, join): _shared_join's BY_HASH join on a four-device
+    mesh, with the planner's stamp on its sides where given."""
+    root, join = _shared_join(False)
+    for side, est in ((join.probe, probe_est), (join.build, build_est)):
+        if est is not None:
+            side.est_rows = est
+    return DistFusedRunner(root, make_mesh(4)), join
+
+
+# the pair a tree without estimates gets: a probe chunk's 256 lanes, and a
+# shard's share of the build's 512 scan rows (the parent's, ISSUE 29)
+_LANES_PAIR = (exchange_bucket(256, 4), exchange_bucket(512 // 4, 4))
+
+
+@pytest.mark.parametrize("probe_est, build_est, want", [
+    (None, None, _Exchange(*_LANES_PAIR)),
+    # 4,000 rows: 1,000 a shard, 250 a destination, twice the room: 512
+    (4000, None, _Exchange(*_LANES_PAIR, 512, None)),
+    # 520 build rows: 130 a shard, 32 a destination: the floor of 64,
+    # which is what the lanes give too, so the estimate adds nothing
+    (4000, 520, _Exchange(*_LANES_PAIR, 512, None)),
+    (None, 100, _Exchange(*_LANES_PAIR)),
+    # an estimate beyond the lanes is kept for the probe (the whole side's
+    # lanes are the tracer's to know) and capped there (side_bucket)
+    (1 << 20, None, _Exchange(*_LANES_PAIR, 1 << 17, None)),
+])
+def test_exchange_is_sized_from_the_estimate_where_there_is_one(
+        probe_est, build_est, want):
+    runner, join = _estimated_join(probe_est, build_est)
+    assert runner._exchange(join, 512) == want
+    assert want.estimated == (want.probe_est is not None)
+    # a full bucket has sent the join back to its lanes: the estimates
+    # no longer count, whatever they say
+    join._lanes_buckets = 1
+    assert runner._exchange(join, 512) == _Exchange(*_LANES_PAIR)
+
+
+def test_a_build_estimate_under_the_lanes_sizes_the_build_bucket():
+    root, join = _shared_join(False)
+    join.build.est_rows = 600
+    runner = DistFusedRunner(root, make_mesh(4))
+    # 16,384 scan rows under the build: 4,096 a shard, buckets of 2,048;
+    # 600 estimated: 150 a shard, 37 a destination: 128
+    x = runner._exchange(join, 16384)
+    assert (x.build, x.build_est) == (2048, 128)
+    assert x.estimated and x.probe_est is None
+
+
+def _config_key_of(probe_est):
+    runner, join = _estimated_join(probe_est)
+    scans, _sources, chunks = runner._prime()
+    sharded, repart = runner._classify(chunks)
+    assert set(repart) == {id(join)}
+    layout = {id(sc): ("sharded" if id(sc) in sharded else "replicated", 2)
+              for sc in scans}
+    return runner._config_key(layout, repart)
+
+
+def test_config_key_carries_the_estimates_bucket_pair():
+    """Two states of the statistics share a program until their estimates
+    round to different buckets; a tree without an estimate keeps the
+    parent's key, entry for entry."""
+    s = Settings()
+    old = s.get(BROADCAST_LIMIT)
+    s.set(BROADCAST_LIMIT, 256)   # the build's 512 rows go BY_HASH
+    try:
+        bare, a, b, c = (_config_key_of(e) for e in (None, 3000, 4000, 5000))
+    finally:
+        s.set(BROADCAST_LIMIT, old)
+    joins = [[e for e in k if e[0] == "JoinOp"] for k in (bare, a, b, c)]
+    # 3,000 and 4,000 rows both give buckets of 512; 5,000 gives 1,024
+    assert joins[1] == joins[2] and a == b
+    assert joins[3] != joins[2] and c != b
+    assert [j[0][6:] for j in joins] == [(), (512, None), (512, None),
+                                         (1024, None)]
+    # without an estimate: the six entries the key had before there were any
+    assert len(joins[0][0]) == 6 and len(bare) == len(a)
+    assert [e for e in bare if e[0] != "JoinOp"] == [
+        e for e in a if e[0] != "JoinOp"]
+
+
+def test_a_low_estimate_restarts_once_on_the_lanes_buckets():
+    """The router's flag has a restart target of its own where the
+    buckets are estimated: a full bucket sends the join back to the
+    buckets its lanes give, in one restart, and the answer is the
+    single-chip one."""
+    from cockroach_tpu.parallel import dist_flow
+    from cockroach_tpu.util.metric import default_registry
+
+    want = collect(_shared_join(False)[0])
+    bucket_restarts = default_registry().counter(
+        "sql_distsql_bucket_restarts_total")
+    flow_restarts = default_registry().counter("sql_flow_restarts_total")
+    s = Settings()
+    old = s.get(BROADCAST_LIMIT)
+    s.set(BROADCAST_LIMIT, 256)
+    try:
+        # 2,048 probe rows over four shards, 128 for each destination of a
+        # shard, into buckets sized for 40 rows in all: 64
+        root, join = _shared_join(False)
+        join.probe.est_rows = 40
+        b0, f0 = bucket_restarts.value(), flow_restarts.value()
+        got = collect_distributed(root, make_mesh(4), strict=True)
+        assert bucket_restarts.value() == b0 + 1
+        assert flow_restarts.value() == f0 + 1
+        assert join._lanes_buckets == 1 and join.expansion == 1
+        estimated, lanes = dist_flow._PROGS.values()
+        assert "_BucketGuard" in estimated.flag_types
+        assert "_BucketGuard" not in lanes.flag_types
+        assert estimated.a2a_bytes < lanes.a2a_bytes
+        # the widened tree runs again without a restart or a compile
+        again = collect_distributed(root, make_mesh(4), strict=True)
+        assert bucket_restarts.value() == b0 + 1
+        assert len(dist_flow._PROGS) == 2
+    finally:
+        s.set(BROADCAST_LIMIT, old)
+    for res in (got, again):
+        order = np.argsort(res["fk"])
+        np.testing.assert_array_equal(res["fk"][order],
+                                      np.sort(want["fk"]))
+        np.testing.assert_array_equal(
+            res["sv"][order], want["sv"][np.argsort(want["fk"])])
+
+
+def test_a_sound_estimate_answers_without_a_restart():
+    """Buckets from an estimate that holds: the router's flag is a flag
+    of its own, stays down, and a cached program finds its guard again
+    on a new tree of the same plan."""
+    from cockroach_tpu.parallel import dist_flow
+    from cockroach_tpu.util.metric import default_registry
+
+    want = collect(_shared_join(False)[0])
+    bucket_restarts = default_registry().counter(
+        "sql_distsql_bucket_restarts_total")
+    s = Settings()
+    old = s.get(BROADCAST_LIMIT)
+    s.set(BROADCAST_LIMIT, 256)
+    try:
+        b0 = bucket_restarts.value()
+        for _ in range(2):          # the second tree hits the program cache
+            root, join = _shared_join(False)
+            join.probe.est_rows = 1100   # 275 a shard: buckets of 256
+            res = collect_distributed(root, make_mesh(4), strict=True)
+            order = np.argsort(res["fk"])
+            np.testing.assert_array_equal(
+                res["sv"][order], want["sv"][np.argsort(want["fk"])])
+            assert getattr(join, "_lanes_buckets", 0) == 0
+        (prog,) = dist_flow._PROGS.values()
+        assert prog.flag_types.count("_BucketGuard") == 1
+        guards = [f for f in prog.flag_ops(list(dist_flow.walk_operators(root)))
+                  if isinstance(f, dist_flow._BucketGuard)]
+        assert [g.op for g in guards] == [join]
+        assert bucket_restarts.value() == b0
+    finally:
+        s.set(BROADCAST_LIMIT, old)

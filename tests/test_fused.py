@@ -292,7 +292,8 @@ def _mesh_jaxpr(root, n_dev, limit):
         s.set(dist_flow.BROADCAST_LIMIT, old)
 
 
-@pytest.mark.parametrize("program", ["compact", "two_step", "mesh"])
+@pytest.mark.parametrize("program", ["compact", "two_step", "mesh",
+                                     "mesh_lanes"])
 def test_q3_program_sorts_per_join(program, monkeypatch):
     """Per join of Q3's fused program: exactly two sorts at lcap + rcap
     lanes, the key sort and the compaction's single-operand sort, none
@@ -302,7 +303,10 @@ def test_q3_program_sorts_per_join(program, monkeypatch):
     (the mesh cell's program at this scale) the local semi join compacts
     and the BY_HASH inner join takes the two steps
     (_DistTracer._compactable), behind the router's two destination
-    sorts, which carry a side's lanes as operands."""
+    sorts, which carry a side's lanes as operands; the join's lanes
+    follow the buckets, which the planner's estimates size (ISSUE 30);
+    without estimates (`mesh_lanes`: a tree built by hand carries none)
+    they are the buckets the lanes give, and the program the parent's."""
     from cockroach_tpu.exec.operators import ScanOp, walk_operators
     from cockroach_tpu.sql.bind import plan_sql
     from cockroach_tpu.sql.plan_compile import compile_plan
@@ -328,12 +332,16 @@ def test_q3_program_sorts_per_join(program, monkeypatch):
                             lambda self, op: False)
     cp = compile_plan(plan_sql(Q3_SQL, cat), cat, 1 << 14, sql=Q3_SQL,
                       setting="tpu")
-    if program == "mesh":
+    if program.startswith("mesh"):
         if len(jax.devices()) < 4:
             pytest.skip("needs four virtual CPU devices")
+        if program == "mesh_lanes":
+            for op in walk_operators(cp.op):
+                if not isinstance(op, ScanOp):
+                    del op.est_rows
         # lineitem's four chunks shard one to a device; orders + customer
         # (two chunks) are over the limit, so the inner join goes BY_HASH
-        jaxpr, _flag_ops = _mesh_jaxpr(cp.op, 4, 1 << 14)
+        jaxpr, flag_ops = _mesh_jaxpr(cp.op, 4, 1 << 14)
     else:
         _prog, args = cp.runner._prepare()
         compacted.clear()
@@ -342,7 +350,7 @@ def test_q3_program_sorts_per_join(program, monkeypatch):
         prog, _box = cp.runner._make_prog([id(s) for s in scans])
         jaxpr = jax.make_jaxpr(prog)(*args)
     sorts = _sorts(jaxpr.jaxpr)
-    if program == "mesh":
+    if program.startswith("mesh"):
         # the semi join orders x customer is local to a shard (16,384
         # lanes a side) and compacts: key sort and compaction sort
         assert compacted == [(16384, 16384)]
@@ -351,16 +359,25 @@ def test_q3_program_sorts_per_join(program, monkeypatch):
         # the router: one stable sort by destination a side, carrying the
         # side's four column lanes as operands: a shard's 16,384 lineitem
         # lanes into buckets of 8,192, its 4,096 shrunk orders lanes into
-        # buckets of 4,096
+        # buckets of 4,096 by their lanes. The planner expects 32,851
+        # lineitem rows to pass the date (8,212 a shard, 2,053 a
+        # destination: still 8,192) and 1,439 orders (359 a shard, 89 a
+        # destination): buckets of 256
         assert sorts.count((16384, "int32", 5)) == 1
         assert sorts.count((4096, "int32", 5)) == 1
-        # so the inner join sees 4 x 8,192 probe and 4 x 4,096 build
-        # lanes, and takes the two steps: key sort, resort to probe order,
-        # and the Shrink's argsort over the probe lanes
-        assert two_step == [(32768, 16384)]
-        assert sorts.count((49152, "uint32", 2)) == 1
-        assert sorts.count((49152, "int32", 2)) == 1
+        build_bucket = {"mesh": 256, "mesh_lanes": 4096}[program]
+        # so the inner join sees 4 x 8,192 probe and 4 x 256 (or 4,096)
+        # build lanes, and takes the two steps: key sort, resort to probe
+        # order, and the Shrink's argsort over the probe lanes
+        n = 4 * 8192 + 4 * build_bucket
+        assert two_step == [(32768, 4 * build_bucket)]
+        assert sorts.count((n, "uint32", 2)) == 1
+        assert sorts.count((n, "int32", 2)) == 1
         assert sorts.count((32768, "bool", 2)) == 1
+        # estimated buckets give the router's flag a restart target of its
+        # own, ahead of the join's
+        guards = [type(f).__name__ for f in flag_ops].count("_BucketGuard")
+        assert guards == {"mesh": 1, "mesh_lanes": 0}[program]
         return
     assert (len(compacted), len(two_step)) == {
         "compact": (2, 0), "two_step": (0, 2)}[program]

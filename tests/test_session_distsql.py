@@ -44,15 +44,26 @@ N_DEV = 4
 Q3 = manifest.cell("tpch-sf1.q3-1stream")["statements"][0]["sql"]
 Q1 = manifest.cell("tpch-sf1.q1-2streams")["statements"][0]["sql"]
 # what one device sends through Q3's exchanges in one dispatch at this
-# scale, by hand: lineitem's 15 chunks shard 4 to a device (16,384 lanes),
-# so the probe's bucket is pow2(16384 // 4 * 2) = 8192 rows; the build
-# (orders' 4 chunks + customer's 1 = 20,480 rows, 5,120 a device) gets
-# pow2(5120 // 4 * 2) = 4096; three of a device's four buckets leave it.
+# scale, by hand. From lanes (ISSUE 27): lineitem's 15 chunks shard 4 to a
+# device (16,384 lanes), so the probe's bucket is pow2(16384 // 4 * 2) =
+# 8192 rows; the build (orders' 4 chunks + customer's 1 = 20,480 rows,
+# 5,120 a device) gets pow2(5120 // 4 * 2) = 4096. From the planner's
+# estimates (ISSUE 30), which the loader's statistics give and which are
+# what runs: 32,278 lineitem rows pass the date, 8,069 a shard, so
+# pow2(8069 // 4 * 2) = 4096; 1,458 orders reach the join, 364 a shard, so
+# pow2(364 // 4 * 2) = 256. Three of a device's four buckets leave it.
 # A probe row: l_orderkey, l_extendedprice, l_discount (8 bytes each),
 # l_shipdate (4) and the selection lane (1); a build row: o_orderkey,
 # o_custkey, o_shippriority (8 each), o_orderdate (4), selection (1).
-Q3_A2A_BYTES = (N_DEV - 1) * (8192 * (8 + 8 + 8 + 4 + 1)
-                              + 4096 * (8 + 8 + 8 + 4 + 1))
+Q3_EST = dist_flow._Exchange(probe=2048, build=4096,   # a probe CHUNK's
+                             probe_est=4096, build_est=256)
+Q3_A2A_BYTES = (N_DEV - 1) * (4096 * (8 + 8 + 8 + 4 + 1)
+                              + 256 * (8 + 8 + 8 + 4 + 1))
+Q3_A2A_BYTES_LANES = (N_DEV - 1) * (8192 * (8 + 8 + 8 + 4 + 1)
+                                    + 4096 * (8 + 8 + 8 + 4 + 1))
+Q3_BY_HASH = ("  inner join on l_orderkey=o_orderkey: BY_HASH (all_to_all "
+              "of both sides; buckets of 4096 probe (estimated 32278 rows) "
+              "and 256 build (estimated 1458 rows) rows a shard)")
 
 WARM_TREE = {
     "wire.statement": ["wire.decode", "session.execute", "wire.render",
@@ -154,7 +165,7 @@ def test_q3s_big_join_is_repartitioned_and_the_semi_join_is_local(catalog):
     joins = [op for op in walk_operators(prep.op) if isinstance(op, JoinOp)]
     assert [(j.how, id(j) in repart) for j in joins] == [
         ("inner", True), ("semi", False)]
-    assert repart[id(joins[0])] == (2048, 4096)   # per streamed chunk
+    assert repart[id(joins[0])] == Q3_EST
     assert len(sharded) == 2                       # lineitem and orders
     images = {img.role: img for img in dist_flow.ingest._CACHE.values()}
     li = images[dist_flow.ingest.SHARDED]
@@ -466,8 +477,7 @@ def test_explain_shows_shards_routers_and_placements(catalog):
     assert kind == "explain"
     at = lines.index("distribution: full (4 shards, mesh axis 'x')")
     assert lines[at + 1:] == [
-        "  inner join on l_orderkey=o_orderkey: BY_HASH (all_to_all of "
-        "both sides; buckets of 2048 probe and 4096 build rows a shard)",
+        Q3_BY_HASH,
         "  scan lineitem: sharded (15 chunks of 4096 rows)",
         "  semi join on o_custkey=c_custkey: MIRROR (build of 4096 rows "
         "replicated, local join)",
@@ -477,6 +487,64 @@ def test_explain_shows_shards_routers_and_placements(catalog):
     sess.execute("set distsql = off")
     assert not any(ln.startswith("distribution:")
                    for ln in sess.execute("explain " + Q3)[1])
+
+
+def test_explain_under_always_prints_the_estimated_buckets(catalog):
+    sess = _session(catalog, "set distsql = always")
+    lines = sess.execute("explain " + Q3)[1]
+    assert Q3_BY_HASH in lines
+    assert not dist_flow._PROGS and not dist_flow.ingest._CACHE
+
+
+# ---------------------------------------- a low estimate (ISSUE 30) ----
+
+def test_a_low_estimate_restarts_once_and_answers_exactly(
+        tpch, catalog, monkeypatch):
+    """The planner's estimates forced far too low (40 rows a side):
+    buckets of 64, which fill. The router's flag restarts the flow ONCE,
+    on the buckets the lanes give (the program of ISSUE 27, by its
+    exchanged bytes), the rows are the reference's, and the counter of
+    such restarts moves by one; the prepared statement stays there."""
+    from cockroach_tpu.sql import plan_compile
+
+    real = plan_compile.estimate_cardinality
+    monkeypatch.setattr(plan_compile, "estimate_cardinality",
+                        lambda node, cat: min(real(node, cat), 40.0))
+    reg = default_registry()
+    bucket = reg.counter("sql_distsql_bucket_restarts_total")
+    flow = reg.counter("sql_flow_restarts_total")
+    b0, f0 = bucket.value(), flow.value()
+    col = stats.enable()
+    sess = _session(catalog, "set distsql = always")
+    dist, root = _run(sess, Q3)
+    assert root.tags["tier"] == "dist"
+    want = tpch_q3.Reference(tpch["data"], tpch["dicts"], {}).answer()
+    got = list(zip(dist["l_orderkey"].tolist(), dist["revenue"].tolist(),
+                   dist["o_orderdate"].tolist(),
+                   dist["o_shippriority"].tolist()))
+    assert got == want and len(got) == 10
+    assert (bucket.value(), flow.value()) == (b0 + 1, f0 + 1)
+    restarts = [(tags["n"], tags["op"]) for s in root.walk()
+                for _t, msg, tags in s.events if msg == "flow.restart"]
+    assert restarts == [(1, "_BucketGuard")]
+    # two programs: the estimate's and, after the restart, the lanes'
+    assert _events(col, "dist.compile") == 2
+    chosen = [[(tags["side"], tags["est_rows"], tags["bucket"],
+                tags["lanes_bucket"])
+               for _t, msg, tags in s.events if msg == "dist.bucket"]
+              for s in root.walk() if s.name == "dist.compile"]
+    assert chosen == [
+        [("probe", 40, 64, 8192), ("build", 40, 64, 4096)],
+        [("probe", 40, 8192, 8192), ("build", 40, 4096, 4096)]]
+    assert sorted(p.a2a_bytes for p in dist_flow._PROGS.values()) == [
+        (N_DEV - 1) * 2 * 64 * 29, Q3_A2A_BYTES_LANES]
+    assert col.stages["dist.a2a"].events == 2
+    # the prepared tree keeps the lanes' buckets: no restart, no compile
+    again, root = _run(sess, Q3)
+    _same(dist, again)
+    assert (bucket.value(), flow.value()) == (b0 + 1, f0 + 1)
+    assert _events(col, "dist.compile") == 2
+    assert "sql_distsql_bucket_restarts_total" in reg.export_prometheus()
 
 
 # ------------------------------------------------- spans and counters ----
