@@ -501,9 +501,15 @@ def phase_ycsb_and_write(obs, store, seed: int, n_rows: int,
         scan_sql = ("select ycsb_key, " + ", ".join(fields)
                     + " from usertable where ycsb_key >= $1 "
                     "order by ycsb_key limit %d" % YCSB_SCAN_LEN)
-        # the same bind twice: the BOUND text is the prepared cache's key
-        # and the key is baked into the program, so every new start key
-        # is another cold run (70-85 s on the chip, CHANGES.md PR 22)
+        # the same bind twice. Since PR 31 `$1` stays a value: the binder
+        # types it from `ycsb_key`, the prepared entry is keyed on the
+        # parameterised text, and the start key is an argument of the
+        # statement's one program (Session.bind_params, counter
+        # sql_bind_params_total), so a new start key is no longer another
+        # cold run (it was: 70-85 s on the chip, CHANGES.md PR 22). The
+        # workload's own `LIMIT n` with n drawn per request is still
+        # outside that scope (`LIMIT $2` binds as text, counter
+        # sql_bind_textual_total; ROADMAP R2 waits for it and for M5)
         start = int(rng.integers(0, int(pks[-1]) - 3 * YCSB_SCAN_LEN))
         forced = place(wire_ask(a), scan_sql.replace("$1", str(start)),
                        coster_stale)

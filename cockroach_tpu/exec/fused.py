@@ -63,6 +63,7 @@ from cockroach_tpu.ops.agg import (
     _identity as _agg_identity, dense_aggregate, dense_merge,
     hash_aggregate,
 )
+from cockroach_tpu.ops import expr as _expr
 from cockroach_tpu.ops.sort import _sortable_int
 from cockroach_tpu.ops.vector import distance_fn
 from cockroach_tpu.ops.join import (
@@ -242,6 +243,16 @@ def _join_flags(guard, b_ovf, p_ovf, own) -> tuple:
 def _flag_targets(guard, op: JoinOp) -> list:
     """The operators behind _join_flags' flags, in its order."""
     return [op] if guard is None else [guard, op]
+
+
+def takes_params(root: Operator) -> bool:
+    """Does a filter or projection under `root` read a bound parameter
+    (ops/expr.Param)? Such a tree's program takes the statement's bound
+    values as arguments, after the scan images."""
+    return any(_expr.has_params([payload if kind == "filter"
+                                 else [e for _n, e in payload]
+                                 for kind, payload in op.steps])
+               for op in walk_operators(root) if isinstance(op, MapOp))
 
 
 def _shared_ops(root: Operator) -> set:
@@ -892,6 +903,24 @@ class FusedRunner:
         # the same thread) must not self-deadlock.
         self._mu = threading.RLock()
         self._served_once = False
+        # a tree that reads bound parameters: the program takes them as
+        # one more argument (the statement's int64 vector of (value,
+        # valid) pairs, the same shape at every binding), so program,
+        # config key and persistent-cache entry belong to the statement
+        # and not to a binding
+        self._takes_params = takes_params(root)
+
+    def _bound_args(self) -> tuple:
+        """The bound values of the statement this thread is running
+        (ops/expr.bound_args), as the program's trailing argument; () for
+        a tree without parameters."""
+        if not self._takes_params:
+            return ()
+        bound = _expr.current_args()
+        if bound is None:
+            raise _expr.ParamOutsideProgram(
+                "a parameterised plan was run with no values bound")
+        return (bound,)
 
     @staticmethod
     def _warm_key(scans) -> Optional[tuple]:
@@ -994,10 +1023,15 @@ class FusedRunner:
         data-driven prepare path and the abstract-shape AOT ladder."""
         tracer_box: dict = {}
         schema = self.schema
+        n_scans = len(scan_ids)
 
         def prog(*stacked_args):
             t = _Tracer(dict(zip(scan_ids, stacked_args)), self.root)
-            out = t._mat(self.root)
+            # a parameterised tree: the bound values are the argument
+            # after the images, and its filters read them while traced
+            bound = stacked_args[n_scans] if self._takes_params else None
+            with _expr.traced_params(bound):
+                out = t._mat(self.root)
             tracer_box["flag_ops"] = list(t.flag_ops)
             # the packed window never exceeds the result's own static
             # capacity — a 12-lane aggregate reads back ~1 KB, not MBs
@@ -1071,10 +1105,12 @@ class FusedRunner:
             return self._progs[key], args
         if key not in self._progs:
             prog, tracer_box = self._make_prog(scan_ids)
+            bound = self._bound_args()
 
             def build():
                 maybe_fail("fused.compile")
-                return self._vault_compile(lower_program(prog, args))
+                return self._vault_compile(
+                    lower_program(prog, args + bound))
 
             with stats.timed("fused.compile"):
                 # trace + compile eagerly so Unsupported surfaces here
@@ -1126,7 +1162,8 @@ class FusedRunner:
                      jax.ShapeDtypeStruct(
                         (chunks[sid],) + tuple(a[1].shape[1:]),
                         a[1].dtype))
-                    for sid, a in zip(scan_ids, args))
+                    for sid, a in zip(scan_ids, args)) \
+                    + self._bound_args()
 
                 def build(prog=prog, sds=sds):
                     maybe_fail("fused.compile")
@@ -1175,6 +1212,8 @@ class FusedRunner:
                 "fused fallback -> streaming (unsupported: {})", e)
             yield from self.root.batches()
             return
+        bound = self._bound_args()
+
         def dispatch():
             _cancel.checkpoint()
             maybe_fail("fused.exec")
@@ -1182,7 +1221,7 @@ class FusedRunner:
             # is enqueueing; after that it waits for the device, which may
             # first finish another session's program
             with stats.timed("fused.dispatch"):
-                out = prog(*args)
+                out = prog(*args, *bound)
             # block: without the sync the dispatch returns immediately
             # and the device's execution time is billed to
             # fused.readback; readback measures only the transfer

@@ -2089,6 +2089,9 @@ def _run_flow_inner(op: Operator, reset: Callable[[], None],
 
     reg = default_registry()
     reg.counter("sql_queries_total", "queries run by the flow driver").inc()
+    # registered with the first flow, so that a reader of the registry
+    # can tell "no restart yet" (0) from "this program has no such counter"
+    reg.counter("sql_flow_restarts_total", "deferred-flag flow restarts")
     q_hist = reg.histogram("sql_query_seconds",
                            "end-to-end query wall time")
     t_start = time.perf_counter()

@@ -14,7 +14,12 @@ Semantics follow SQL:
   / produces float32 (exact decimal division is a planner rewrite);
 - strings are dictionary codes; predicates against literals are resolved
   host-side through the schema's dictionary (equality -> code compare,
-  LIKE -> boolean lookup table indexed by code).
+  LIKE -> boolean lookup table indexed by code);
+- a bound parameter (`Param`) is an ARGUMENT of the program that
+  evaluates it (an entry of the statement's one packed vector), never a
+  constant of it: the program that takes the
+  arguments opens `traced_params` around its trace, and outside one a
+  `Param` refuses to evaluate (ParamOutsideProgram).
 
 Dates are int32 days since epoch; EXTRACT uses the standard civil-calendar
 integer algorithm so it stays on device.
@@ -22,7 +27,9 @@ integer algorithm so it stays on device.
 
 from __future__ import annotations
 
+import contextlib
 import re
+import threading
 from dataclasses import dataclass
 from typing import Optional, Sequence, Tuple
 
@@ -94,6 +101,76 @@ class Lit(Expr):
         if isinstance(v, str):
             return STRING
         raise TypeError(f"cannot type literal {v!r}")
+
+
+@dataclass(frozen=True, eq=False)
+class Param(Expr):
+    """A value bound at execution (pgwire Bind, sql/params.py): slot
+    `index` of the statement's bound arguments, already in the device's
+    representation of `ty` (days, scaled integer, dictionary code).
+    `sample` is the slot's value at the binding the plan was made at, in
+    a Lit's units: the planner's estimates read it (sql/stats.py) and no
+    program ever does."""
+
+    index: int
+    ty: ColType
+    sample: object = None
+
+    def type(self, schema):
+        return self.ty
+
+
+class ParamOutsideProgram(Exception):
+    """A Param was evaluated by a program that does not take the bound
+    values as arguments (the streaming operators' own jits, the
+    distributed runner): tracing on would bake this binding's value into
+    a program that serves every binding. The session answers by binding
+    the statement as text (sql/session.py)."""
+
+
+_params = threading.local()
+
+
+@contextlib.contextmanager
+def _frame(slot: str, values):
+    prev = getattr(_params, slot, None)
+    setattr(_params, slot, values)
+    try:
+        yield
+    finally:
+        setattr(_params, slot, prev)
+
+
+def bound_args(values):
+    """The statement's bound arguments for the runs inside the block: the
+    int64 vector of (value, valid) pairs, two entries a slot, that
+    sql/params.evaluate packs; what a program that takes them
+    (exec/fused.FusedRunner) is called with."""
+    return _frame("args", values)
+
+
+def current_args():
+    return getattr(_params, "args", None)
+
+
+def traced_params(values):
+    """Inside the trace of a program whose argument it is: the packed
+    vector eval_expr unpacks a Param from."""
+    return _frame("traced", values)
+
+
+def has_params(e) -> bool:
+    """Does the expression (or tuple of them) hold a Param?"""
+    import dataclasses
+
+    if isinstance(e, Param):
+        return True
+    if isinstance(e, (tuple, list)):
+        return any(has_params(x) for x in e)
+    if isinstance(e, Expr) and dataclasses.is_dataclass(e):
+        return any(has_params(getattr(e, f.name))
+                   for f in dataclasses.fields(e))
+    return False
 
 
 @dataclass(frozen=True, eq=False)
@@ -328,6 +405,25 @@ def eval_expr(expr: Expr, batch: Batch, schema: Schema) -> Column:
             raise ValueError("string literals must appear inside Cmp/InList/Like")
         return Column(jnp.full((cap,), v, dtype=ty.dtype))
 
+    if isinstance(expr, Param):
+        traced = getattr(_params, "traced", None)
+        if traced is None:
+            raise ParamOutsideProgram(
+                f"parameter slot {expr.index} evaluated outside a program "
+                "that takes the bound values as arguments")
+        # the statement's one int64 vector of (value, valid) pairs
+        # (sql/params.evaluate); a float32 rides as its bit pattern
+        raw, valid = traced[2 * expr.index], traced[2 * expr.index + 1] != 0
+        if expr.ty.kind is Kind.FLOAT:
+            import jax as _jax
+
+            value = _jax.lax.bitcast_convert_type(raw.astype(jnp.int32),
+                                                  jnp.float32)
+        else:
+            value = raw.astype(expr.ty.dtype)
+        return Column(jnp.full((cap,), value, dtype=expr.ty.dtype),
+                      jnp.full((cap,), valid, dtype=jnp.bool_))
+
     if isinstance(expr, BinOp):
         lt, rt = expr.left.type(schema), expr.right.type(schema)
         lc = eval_expr(expr.left, batch, schema)
@@ -372,6 +468,14 @@ def eval_expr(expr: Expr, batch: Batch, schema: Schema) -> Column:
 
     if isinstance(expr, Cmp):
         lt, rt = expr.left.type(schema), expr.right.type(schema)
+        if lt.kind is Kind.STRING and isinstance(expr.right, Param):
+            # a bound string arrives as its dictionary code (looked up on
+            # the host at bind time; -1: absent, equal to no row)
+            lc = eval_expr(expr.left, batch, schema)
+            rc = eval_expr(expr.right, batch, schema)
+            vals = lc.values == rc.values
+            return Column(~vals if expr.op == "!=" else vals,
+                          _combine_validity(lc, rc))
         # string vs literal: compare dictionary codes
         if lt.kind is Kind.STRING and isinstance(expr.right, Lit):
             col = _find_string_col(expr.left)

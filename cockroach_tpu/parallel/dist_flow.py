@@ -88,6 +88,7 @@ from cockroach_tpu.exec import stats
 from cockroach_tpu.exec.fused import (
     RESULT_CAP, HBMExceeded, Unsupported, _ModeBumpGuard, _Tracer,
     _pack_result, _unpack_result, compile_via_vault, lower_program,
+    takes_params,
 )
 from cockroach_tpu.exec.operators import (
     FlowRestart, HashAggOp, JoinOp, Operator, ScanOp, ShrinkOp, SortOp, TopKOp,
@@ -460,7 +461,18 @@ class DistFusedRunner:
     # correct sharded result; a sharded build is only correct through the
     # explicit repartition path — nested repartition inside a build is
     # rejected (falls back to single-chip).
+    def _refuse_params(self) -> None:
+        """The shard_map program has no argument for a statement's bound
+        values yet (replicated scalars beside the images), and tracing
+        one in would bake a binding into a program that serves all: the
+        single-chip ladder answers, whose fused program takes them (0A000
+        under distsql = always). Asked before any scan is primed."""
+        if takes_params(self.root):
+            raise Unsupported("bound parameters are not arguments of the "
+                              "distributed program")
+
     def _classify(self, chunks: Dict[int, int]):
+        self._refuse_params()
         limit = Settings().get(BROADCAST_LIMIT)
         sharded: set = set()
         repart: dict = {}
@@ -866,6 +878,7 @@ class DistFusedRunner:
         outer on the sharded spine, a repartition nested in a build, an
         empty scan, more rows than the packed result window): the caller
         decides what answers instead (collect_distributed)."""
+        self._refuse_params()
         with stats.timed("dist.prepare"):
             prog, flag_ops, args = self._prepare()
         compiled, a2a_bytes = prog.compiled, prog.a2a_bytes

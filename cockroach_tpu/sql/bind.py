@@ -22,12 +22,22 @@ them against the other operand (DECIMAL(s) columns make `0.05` a
 scale-s scaled integer — ops/expr.py evaluates `Lit(v, DECIMAL(s))` as
 `round(v*10^s)`), and DATE +- INTERVAL folds at bind time so the device
 only ever sees int day comparisons.
+
+Parameters: a constant subexpression over `$n` (`$1`, `$1 + interval '1'
+year`, `$2 - 0.01`) takes its type where a literal would, from the operand
+beside it in a comparison, BETWEEN or + - * arithmetic, and becomes a
+`Param` naming a slot of `Binder.param_slots` (sql/params.py evaluates the
+slots per Bind). One that finds no typed operand (a projection, `$1 =
+$2`, IN, LIKE, string ordering) raises ParamOutOfScope: the session then
+binds that statement as text. The values the binder is given are the
+binding its estimates are taken at; no value enters the plan's programs.
 """
 
 from __future__ import annotations
 
 import datetime
 from dataclasses import dataclass, field as dc_field
+from decimal import Decimal
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from cockroach_tpu.coldata.batch import (
@@ -36,10 +46,13 @@ from cockroach_tpu.coldata.batch import (
 from cockroach_tpu.ops.agg import AggSpec
 from cockroach_tpu.ops.expr import (
     BinOp, BoolOp, Case, Cast, Cmp, Col, Expr, Extract, InList, IsNull,
-    Like, Lit, Not, VecDistance, VecLit,
+    Like, Lit, Not, Param, VecDistance, VecLit,
 )
 from cockroach_tpu.ops.sort import SortKey
 from cockroach_tpu.sql import parser as P
+from cockroach_tpu.sql.params import (
+    ParamOutOfScope, ParamSlot, date_add, is_param_const, sample_of,
+)
 from cockroach_tpu.sql.plan import (
     Aggregate, Catalog, Distinct, Filter, Join, Limit, OrderBy, Plan,
     Project, Scan, VectorTopK, _plan_columns,
@@ -86,30 +99,24 @@ _CAST_TYPES = {
 
 
 def _fold_dates(node: P.Node) -> P.Node:
-    """Constant-fold DATE +- INTERVAL into a DateLit, recursing through
-    the whole AST (bind-time calendar arithmetic; the device never sees
-    intervals)."""
+    """Constant-fold DATE +- INTERVAL into a DateLit and arithmetic of two
+    numeric literals into one, recursing through the whole AST (bind-time
+    calendar and decimal arithmetic; the device never sees intervals)."""
     if isinstance(node, P.Binary):
         left = _fold_dates(node.left)
         right = _fold_dates(node.right)
         if (node.op in ("+", "-") and isinstance(left, P.DateLit)
                 and isinstance(right, P.IntervalLit)):
-            base = datetime.date(1970, 1, 1) + datetime.timedelta(left.days)
             n = right.n if node.op == "+" else -right.n
-            if right.unit == "day":
-                d = base + datetime.timedelta(days=n)
-            else:
-                months = n * (12 if right.unit == "year" else 1)
-                total = base.year * 12 + (base.month - 1) + months
-                y, m = divmod(total, 12)
-                # clamp day to target month length
-                for day in range(base.day, 0, -1):
-                    try:
-                        d = datetime.date(y, m + 1, day)
-                        break
-                    except ValueError:
-                        continue
-            return P.DateLit((d - datetime.date(1970, 1, 1)).days)
+            return P.DateLit(date_add(left.days, n, right.unit))
+        if (node.op in ("+", "-", "*") and isinstance(left, P.Num)
+                and isinstance(right, P.Num)):
+            # literal arithmetic is exact, as a parameter's is
+            # (sql/params._fold): `0.09 + 0.01` reaches a DECIMAL column
+            # as 0.10, not as a float32 sum just under it
+            a, b = Decimal(left.text), Decimal(right.text)
+            return P.Num(format(a + b if node.op == "+" else
+                                a - b if node.op == "-" else a * b, "f"))
         return P.Binary(node.op, left, right)
     if isinstance(node, P.Unary):
         return P.Unary(node.op, _fold_dates(node.arg))
@@ -155,13 +162,41 @@ class _Edge:
     pairs: List[Tuple[str, str]]  # (a-side col, b-side col)
 
 
+@dataclass(frozen=True, eq=False)
+class _OpenParam(Expr):
+    """A constant subexpression over `$n` on its way to the operand that
+    types it (Binder._retype closes it into a Param)."""
+
+    node: P.Node
+
+    def type(self, schema):
+        raise ParamOutOfScope(
+            "a parameter stands where no operand beside it gives its type")
+
+
+_PARAM_KINDS = (Kind.DATE, Kind.INT, Kind.DECIMAL, Kind.STRING, Kind.FLOAT)
+
+
 class Binder:
-    def __init__(self, catalog: Catalog):
+    def __init__(self, catalog: Catalog, params: Optional[Sequence] = None):
         self.catalog = catalog
+        # the values of the binding this plan is made at (estimates read
+        # them through Param.sample), or None: a `$n` is then unbound
+        self.params = params
+        self.param_slots: List[ParamSlot] = []
+        self._open_params = 0
 
     # ---------------------------------------------------------------- bind
 
     def bind(self, stmt: P.SelectStmt) -> Plan:
+        plan = self._bind_select(stmt)
+        if self._open_params:
+            raise ParamOutOfScope(
+                "a parameter stands where no operand beside it gives its "
+                "type")
+        return plan
+
+    def _bind_select(self, stmt: P.SelectStmt) -> Plan:
         # -- resolve FROM tables ------------------------------------------
         rels: Dict[str, _Rel] = {}
         schemas: Dict[str, Schema] = {}
@@ -305,6 +340,12 @@ class Binder:
 
     def _bx(self, node: P.Node, refs: Set[str], allow_agg: bool,
             aggs) -> Expr:
+        if is_param_const(node):
+            if self.params is None:
+                raise BindError("the statement has parameters and no "
+                                "values were bound")
+            self._open_params += 1
+            return _OpenParam(node)
         if isinstance(node, P.ColRef):
             return self._col(node, refs)
         if isinstance(node, P.Num):
@@ -341,8 +382,19 @@ class Binder:
                 return self._bind_vec_distance(node.op, left, right)
             left, right = self._retype(left, right)
             if node.op in ("+", "-", "*", "/"):
+                if node.op == "/" and (isinstance(left, Param)
+                                       or isinstance(right, Param)):
+                    raise ParamOutOfScope("a parameter under division")
                 return BinOp(node.op, left, right)
             op = {"=": "==", "<>": "!=", "!=": "!="}.get(node.op, node.op)
+            if isinstance(left, Param) and left.ty.kind is Kind.STRING:
+                # the code compare reads the column on the left
+                left, right = right, left
+                op = {"<": ">", "<=": ">=", ">": "<", ">=": "<="}.get(op, op)
+            if (isinstance(right, Param) and right.ty.kind is Kind.STRING
+                    and op not in ("==", "!=")):
+                raise ParamOutOfScope("a string parameter under an "
+                                      "ordering comparison")
             return Cmp(op, left, right)
         if isinstance(node, P.Between):
             arg = self._bx(node.arg, refs, allow_agg, aggs)
@@ -479,6 +531,8 @@ class Binder:
         (DECIMAL columns make `0.05` an exact scaled integer)."""
 
         def fix(lit: Expr, other: Expr) -> Expr:
+            if isinstance(lit, _OpenParam):
+                return self._close_param(lit, other)
             if not isinstance(lit, Lit):
                 return lit
             try:
@@ -505,6 +559,34 @@ class Binder:
             return lit
 
         return fix(left, right), fix(right, left)
+
+    def _close_param(self, open_: _OpenParam, other: Expr) -> Param:
+        """Type a parameter expression from the operand beside it, as
+        _retype types a literal: a new slot of the statement."""
+        if isinstance(other, (_OpenParam, Lit)):
+            raise ParamOutOfScope("a parameter compared with a constant")
+        try:
+            ty = other.type(self._global)
+        except (KeyError, ValueError, TypeError):
+            raise ParamOutOfScope("the operand beside a parameter has no "
+                                  "type") from None
+        column = other.name if isinstance(other, Col) else None
+        if ty.kind not in _PARAM_KINDS or (ty.kind is Kind.STRING
+                                           and column is None):
+            raise ParamOutOfScope(f"a parameter of type {ty!r}")
+        self._open_params -= 1
+        slot = ParamSlot(len(self.param_slots), open_.node, ty,
+                         column if ty.kind is Kind.STRING else None,
+                         self._global)
+        for seen in self.param_slots:
+            # `$2` beside two date columns is one argument
+            if (seen.ty, seen.column, seen.node) == (ty, slot.column,
+                                                     slot.node):
+                slot = seen
+                break
+        else:
+            self.param_slots.append(slot)
+        return Param(slot.index, ty, sample_of(slot, self.params))
 
     def _split_and(self, node: P.Node) -> List[P.Node]:
         if isinstance(node, P.Binary) and node.op == "and":

@@ -136,15 +136,22 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
                       mesh=None, ast=None,
                       op_sink=None,
                       setting: str = "auto",
-                      strict: bool = False) -> Tuple[str, object, object]:
+                      strict: bool = False,
+                      params=None) -> Tuple[str, object, object]:
     """-> (kind, payload, output Schema or None) — the schema is the
     built operator tree's own, for exact result decoding. Pass `ast` to
     skip re-parsing (Session already parsed for dispatch). `op_sink` (a
     list) receives {"plan": bound plan, "op": built operator tree} for
     non-EXPLAIN statements — Session's prepared-statement cache. With a
     `mesh` the statement runs distributed (sql/plan.run) and EXPLAIN
-    shows the distribution; `strict` is `distsql = always`."""
+    shows the distribution; `strict` is `distsql = always`. `params`
+    (sql/params.BoundParams) are the values of the statement's `$n`
+    placeholders: the plan is made at this binding and serves all, its
+    programs take the values as arguments, and the sink also receives
+    the statement's `slots`."""
     from cockroach_tpu.exec import stats
+    from cockroach_tpu.ops.expr import bound_args
+    from cockroach_tpu.sql import params as _params
     from cockroach_tpu.sql.plan import run
     from cockroach_tpu.util.tracing import tracer
 
@@ -165,16 +172,23 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
     qreg = default_query_registry()
     qreg.set_phase_current("compiling")
     with stats.timed("sql.bind"):
-        plan = Binder(catalog).bind(stmt)
+        binder = Binder(catalog,
+                        params=None if params is None else params.values)
+        plan = binder.bind(stmt)
+        slots = binder.param_slots
+        args = (None if params is None
+                else _params.evaluate(slots, params.values))
     if not is_explain:
         qreg.set_phase_current("executing")
         sink = [] if op_sink is not None else None
-        result, schema = run(plan, catalog, capacity, mesh=mesh,
-                             with_schema=True, op_sink=sink, sql=sql,
-                             setting=setting, strict=strict)
+        with bound_args(args):
+            result, schema = run(plan, catalog, capacity, mesh=mesh,
+                                 with_schema=True, op_sink=sink, sql=sql,
+                                 setting=setting, strict=strict)
         if op_sink is not None:
             op_sink.append({"plan": plan,
-                            "op": sink[0] if sink else None})
+                            "op": sink[0] if sink else None,
+                            "slots": slots})
         return "rows", result, schema
 
     norm = normalize(plan, catalog)
@@ -223,6 +237,14 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
                      f"floor {1000 * est_tpu_seconds(0):.0f}ms)")
     if mesh is not None and explained is not None:
         lines.extend(_distribution_lines(explained.op, mesh, catalog))
+    if slots:
+        # one plan and one program for every binding: each slot is an
+        # argument of the program; the estimates above were taken at the
+        # binding this EXPLAIN was sent with
+        lines.append("parameters: "
+                     + ", ".join(s.describe() for s in slots))
+        lines.append("estimates taken at: "
+                     + _params.describe_binding(params.values))
     if analyze:
         from cockroach_tpu.util.tracing import summarize
 
@@ -230,8 +252,9 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
         try:
             with tracer().span("query", sql=sql[:60]) as sp:
                 t0 = time.perf_counter()
-                res = run(norm, catalog, capacity, mesh=mesh, sql=sql,
-                          strict=strict)
+                with bound_args(args):
+                    res = run(norm, catalog, capacity, mesh=mesh, sql=sql,
+                              strict=strict)
                 elapsed = time.perf_counter() - t0
             n = len(next(iter(res.values()))) if res else 0
             lines.append("")

@@ -32,6 +32,7 @@ _TOKEN_RE = re.compile(
   | (?P<num>\d+(\.\d+)?([eE][+-]?\d+)?)
   | (?P<str>'(?:[^']|'')*')
   | (?P<name>[A-Za-z_][A-Za-z_0-9]*)
+  | (?P<param>\$\d+)
   | (?P<op>\|\||<->|<=>|<=|>=|<>|!=|=|<|>|\+|-|\*|/|\(|\)|,|\.|;)
     """,
     re.VERBOSE,
@@ -51,7 +52,7 @@ KEYWORDS = {
 
 @dataclass
 class Token:
-    kind: str  # num | str | name | kw | op | eof
+    kind: str  # num | str | name | kw | param | op | eof
     text: str
     pos: int
 
@@ -103,6 +104,13 @@ class Num(Node):
 @dataclass
 class Str(Node):
     value: str
+
+
+@dataclass
+class Placeholder(Node):
+    """`$n` of the extended protocol: a value bound as data at Bind
+    (sql/params.py), typed by the binder from its sibling operand."""
+    index: int  # 1-based, as written
 
 
 @dataclass
@@ -969,6 +977,9 @@ class Parser:
         if t.kind == "str":
             self.next()
             return Str(t.text[1:-1].replace("''", "'"))
+        if t.kind == "param":
+            self.next()
+            return Placeholder(int(t.text[1:]))
         if t.kind == "op" and t.text == "(":
             self.next()
             e = self.expr()

@@ -19,6 +19,14 @@ attaches its stages to it (exec/stats.timed): wire.decode,
 session.execute, wire.render, wire.encode, wire.flush. The wait for the
 client's next message is outside every span. Parse and Bind are stages
 of their own, `wire.parse` and `wire.bind`.
+
+Bind keeps a statement's parameters VALUES (sql/params.py decodes text
+and binary formats): the session types them against the statement's
+prepared entry, keyed on the parameterised text, and Execute runs that
+entry's one program with them as arguments (Session.bind_params, stage
+`sql.bind_params` inside `wire.bind`). Only a statement outside that scope
+(DML, `LIMIT $1`, `IN ($1, ...)`), or one that the serving queue's match
+on the bound text claims first, has its values written into its text.
 """
 
 from __future__ import annotations
@@ -33,6 +41,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from cockroach_tpu.exec import stats
+from cockroach_tpu.sql import params as _params
 from cockroach_tpu.util import tracing
 from cockroach_tpu.util.log import Channel, get_logger
 
@@ -68,30 +77,6 @@ def _oid_for(ty) -> int:
         # vectors travel as pgvector-style text '[1,2,...]'
         Kind.VECTOR: OID_TEXT,
     }[ty.kind]
-
-
-# binary-format (format code 1) parameter decoders, keyed by the OID
-# the client declared in Parse. Everything renders to text because
-# binding is textual (_substitute); drivers like psycopg send int/float
-# params in binary once they know the statement's parameter types.
-OID_INT2, OID_INT4, OID_FLOAT8 = 21, 23, 701
-_BINARY_DECODERS = {
-    OID_INT2: lambda b: str(struct.unpack(">h", b)[0]),
-    OID_INT4: lambda b: str(struct.unpack(">i", b)[0]),
-    OID_INT8: lambda b: str(struct.unpack(">q", b)[0]),
-    OID_FLOAT4: lambda b: repr(struct.unpack(">f", b)[0]),
-    OID_FLOAT8: lambda b: repr(struct.unpack(">d", b)[0]),
-    OID_BOOL: lambda b: "t" if b and b[0] else "f",
-}
-
-
-def _decode_binary_param(raw: bytes, oid: int) -> str:
-    dec = _BINARY_DECODERS.get(oid)
-    if dec is None:
-        raise ValueError(
-            f"binary parameter format not supported for OID {oid} "
-            "(use text)")
-    return dec(raw)
 
 
 def _pgcode(e: BaseException) -> str:
@@ -348,11 +333,7 @@ class _Conn:
         # retain the declared parameter OIDs: Bind needs them to decode
         # binary-format parameter values
         oids = struct.unpack(f">{n_oids}I", body[off:off + 4 * n_oids])
-        n_params = 0
-        import re as _re
-
-        for m in _re.finditer(r"\$(\d+)", sql):
-            n_params = max(n_params, int(m.group(1)))
+        n_params = _params.count_placeholders(sql)
         self._stmts[name] = (sql, max(n_params, n_oids), tuple(oids))
         self._send(b"1")  # ParseComplete
 
@@ -368,12 +349,12 @@ class _Conn:
         off += 2 * n_fmt
         (n_params,) = struct.unpack(">H", body[off:off + 2])
         off += 2
-        params: List[Optional[str]] = []
+        values: List[object] = []
         for i in range(n_params):
             (plen,) = struct.unpack(">i", body[off:off + 4])
             off += 4
             if plen < 0:
-                params.append(None)
+                values.append(None)
             else:
                 raw = body[off:off + plen]
                 off += plen
@@ -385,48 +366,33 @@ class _Conn:
                     fmt = fmts[i]
                 if fmt == 1:
                     oid = oids[i] if i < len(oids) else 0
-                    params.append(_decode_binary_param(raw, oid))
+                    values.append(_params.decode_binary(raw, oid))
                 else:
-                    params.append(raw.decode())
-        # substitute $n with typed literals (text-format params; the
-        # session parser has no placeholder support, so binding is
-        # textual — quoting strings, passing numerics through)
-        bound = self._substitute(sql, params)
-        # EXECUTE seam: re-match the BOUND text against the serving
-        # batch classes, so prepared statements differing only in bind
-        # values join their class's coalescing group at Execute time
-        # (Session.execute_spec) instead of re-running parse/plan
-        spec = None
-        try:
+                    values.append(raw.decode())
+        bound, spec = None, None
+        if values:
+            # EXECUTE seam, first: match the statement with its values
+            # written in against the serving batch classes, so prepared
+            # statements differing only in bind values join their class's
+            # coalescing group at Execute time (Session.execute_spec)
+            # instead of re-running parse/plan
             from cockroach_tpu.sql import serving as _serving
 
-            spec = _serving.match_bound_sql(self.session, bound)
-        except Exception:  # noqa: BLE001 — matching must never fail Bind
-            spec = None
-        self._portals[portal] = {"sql": bound, "result": None,
-                                 "spec": spec}
+            if _serving.enabled():
+                try:
+                    spec = _serving.match_bound_sql(
+                        self.session, _params.substitute(sql, values))
+                except Exception:  # noqa: BLE001 — must never fail Bind
+                    spec = None
+            if spec is not None:
+                sql = _params.substitute(sql, values)
+            else:
+                bound, sql = self.session.bind_params(sql, values)
+        self._portals[portal] = {"sql": sql, "result": None,
+                                 "spec": spec, "params": bound}
         self._send(b"2")  # BindComplete
 
-    @staticmethod
-    def _substitute(sql: str, params: List[Optional[str]]) -> str:
-        import re as _re
-
-        def repl(m):
-            i = int(m.group(1)) - 1
-            if i >= len(params):
-                raise ValueError(f"parameter ${i + 1} not bound")
-            v = params[i]
-            if v is None:
-                return "NULL"
-            try:
-                float(v)
-                return v
-            except ValueError:
-                return "'" + v.replace("'", "''") + "'"
-
-        return _re.sub(r"\$(\d+)", repl, sql)
-
-    def _execute_stmt(self, sql: str) -> tuple:
+    def _execute_stmt(self, sql: str, params=None) -> tuple:
         """session.execute wrapped as a Stopper task: drain waits for
         every in-flight statement (then cancels stragglers); once the
         stopper quiesces, new statements are refused with 57P01."""
@@ -436,7 +402,7 @@ class _Conn:
             raise AdminShutdownError("server is draining")
         try:
             with self.server.stopper.task("pgwire-stmt"):
-                return self.session.execute(sql)
+                return self.session.execute(sql, params)
         except StopperStopped as e:
             raise AdminShutdownError("server is draining") from e
 
@@ -447,7 +413,7 @@ class _Conn:
             if spec is not None:
                 p["result"] = self._execute_spec(spec, p["sql"])
             if p["result"] is None:
-                p["result"] = self._execute_stmt(p["sql"])
+                p["result"] = self._execute_stmt(p["sql"], p.get("params"))
         return p["result"]
 
     def _execute_spec(self, spec, sql: str):

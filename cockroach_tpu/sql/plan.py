@@ -21,6 +21,8 @@ implement it, so the same plans run over synthetic data or the C++ LSM.
 
 from __future__ import annotations
 
+import threading
+from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -176,6 +178,11 @@ class MVCCCatalog(Catalog):
         self.pks = dict(pks or {})
         self.stats = dict(stats or {})
         self._scan_ts: Dict[str, object] = {}  # name -> pinned read ts
+        # one prepared-statement cache for every session of this catalog,
+        # as SessionCatalog has (sql/session.py adopts the pair): a
+        # statement planned on one connection is warm on all, and what a
+        # flow restart widened on its tree stays widened for the process
+        self.shared_prepared = (OrderedDict(), threading.Lock())
 
     def table_stats(self, name: str):
         return self.stats.get(name)

@@ -32,8 +32,9 @@ from cockroach_tpu.coldata.batch import (
     Schema, VECTOR,
 )
 from cockroach_tpu.kv.txn import DB, TxnRetryError
+from cockroach_tpu.sql import params as _params
 from cockroach_tpu.sql import parser as P
-from cockroach_tpu.sql.bind import BindError
+from cockroach_tpu.sql.bind import BindError, Binder
 from cockroach_tpu.sql.plan import Catalog
 from cockroach_tpu.storage.mvcc import MVCCStore
 from cockroach_tpu.util.hlc import Timestamp
@@ -753,18 +754,25 @@ class _Prepared:
     reader's), the batchable-statement spec when the statement is in
     the serving queue's coalescible class (sql/serving.py), and whether
     a distributing session made it (`distsql`; the cache is shared by a
-    catalog's sessions, and an entry serves only its own kind)."""
+    catalog's sessions, and an entry serves only its own kind). A
+    statement with `$n` placeholders is ONE entry under its parameterised
+    text: `slots` (sql/params.ParamSlot, one a program argument) turn a
+    Bind's values into the arguments of the tree's one program, and what
+    a flow restart widened stays on the tree for every later binding."""
 
-    __slots__ = ("op", "schema", "vkeys", "capacity", "bspec", "dist")
+    __slots__ = ("op", "schema", "vkeys", "capacity", "bspec", "dist",
+                 "slots")
 
     def __init__(self, op, schema, vkeys: Dict[str, tuple],
-                 capacity: int, bspec=None, dist: bool = False):
+                 capacity: int, bspec=None, dist: bool = False,
+                 slots=()):
         self.op = op
         self.schema = schema
         self.vkeys = vkeys
         self.capacity = capacity
         self.bspec = bspec
         self.dist = dist
+        self.slots = tuple(slots)
 
 
 _session_ids = itertools.count(1)
@@ -821,7 +829,9 @@ class Session:
         # prepared-statement cache: EXACT SQL text -> _Prepared. Keyed on
         # the text, NOT sqlstats.fingerprint — the fingerprint strips
         # literals, and two statements differing only in literals need
-        # different plans. Validity is checked per hit against the
+        # different plans (a statement sent with `$n` placeholders is
+        # keyed on its parameterised text: one entry for all bindings,
+        # see bind_params). Validity is checked per hit against the
         # catalog's current scan-cache keys (which embed each table's
         # MVCC write version), so one write to any scanned table rotates
         # the key and forces a rebuild. Guarded by _prepared_mu: the
@@ -841,8 +851,13 @@ class Session:
         # cancel_query() from OTHER threads
         self._cancel_mu = threading.Lock()
         self._active_cancel = None
+        # parameterised texts that bind_params found outside the typed
+        # scope (DML, LIMIT $1, IN ($1, ...)): bound as text without a
+        # second look
+        self._textual_only: set = set()
 
     PREPARED_CACHE_ENTRIES = 32
+    TEXTUAL_MEMO_ENTRIES = 256
 
     # ------------------------------------------------------ cancellation
 
@@ -886,9 +901,11 @@ class Session:
     _CONTROL_HEADS = ("begin", "commit", "rollback", "abort", "start",
                       "set", "show", "cancel")
 
-    def execute(self, sql: str) -> Tuple[str, object, object]:
+    def execute(self, sql: str, params=None) -> Tuple[str, object, object]:
         """-> (kind, payload, schema) like explain.execute_with_plan,
-        plus kinds: 'ok' (DDL/DML, payload = tag string). Every
+        plus kinds: 'ok' (DDL/DML, payload = tag string). `params`
+        (sql/params.BoundParams, from bind_params) are the values of the
+        statement's `$n` placeholders, bound as data. Every
         statement records into sqlstats (the statements-page feed); a
         root span covers the statement when `sql.trace.enabled` is on.
 
@@ -920,7 +937,9 @@ class Session:
         qid = 0
         serving_path = False
         try:
-            with tracing.query_span("session.execute", sql=sql[:60]), \
+            tags = {} if params is None else {"params": len(params)}
+            with tracing.query_span("session.execute", sql=sql[:60],
+                                    **tags), \
                     _stats.query_stats() as qcol:
                 try:
                     with _stats.timed("session.admit"):
@@ -949,7 +968,7 @@ class Session:
                             queue = self._admit(head, ctx)
                             qentry.phase = _registry.PHASE_EXECUTING
                     with _cancel.active(ctx):
-                        kind, payload, schema = self._execute(sql)
+                        kind, payload, schema = self._execute(sql, params)
                 except Exception as e:
                     elapsed = _time.perf_counter() - t0
                     default_sqlstats().record(
@@ -1171,6 +1190,79 @@ class Session:
             sql=Redactable(sql), latency_s=round(elapsed, 4), rows=rows,
             error=error, session=self.session_id)
 
+    # ------------------------------------------------- bound parameters
+
+    def bind_params(self, sql: str, values) -> Tuple[object, str]:
+        """pgwire's Bind for a statement with `$n` placeholders ->
+        (BoundParams, the text to execute them with), or (None, the
+        statement with the values written in) where the statement is
+        outside the typed scope: not a SELECT whose parameters all stand
+        beside a typed operand in a comparison, BETWEEN or + - *
+        arithmetic (sql/bind.py). Stage `sql.bind_params`: typing (a
+        parse and a bind of the parameterised text, once a statement),
+        the dictionary lookup of a string, the host folding of `$1 +
+        interval '1' year`; `rows` counts the values."""
+        from cockroach_tpu.exec import stats
+        from cockroach_tpu.util.metric import default_registry
+
+        values = tuple(values)
+        with stats.timed("sql.bind_params", rows=len(values)):
+            bound = None
+            if sql not in self._textual_only:
+                try:
+                    bound = self._type_params(sql, values)
+                except _params.ValueOutOfScope:
+                    pass    # this binding only: the next may fit
+                except Exception:  # noqa: BLE001 — whatever the typed
+                    # path cannot take runs as it always did, and a real
+                    # error is the textual path's to report, at Execute
+                    self._note_textual(sql)
+            if bound is None:
+                return None, self._bind_textual(sql, values)
+            default_registry().counter(
+                "sql_bind_params_total",
+                "parameter values bound as data: arguments of the "
+                "statement's one program").inc(len(values))
+            return bound, sql
+
+    def _type_params(self, sql: str, values: tuple):
+        """The statement's slots -> this binding's program arguments.
+        Warm: the prepared entry of the parameterised text holds the
+        slots. Cold: parse and bind once to learn whether the statement
+        is in scope (the plan itself is made at Execute, at this
+        binding)."""
+
+        if self._txn is None and not self._txn_aborted:
+            prep = self._prepared_lookup(sql)
+            if prep is not None and prep.slots:
+                return _params.BoundParams(
+                    values, _params.evaluate(prep.slots, values))
+        ast = P.parse(sql)
+        stmt = ast.stmt if isinstance(ast, P.ExplainStmt) else ast
+        if not isinstance(stmt, P.SelectStmt):
+            raise _params.ParamOutOfScope(type(stmt).__name__)
+        binder = Binder(self.catalog, params=values)
+        binder.bind(stmt)
+        _params.evaluate(binder.param_slots, values)
+        return _params.BoundParams(values)
+
+    def _note_textual(self, sql: str) -> None:
+        if len(self._textual_only) >= self.TEXTUAL_MEMO_ENTRIES:
+            self._textual_only.clear()
+        self._textual_only.add(sql)
+
+    def _bind_textual(self, sql: str, values) -> str:
+        """The statement with this binding's values written into its
+        text: planned, compiled and cached per binding, as every
+        parameterised statement was before bind_params."""
+        from cockroach_tpu.util.metric import default_registry
+
+        default_registry().counter(
+            "sql_bind_textual_total",
+            "statements whose parameter values were written into the "
+            "text and planned as literals").inc()
+        return _params.substitute(sql, values)
+
     # ------------------------------------------------ prepared statements
 
     def _prepared_lookup(self, sql: str) -> Optional[_Prepared]:
@@ -1269,12 +1361,18 @@ class Session:
                                                  self.capacity)
             except Exception:  # noqa: BLE001 — matcher must never
                 bspec = None   # block the prepared path
+        slots = sunk.get("slots", ())
         with self._prepared_mu:
             self._prepared[sql] = _Prepared(op, op.schema, vkeys,
-                                            self.capacity, bspec, dist)
+                                            self.capacity, bspec, dist,
+                                            slots)
             self._prepared.move_to_end(sql)
             while len(self._prepared) > self.PREPARED_CACHE_ENTRIES:
                 self._prepared.popitem(last=False)
+        if slots:
+            # the ladder pre-warm compiles off the query path, where no
+            # binding exists to give the program its arguments
+            return
         # compile-at-prepare: hand the statement's pow2 bucket ladder to
         # the background pre-warm job (no-op unless sql.prewarm.enabled)
         # — the remaining rungs and the vault artifacts materialize off
@@ -1303,8 +1401,25 @@ class Session:
         if svc is not None:
             svc.forget()
 
-    def _execute(self, sql: str) -> Tuple[str, object, object]:
+    def _execute(self, sql: str, params=None) -> Tuple[str, object, object]:
+        from cockroach_tpu.ops.expr import ParamOutsideProgram
+
+        if params is not None:
+            # the typed path reaches from here to the fused program; a
+            # tree that leaves it (the streaming operators' own jits, a
+            # binding the slots cannot hold) is answered as bound text
+            try:
+                return self._execute_bound(sql, params)
+            except _params.ValueOutOfScope:
+                sql = self._bind_textual(sql, params.values)
+            except (ParamOutsideProgram, _params.ParamOutOfScope):
+                self._note_textual(sql)
+                sql = self._bind_textual(sql, params.values)
+        return self._execute_bound(sql, None)
+
+    def _execute_bound(self, sql: str, params) -> Tuple[str, object, object]:
         from cockroach_tpu.exec import collect, stats
+        from cockroach_tpu.ops.expr import bound_args
 
         # warm-path short-circuit BEFORE the parse: a prepared hit needs
         # no ast at all (only SELECTs are ever stored, and the entry
@@ -1330,16 +1445,24 @@ class Session:
                     payload = _serving.maybe_submit(self, prep, sql=sql)
                     if payload is not None:
                         return "rows", payload, prep.schema
+                args = None
+                if params is not None:
+                    args = params.args
+                    if args is None:  # bound before the entry existed
+                        args = _params.evaluate(prep.slots, params.values)
                 if prep.op is not None and mesh is not None:
                     from cockroach_tpu.parallel.dist_flow import (
                         collect_distributed,
                     )
 
-                    return "rows", collect_distributed(
-                        prep.op, mesh, strict=strict), prep.schema
+                    with bound_args(args):
+                        return "rows", collect_distributed(
+                            prep.op, mesh, strict=strict), prep.schema
                 if prep.op is not None:
-                    return "rows", collect(
-                        prep.op, backend=self.vars["vectorize"]), prep.schema
+                    with bound_args(args):
+                        return "rows", collect(
+                            prep.op, backend=self.vars["vectorize"]), \
+                            prep.schema
                 # serving-only entry (stale plan over a resident table)
                 # whose batch submit declined: fall through to the cold
                 # parse path, which also re-stores a full entry
@@ -1392,7 +1515,7 @@ class Session:
                 out = execute_with_plan(sql, catalog, self.capacity,
                                         mesh=mesh, ast=ast, op_sink=sink,
                                         setting=self.vars["vectorize"],
-                                        strict=strict)
+                                        strict=strict, params=params)
                 if sink:
                     self._prepared_store(sql, sink[0], ast,
                                          dist=mesh is not None)
@@ -1400,7 +1523,10 @@ class Session:
             return execute_with_plan(sql, catalog, self.capacity,
                                      mesh=mesh, ast=ast,
                                      setting=self.vars["vectorize"],
-                                     strict=strict)
+                                     strict=strict, params=params)
+        if params is not None:
+            # DDL, DML, SET: their values are written into the text
+            raise _params.ParamOutOfScope(type(ast).__name__)
         if isinstance(ast, P.TxnControl):
             return self._txn_control(ast)
         if isinstance(ast, P.SetVar):

@@ -19,7 +19,9 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from cockroach_tpu.ops.expr import BoolOp, Cmp, Col, InList, Like, Lit
+from cockroach_tpu.ops.expr import (
+    BoolOp, Cmp, Col, InList, Like, Lit, Param,
+)
 from cockroach_tpu.util.hlc import Timestamp
 
 STATS_TABLE = 0xFFE1  # system.table_statistics keyspace
@@ -154,6 +156,13 @@ def _range_frac(cs: ColumnStats, lo: float, hi: float) -> float:
     return max(0.0, min(1.0, frac))
 
 
+def _constant(e):
+    """A literal's value; for a bound parameter the value at the binding
+    the plan is made at (the plan then serves every binding: an estimate
+    that a later one overflows costs one flow restart)."""
+    return e.sample if isinstance(e, Param) else e.value
+
+
 def conjunct_selectivity(e, stats: Optional[TableStats]) -> float:
     """Estimated fraction of rows satisfying one bound conjunct."""
     if isinstance(e, BoolOp):
@@ -171,10 +180,10 @@ def conjunct_selectivity(e, stats: Optional[TableStats]) -> float:
         return _DEFAULT_SEL
     if isinstance(e, Cmp):
         col, lit = None, None
-        if isinstance(e.left, Col) and isinstance(e.right, Lit):
-            col, lit, op = e.left.name, e.right.value, e.op
-        elif isinstance(e.right, Col) and isinstance(e.left, Lit):
-            col, lit = e.right.name, e.left.value
+        if isinstance(e.left, Col) and isinstance(e.right, (Lit, Param)):
+            col, lit, op = e.left.name, _constant(e.right), e.op
+        elif isinstance(e.right, Col) and isinstance(e.left, (Lit, Param)):
+            col, lit = e.right.name, _constant(e.left)
             op = {"<": ">", "<=": ">=", ">": "<", ">=": "<="}.get(
                 e.op, e.op)
         else:

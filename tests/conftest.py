@@ -35,8 +35,31 @@ from cockroach_tpu.util.compile_cache import (  # noqa: E402
 enable_persistent_cache(default=os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache_cpu"))
 
+import faulthandler  # noqa: E402
+import sys  # noqa: E402
+
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+# A test that hangs must cost its own result and not the run: the driver
+# cuts the whole command at its time limit (twice a run ended at rc 124
+# with every worker idle, four tests short, one worker at 0% CPU inside
+# tests/test_tpu_compile.py's compiles). pytest-timeout is not installed,
+# so each test arms the interpreter's own watchdog at its set-up and
+# disarms it after its teardown: past HANG_SECONDS it prints every
+# thread's stack and exits the process; xdist reports the test as crashed,
+# replaces the worker and runs the rest. The longest FILE takes about 280 s.
+HANG_SECONDS = 600
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    faulthandler.dump_traceback_later(HANG_SECONDS, exit=True,
+                                      file=sys.__stderr__)
+    try:
+        yield
+    finally:
+        faulthandler.cancel_dump_traceback_later()
 
 
 @pytest.fixture

@@ -496,6 +496,55 @@ def test_explain_under_always_prints_the_estimated_buckets(catalog):
     assert not dist_flow._PROGS and not dist_flow.ingest._CACHE
 
 
+# ---------------------------- bound parameters on the mesh (ISSUE 31) ----
+
+Q3_PARAMS = manifest.cell("tpch-sf1-qgen.q3-1stream")["statements"][0]["sql"]
+
+
+def test_bound_parameters_are_never_baked_into_a_mesh_program(tpch, catalog):
+    """The shard_map program has no argument for a statement's bound
+    values, so a parameterised statement is outside the distributed
+    grammar: under `on` the single-chip ladder answers with the values as
+    arguments of its ONE fused program (no distributed program is traced,
+    nothing is primed on the mesh), under `always` it is 0A000."""
+    from benchmark.reference import tpch_q3_qgen
+
+    sess = _session(catalog, "set vectorize = tpu", "set distsql = on")
+    ref = tpch_q3_qgen.Reference(tpch["data"], tpch["dicts"], {})
+    col = stats.enable()
+    for n, values in enumerate((("BUILDING", "1995-03-15"),
+                                ("MACHINERY", "1995-03-01"),
+                                ("HOUSEHOLD", "1995-03-31")), 1):
+        bound, text = sess.bind_params(Q3_PARAMS, values)
+        assert bound is not None and text == Q3_PARAMS
+        with tracing.tracer().span("test.root") as root:
+            _kind, got, _schema = sess.execute(text, params=bound)
+        assert root.tags["tier"] == "fused"
+        assert list(zip(got["l_orderkey"].tolist(), got["revenue"].tolist(),
+                        got["o_orderdate"].tolist(),
+                        got["o_shippriority"].tolist())) == ref.answer(values)
+        assert _events(col, "dist.fallback_unsupported") == n
+    assert _dist_stages(col) == ["dist.fallback_unsupported"]
+    assert not dist_flow._PROGS and not dist_flow.ingest._CACHE
+    (prep,) = sess._prepared.values()          # one entry for all bindings
+    assert len(prep.slots) == 2 and prep.dist
+    runner = prep.op._fused_runner
+    assert runner._takes_params and len(runner._exec_cache) == 1
+    lines = sess.execute("explain " + Q3_PARAMS,
+                         params=sess.bind_params("explain " + Q3_PARAMS,
+                                                 values)[0])[1]
+    assert ("distribution: local (outside the distributed grammar: bound "
+            "parameters are not arguments of the distributed program)"
+            in lines)
+    assert "parameters: $1 string(code), $2 date" in lines
+    sess.execute("set distsql = always")
+    bound, text = sess.bind_params(Q3_PARAMS, values)
+    with pytest.raises(Exception) as e:
+        sess.execute(text, params=bound)
+    assert getattr(e.value, "pgcode", None) == "0A000"
+    assert "bound parameters" in str(e.value)
+
+
 # ---------------------------------------- a low estimate (ISSUE 30) ----
 
 def test_a_low_estimate_restarts_once_and_answers_exactly(
