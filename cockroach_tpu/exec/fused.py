@@ -29,6 +29,14 @@ Supported tree grammar (anything else -> streaming fallback):
              ScanOp
     Mat   := any supported subtree materialized as ONE traced Batch
 
+A HashAggOp over a Chain is a Fold only when it must be: while the Chain's
+materialized output (lanes x row bytes) fits the operator's workmem, the
+aggregate, grouped or scalar, takes the Chain as a Mat (a multi-chunk scan
+unpacks flat off the stacked image) and aggregates ONCE; over the budget
+it folds chunk by chunk, the out-of-core answer (_Tracer._agg_stream;
+_mat_agg counts fused.agg_materialized / fused.agg_folded). A range-dense
+aggregate folds any Chain, and a TopKOp over a Chain always folds.
+
 Overflow posture matches streaming: joins and generic agg folds carry
 deferred overflow flags through the scan; the runner checks them once after
 the sink consumed the result and raises FlowRestart to the shared retry
@@ -656,20 +664,42 @@ class _Tracer:
         self.flags.append(res.fallback)
         return op._final_project(res.batch)
 
+    def _agg_stream(self, op: HashAggOp) -> Optional[_Stream]:
+        """The chunk stream `op` folds over, or None: it aggregates ONCE
+        over its materialized input. One aggregation beats a per-chunk
+        fold whenever the materialized input fits the operator budget,
+        grouped or not: every fold step unpacks its chunk's byte lanes on
+        its own, where the flat unpack of the stacked image fuses into the
+        filter and the reduction, and a grouped step re-sorts acc + chunk
+        (N chunks cost ~2N sorted-agg passes vs ONE at N times the lanes).
+        An input over the budget keeps the fold, the out-of-core answer;
+        the range-dense accumulator is its static domain and folds any
+        stream."""
+        s = self._stream(op.child)
+        if s is not None and op._range_dense is None:
+            n_chunks = self.stacked[id(s.scan)][0].shape[0]
+            mat_rows = s.cap * n_chunks
+            if mat_rows * self._row_bytes(op.child.schema) <= op.workmem:
+                s = None
+        return s
+
     def _mat_agg(self, op: HashAggOp) -> Batch:
-        gj = self._try_groupjoin(op)
-        if gj is not None:
-            return gj
-        ia = self._try_int_agg(op)
-        if ia is not None:
-            return ia
+        out = self._try_groupjoin(op)
+        if out is None:
+            out = self._try_int_agg(op)
+        # both fast paths aggregate over the materialized input
+        s = self._agg_stream(op) if out is None else None
+        # the lowering taken, one event a traced HashAggOp
+        stats.add("fused.agg_folded" if s is not None
+                  else "fused.agg_materialized")
+        if out is not None:
+            return out
         group_by, internal = tuple(op.group_by), tuple(op.internal)
         if op._range_dense is not None:
             from cockroach_tpu.ops.agg import range_dense_aggregate
 
             lo, span = op._range_dense
-            s2 = self._stream(op.child)
-            if s2 is not None:
+            if s is not None:
                 def init(b):
                     return range_dense_aggregate(b, group_by[0], lo,
                                                  span, internal)
@@ -681,8 +711,8 @@ class _Tracer:
                     return dense_merge(acc, part, group_by,
                                        internal), fl | fl2
 
-                (acc, fl), chain_fl = self._fold(s2, init, step)
-                self.flag_ops.extend(s2.flag_ops + [op])
+                (acc, fl), chain_fl = self._fold(s, init, step)
+                self.flag_ops.extend(s.flag_ops + [op])
                 self.flags.extend(list(chain_fl) + [fl])
                 return op._final_project(acc)
             m2 = self._mat(op.child)
@@ -691,16 +721,6 @@ class _Tracer:
             self.flag_ops.append(op)
             self.flags.append(fl)
             return op._final_project(out)
-        s = self._stream(op.child)
-        if s is not None and group_by:
-            # one aggregation over the materialized input beats a per-chunk
-            # fold (each fold step re-sorts acc+chunk: N chunks cost
-            # ~2N sorted-agg passes vs ONE at N-times the lanes) whenever
-            # the materialized input fits the operator budget
-            n_chunks = self.stacked[id(s.scan)][0].shape[0]
-            mat_rows = s.cap * n_chunks
-            if mat_rows * self._row_bytes(op.child.schema) <= op.workmem:
-                s = None
         if s is not None and op._dense_sizes is not None:
             sizes = tuple(op._dense_sizes)
 
@@ -750,11 +770,13 @@ class _Tracer:
             return op._final_project(out.compact())
         # materialized aggregate: output capacity == input capacity, which
         # by construction holds every group — no overflow is possible, but
-        # a hash-grouping collision still forces a re-seeded rerun
+        # a hash-grouping collision still forces a re-seeded rerun (a
+        # scalar aggregate hashes nothing: no flag, no restart target)
         out, coll = hash_aggregate(m, group_by, internal, seed=op.seed,
                                    method="hash", with_flag=True)
-        self.flag_ops.append(op)
-        self.flags.append(coll)
+        if group_by:
+            self.flag_ops.append(op)
+            self.flags.append(coll)
         return op._final_project(out)
 
 

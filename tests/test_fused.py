@@ -207,9 +207,9 @@ def _sql_catalog():
     return gen, TPCHCatalog(gen)
 
 
-def _join_compacts(fn):
-    """fn() under a fresh stats collection -> (result, events of counter
-    `fused.join_compact`: one per Join+Shrink pair per traced program)."""
+def _stage_events(fn, prefix):
+    """fn() under a fresh stats collection -> (result, {stage: events} of
+    the stages named `prefix`...)."""
     from cockroach_tpu.exec import stats
 
     col = stats.enable()
@@ -217,8 +217,15 @@ def _join_compacts(fn):
         out = fn()
     finally:
         stats.disable()
-    st = col.stages.get("fused.join_compact")
-    return out, (st.events if st is not None else 0)
+    return out, {name: st.events for name, st in col.stages.items()
+                 if name.startswith(prefix)}
+
+
+def _join_compacts(fn):
+    """-> (fn(), events of counter `fused.join_compact`: one per
+    Join+Shrink pair per traced program)."""
+    out, events = _stage_events(fn, "fused.join_compact")
+    return out, events.get("fused.join_compact", 0)
 
 
 def test_q3_sql_compacts_both_joins_and_matches_oracle():
@@ -485,3 +492,196 @@ def test_shrunk_joins_match_oracles(qn, path):
                    res["o_orderdate"], res["o_totalprice"],
                    res["sum_qty"])]
         assert got == Q.q18_oracle(gen)
+
+
+# -- a scalar aggregate within the operator budget aggregates once ---------
+
+_AGG_ROWS, _AGG_CAP = 300, 64     # five chunks of 64 lanes (stacked: 8)
+
+
+def _agg_table():
+    """-> (columns as numpy: q, p and d in cents, n with NULLs; its mask)
+    of table `t`, made from a fixed seed."""
+    rng = np.random.default_rng(32)
+    cols = {"q": rng.integers(1, 51, _AGG_ROWS),
+            "p": rng.integers(100, 100000, _AGG_ROWS),
+            "d": rng.integers(0, 11, _AGG_ROWS),
+            "n": rng.integers(-50, 50, _AGG_ROWS)}
+    return cols, rng.random(_AGG_ROWS) < 0.3
+
+
+@pytest.fixture(scope="module")
+def agg_catalog():
+    from cockroach_tpu.sql.session import Session, SessionCatalog
+    from cockroach_tpu.storage.mvcc import MVCCStore
+
+    cat = SessionCatalog(MVCCStore())
+    sess = Session(cat, capacity=_AGG_CAP)
+    sess.execute("create table t (id int primary key, q int, "
+                 "p decimal(2), d decimal(2), n int)")
+    c, null = _agg_table()
+    sess.execute("insert into t values " + ", ".join(
+        f"({i}, {c['q'][i]}, {c['p'][i] / 100:.2f}, {c['d'][i] / 100:.2f}, "
+        f"{'null' if null[i] else c['n'][i]})" for i in range(_AGG_ROWS)))
+    return cat
+
+
+def _agg_session(cat, *setup):
+    from cockroach_tpu.sql.session import Session
+
+    sess = Session(cat, capacity=_AGG_CAP)
+    for text in ("set vectorize = tpu",) + setup:
+        assert sess.execute(text)[0] == "ok"
+    return sess
+
+
+def _agg_branches(fn):
+    """-> (fn(), {"materialized" | "folded": events} of the counters
+    `fused.agg_materialized` / `fused.agg_folded`: one event per
+    HashAggOp per traced program)."""
+    out, events = _stage_events(fn, "fused.agg_")
+    return out, {name[len("fused.agg_"):]: n for name, n in events.items()}
+
+
+def _q6_shape(c, null):
+    m = (c["d"] >= 4) & (c["d"] <= 6) & (c["q"] < 24)
+    return {"revenue": int((c["p"][m] * c["d"][m]).sum())}
+
+
+def _count_star(c, null):
+    return {"c": int((c["q"] < 24).sum())}
+
+
+def _count_avg(c, null):
+    m = (c["q"] < 24) & ~null
+    return {"cn": int(m.sum()),
+            "an": np.float32(c["n"][m].sum() / m.sum())}
+
+
+def _min_max(c, null):
+    m = (c["q"] < 24) & ~null
+    return {"mn": int(c["n"][m].min()), "mx": int(c["n"][m].max()),
+            "pmin": int(c["p"][c["q"] < 24].min())}
+
+
+def _no_row(c, null):
+    return {"revenue": None, "c": 0, "cn": 0, "mx": None}
+
+
+@pytest.mark.parametrize("sql,values,want", [
+    ("select sum(p * d) as revenue from t where d between $1 - 0.01 "
+     "and $1 + 0.01 and q < $2", ("0.05", "24"), _q6_shape),
+    ("select count(*) as c from t where q < 24", None, _count_star),
+    ("select count(n) as cn, avg(n) as an from t where q < 24", None,
+     _count_avg),
+    ("select min(n) as mn, max(n) as mx, min(p) as pmin from t "
+     "where q < 24", None, _min_max),
+    ("select sum(p * d) as revenue, count(*) as c, count(n) as cn, "
+     "max(n) as mx from t where q < 0", None, _no_row),
+], ids=["q6_shape_bound", "count_star", "count_avg_nullable", "min_max",
+        "no_row"])
+def test_scalar_aggregate_materializes_within_workmem_else_folds(
+        agg_catalog, monkeypatch, sql, values, want):
+    """A scalar aggregate over a multi-chunk scan: one aggregation over
+    the flat-unpacked image (no loop in the program) while the input fits
+    the operator's workmem, the chunk fold under lax.scan once it does
+    not; both programs answer as numpy does, in one row."""
+    from cockroach_tpu.exec.operators import walk_operators
+
+    texts = []
+    lower = fused.lower_program
+
+    def recording(fn, args):
+        lowered = lower(fn, args)
+        texts.append(lowered.as_text())
+        return lowered
+
+    monkeypatch.setattr(fused, "lower_program", recording)
+    sess = _agg_session(agg_catalog)
+    bound = None
+    if values is not None:
+        bound, text = sess.bind_params(sql, values)
+        assert bound is not None and text == sql
+
+    def run():
+        _kind, payload, _schema = sess.execute(sql, params=bound)
+        return payload
+
+    got, branches = _agg_branches(run)
+    assert branches == {"materialized": 1}
+    with sess._prepared_mu:
+        prep = sess._prepared.get(sql)
+    agg, = [op for op in walk_operators(prep.op)
+            if isinstance(op, HashAggOp)]
+    # under any input's bytes (count(*) reads no column: 0 bytes a lane)
+    agg.workmem = -1
+    folded, branches = _agg_branches(run)
+    assert branches == {"folded": 1}
+    # (a one-chunk fold would have no loop either: the scan is multi-chunk)
+    assert [t.count("stablehlo.while") for t in texts] == [0, 1]
+    c, null = _agg_table()
+    for name, value in want(c, null).items():
+        for res in (got, folded):
+            assert len(res[name]) == 1
+            if value is None:
+                assert not res[name + "__valid"][0], name
+            else:
+                assert res[name + "__valid"][0], name
+                assert res[name][0] == value, name
+    for name in got:
+        np.testing.assert_array_equal(got[name], folded[name], name)
+
+
+def test_scalar_aggregate_over_a_streamed_join_follows_the_budget():
+    """A scalar aggregate above a chunkable join: within the budget the
+    join runs whole (_mat_join) under ONE aggregation; over it the join
+    probes chunk by chunk inside the fold. The same exact answer."""
+    rng = np.random.default_rng(11)
+    bk = rng.permutation(400)[:48]
+    pk = rng.integers(0, 400, 300)
+    pv = rng.integers(-30, 90, 300)
+    hit = np.isin(pk, bk)
+
+    def tree(**kw):
+        probe = _int_scan({"fk": pk, "v": pv}, 64)   # 5 chunks of 64
+        build = _int_scan({"k": bk}, 64)
+        join = JoinOp(probe, build, ["fk"], ["k"], how="inner")
+        return HashAggOp(join, [], [AggSpec("sum", "v", "s"),
+                                    AggSpec("count_star", None, "c"),
+                                    AggSpec("min", "v", "lo")], **kw)
+
+    for kw, branch in (({}, "materialized"), ({"workmem": -1}, "folded")):
+        res, branches = _agg_branches(lambda: collect(tree(**kw), fuse=True))
+        assert branches == {branch: 1}
+        assert (int(res["s"][0]), int(res["c"][0]), int(res["lo"][0])) == (
+            int(pv[hit].sum()), int(hit.sum()), int(pv[hit].min()))
+
+
+def test_scalar_aggregate_over_a_sharded_scan_matches_one_chip(agg_catalog):
+    """On a four-shard mesh (_DistTracer) the local partial of a scalar
+    aggregate is the same ONE aggregation over the shard's materialized
+    lanes; the merged answer is the one-chip answer."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four virtual CPU devices")
+    from cockroach_tpu.parallel import make_mesh
+
+    sql = ("select sum(p * d) as revenue, count(*) as c, count(n) as cn, "
+           "min(n) as mn, max(n) as mx from t where q < 24")
+    one, branches = _agg_branches(
+        lambda: _agg_session(agg_catalog).execute(sql)[1])
+    assert branches == {"materialized": 1}
+    agg_catalog.with_mesh(make_mesh(4))
+    try:
+        sess = _agg_session(agg_catalog, "set distsql = always")
+        dist, branches = _agg_branches(lambda: sess.execute(sql)[1])
+        lines = sess.execute("explain " + sql)[1]
+    finally:
+        agg_catalog.with_mesh(None)
+    assert branches == {"materialized": 1}
+    assert "  scan t: sharded (5 chunks of 64 rows)" in lines
+    c, null = _agg_table()
+    m = c["q"] < 24
+    assert int(dist["revenue"][0]) == int((c["p"][m] * c["d"][m]).sum())
+    assert int(dist["c"][0]) == int(m.sum())
+    for name in one:
+        np.testing.assert_array_equal(one[name], dist[name], name)

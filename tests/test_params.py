@@ -341,6 +341,12 @@ def test_every_binding_runs_the_one_program_and_answers_exactly(
     assert runner._takes_params and len(runner._exec_cache) == 1
     assert len([p for p in runner._progs.values() if p is not None]) \
         == len(runner._progs) <= 2       # one, or two after a flow restart
+    # every program traced here (the one with parameters and a literal
+    # text a binding) aggregated ONCE over its materialized input: Q6 over
+    # lineitem's flat-unpacked chunks with no lax.scan (ISSUE 32); a PR
+    # that moves it back under the chunk fold fails here, not on the chip
+    assert "fused.agg_folded" not in col.stages
+    assert col.stages["fused.agg_materialized"].events >= 1
     assert col.stages["sql.prepared_hit"].events == 2 * len(bindings) - 1
     assert col.stages["sql.bind_params"].rows == 2 * len(bindings) * len(kinds)
     assert _counter("sql_bind_textual_total") == textual
