@@ -34,7 +34,9 @@ materialized output (lanes x row bytes) fits the operator's workmem, the
 aggregate, grouped or scalar, takes the Chain as a Mat (a multi-chunk scan
 unpacks flat off the stacked image) and aggregates ONCE; over the budget
 it folds chunk by chunk, the out-of-core answer (_Tracer._agg_stream;
-_mat_agg counts fused.agg_materialized / fused.agg_folded). A range-dense
+_mat_agg counts fused.agg_materialized / fused.agg_folded, or
+fused.agg_int_key where ops/groupjoin.int_key_aggregate took a single
+integer key through ONE sort). A range-dense
 aggregate folds any Chain, and a TopKOp over a Chain always folds.
 
 Overflow posture matches streaming: joins and generic agg folds carry
@@ -287,6 +289,11 @@ class _Tracer:
         # sql/plan.build) materializes ONCE per trace — its flags are
         # appended once and XLA sees one copy of the subgraph
         self._mat_memo: Dict[int, Batch] = {}
+        # lanes through the key sorts of this program's materialized
+        # joins (probe + build capacity) and sort-based aggregates (the
+        # input's capacity): what FusedRunner counts a dispatch as stage
+        # fused.sort_lanes
+        self.sort_lanes = 0
 
     # -- chunk streams -----------------------------------------------------
 
@@ -504,6 +511,7 @@ class _Tracer:
         probe, p_ovf = route(probe)
         guard = self._route_guard(op)
         self.flag_ops.extend(_flag_targets(guard, op))
+        self.sort_lanes += probe.capacity + build.capacity
         if shrink is not None and carries(bt, probe.capacity, op.how):
             res = probe_unique_compact(probe, bt, probe_on, op.how,
                                        shrink.capacity)
@@ -602,6 +610,7 @@ class _Tracer:
         ccap = min(
             _pow2_at_least(max(16, min(probe.capacity, build.capacity))),
             (1 << 16) * op.expansion)
+        self.sort_lanes += probe.capacity + build.capacity
         res = group_join_aggregate(
             probe, build, pon, bon, key_out,
             probe.col(pon).values.dtype if key_out == pon
@@ -660,6 +669,7 @@ class _Tracer:
         res = int_key_aggregate(
             m, key, list(op.internal), out_capacity=out_cap,
             key64=getattr(op, "_ia_wide", False))
+        self.sort_lanes += m.capacity
         self.flag_ops.append(_GroupJoinGuard(op, "_ia_wide", "_ia_ok"))
         self.flags.append(res.fallback)
         return op._final_project(res.batch)
@@ -685,12 +695,15 @@ class _Tracer:
 
     def _mat_agg(self, op: HashAggOp) -> Batch:
         out = self._try_groupjoin(op)
+        int_key = False
         if out is None:
             out = self._try_int_agg(op)
+            int_key = out is not None
         # both fast paths aggregate over the materialized input
         s = self._agg_stream(op) if out is None else None
         # the lowering taken, one event a traced HashAggOp
-        stats.add("fused.agg_folded" if s is not None
+        stats.add("fused.agg_int_key" if int_key
+                  else "fused.agg_folded" if s is not None
                   else "fused.agg_materialized")
         if out is not None:
             return out
@@ -913,7 +926,9 @@ class FusedRunner:
     def __init__(self, root: Operator):
         self.root = root
         self.schema = root.schema
-        self._progs: Dict[tuple, Tuple[Callable, List[Operator]]] = {}
+        # config key -> (program, flag_ops, result_cap, sort_lanes), or
+        # None for a config that proved unsupported
+        self._progs: Dict[tuple, Optional[tuple]] = {}
         # vkey (per-scan content-identity tuple) -> (args, chunks): lets a
         # warm run skip the prime walk (scan.stack + transfer) entirely
         self._exec_cache: "OrderedDict[tuple, Tuple[tuple, Dict[int, int]]]" \
@@ -1058,10 +1073,18 @@ class FusedRunner:
             # the packed window never exceeds the result's own static
             # capacity — a 12-lane aggregate reads back ~1 KB, not MBs
             tracer_box["result_cap"] = min(RESULT_CAP, out.capacity)
+            tracer_box["sort_lanes"] = t.sort_lanes
             return _pack_result(out, tuple(t.flags), schema,
                                 tracer_box["result_cap"])
 
         return prog, tracer_box
+
+    @staticmethod
+    def _prog_entry(compiled, tracer_box: dict) -> tuple:
+        """What _progs keeps of a compiled config: the program and what
+        its trace left in the side-box."""
+        return (compiled, tracer_box["flag_ops"], tracer_box["result_cap"],
+                tracer_box["sort_lanes"])
 
     def _prepare(self):
         # one sessions-shared critical section covering the warm-key
@@ -1144,8 +1167,7 @@ class FusedRunner:
                 except Unsupported:
                     self._progs[key] = None
                     raise
-            self._progs[key] = (compiled, tracer_box["flag_ops"],
-                                tracer_box["result_cap"])
+            self._progs[key] = self._prog_entry(compiled, tracer_box)
         return self._progs[key], args
 
     def aot_compile(self, extra_buckets: int = 1) -> int:
@@ -1202,8 +1224,7 @@ class FusedRunner:
                         # rungs still serve
                         self._progs[key] = None
                         continue
-                self._progs[key] = (compiled, tracer_box["flag_ops"],
-                                    tracer_box["result_cap"])
+                self._progs[key] = self._prog_entry(compiled, tracer_box)
                 done += 1
             return done
 
@@ -1219,7 +1240,8 @@ class FusedRunner:
         t_first = _time.perf_counter()
         try:
             with stats.timed("fused.prepare"):
-                (prog, flag_ops, result_cap), args = self._prepare()
+                (prog, flag_ops, result_cap, sort_lanes), args = \
+                    self._prepare()
         except Unsupported as e:
             # this run's volume (or shape) is outside the fusion grammar:
             # delegate wholesale to the streaming runtime
@@ -1248,7 +1270,10 @@ class FusedRunner:
             # and the device's execution time is billed to
             # fused.readback; readback measures only the transfer
             with stats.timed("fused.wait"):
-                return jax.block_until_ready(out)
+                out = jax.block_until_ready(out)
+            # one event a dispatch; the lanes are the traced shapes'
+            stats.add("fused.sort_lanes", rows=sort_lanes)
+            return out
 
         try:
             with stats.timed("fused.exec"):

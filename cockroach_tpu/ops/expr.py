@@ -30,8 +30,9 @@ from __future__ import annotations
 import contextlib
 import re
 import threading
+import weakref
 from dataclasses import dataclass
-from typing import Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -372,13 +373,31 @@ def _decimal_to_float(values, scale: int):
     return values.astype(jnp.float32) / jnp.float32(10 ** scale)
 
 
+# id(dictionary array) -> (weakref to it, {string: first code}): a string
+# bound against a served catalog's dictionary (sql/params.slot_value, once
+# a Bind) is one dict probe, not a walk of the dictionary
+_CODE_INDEX: Dict[int, Tuple[weakref.ref, Dict[str, int]]] = {}
+
+
+def _code_index(d: np.ndarray) -> Dict[str, int]:
+    hit = _CODE_INDEX.get(id(d))
+    if hit is not None and hit[0]() is d:
+        return hit[1]
+    # filled from the back, so a string that occurs twice keeps its
+    # first code (what np.nonzero(d == s)[0][0] gave)
+    index = dict(zip(d[::-1].tolist(), range(len(d) - 1, -1, -1)))
+    key = id(d)
+    _CODE_INDEX[key] = (weakref.ref(d, lambda _r: _CODE_INDEX.pop(key, None)),
+                        index)
+    return index
+
+
 def _string_code(schema: Schema, col: str, s: str) -> int:
     """Host-side: literal string -> dictionary code (-1 if absent)."""
     d = schema.dictionary(col)
     if d is None:
         raise ValueError(f"column {col} has no dictionary")
-    hits = np.nonzero(d == s)[0]
-    return int(hits[0]) if len(hits) else -1
+    return _code_index(d).get(s, -1)
 
 
 def _find_string_col(e: Expr) -> Optional[str]:

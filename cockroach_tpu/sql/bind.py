@@ -185,6 +185,7 @@ class Binder:
         self.params = params
         self.param_slots: List[ParamSlot] = []
         self._open_params = 0
+        self._having_aggs: Optional["_AggCollector"] = None
 
     # ---------------------------------------------------------------- bind
 
@@ -252,7 +253,11 @@ class Binder:
                 if not isinstance(arg, Col) or len(refs) != 1:
                     raise BindError("IN (subquery) needs a plain column "
                                     "on the left")
-                sub = Binder(self.catalog).bind(ast.query)
+                # one statement, one list of slots: a `$n` inside the
+                # subquery is an argument of the same program
+                sub_binder = Binder(self.catalog, params=self.params)
+                sub_binder.param_slots = self.param_slots
+                sub = sub_binder.bind(ast.query)
                 sub_cols = _plan_columns(sub, self.catalog)
                 key = f"__sub{sub_n}"
                 sub_n += 1
@@ -568,8 +573,10 @@ class Binder:
         try:
             ty = other.type(self._global)
         except (KeyError, ValueError, TypeError):
+            ty = self._agg_result_type(other)
+        if ty is None:
             raise ParamOutOfScope("the operand beside a parameter has no "
-                                  "type") from None
+                                  "type")
         column = other.name if isinstance(other, Col) else None
         if ty.kind not in _PARAM_KINDS or (ty.kind is Kind.STRING
                                            and column is None):
@@ -587,6 +594,16 @@ class Binder:
         else:
             self.param_slots.append(slot)
         return Param(slot.index, ty, sample_of(slot, self.params))
+
+    def _agg_result_type(self, e: Expr):
+        """The type of an aggregate's result that the HAVING being bound
+        names first (`having sum(l_quantity) > $1`): the schema HAVING's
+        literals are retyped against was made before HAVING's own
+        aggregates were collected, so it does not hold the column yet."""
+        if self._having_aggs is None or not isinstance(e, Col):
+            return None
+        out = self._having_aggs.output_schema(self._global)
+        return out.field(e.name).type if e.name in out.names() else None
 
     def _split_and(self, node: P.Node) -> List[P.Node]:
         if isinstance(node, P.Binary) and node.op == "and":
@@ -864,8 +881,10 @@ class Binder:
             # make aggregate outputs typable for literal retyping
             self._global = self._merge_schemas(
                 [self._global, collector.output_schema(self._global)])
+            self._having_aggs = collector
             having_expr = self._bx(_fold_dates(stmt.having), refs,
                                    allow_agg=True, aggs=collector)
+            self._having_aggs = None
             has_agg = True
 
         self._select_names = [n for n, _ in items]
