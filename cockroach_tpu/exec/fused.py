@@ -79,7 +79,7 @@ from cockroach_tpu.ops.vector import distance_fn
 from cockroach_tpu.ops.join import (
     effective_build_mode, hash_join_prepared, prepare_build,
 )
-from cockroach_tpu.ops.sortjoin import carries, probe_unique_compact
+from cockroach_tpu.ops.sortjoin import compacts, probe_unique_compact
 from cockroach_tpu.ops.sort import sort_batch, top_k_batch
 
 
@@ -496,13 +496,15 @@ class _Tracer:
     def _mat_join(self, op: JoinOp,
                   shrink: Optional[ShrinkOp] = None) -> Tuple[Batch, bool]:
         """-> (batch, compacted). With `shrink` (the ShrinkOp above a
-        _compactable join) and a build the carry join takes, the pair is
-        ONE step that compacts the matched probe lanes in key order
-        (ops/sortjoin.probe_unique_compact): the join never restores the
-        probe order that the Shrink would discard one operator later.
-        The join's fallback flag and the Shrink's overflow flag keep
-        their operators and their order, so the restart ladder is the
-        two-step path's."""
+        _compactable join) and a build whose key takes the narrow
+        packing (`compacts`: nothing is asked of its other columns), the
+        pair is ONE step that compacts the matched probe lanes in key
+        order (ops/sortjoin.probe_unique_compact): the join never
+        restores the probe order that the Shrink would discard one
+        operator later, and fetches the build's columns by row index at
+        the Shrink's lanes. The join's fallback flag and the Shrink's
+        overflow flag keep their operators and their order, so the
+        restart ladder is the two-step path's."""
         probe = self._mat(op.probe)
         build, b_ovf = self._join_build(op)
         probe_on, build_on = tuple(op.probe_on), tuple(op.build_on)
@@ -512,7 +514,7 @@ class _Tracer:
         guard = self._route_guard(op)
         self.flag_ops.extend(_flag_targets(guard, op))
         self.sort_lanes += probe.capacity + build.capacity
-        if shrink is not None and carries(bt, probe.capacity, op.how):
+        if shrink is not None and compacts(bt, probe.capacity, op.how):
             res = probe_unique_compact(probe, bt, probe_on, op.how,
                                        shrink.capacity)
             stats.add("fused.join_compact")

@@ -1302,12 +1302,16 @@ class JoinOp(Operator):
 
     def widen(self):
         """FlowRestart remedy — descend the mode ladder: payload-carry
-        unique ("unique", flags when the bit-packed payload exceeds 62
-        bits) -> row-matrix unique ("unique-mat", flags on duplicate
-        build keys) -> general expansion -> doubled output expansion.
-        Checks the EFFECTIVE mode: a join statically downgraded (wide
-        build side) was already running expand, so its first restart
-        must widen, not burn a rerun on a no-op mode flip."""
+        unique ("unique", flags on duplicate build keys, on a key
+        outside the narrow packing's [0, 2^30) and, where the join
+        resorts to probe order, on a bit-packed payload over 62 bits;
+        the compacting form under a Shrink carries a row index and has
+        no such width) -> row-matrix unique ("unique-mat", flags on
+        duplicate build keys) -> general expansion -> doubled output
+        expansion. Checks the EFFECTIVE mode: a join statically
+        downgraded (wide build side) was already running expand, so its
+        first restart must widen, not burn a rerun on a no-op mode
+        flip."""
         from cockroach_tpu.ops.join import effective_build_mode
 
         eff = effective_build_mode(self.build_mode,

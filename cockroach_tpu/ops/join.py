@@ -64,9 +64,11 @@ def effective_build_mode(mode: str, build_names: Sequence[str],
                          build_on: Sequence[str]) -> str:
     """Static downgrade of the unique fast paths. Modes (the restart
     ladder JoinOp.widen descends): "unique" = payload-carry sort join
-    (build columns ride the sorts bit-packed); "unique-mat" = sort join
-    with a row-matrix gather (the r4 path — the fallback when the carry
-    payload exceeds 62 bits at run time); "expand" = general
+    (build columns ride the sorts bit-packed; directly under a Shrink
+    the build's row index rides instead and no width binds);
+    "unique-mat" = sort join with a row-matrix gather (the r4 path —
+    the fallback when the resorting form's payload exceeds 62 bits at
+    run time, or a key leaves [0, 2^30)); "expand" = general
     many-to-many. The row matrix's packed-boolean lane holds at most 64
     bits — worst case 1 (sel) + 2 per column, so 31 columns is the safe
     bound; wider build sides go straight to expand."""

@@ -35,18 +35,23 @@ key + one i32 iota) and moves whole rows exactly once:
      b. COMPACT the matched probe lanes where they stand, in key order
         (probe_unique_compact): one single-operand u32 sort of lcap +
         rcap lanes puts the matches first (_first_matches), and C-row
-        gathers fetch their probe lane index and payload and (step 6)
-        their probe rows. For an inner or semi join whose only reader
-        is a ShrinkOp of capacity C (exec/fused._Tracer._mat_join): a
-        selective join keeps a sliver of its probe, and the second
-        full-width sort of (a) would restore an order that the Shrink
-        discards one operator later (Q3 at SF1: 37.6 of 211 ms a
-        statement, PERF.md section 6, PR 26);
+        gathers fetch their probe lane index and build ROW INDEX and
+        (step 6) the rows of both sides. For an inner or semi join
+        whose only reader is a ShrinkOp of capacity C
+        (exec/fused._Tracer._mat_join): a selective join keeps a sliver
+        of its probe, and the second full-width sort of (a) would
+        restore an order that the Shrink discards one operator later
+        (Q3 at SF1: 37.6 of 211 ms a statement, PERF.md section 6,
+        PR 26). Here a build lane carries its row index, not its
+        columns, through the key sort: they are wanted at C lanes, not
+        at lcap, so their number and width ask nothing of the sort
+        (Q18's last join builds on five columns, 91 bits: PR 34);
   6. ONE (lcap, W) row gather pulls each matched build row's columns
      from the build side's pre-packed row matrix (rowmat.pack_rows at
      prepare time) — a row gather costs the same as a 1-D gather. (The
-     payload-carry build of round 5 needs none: its columns ride the
-     sorts. Form (b) gathers C rows of the PROBE's columns instead.)
+     payload-carry build of round 5 needs none under form (a): its
+     columns ride the sorts. Form (b) gathers C rows of the probe's
+     columns and, for an inner join, C rows of the build's.)
 
 Unique-build covers every FK->PK join TPC-H runs (the build side of
 every flagship-query join is its primary key). Output capacity == probe
@@ -79,12 +84,15 @@ _MASK62 = np.uint64((1 << 62) - 1)
 class UniqueBuild(NamedTuple):
     """A build side prepared for the unique-key sort join.
 
-    Round 5: int-keyed builds whose non-key columns bit-pack into <=62
-    bits carry them as ONE sort value operand (`payv`/`pay_plan`,
-    ops/bitpack.py) instead of a row matrix — the join then moves build
-    data exclusively through its two sorts and the row-matrix gather
-    (the single largest device cost of r4 joins, ~30ms per 4M rows)
-    disappears. `mat` stays for the hash-kind/verification and
+    Round 5: int-keyed builds whose non-key columns bit-pack (ops/
+    bitpack.py) carry them as ONE u64 sort value operand (`payv`/
+    `pay_plan`) instead of a row matrix — the resorting join then moves
+    build data exclusively through its two sorts and the row-matrix
+    gather (the single largest device cost of r4 joins, ~30ms per 4M
+    rows) disappears, while 62 bits hold the payload (`fallback`
+    otherwise). The compacting join reads `batch` and the narrow
+    `packed` keys of the same build and neither `payv` nor `pay_plan`.
+    `mat` stays for the hash-kind/verification and
     matched-build-tracking paths."""
 
     batch: Batch
@@ -235,32 +243,40 @@ class _CarrySorted(NamedTuple):
     s_val: jnp.ndarray         # build lanes: payload; probe lanes: lane idx
     is_build: jnp.ndarray
     match_sorted: jnp.ndarray  # probe lane whose run starts with a build
-    bpay: jnp.ndarray          # uint64: the run's build payload
+    bpay: jnp.ndarray          # the run's build payload: uint64 packed
+    #                            columns, or (rows=True) int32 build row
     fallback: jnp.ndarray
 
 
 def _carry_sort(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
-                payload: bool = True) -> _CarrySorted:
+                rows: bool = False) -> _CarrySorted:
     """Steps 1-4 of the carry join: probe key packing, key sort, run
-    detection, the duplicate / range / wide-payload `fallback` flag and
-    the split-cummax broadcast of each run's build payload (ONE copy,
-    whatever step 5 does with it). `payload=False` (a compacting
-    semi join: nothing of the build is emitted) sorts a u32 lane index
-    beside the key instead of the u64 payload; `bpay` is then unused and
-    XLA drops its half of the broadcast."""
+    detection, the deferred `fallback` flag and the broadcast of each
+    run's build payload (ONE copy, whatever step 5 does with it). What a
+    build lane carries beside its key is one of two things:
+
+    - its non-key columns bit-packed into one u64 (`ub.payv`): the form
+      the resort needs, where every probe lane receives the columns. 62
+      bits hold it or `fallback` is raised, and the broadcast is the
+      split cummax (two 31-bit halves under the run id);
+    - `rows`: its own ROW INDEX as a u32 (< rcap < 2^30, whatever the
+      build's columns): the form the compaction takes, which fetches
+      the columns from `ub.batch` at the C surviving lanes only. No
+      width to overflow, and ONE cummax of (runid << 32 | row + 1)
+      broadcasts it. A semi join ignores the row and reads the match."""
     lcap, rcap = probe.capacity, ub.batch.capacity
     n = lcap + rcap
     p_packed, p_range = _pack_keys(
         probe, probe_on, 1, ub.seed, ub.key_kind,
         narrow=(ub.packed.dtype == jnp.uint32))
     packed = jnp.concatenate([ub.packed, p_packed])
-    # value operand: build lanes carry the packed payload, probe lanes
-    # their own lane index (the destination for the resort)
+    # value operand: build lanes carry their payload, probe lanes their
+    # own lane index (the destination for the resort)
     lane = jnp.arange(lcap, dtype=jnp.uint32)
-    if payload:
-        val = jnp.concatenate([ub.payv, lane.astype(jnp.uint64)])
+    if rows:
+        val = jnp.concatenate([jnp.arange(rcap, dtype=jnp.uint32), lane])
     else:
-        val = jnp.concatenate([jnp.zeros((rcap,), jnp.uint32), lane])
+        val = jnp.concatenate([ub.payv, lane.astype(jnp.uint64)])
     s_packed, s_val = jax.lax.sort((packed, val), num_keys=1)
 
     one = s_packed.dtype.type(1)  # u32 (narrow carry keys) or u64
@@ -270,8 +286,13 @@ def _carry_sort(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
     newrun = (pos == 0) | ~same_key
     is_build = (s_packed & one) == s_packed.dtype.type(0)
     dup = jnp.any(is_build & ~newrun)
-    pay_wide = ub.pay_plan.total_bits > jnp.int32(62)
-    fallback = dup | ub.range_flag | p_range | pay_wide
+    fallback = dup | ub.range_flag | p_range
+    if rows:
+        has_b, brow = _run_build_broadcast(newrun, is_build,
+                                           s_val.astype(jnp.int32))
+        return _CarrySorted(s_packed, s_val, is_build, ~is_build & has_b,
+                            brow, fallback)
+    fallback = fallback | (ub.pay_plan.total_bits > jnp.int32(62))
 
     # broadcast the build payload to its run: split-cummax (62-bit
     # payload in two 31-bit halves; runid rides the high 32 bits so a
@@ -279,9 +300,8 @@ def _carry_sort(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
     runid = jnp.cumsum(newrun.astype(jnp.int32)).astype(jnp.int64)
     M31 = np.uint64(0x7FFFFFFF)
     M32 = np.int64(0xFFFFFFFF)
-    pay = s_val.astype(jnp.uint64)
-    lo31 = (pay & M31).astype(jnp.int64)
-    hi31 = (pay >> np.uint64(31)).astype(jnp.int64)
+    lo31 = (s_val & M31).astype(jnp.int64)
+    hi31 = (s_val >> np.uint64(31)).astype(jnp.int64)
     m1 = jax.lax.cummax((runid << np.int64(32))
                         | jnp.where(is_build, lo31 + 1, 0))
     m2 = jax.lax.cummax((runid << np.int64(32))
@@ -293,17 +313,6 @@ def _carry_sort(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
                       << np.uint64(31))
     return _CarrySorted(s_packed, s_val, is_build, ~is_build & has_b,
                         bpay, fallback)
-
-
-def _synth_build_keys(bcols, probe: Batch, ub: UniqueBuild,
-                      probe_on: Sequence[str], match) -> None:
-    """The build key equals the probe key on every matched lane: the
-    carry payload holds non-key columns only."""
-    for pn, bn in zip(probe_on, ub.build_on):
-        bdt = ub.batch.col(bn).values.dtype
-        v = jnp.where(match, probe.col(pn).values.astype(bdt),
-                      jnp.zeros((), bdt))
-        bcols[bn] = Column(v, match)
 
 
 def _probe_carry(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
@@ -341,7 +350,13 @@ def _probe_carry(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
                           None)
     bcols = bitpack.unpack_lanes(o_bpay, ub.pay_plan, build,
                                  valid_and=match)
-    _synth_build_keys(bcols, probe, ub, probe_on, match)
+    # the build key equals the probe key on every matched lane: the
+    # payload holds non-key columns only
+    for pn, bn in zip(probe_on, ub.build_on):
+        bdt = build.col(bn).values.dtype
+        v = jnp.where(match, probe.col(pn).values.astype(bdt),
+                      jnp.zeros((), bdt))
+        bcols[bn] = Column(v, match)
     cols = dict(probe.columns)
     cols.update(bcols)
     sel = probe.sel if how == "left" else (probe.sel & match)
@@ -361,6 +376,16 @@ def carries(ub: UniqueBuild, probe_capacity: int, how: str,
     return (ub.pay_plan is not None
             and how in ("inner", "left", "semi", "anti")
             and not track_build
+            and probe_capacity + ub.batch.capacity < (1 << 30))
+
+
+def compacts(ub: UniqueBuild, probe_capacity: int, how: str) -> bool:
+    """Does probe_unique_compact take this probe? An inner or semi join
+    over an int-kind key in the narrow u32 packing (prepare_unique's
+    carry form). Nothing is asked of the build's other columns: they do
+    not ride the sort."""
+    return (ub.key_kind == "int" and ub.packed.dtype == jnp.uint32
+            and how in ("inner", "semi")
             and probe_capacity + ub.batch.capacity < (1 << 30))
 
 
@@ -391,20 +416,21 @@ def probe_unique_compact(probe: Batch, ub: UniqueBuild,
     probe order: the matched probe lanes are known in the sorted domain,
     so the destination resort is replaced by the compaction's own sort
     there (_first_matches), one C-row gather of each match's probe lane
-    index and broadcast payload, and one C-row gather of the probe's
-    columns. A semi join emits nothing of the build: its compaction
-    carries the probe lane index itself and gathers probe rows only.
-    Inner and semi joins over a carry-eligible build (`carries`) only;
-    the lane order of the result is the key order, which no consumer of
-    a compacted batch may rely on."""
+    index and build row index, and one C-row gather a side of the
+    columns themselves. The build's columns never ride a sort, so their
+    number and width are free (ops/groupjoin.group_join_aggregate
+    fetches its build columns the same way). A semi join emits nothing
+    of the build: its compaction carries the probe lane index itself and
+    gathers probe rows only. Inner and semi joins over a build that
+    `compacts` only; the lane order of the result is the key order,
+    which no consumer of a compacted batch may rely on."""
     from cockroach_tpu.coldata.batch import mask_padding
-    from cockroach_tpu.ops import bitpack
 
-    if how not in ("inner", "semi") or not carries(ub, probe.capacity, how):
+    if not compacts(ub, probe.capacity, how):
         raise ValueError(f"no compacting probe for a {how} join of this "
                          f"build")
     C = capacity
-    cs = _carry_sort(probe, ub, probe_on, payload=(how == "inner"))
+    cs = _carry_sort(probe, ub, probe_on, rows=True)
     # a sentinel probe lane (dead lane or NULL key: top bit) pairs with
     # the same-index build sentinel and is no match: the key-liveness
     # guard of the resorting form, taken in the sorted domain
@@ -414,27 +440,25 @@ def probe_unique_compact(probe: Batch, ub: UniqueBuild,
     n_match = jnp.sum(match).astype(jnp.int32)
     length = jnp.minimum(n_match, C).astype(jnp.int32)
     sel = jnp.arange(C) < length
-    lane_sorted = cs.s_val.astype(jnp.uint32)  # probe lane index < 2^30
     if how == "semi":
-        lane = _first_matches(match, lane_sorted, C)
+        lane = _first_matches(match, cs.s_val, C)  # lane index < 2^30
     else:
-        # one (C, 3) row gather: three 1-D gathers cost three times it
+        # one (C, 2) row gather: two 1-D gathers cost twice it
         kidx = _first_matches(
             match, jnp.arange(match.shape[0], dtype=jnp.uint32), C)
-        got = jnp.stack(
-            [lane_sorted, cs.bpay.astype(jnp.uint32),
-             (cs.bpay >> np.uint64(32)).astype(jnp.uint32)],
-            axis=1)[kidx]
-        lane = got[:, 0].astype(jnp.int32)
-        bpay = got[:, 1].astype(jnp.uint64) | (
-            got[:, 2].astype(jnp.uint64) << np.uint64(32))
-    out = probe.gather(lane, sel=sel, length=length)
-    cols = dict(out.columns)
+        got = jnp.stack([cs.s_val.astype(jnp.int32), cs.bpay],
+                        axis=1)[kidx]
+        lane = got[:, 0]
+    cols = dict(probe.gather(lane, sel=sel, length=length).columns)
     if how == "inner":
-        bcols = bitpack.unpack_lanes(bpay, ub.pay_plan, ub.batch,
-                                     valid_and=sel)
-        _synth_build_keys(bcols, out, ub, probe_on, sel)
-        cols.update(bcols)
+        # the build's columns, its key among them, from its own lanes
+        # (a matched lane is live, and its key the probe's), under the
+        # join's NULL-padding contract
+        for name, c in ub.batch.gather(got[:, 1]).columns.items():
+            valid = sel if c.validity is None else c.validity & sel
+            cols[name] = Column(
+                jnp.where(valid, c.values, jnp.zeros((), c.values.dtype)),
+                valid)
     return CompactJoin(Batch(mask_padding(cols, sel), sel, length),
                        cs.fallback, n_match > C)
 

@@ -267,6 +267,34 @@ def _sorts(jaxpr):
             for eqn in _eqns(jaxpr) if eqn.primitive.name == "sort"]
 
 
+def _cummaxes(jaxpr):
+    """Lanes of every cummax equation (a carry join's run broadcast: one
+    for a row index, two for the 62-bit payload's halves)."""
+    return [eqn.invars[0].aval.shape[0] for eqn in _eqns(jaxpr)
+            if eqn.primitive.name == "cummax"]
+
+
+def _record_joins(monkeypatch):
+    """-> (compacted, two_step): lists that fill with (lcap, rcap, how)
+    of every join the tracer lowers as one step with its Shrink, and of
+    every join it hands to hash_join_prepared."""
+    compacted, two_step = [], []
+    real_compact, real_join = (fused.probe_unique_compact,
+                               fused.hash_join_prepared)
+
+    def compact(probe, ub, probe_on, how, capacity):
+        compacted.append((probe.capacity, ub.batch.capacity, how))
+        return real_compact(probe, ub, probe_on, how, capacity)
+
+    def join(probe, bt, *a, **kw):
+        two_step.append((probe.capacity, bt.batch.capacity, kw["how"]))
+        return real_join(probe, bt, *a, **kw)
+
+    monkeypatch.setattr(fused, "probe_unique_compact", compact)
+    monkeypatch.setattr(fused, "hash_join_prepared", join)
+    return compacted, two_step
+
+
 def _mesh_jaxpr(root, n_dev, limit):
     """-> (jaxpr, flag_ops) of `root`'s distributed program over `n_dev`
     virtual devices, with the broadcast limit at `limit` rows (what
@@ -320,20 +348,7 @@ def test_q3_program_sorts_per_join(program, monkeypatch):
     from tests.test_sql import Q3_SQL
 
     _gen, cat = _sql_catalog()
-    compacted, two_step = [], []  # (lcap, rcap) of the joins lowered so
-    real_compact, real_join = (fused.probe_unique_compact,
-                               fused.hash_join_prepared)
-
-    def compact(probe, ub, *a):
-        compacted.append((probe.capacity, ub.batch.capacity))
-        return real_compact(probe, ub, *a)
-
-    def join(probe, bt, *a, **kw):
-        two_step.append((probe.capacity, bt.batch.capacity))
-        return real_join(probe, bt, *a, **kw)
-
-    monkeypatch.setattr(fused, "probe_unique_compact", compact)
-    monkeypatch.setattr(fused, "hash_join_prepared", join)
+    compacted, two_step = _record_joins(monkeypatch)
     if program == "two_step":
         monkeypatch.setattr(fused._Tracer, "_compactable",
                             lambda self, op: False)
@@ -360,7 +375,7 @@ def test_q3_program_sorts_per_join(program, monkeypatch):
     if program.startswith("mesh"):
         # the semi join orders x customer is local to a shard (16,384
         # lanes a side) and compacts: key sort and compaction sort
-        assert compacted == [(16384, 16384)]
+        assert compacted == [(16384, 16384, "semi")]
         assert sorts.count((32768, "uint32", 2)) == 1
         assert sorts.count((32768, "uint32", 1)) == 1
         # the router: one stable sort by destination a side, carrying the
@@ -377,7 +392,7 @@ def test_q3_program_sorts_per_join(program, monkeypatch):
         # build lanes, and takes the two steps: key sort, resort to probe
         # order, and the Shrink's argsort over the probe lanes
         n = 4 * 8192 + 4 * build_bucket
-        assert two_step == [(32768, 4 * build_bucket)]
+        assert two_step == [(32768, 4 * build_bucket, "inner")]
         assert sorts.count((n, "uint32", 2)) == 1
         assert sorts.count((n, "int32", 2)) == 1
         assert sorts.count((32768, "bool", 2)) == 1
@@ -388,14 +403,19 @@ def test_q3_program_sorts_per_join(program, monkeypatch):
         return
     assert (len(compacted), len(two_step)) == {
         "compact": (2, 0), "two_step": (0, 2)}[program]
-    for lcap, rcap in compacted:
+    cummaxes = _cummaxes(jaxpr.jaxpr)
+    for lcap, rcap, _how in compacted:
         at_n = sorted(s[1:] for s in sorts if s[0] == lcap + rcap)
         assert at_n == [("uint32", 1), ("uint32", 2)]
         assert (lcap, "bool", 2) not in sorts
-    for lcap, rcap in two_step:
+        # the build's row index under the run id: one scan, where the
+        # resorting form's 62-bit payload takes two
+        assert cummaxes.count(lcap + rcap) == 1
+    for lcap, rcap, _how in two_step:
         at_n = sorted(s[1:] for s in sorts if s[0] == lcap + rcap)
         assert at_n == [("int32", 2), ("uint32", 2)]
         assert sorts.count((lcap, "bool", 2)) == 1
+        assert cummaxes.count(lcap + rcap) == 2
 
 
 def _shrunk_join(how, capacity=512, second_parent=False):

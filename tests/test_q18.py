@@ -15,6 +15,7 @@ sorted lanes counted with the lanes written out.
 import datetime
 from decimal import Decimal
 
+import jax
 import numpy as np
 import pytest
 
@@ -24,6 +25,7 @@ from benchmark.paramgen import tpch_qgen_q18
 from benchmark.reference import tpch_q18
 from cockroach_tpu.coldata.batch import Kind
 from cockroach_tpu.exec import fused, stats
+from cockroach_tpu.exec.operators import JoinOp, ScanOp, walk_operators
 from cockroach_tpu.ops import expr as expr_mod
 from cockroach_tpu.sql import params as P_
 from cockroach_tpu.sql import parser
@@ -228,9 +230,10 @@ def test_four_bindings_run_one_program(served, monkeypatch):
             if "flow.restart" in col.stages else 0
     finally:
         stats.disable()
-    # the first binding lowers the statement's program (and once more
-    # where a guard restarted it wider); the other three lower nothing
-    assert len(texts) == lowered_after_first <= 2
+    # the first binding lowers the statement's ONE program (no guard
+    # restarts it: the last join's build columns, 91 bits at SF1, ride
+    # no sort since ISSUE 34); the other three lower nothing
+    assert len(texts) == lowered_after_first == 1
     assert all("312" not in t.split("main")[0] for t in texts)
     prep = sess._prepared.get(Q18)
     assert prep is not None and len(prep.slots) == 1
@@ -240,7 +243,55 @@ def test_four_bindings_run_one_program(served, monkeypatch):
     assert _counter("sql_bind_params_total") == as_data + 4
     assert _counter("sql_bind_textual_total") == textual
     assert col.stages["sql.prepared_hit"].events == 3
-    assert restarts <= 1
+    assert restarts == 0
+    assert col.stages["fused.join_compact"].events == 2
+    assert [j.build_mode for j in walk_operators(prep.op)
+            if isinstance(j, JoinOp)] == ["unique"] * 3
+
+
+def test_q18_program_sorts_per_join(served, monkeypatch):
+    """Q18's served program at SF 0.01 (tests/test_fused's
+    test_q3_program_sorts_per_join, for this statement): BOTH joins under
+    a Shrink lower with it, the semi join and the lineitem join whose
+    build carries o_custkey, o_totalprice, o_orderdate, c_custkey and
+    c_name (no u64 holds them at SF1; the row index rides the sort
+    instead). Per compacted join: the key sort and the compaction's
+    one-operand sort at lcap + rcap lanes, no resort by destination, ONE
+    cummax (the row index under the run id), and no argsort of its own
+    at lcap: the one `(pred, i32)` argsort left at 131,072 lanes is the
+    HAVING's Shrink over the aggregate's run-ends view. The customer
+    join has no Shrink above it and resorts, with the split cummax."""
+    from tests.test_fused import _cummaxes, _record_joins, _sorts
+
+    sess = _session(served)
+    sess._prepared = type(sess._prepared)()
+    bound, text = sess.bind_params(Q18, ("312",))
+    sess.execute(text, params=bound)
+    prep = sess._prepared.get(Q18)
+    runner = prep.op._fused_runner
+    _entry, args = runner._prepare()
+    compacted, two_step = _record_joins(monkeypatch)
+    scans = [n for n in walk_operators(prep.op) if isinstance(n, ScanOp)]
+    prog, _box = runner._make_prog([id(sc) for sc in scans])
+    col = stats.enable()
+    try:
+        jaxpr = jax.make_jaxpr(prog)(*args, P_.evaluate(prep.slots,
+                                                        ("312",)))
+    finally:
+        stats.disable()
+    assert col.stages["fused.join_compact"].events == 2
+    assert compacted == [(CAP, 4096, "semi"), (CAP, 16384, "inner")]
+    assert two_step == [(CAP, CAP, "inner")]
+    sorts = _sorts(jaxpr.jaxpr)
+    cummaxes = _cummaxes(jaxpr.jaxpr)
+    for lcap, rcap, _how in compacted:
+        at_n = sorted(s[1:] for s in sorts if s[0] == lcap + rcap)
+        assert at_n == [("uint32", 1), ("uint32", 2)]
+        assert cummaxes.count(lcap + rcap) == 1
+    assert sorted(s[1:] for s in sorts if s[0] == 2 * CAP) == [
+        ("int32", 2), ("uint32", 2)]
+    assert cummaxes.count(2 * CAP) == 2
+    assert sorts.count((CAP, "bool", 2)) == 1
 
 
 @pytest.mark.parametrize("value", ["312.005", "many"])

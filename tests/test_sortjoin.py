@@ -12,6 +12,7 @@ import pytest
 
 import cockroach_tpu  # noqa: F401  (x64 config)
 from cockroach_tpu.coldata.batch import Batch, Column
+from cockroach_tpu.ops import bitpack
 from cockroach_tpu.ops.join import hash_join
 
 
@@ -263,12 +264,45 @@ def _compact_cases():
                     rng.random(m) > 0.2),
              "bw": rng.integers(-(1 << 15), 1 << 15, m).astype(np.int64)},
             rng.random(m) > 0.1, 512, False, False),
+        # a build whose columns no u64 holds compacts all the same: they
+        # do not ride the sort (the row index does); only the resorting
+        # form (_two_step's probe_unique on "unique") flags the width
         "payload_over_62_bits": (
             plain_probe, None,
             {"bk": keys,
              "bv": rng.integers(0, 1 << 40, m).astype(np.int64),
              "bw": rng.integers(0, 1 << 40, m).astype(np.int64)}, None,
-            512, True, False),
+            512, False, False),
+        "three_wide_columns": (
+            plain_probe, rng.random(n) > 0.1,
+            {"bk": keys,
+             "bv": rng.integers(-(1 << 50), 1 << 50, m).astype(np.int64),
+             "bw": (rng.integers(0, 1 << 45, m).astype(np.int64),
+                    rng.random(m) > 0.3),
+             "bx": rng.integers(0, 1 << 61, m).astype(np.int64)},
+            rng.random(m) > 0.1, 512, False, False),
+        "wide_columns_and_a_float32": (
+            plain_probe, None,
+            {"bk": (keys, rng.random(m) > 0.1),
+             "bv": rng.integers(0, 1 << 40, m).astype(np.int64),
+             "bw": rng.integers(0, 1 << 40, m).astype(np.int64),
+             "bf": (rng.integers(-999, 999, m).astype(np.float32),
+                    rng.random(m) > 0.2),
+             "bb": rng.random(m) > 0.5}, None,
+            512, False, False),
+        "wide_columns_more_matches_than_c": (
+            plain_probe, None,
+            {"bk": keys,
+             "bv": rng.integers(0, 1 << 40, m).astype(np.int64),
+             "bw": rng.integers(0, 1 << 40, m).astype(np.int64)}, None,
+            16, False, True),
+        "wide_columns_duplicate_build_keys": (
+            {"pk": np.array([1, 2, 3], dtype=np.int64),
+             "pv": np.arange(3, dtype=np.int64)}, None,
+            {"bk": np.array([2, 2, 3], dtype=np.int64),
+             "bv": np.array([7, 8, 9], dtype=np.int64) << 40,
+             "bw": np.array([7, 8, 9], dtype=np.int64) << 41}, None,
+            8, True, False),
     }
 
 
@@ -276,11 +310,18 @@ _COMPACT_CASES = _compact_cases()
 
 
 def _two_step(probe, build, how, capacity):
-    """probe_unique, then the ShrinkOp's own compaction."""
+    """probe_unique, then the ShrinkOp's own compaction. A build the
+    carry join's one u64 cannot hold takes the restart ladder's next
+    rung, the row-matrix join, as JoinOp.widen would after the flag."""
     from cockroach_tpu.exec.operators import ShrinkOp
     from tests.test_exec import _source
 
     res = hash_join(probe, build, ("pk",), ("bk",), how=how, mode="unique")
+    wide = [c for c in build.columns if c != "bk"]
+    if wide and int(bitpack.plan_pack(build, wide).total_bits) > 62:
+        assert bool(res.overflow)
+        res = hash_join(probe, build, ("pk",), ("bk",), how=how,
+                        mode="unique-mat")
     shrink = ShrinkOp(_source({"x": np.zeros(1, np.int64)}, capacity=1),
                       capacity)
     out, overflow = shrink.shrink_traceable(res.batch)
@@ -293,12 +334,12 @@ def test_compacting_probe_matches_probe_then_shrink(case, how):
     """probe_unique_compact == probe_unique + ShrinkOp.shrink_traceable:
     the same row multiset, the same fallback and overflow flags."""
     from cockroach_tpu.ops.join import prepare_build
-    from cockroach_tpu.ops.sortjoin import carries, probe_unique_compact
+    from cockroach_tpu.ops.sortjoin import compacts, probe_unique_compact
 
     pcols, psel, bcols, bsel, C, fallback, overflow = _COMPACT_CASES[case]
     probe, build = _batch(pcols, psel), _batch(bcols, bsel)
     ub = prepare_build(build, ("bk",), mode="unique")
-    assert carries(ub, probe.capacity, how)
+    assert compacts(ub, probe.capacity, how)
     got = probe_unique_compact(probe, ub, ("pk",), how, C)
     want, want_fallback, want_overflow = _two_step(probe, build, how, C)
     assert (bool(got.fallback), bool(got.overflow)) == (fallback, overflow)
@@ -332,15 +373,40 @@ def test_compacting_probe_refuses_other_join_types(how):
         probe_unique_compact(_batch(pcols), ub, ("pk",), how, C)
 
 
-def test_compacting_probe_needs_a_carry_build():
-    """A row-matrix build (unique-mat, or a hash-kind key) has no payload
-    to broadcast: the tracer sees `carries` false and takes two steps."""
+@pytest.mark.parametrize("build_cols,mode,takes", [
+    ("plain", "unique", True),
+    ("payload_over_62_bits", "unique", True),
+    # the row-matrix rung sorts u64 keys and has no narrow packing
+    ("plain", "unique-mat", False),
+    # a hash-kind key (two columns, or a float) needs its verification
+    # gather at every probe lane: the row-matrix form
+    ("two_key_columns", "unique", False),
+    ("float_key", "unique", False),
+])
+def test_compacting_probe_needs_a_carry_build(build_cols, mode, takes):
+    """`compacts` asks for an int-kind key in the narrow u32 packing and
+    nothing of the build's other columns; where it is false the tracer
+    takes two steps and probe_unique_compact refuses."""
     from cockroach_tpu.ops.join import prepare_build
-    from cockroach_tpu.ops.sortjoin import carries, probe_unique_compact
+    from cockroach_tpu.ops.sortjoin import (
+        carries, compacts, probe_unique_compact,
+    )
 
-    pcols, _ps, bcols, _bs, C, _f, _o = _COMPACT_CASES["plain"]
-    probe = _batch(pcols)
-    ub = prepare_build(_batch(bcols), ("bk",), mode="unique-mat")
-    assert not carries(ub, probe.capacity, "inner")
+    pcols, _ps, plain, _bs, C, _f, _o = _COMPACT_CASES["plain"]
+    probe, on, probe_on = _batch(pcols), ("bk",), ("pk",)
+    if build_cols == "two_key_columns":
+        bcols, on, probe_on = (dict(plain, bk2=plain["bk"]), ("bk", "bk2"),
+                               ("pk", "pv"))
+    elif build_cols == "float_key":
+        bcols = dict(plain, bk=plain["bk"].astype(np.float64))
+    else:
+        bcols = _COMPACT_CASES[build_cols][2]
+    ub = prepare_build(_batch(bcols), on, mode=mode)
+    assert compacts(ub, probe.capacity, "inner") == takes
+    assert compacts(ub, probe.capacity, "semi") == takes
+    if takes:
+        # the resorting form still wants the packed payload
+        assert carries(ub, probe.capacity, "inner")
+        return
     with pytest.raises(ValueError):
-        probe_unique_compact(probe, ub, ("pk",), "inner", C)
+        probe_unique_compact(probe, ub, probe_on, "inner", C)
