@@ -52,6 +52,7 @@ import functools
 import operator
 import threading
 from collections import OrderedDict
+from contextlib import contextmanager
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -265,6 +266,29 @@ def takes_params(root: Operator) -> bool:
                for op in walk_operators(root) if isinstance(op, MapOp))
 
 
+SCOPE_PREFIX = "crdb."
+RESULT_SCOPE = "result"     # crdb.result: _pack_result
+# parts of an operator's scope that the distributed tracer tells apart
+# (parallel/dist_flow.py): what it adds to a join, to an aggregate or top-K
+EXCHANGE, MERGE = "exchange", "merge"
+
+
+def scope(name: str):
+    """The program's own name for what is lowered inside: a
+    jax.named_scope, so every instruction traced under it carries
+    `crdb.<name>` in its `op_name` and exec/device_profile.py can book
+    the instruction's device time to it. Debug information only: the
+    persistent compile cache's key does not see it."""
+    return jax.named_scope(SCOPE_PREFIX + name)
+
+
+def op_scope_name(n: int, op: Operator, part: str = "") -> str:
+    """`op<N>.<Kind>[.<part>]`: N is the operator's pre-order position
+    under walk_operators(root), the order EXPLAIN ANALYZE (DEVICE)
+    prints."""
+    return f"op{n}.{type(op).__name__}{'.' + part if part else ''}"
+
+
 def _shared_ops(root: Operator) -> set:
     """ids of the operators under `root` that more than one parent reads
     (plan-level CSE, sql/plan.build)."""
@@ -283,6 +307,10 @@ class _Tracer:
         self.stacked = stacked  # id(scan) -> (bufs (N,B), ms (N,))
         # operators more than one parent reads (see _mat_memo)
         self._shared = _shared_ops(root)
+        # id(op) -> its pre-order position: the N of its scope's name
+        self._op_n = {id(op): n
+                      for n, op in enumerate(walk_operators(root))}
+        self._scope_top = None  # the innermost open scope's name
         self.flag_ops: List[Operator] = []
         self.flags: List[jnp.ndarray] = []
         # shared-subtree memo: a deduped operator (plan-level CSE,
@@ -295,13 +323,38 @@ class _Tracer:
         # fused.sort_lanes
         self.sort_lanes = 0
 
+    @contextmanager
+    def _scope(self, op: Operator, part: str = ""):
+        """What is traced inside is `op`'s: instructions carry
+        `crdb.op<N>.<Kind>[.<part>]`, innermost last (a child lowered
+        inside its parent's scope is the child's). Opening the scope that
+        is already the innermost opens nothing."""
+        name = op_scope_name(self._op_n[id(op)], op, part)
+        if name == self._scope_top:
+            yield
+            return
+        prev, self._scope_top = self._scope_top, name
+        try:
+            with scope(name):
+                yield
+        finally:
+            self._scope_top = prev
+
     # -- chunk streams -----------------------------------------------------
+    #
+    # A stream's stages run later, inside whatever folds them (a lax.scan
+    # body under the aggregate's scope): each opens its own operator's
+    # scope where it runs, so a fold's step is split by operator too.
 
     def _stream(self, op: Operator) -> Optional[_Stream]:
         if isinstance(op, ScanOp):
             unpack = op._unpack
-            return _Stream(op, lambda item: (unpack(*item), ()),
-                           op.capacity, [])
+
+            def fn(item):
+                with self._scope(op):
+                    return unpack(*item), ()
+
+            return _Stream(op, fn, op.capacity, [])
         if isinstance(op, MapOp):
             s = self._stream(op.child)
             if s is None:
@@ -310,7 +363,8 @@ class _Tracer:
 
             def fn(item, f=s.fn):
                 b, fl = f(item)
-                return run(b), fl
+                with self._scope(op):
+                    return run(b), fl
 
             return _Stream(s.scan, fn, s.cap, s.flag_ops)
         if isinstance(op, JoinOp) and op.how in CHUNKABLE_JOINS:
@@ -319,7 +373,8 @@ class _Tracer:
                 return None
             build, b_ovf = self._join_build(op)
             mode = _build_mode(op)
-            bt = prepare_build(build, tuple(op.build_on), mode=mode)
+            with self._scope(op):
+                bt = prepare_build(build, tuple(op.build_on), mode=mode)
             n_chunks = int(self.stacked[id(s.scan)][0].shape[0])
             p_cap, route = self._join_probe(op, s.cap, n_chunks)
             guard = self._route_guard(op)
@@ -329,11 +384,12 @@ class _Tracer:
 
             def fn(item, f=s.fn):
                 b, fl = f(item)
-                b, p_ovf = route(b)
-                res = hash_join_prepared(b, bt, probe_on, build_on,
-                                         how=how, out_capacity=out_cap)
-                return res.batch, fl + _join_flags(guard, b_ovf, p_ovf,
-                                                   res.overflow)
+                with self._scope(op):
+                    b, p_ovf = route(b)
+                    res = hash_join_prepared(b, bt, probe_on, build_on,
+                                             how=how, out_capacity=out_cap)
+                    return res.batch, fl + _join_flags(guard, b_ovf, p_ovf,
+                                                       res.overflow)
 
             if mode == "unique":
                 # one output lane per probe row for every chunkable type
@@ -413,7 +469,8 @@ class _Tracer:
         hit = self._mat_memo.get(id(op))
         if hit is not None:
             return hit
-        out = self._mat_inner(op)
+        with self._scope(op):
+            out = self._mat_inner(op)
         self._mat_memo[id(op)] = out
         return out
 
@@ -504,28 +561,32 @@ class _Tracer:
         operator later, and fetches the build's columns by row index at
         the Shrink's lanes. The join's fallback flag and the Shrink's
         overflow flag keep their operators and their order, so the
-        restart ladder is the two-step path's."""
-        probe = self._mat(op.probe)
-        build, b_ovf = self._join_build(op)
-        probe_on, build_on = tuple(op.probe_on), tuple(op.build_on)
-        bt = prepare_build(build, build_on, mode=_build_mode(op))
-        _, route = self._join_probe(op, probe.capacity)
-        probe, p_ovf = route(probe)
-        guard = self._route_guard(op)
-        self.flag_ops.extend(_flag_targets(guard, op))
-        self.sort_lanes += probe.capacity + build.capacity
-        if shrink is not None and compacts(bt, probe.capacity, op.how):
-            res = probe_unique_compact(probe, bt, probe_on, op.how,
-                                       shrink.capacity)
-            stats.add("fused.join_compact")
-            self.flag_ops.append(shrink)
-            self.flags.extend(_join_flags(guard, b_ovf, p_ovf, res.fallback)
-                              + (res.overflow,))
-            return res.batch, True
-        res = hash_join_prepared(probe, bt, probe_on, build_on, how=op.how,
-                                 out_capacity=probe.capacity * op.expansion)
-        self.flags.extend(_join_flags(guard, b_ovf, p_ovf, res.overflow))
-        return res.batch, False
+        restart ladder is the two-step path's. The step is the JOIN's
+        (its scope opens here: the Shrink calls this, not _mat)."""
+        with self._scope(op):
+            probe = self._mat(op.probe)
+            build, b_ovf = self._join_build(op)
+            probe_on, build_on = tuple(op.probe_on), tuple(op.build_on)
+            bt = prepare_build(build, build_on, mode=_build_mode(op))
+            _, route = self._join_probe(op, probe.capacity)
+            probe, p_ovf = route(probe)
+            guard = self._route_guard(op)
+            self.flag_ops.extend(_flag_targets(guard, op))
+            self.sort_lanes += probe.capacity + build.capacity
+            if shrink is not None and compacts(bt, probe.capacity, op.how):
+                res = probe_unique_compact(probe, bt, probe_on, op.how,
+                                           shrink.capacity)
+                stats.add("fused.join_compact")
+                self.flag_ops.append(shrink)
+                self.flags.extend(
+                    _join_flags(guard, b_ovf, p_ovf, res.fallback)
+                    + (res.overflow,))
+                return res.batch, True
+            res = hash_join_prepared(
+                probe, bt, probe_on, build_on, how=op.how,
+                out_capacity=probe.capacity * op.expansion)
+            self.flags.extend(_join_flags(guard, b_ovf, p_ovf, res.overflow))
+            return res.batch, False
 
     def _try_groupjoin(self, op: HashAggOp) -> Optional[Batch]:
         """Aggregate-over-join collapse (ops/groupjoin.py): when the
@@ -948,6 +1009,10 @@ class FusedRunner:
         # config key and persistent-cache entry belong to the statement
         # and not to a binding
         self._takes_params = takes_params(root)
+        # what the last dispatch passed after the images (_bound_args):
+        # device_profile() runs the program at that binding; None until
+        # the runner has dispatched
+        self._last_bound: Optional[tuple] = None
 
     def _bound_args(self) -> tuple:
         """The bound values of the statement this thread is running
@@ -1076,8 +1141,9 @@ class FusedRunner:
             # capacity — a 12-lane aggregate reads back ~1 KB, not MBs
             tracer_box["result_cap"] = min(RESULT_CAP, out.capacity)
             tracer_box["sort_lanes"] = t.sort_lanes
-            return _pack_result(out, tuple(t.flags), schema,
-                                tracer_box["result_cap"])
+            with scope(RESULT_SCOPE):
+                return _pack_result(out, tuple(t.flags), schema,
+                                    tracer_box["result_cap"])
 
         return prog, tracer_box
 
@@ -1258,7 +1324,7 @@ class FusedRunner:
                 "fused fallback -> streaming (unsupported: {})", e)
             yield from self.root.batches()
             return
-        bound = self._bound_args()
+        bound = self._last_bound = self._bound_args()
 
         def dispatch():
             _cancel.checkpoint()
@@ -1326,6 +1392,28 @@ class FusedRunner:
             ).observe(dt)
             stats.add("fused.first_execution")
         yield batch
+
+    def device_profile(self, repeats: int = 5):
+        """Device milliseconds by plan operator of the program this
+        runner serves with, at its last binding
+        (exec/device_profile.DeviceProfile): a profile of `repeats`
+        serial executions of the warm _prepare()'s program. None for a
+        runner that has not dispatched, or whose tree is Unsupported
+        now."""
+        from cockroach_tpu.exec import device_profile as _dp
+
+        bound = self._last_bound
+        if bound is None:
+            return None
+        try:
+            with _expr.bound_args(bound[0] if bound else None):
+                (prog, _ops, _cap, _lanes), args = self._prepare()
+        except Unsupported:
+            return None
+        stages = ("fused.dispatch", "fused.wait")
+        return _dp.profile(
+            _dp.run_annotated(lambda: prog(*args, *bound), stages),
+            prog, repeats, stages)
 
 
 def try_compile(op: Operator) -> Optional[FusedRunner]:

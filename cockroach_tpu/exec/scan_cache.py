@@ -24,7 +24,6 @@ from collections import OrderedDict
 from typing import Any, Optional, Tuple
 
 from cockroach_tpu.exec import stats
-from cockroach_tpu.util import tracing as _tracing
 from cockroach_tpu.util.fault import maybe_fail
 from cockroach_tpu.util.settings import SCAN_IMAGE_CACHE_BUDGET, Settings
 
@@ -56,11 +55,9 @@ class ScanImageCache:
             hit = self._entries.get(key)
             if hit is None:
                 stats.add("scan.cache_miss")
-                _tracing.record("scan.cache_miss")
                 return None
             self._entries.move_to_end(key)
         stats.add("scan.cache_hit", bytes=hit[1])
-        _tracing.record("scan.cache_hit", bytes=hit[1])
         return hit[0]
 
     def contains(self, key: tuple) -> bool:

@@ -396,7 +396,6 @@ class BlockSource:
                 return Batch(cols, sel, jnp.int32(n))
 
             stats.add("spill.replay", rows=n)
-            _tracing.record("spill.replay", rows=n)
             yield _retry.with_retry(upload, name="spill.block_read")
 
     def pipeline(self):

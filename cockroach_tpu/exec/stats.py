@@ -6,14 +6,14 @@ ComponentStats protos (execinfrapb/component_stats.proto:64) that flow
 back as trailing metadata and render in EXPLAIN ANALYZE
 (sql/instrumentation.go:72).
 
-TPU twist: the flow runtime dispatches work asynchronously and every
-device sync stalls the pipeline for a host round trip, so per-stage DEVICE
-time cannot be measured without destroying the performance being
-measured. What this collector records instead is the host-side cost
-structure that actually dominates this architecture: pack time, transfer dispatch time, kernel
-dispatch time, forced syncs (readbacks), and row/byte counts. For true
-on-device kernel attribution use jax.profiler traces around a flow run
-(the XLA-trace analog of the reference's goexectrace, SURVEY.md §5.1).
+What this collector records is HOST stage seconds by name: pack time,
+transfer dispatch time, program dispatch and wait, forced syncs
+(readbacks), and row/byte counts. A whole-query program (exec/fused.py,
+parallel/dist_flow.py) is one dispatch and one wait here, whatever its
+plan; its device time by plan operator comes from inside the program:
+every operator's lowering carries a `crdb.op<N>.<Kind>` scope, and
+exec/device_profile.py reads a profile of the program's own executions by
+those scopes (EXPLAIN ANALYZE (DEVICE)).
 
 `timed(name)` is the one way to open a stage, and a stage is a span is
 an annotation: besides feeding the collections it attaches a child span
@@ -235,11 +235,11 @@ def timed(name: str, rows: int = 0, bytes: int = 0):
 
 # ------------------------------------------------- per-operator breakdown
 
-# stage prefixes that represent query execution work (device dispatch,
-# readback, host fold) — the device-ms column of EXPLAIN ANALYZE's
-# operator table and the device_seconds rolled into sqlstats. Compile
-# and background stages are excluded: they are amortized, not per-query
-# execution cost.
+# stage prefixes that represent query execution work (device dispatch
+# and wait as the HOST clock sees them, readback, host fold): the
+# `device-ms` column of EXPLAIN ANALYZE's stage-family table and the
+# device_seconds rolled into sqlstats. Compile and background stages are
+# excluded: they are amortized, not per-query execution cost.
 _EXEC_PREFIXES = ("scan", "agg", "join", "sort", "fused", "serving",
                   "dist", "vector", "spill", "sql")
 _NON_EXEC_STAGES = ("compile", "vault", "image_build", "prime",
@@ -261,11 +261,13 @@ def _is_exec_stage(name: str) -> bool:
 
 
 def operator_breakdown(col: Optional[StatsCollection]) -> list:
-    """Group a collection's stages by operator family (the prefix before
-    the first '.') -> [{operator, device_ms, rows, bytes, events}],
-    sorted by device_ms desc. Only execution stages count toward
-    device_ms; compile/prewarm stages are listed under their family's
-    other_ms so the rendering stays honest about total time."""
+    """HOST stage seconds by the prefix of the stage's name (the part
+    before the first '.') -> [{operator, device_ms, rows, bytes, events}],
+    sorted by device_ms desc. `device_ms` holds the family's execution
+    stages (_is_exec_stage: for a whole-query program `fused.exec` +
+    `fused.readback`, one row whatever the plan); compile/prewarm stages
+    are listed under other_ms so the rendering stays honest about total
+    time. Device time by PLAN operator is exec/device_profile.py's."""
     if col is None:
         return []
     with col._mu:

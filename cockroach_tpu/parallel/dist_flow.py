@@ -86,9 +86,9 @@ from cockroach_tpu.coldata.arrow import pack_layout
 from cockroach_tpu.coldata.batch import Batch, Column, Schema, concat_batches
 from cockroach_tpu.exec import stats
 from cockroach_tpu.exec.fused import (
-    RESULT_CAP, HBMExceeded, Unsupported, _ModeBumpGuard, _Tracer,
-    _pack_result, _unpack_result, compile_via_vault, lower_program,
-    takes_params,
+    EXCHANGE, MERGE, RESULT_CAP, RESULT_SCOPE, HBMExceeded, Unsupported,
+    _ModeBumpGuard, _Tracer, _pack_result, _unpack_result,
+    compile_via_vault, lower_program, scope, takes_params,
 )
 from cockroach_tpu.exec.operators import (
     FlowRestart, HashAggOp, JoinOp, Operator, ScanOp, ShrinkOp, SortOp, TopKOp,
@@ -350,8 +350,10 @@ class _DistTracer(_Tracer):
         bucket = self._bucket("build", op, x.build, x.build_est)
         local = self._mat(op.build)
         self._note_exchange("build", op, local, bucket)
-        return hash_repartition_local(local, tuple(op.build_on), self.axis,
-                                      self.n_dev, bucket, seed=1)
+        with self._scope(op, EXCHANGE):
+            return hash_repartition_local(local, tuple(op.build_on),
+                                          self.axis, self.n_dev, bucket,
+                                          seed=1)
 
     def _join_probe(self, op: JoinOp, cap: int, chunks: Optional[int] = None):
         if id(op) not in self.repart_ops:
@@ -369,8 +371,9 @@ class _DistTracer(_Tracer):
 
         def route(batch):
             self._note_exchange("probe", op, batch, bucket, chunks or 1)
-            return hash_repartition_local(batch, probe_on, self.axis,
-                                          self.n_dev, bucket, seed=1)
+            with self._scope(op, EXCHANGE):
+                return hash_repartition_local(batch, probe_on, self.axis,
+                                              self.n_dev, bucket, seed=1)
 
         return self.n_dev * bucket, route
 
@@ -389,10 +392,11 @@ class _DistTracer(_Tracer):
             local = super()._mat_agg(op)
         finally:
             op._final_project = final
-        gathered = _all_gather_batch(local.compact(), self.axis)
-        merged, coll = hash_aggregate(
-            gathered, group_by, op._merge_aggs, seed=op.seed + 7,
-            method="hash", with_flag=True)
+        with self._scope(op, MERGE):
+            gathered = _all_gather_batch(local.compact(), self.axis)
+            merged, coll = hash_aggregate(
+                gathered, group_by, op._merge_aggs, seed=op.seed + 7,
+                method="hash", with_flag=True)
         if group_by:
             self.flag_ops.append(op)
             self.flags.append(coll)
@@ -433,12 +437,15 @@ class _DistTracer(_Tracer):
                 self.flags.extend(fl)
             else:
                 acc = top_k_batch(self._mat(op.child), keys, k, schema)
-            gathered = _all_gather_batch(acc, self.axis)
-            return top_k_batch(gathered, keys, k, schema)
+            with self._scope(op, MERGE):
+                gathered = _all_gather_batch(acc, self.axis)
+                return top_k_batch(gathered, keys, k, schema)
         if isinstance(op, SortOp) and self._is_sharded(op.child):
             from cockroach_tpu.ops.sort import sort_batch
 
-            m = _all_gather_batch(self._mat(op.child), self.axis)
+            m = self._mat(op.child)
+            with self._scope(op, MERGE):
+                m = _all_gather_batch(m, self.axis)
             return sort_batch(m, tuple(op.keys), op.child.schema)
         return super()._mat_inner(op)
 
@@ -699,10 +706,11 @@ class DistFusedRunner:
             box["result_cap"] = min(RESULT_CAP, out.capacity)
             box["a2a_bytes"] = sum(t.a2a.values())
             box["buckets"] = dict(t.buckets)
-            flags = tuple(
-                lax.psum(f.astype(jnp.int32), axis) > 0
-                for f in t.flags)
-            return _pack_result(out, flags, schema, box["result_cap"])
+            with scope(RESULT_SCOPE):
+                flags = tuple(
+                    lax.psum(f.astype(jnp.int32), axis) > 0
+                    for f in t.flags)
+                return _pack_result(out, flags, schema, box["result_cap"])
 
         return step
 
@@ -918,6 +926,22 @@ class DistFusedRunner:
             raise Unsupported("result exceeds the packed window")
         yield batch
 
+    def device_profile(self, repeats: int = 5):
+        """FusedRunner.device_profile for the mesh's program: per
+        operator the mean over the chips with the largest chip beside
+        it, the exchanges and merges apart from the operators behind
+        them. None where the tree is Unsupported now."""
+        from cockroach_tpu.exec import device_profile as _dp
+
+        try:
+            prog, _flag_ops, args = self._prepare()
+        except Unsupported:
+            return None
+        stages = ("dist.dispatch", "dist.wait")
+        return _dp.profile(
+            _dp.run_annotated(lambda: prog.compiled(*args), stages),
+            prog.compiled, repeats, stages)
+
 
 def _trim_progs() -> None:
     while len(_PROGS) > _PROGS_CAP:
@@ -1040,6 +1064,9 @@ def collect_distributed(root: Operator, mesh: Mesh, axis: str = "x",
                           trace_info=trace_info)
                 done = True
                 br.success()
+                # the runner that served: what EXPLAIN ANALYZE (DEVICE)
+                # and device_profile.profile_prepared profile
+                root._dist_runner = runner
                 _tracing.tag_root(tier="dist")
                 default_registry().counter(
                     "sql_distsql_queries_total",

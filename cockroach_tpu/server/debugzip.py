@@ -209,10 +209,12 @@ def collect_http(base_url: str, out_path: str) -> str:
 
 def write_statement_bundle(out_path: str, sql: str, plan_lines,
                            span=None, operators=None,
-                           digest: Optional[dict] = None) -> str:
+                           digest: Optional[dict] = None,
+                           device: Optional[dict] = None) -> str:
     """EXPLAIN ANALYZE (DEBUG)'s per-statement bundle: the plan, the
-    full span tree (structured + rendered), the operator device-time
-    breakdown, and the resilience digest."""
+    full span tree (structured + rendered), the host stage seconds by
+    family, the resilience digest and, asked with (DEBUG, DEVICE), the
+    device time by plan operator (exec/device_profile.as_dict)."""
     with zipfile.ZipFile(out_path, "w",
                          compression=zipfile.ZIP_DEFLATED) as zf:
         zf.writestr("stmt.sql", sql + "\n")
@@ -224,5 +226,7 @@ def write_statement_bundle(out_path: str, sql: str, plan_lines,
             _write_json(zf, "operators.json", operators)
         if digest is not None:
             _write_json(zf, "digest.json", digest)
+        if device is not None:
+            _write_json(zf, "device_profile.json", device)
     _metrics()["bundles"].inc()
     return out_path

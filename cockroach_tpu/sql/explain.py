@@ -119,6 +119,30 @@ def _distribution_lines(op, mesh, catalog: Catalog) -> List[str]:
     return DistFusedRunner(op, mesh).describe(chunks)
 
 
+def _device_lines(op, tier, lines: List[str]):
+    """EXPLAIN ANALYZE (DEVICE): profile the whole-query runner that just
+    served the statement (exec/device_profile.py) and append device time
+    by plan operator to `lines`; -> the profile as a dict, or None where
+    no such runner served it (the lines then say so, and no table)."""
+    from cockroach_tpu.exec import device_profile
+
+    runner = (device_profile.served_runner(op)
+              if op is not None and tier in ("fused", "dist") else None)
+    prof = runner.device_profile() if runner is not None else None
+    lines.append("")
+    if prof is None:
+        # a runner that is there and has no program handed the statement
+        # to the streaming runtime (fused.fallback_*)
+        lines.append(f"device time by operator: no whole-query device "
+                     f"program served this statement (tier="
+                     f"{tier or 'n/a'}"
+                     + (", which handed it to the streaming runtime)"
+                        if tier in ("fused", "dist") else ")"))
+        return None
+    lines.extend(device_profile.render(op, prof))
+    return device_profile.as_dict(op, prof)
+
+
 def execute(sql: str, catalog: Catalog, capacity: int = 1 << 17,
             mesh=None) -> Tuple[str, object]:
     """-> ("rows", columns-dict) | ("explain", [lines]).
@@ -252,9 +276,10 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
         try:
             with tracer().span("query", sql=sql[:60]) as sp:
                 t0 = time.perf_counter()
+                built: List[object] = []
                 with bound_args(args):
                     res = run(norm, catalog, capacity, mesh=mesh, sql=sql,
-                              strict=strict)
+                              strict=strict, op_sink=built)
                 elapsed = time.perf_counter() - t0
             n = len(next(iter(res.values()))) if res else 0
             lines.append("")
@@ -263,12 +288,14 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
             rep = st.report()
             if rep:
                 lines.extend(rep.splitlines())
-            # per-operator device-time attribution: the stage timers
-            # grouped by operator family (exec/stats.operator_breakdown),
-            # annotated with each family's placement tier. Host-tier
-            # operators get an EXPLICIT tier=host row — the row engine
-            # spends no device time, and a 0/missing device-ms line
-            # misreads as "free" rather than "placed on the host".
+            # the HOST stage timers grouped by the prefix of their name
+            # (exec/stats.operator_breakdown), annotated with each
+            # family's placement tier: a whole-query program is ONE row
+            # here (`fused` or `dist`), and EXPLAIN ANALYZE (DEVICE)
+            # splits it by plan operator. Host-tier operators get an
+            # EXPLICIT tier=host row — the row engine spends no device
+            # time, and a 0/missing device-ms line misreads as "free"
+            # rather than "placed on the host".
             ops = stats.operator_breakdown(st)
             fam_tier: Dict[str, str] = {}
             host_ops: List[object] = []
@@ -310,6 +337,10 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
                 if tier is not None:
                     row += f"  tier={tier}"
                 lines.append(row)
+                if f"{o['operator']}.exec" in st.stages and \
+                        o["operator"] in ("fused", "dist"):
+                    lines.append("    one device program: EXPLAIN ANALYZE "
+                                 "(DEVICE) splits it by operator")
             if host_ops and not seen_host_fam:
                 # nothing in the stage table covered the host work (the
                 # row engine records under the "host" family only while
@@ -329,6 +360,10 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
                 f"retries={summ['retries']} "
                 f"degradations={summ['degradations']} "
                 f"restarts={summ['restarts']}")
+            device = None
+            if ast.device:
+                device = _device_lines(built[0] if built else None,
+                                       summ["tier"], lines)
             if getattr(ast, "debug", False):
                 # EXPLAIN ANALYZE (DEBUG): persist the statement bundle
                 # (plan + span tree + operator times + digest) and tell
@@ -345,7 +380,8 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
                     tempfile.gettempdir(),
                     f"stmt-bundle-{sp.trace_id:x}.zip")
                 write_statement_bundle(path, sql, lines, span=sp,
-                                       operators=ops, digest=summ)
+                                       operators=ops, digest=summ,
+                                       device=device)
                 lines.append("")
                 lines.append(f"statement bundle: {path}")
         finally:

@@ -235,6 +235,7 @@ class ExplainStmt(Node):
     stmt: "SelectStmt"
     analyze: bool = False
     debug: bool = False  # EXPLAIN ANALYZE (DEBUG): statement bundle
+    device: bool = False  # EXPLAIN ANALYZE (DEVICE): time by operator
 
 
 @dataclass
@@ -449,20 +450,28 @@ class Parser:
         if word == "explain":
             self.next()
             analyze = False
-            debug = False
+            options = set()
             t2 = self.peek()
             if t2.kind == "name" and t2.text.lower() == "analyze":
                 self.next()
                 analyze = True
                 # EXPLAIN ANALYZE (DEBUG): also write a statement
-                # bundle (the reference's support-bundle-per-statement)
+                # bundle (the reference's support-bundle-per-statement);
+                # (DEVICE): also profile the program that served the
+                # statement, device time by plan operator; (DEBUG, DEVICE)
                 if self.accept("op", "("):
-                    if self._name().lower() != "debug":
-                        raise ParseError(
-                            "expected DEBUG in EXPLAIN ANALYZE (...)")
+                    while True:
+                        word = self._name().lower()
+                        if word not in ("debug", "device"):
+                            raise ParseError(
+                                "expected DEBUG or DEVICE in "
+                                "EXPLAIN ANALYZE (...)")
+                        options.add(word)
+                        if not self.accept("op", ","):
+                            break
                     self.expect("op", ")")
-                    debug = True
-            return ExplainStmt(self.parse_select(), analyze, debug)
+            return ExplainStmt(self.parse_select(), analyze,
+                               "debug" in options, "device" in options)
         if word == "analyze":
             self.next()
             return AnalyzeStmt(self._name())

@@ -403,7 +403,6 @@ class PlanVault:
                 continue
         if n:
             stats.add("compile.vault_invalidated", n=n)
-            _tracing.record("compile.vault_invalidated", n=n)
         return n
 
     def clear(self) -> int:
