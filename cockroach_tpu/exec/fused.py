@@ -36,7 +36,9 @@ unpacks flat off the stacked image) and aggregates ONCE; over the budget
 it folds chunk by chunk, the out-of-core answer (_Tracer._agg_stream;
 _mat_agg counts fused.agg_materialized / fused.agg_folded, or
 fused.agg_int_key where ops/groupjoin.int_key_aggregate took a single
-integer key through ONE sort). A range-dense
+integer key through ONE sort, or fused.agg_ordered where a compacting join
+left the input grouped and ops/agg.run_ends_aggregate aggregates it in
+place: _Tracer._ordered_input). A range-dense
 aggregate folds any Chain, and a TopKOp over a Chain always folds.
 
 Overflow posture matches streaming: joins and generic agg folds carry
@@ -72,7 +74,7 @@ from cockroach_tpu.exec.operators import (
 )
 from cockroach_tpu.ops.agg import (
     _identity as _agg_identity, dense_aggregate, dense_merge,
-    hash_aggregate,
+    hash_aggregate, run_ends_aggregate,
 )
 from cockroach_tpu.ops import expr as _expr
 from cockroach_tpu.ops.sort import _sortable_int
@@ -256,6 +258,22 @@ def _flag_targets(guard, op: JoinOp) -> list:
     return [op] if guard is None else [guard, op]
 
 
+def _keyed_on_join(join: JoinOp, group_by: Sequence[str]) -> Optional[str]:
+    """Does a GROUP BY over `join` (one key a side, unique build) group by
+    the join key and, beside it, only by columns of the BUILD side, which
+    a unique build makes functions of the key? -> the key's name among
+    `group_by` (the probe's, else the build's), or None. Then the rows of
+    one key are one group, and rows of equal group keys have equal keys."""
+    pon, bon = join.probe_on[0], join.build_on[0]
+    key_out = pon if pon in group_by else (bon if bon in group_by else None)
+    if key_out is None:
+        return None
+    build_names = join.build.schema.names()
+    if not all(g in build_names for g in group_by if g != key_out):
+        return None
+    return key_out
+
+
 def takes_params(root: Operator) -> bool:
     """Does a filter or projection under `root` read a bound parameter
     (ops/expr.Param)? Such a tree's program takes the statement's bound
@@ -322,6 +340,10 @@ class _Tracer:
         # input's capacity): what FusedRunner counts a dispatch as stage
         # fused.sort_lanes
         self.sort_lanes = 0
+        # ids of the ShrinkOps that lowered with their join as ONE step in
+        # THIS trace (_mat_join returned compacted=True): what leaves
+        # their lanes in key order for _ordered_input
+        self._compacted: set = set()
 
     @contextmanager
     def _scope(self, op: Operator, part: str = ""):
@@ -496,6 +518,7 @@ class _Tracer:
             if self._compactable(op.child):
                 m, compacted = self._mat_join(op.child, op)
                 if compacted:
+                    self._compacted.add(id(op))
                     return m
             else:
                 m = self._mat(op.child)
@@ -614,15 +637,11 @@ class _Tracer:
         if _build_mode(child) != "unique":
             return None
         pon, bon = child.probe_on[0], child.build_on[0]
-        gb = list(op.group_by)
-        key_out = pon if pon in gb else (bon if bon in gb else None)
+        key_out = _keyed_on_join(child, op.group_by)
         if key_out is None:
             return None
-        build_names = child.build.schema.names()
         probe_names = child.probe.schema.names()
-        rest = [g for g in gb if g != key_out]
-        if not all(g in build_names for g in rest):
-            return None
+        rest = [g for g in op.group_by if g != key_out]
         for a in op.internal:
             if a.func not in GJ_FUNCS:
                 return None
@@ -756,18 +775,76 @@ class _Tracer:
                 s = None
         return s
 
+    def _ordered_input(self, op: HashAggOp) -> Optional[bool]:
+        """Does `op`'s input reach it ALREADY grouped, because a
+        compacting join left it in key order
+        (ops/sortjoin.probe_unique_compact: live rows first, ascending in
+        the join key)? Decided from what this trace did and the plan
+        shows, nothing else. All of:
+
+        - under `op` stand MapOps only, then a ShrinkOp that lowered with
+          its join as one step IN THIS TRACE (_compacted: after a restart
+          down the join's ladder, or where _compactable or `compacts`
+          refuses, it is not there, and the aggregate hashes as before);
+        - the join is inner (a semi join compacts in probe-lane order),
+          over a unique build, one key a side;
+        - every GROUP BY column is, through the MapOps' projections, a
+          bare column of the join's output (renames followed), and
+          together they pass the group-join's test (_keyed_on_join): the
+          join key, and beside it only columns of the build side.
+
+        -> None: not so. Else whether the live lanes are still the
+        batch's first (`dense` of ops/agg.run_ends_aggregate): a filter
+        on the way punches holes into the runs, which the aggregate then
+        looks past with a few scans more; it groups exactly either way.
+        Materializes op.child (what every lowering but the group-join
+        starts with)."""
+        names, dense, node = list(op.group_by), True, op.child
+        while isinstance(node, MapOp):
+            for kind, payload in reversed(node.steps):
+                if kind == "filter":
+                    dense = False
+                    continue
+                exprs = dict(payload)
+                if not all(isinstance(exprs.get(n), _expr.Col)
+                           for n in names):
+                    return None
+                names = [exprs[n].name for n in names]
+            node = node.child
+        if not (isinstance(node, ShrinkOp)
+                and isinstance(node.child, JoinOp)):
+            return None
+        join = node.child
+        if (join.how != "inner" or _build_mode(join) != "unique"
+                or len(join.probe_on) != 1 or len(join.build_on) != 1
+                or _keyed_on_join(join, names) is None):
+            return None
+        self._mat(op.child)
+        return dense if id(node) in self._compacted else None
+
     def _mat_agg(self, op: HashAggOp) -> Batch:
         out = self._try_groupjoin(op)
-        int_key = False
+        lowering = None
+        if out is None and op.group_by:
+            dense = self._ordered_input(op)
+            if dense is not None:
+                # in place: nothing hashed, so no collision flag and no
+                # re-seeded restart; the run-ends view at the Shrink's
+                # lanes is what top_k_batch, a Shrink, a MapOp and
+                # _pack_result take (Q18's first aggregate feeds them it)
+                out = op._final_project(run_ends_aggregate(
+                    self._mat(op.child), tuple(op.group_by),
+                    tuple(op.internal), dense=dense))
+                lowering = "fused.agg_ordered"
         if out is None:
             out = self._try_int_agg(op)
-            int_key = out is not None
-        # both fast paths aggregate over the materialized input
+            if out is not None:
+                lowering = "fused.agg_int_key"
+        # every fast path aggregates over the materialized input
         s = self._agg_stream(op) if out is None else None
         # the lowering taken, one event a traced HashAggOp
-        stats.add("fused.agg_int_key" if int_key
-                  else "fused.agg_folded" if s is not None
-                  else "fused.agg_materialized")
+        stats.add(lowering or ("fused.agg_folded" if s is not None
+                               else "fused.agg_materialized"))
         if out is not None:
             return out
         group_by, internal = tuple(op.group_by), tuple(op.internal)

@@ -1028,7 +1028,7 @@ class HashAggOp(Operator):
 class OrderedAggOp(HashAggOp):
     """Streaming GROUP BY over input whose equal keys arrive in contiguous
     runs (reference orderedAggregator): the per-chunk partial skips the
-    sort entirely (ops/agg.py method="ordered"). Runs that straddle chunk
+    sort entirely (ops/agg.ordered_aggregate). Runs that straddle chunk
     boundaries re-merge in the shared fold, so correctness never depends
     on run containment — the sort is purely elided work. The planner picks
     this over HashAggOp when the child's ordering covers the group keys
@@ -1734,8 +1734,11 @@ class ShrinkOp(Operator):
     sortjoin.probe_unique_compact): the join compacts its matches in key
     order, its resort to probe order never runs, and the row gather packs
     the probe's columns only. The capacity, widen() and the overflow
-    flag are this operator's either way; the lane order of a shrunk
-    batch is no contract."""
+    flag are this operator's either way. The lane order of a shrunk
+    batch is no contract of THIS operator; where it lowered with an
+    inner join as one step, the join's is (key order:
+    sortjoin.probe_unique_compact), and the tracer that saw the step
+    taken may use it (fused._Tracer._ordered_input)."""
 
     START_CAPACITY = 1 << 12
     GROWTH = 16

@@ -30,11 +30,14 @@ from dataclasses import dataclass
 from typing import Optional, Sequence, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from cockroach_tpu.coldata.batch import Batch, Column, mask_padding
 from cockroach_tpu.ops.hashtable import SortedGroups, sorted_groups
-from cockroach_tpu.ops.prefix import blocked_assoc_scan, blocked_cumsum
+from cockroach_tpu.ops.prefix import (
+    blocked_assoc_scan, blocked_cummax, blocked_cumsum,
+)
 
 
 def _shift1(x):
@@ -146,41 +149,6 @@ class _SortedView:
         self.cap = cap
         self._sorted: dict = {}
 
-        if method == "ordered":
-            # input already grouped in contiguous runs (reference
-            # orderedAggregator): no sort at all — boundaries from adjacent
-            # key comparison in place. Precondition (callers': SortOp
-            # output, PK-ordered MVCC scans): equal keys are adjacent among
-            # the selected rows.
-            self.perm = None
-            self.sel_sorted = batch.sel
-            for n, c in batch.columns.items():
-                self._sorted[n] = (c.values, c.validity)
-            idx = jnp.arange(cap)
-            same = jnp.ones(cap, dtype=jnp.bool_)
-            for n in group_by:
-                v, valid = self._sorted[n]
-                pv = _shift1(v)
-                col_eq = v == pv
-                if jnp.issubdtype(v.dtype, jnp.floating):
-                    col_eq = col_eq | (jnp.isnan(v) & jnp.isnan(pv))
-                if valid is not None:
-                    pvalid = _shift1(valid)
-                    col_eq = jnp.where(valid & pvalid, col_eq,
-                                       valid == pvalid)
-                same = same & col_eq
-            same = same & (idx > 0)
-            first_live = self.sel_sorted & (jnp.cumsum(self.sel_sorted) == 1)
-            boundary = self.sel_sorted & (first_live | ~same)
-            boundary = boundary.at[0].set(self.sel_sorted[0])
-            gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-            num_groups = jnp.sum(boundary).astype(jnp.int32)
-            gid = jnp.where(self.sel_sorted, gid, cap)
-            self.sg = SortedGroups(None, None, boundary, gid, num_groups,
-                                   jnp.bool_(False))
-            self._init_extents(cap)
-            return
-
         if method == "hash":
             from cockroach_tpu.ops.hash import hash_columns
 
@@ -284,40 +252,210 @@ class _SortedView:
     def run_end(self, scanned):
         return scanned[self.ends]
 
+    def lane(self, arr, counts: bool = False):
+        """-> (`arr` as an int64 lane of the (cap, L) matrix that
+        `readers` row-gathers, how to decode it)."""
+        dt = arr.dtype
+        if jnp.issubdtype(dt, jnp.floating):
+            return (arr.astype(jnp.float32).view(jnp.uint32)
+                    .astype(jnp.int64)), "f32"
+        if dt == jnp.bool_:
+            return arr.astype(jnp.int64), "bool"
+        return arr.astype(jnp.int64), "i64" if dt != jnp.int32 else "i32"
+
+    def readers(self, lanes):
+        """-> (at_end(i), diff(i)): lane i's value at each group's last
+        row, and that less its value before the group's first, per group
+        lane g. ONE batched row gather at the run ends; the prefix row
+        BEFORE each group needs no second one: runs are contiguous among
+        live lanes (dead lanes contribute zero to every masked prefix),
+        so prefix-before-group-g IS end_rows[g-1], a shift."""
+        dec = [d for _a, d in lanes]
+        P = jnp.stack([a for a, _d in lanes], axis=1)     # (cap, L) int64
+        end_rows = P[self.ends]
+        prev_rows = jnp.concatenate(
+            [jnp.zeros((1, P.shape[1]), P.dtype), end_rows[:-1]], axis=0)
+        has_prev = self.starts > 0
+
+        def at_end(i):
+            v = end_rows[:, i]
+            if dec[i] == "f32":
+                return v.astype(jnp.uint32).view(jnp.float32)
+            if dec[i] == "bool":
+                return v != 0
+            return v.astype(jnp.int32) if dec[i] == "i32" else v
+
+        def diff(i):
+            e, b = at_end(i), prev_rows[:, i]
+            if dec[i] == "f32":
+                b = b.astype(jnp.uint32).view(jnp.float32)
+            elif dec[i] == "i32":
+                b = b.astype(jnp.int32)
+            return e - jnp.where(has_prev, b, jnp.zeros((), e.dtype))
+
+        return at_end, diff
+
+
+def _to_words(x) -> list:
+    """`x` as uint32 words, high first (one, or two for a 64-bit dtype):
+    what rides a 64-bit running maximum under a lane count."""
+    if x.dtype == jnp.bool_ or x.dtype.itemsize < 4:
+        x = x.astype(jnp.int32)
+    if x.dtype.itemsize == 4:
+        return [lax.bitcast_convert_type(x, jnp.uint32)]
+    u = lax.bitcast_convert_type(x, jnp.uint64)
+    return [(u >> np.uint64(32)).astype(jnp.uint32), u.astype(jnp.uint32)]
+
+
+def _from_words(words: Sequence, dtype):
+    """_to_words back."""
+    if len(words) == 2:
+        hi, lo = (w.astype(jnp.uint64) for w in words)
+        return lax.bitcast_convert_type((hi << np.uint64(32)) | lo, dtype)
+    if dtype == jnp.bool_ or jnp.dtype(dtype).itemsize < 4:
+        return lax.bitcast_convert_type(words[0], jnp.int32).astype(dtype)
+    return lax.bitcast_convert_type(words[0], dtype)
+
+
+def _at_last_marked(mark, vals: Sequence):
+    """-> (each of `vals` at the nearest lane at or before a lane where
+    `mark` holds (zeros before the first), whether there is one). No
+    gather and no generic scan (whose compile time the TPU's compiler
+    does not bound at millions of lanes): the marks counted so far ride
+    above bit 32 of a running maximum, so the latest marked lane's word
+    wins over every earlier lane's; one blocked cummax a 32-bit word."""
+    count = blocked_cumsum(mark.astype(jnp.int32))
+    above = count.astype(jnp.int64) << np.int64(32)
+
+    def last(word):
+        enc = above | jnp.where(mark, word.astype(jnp.int64), 0)
+        return (blocked_cummax(enc) & np.int64(0xFFFFFFFF)).astype(
+            jnp.uint32)
+
+    return ([_from_words([last(w) for w in _to_words(v)], v.dtype)
+             for v in vals], count > 0)
+
+
+def _next_live(vals: Sequence, live):
+    """-> (each of `vals` at the nearest live lane AFTER a lane, whether
+    there is one): what a lane with dead neighbours is compared with."""
+    def after(x):  # lane i takes the reversed lanes' lane i + 1
+        return jnp.concatenate([x[::-1][1:], jnp.zeros((1,), x.dtype)])
+
+    got, has = _at_last_marked(live[::-1], [v[::-1] for v in vals])
+    return [after(v) for v in got], after(has)
+
+
+class _RunEndsView:
+    """What _eval_aggs reads of a batch whose equal group keys are ALREADY
+    adjacent among its live lanes (run_ends_aggregate), in place: no
+    hash, no sort, no permutation, and nothing gathered or scattered.
+
+    A live lane ENDS its run when the next live lane's keys differ (or
+    none follows), NULLs equal to each other and NaN to NaN as the sorted
+    views have it; the lane after an end opens the next run, so dead
+    lanes inside a run, before its first live lane or after its last
+    belong to no group and add nothing (every prefix is masked by
+    liveness). `dense`: the caller vouches that no dead lane lies
+    between two live ones (a compacted batch: live lanes first), and the
+    next live lane is the next lane; otherwise its keys are carried back
+    over the dead lanes (_next_live: one running maximum a 32-bit word of
+    the keys), so a filter that punched holes into runs, a run's first
+    lane among them, groups exactly."""
+
+    perm = None  # rows stand where they stood
+
+    def __init__(self, batch: Batch, group_by: Sequence[str], dense: bool):
+        live = batch.sel
+        self.sel_sorted = live
+        keys = [batch.col(n) for n in group_by]
+        mine = [x for c in keys for x in (c.values, c.validity)
+                if x is not None]
+        if dense:
+            theirs = [jnp.concatenate([x[1:], x[-1:]]) for x in mine]
+            follows = jnp.concatenate([live[1:], jnp.zeros((1,), jnp.bool_)])
+        else:
+            theirs, follows = _next_live(mine, live)
+        theirs = iter(theirs)
+        same = jnp.ones(batch.capacity, dtype=jnp.bool_)
+        for c in keys:
+            v, nv = c.values, next(theirs)
+            col_eq = v == nv
+            if jnp.issubdtype(v.dtype, jnp.floating):
+                col_eq = col_eq | (jnp.isnan(v) & jnp.isnan(nv))
+            if c.validity is not None:
+                nvalid = next(theirs)
+                col_eq = jnp.where(c.validity & nvalid, col_eq,
+                                   c.validity == nvalid)
+            same = same & col_eq
+        self.out_sel = live & ~(follows & same)   # one lane a group
+        is_end = self.out_sel
+        opens = jnp.concatenate([jnp.ones((1,), jnp.bool_), is_end[:-1]])
+        num_groups = jnp.sum(is_end).astype(jnp.int32)
+        self.sg = SortedGroups(None, None, opens, None, num_groups,
+                               jnp.bool_(False))
+
+    def sorted_col(self, batch: Batch, name: str):
+        c = batch.col(name)
+        return c.values, (self.sel_sorted if c.validity is None
+                          else self.sel_sorted & c.validity)
+
+    def lane(self, arr, counts: bool = False):
+        if jnp.issubdtype(arr.dtype, jnp.floating):
+            arr = arr.astype(jnp.float32)  # as the sorted views' lanes
+        return arr, counts
+
+    def _before_run(self, x, counts: bool):
+        """Running sum `x` at the end of the PREVIOUS run (0 before the
+        first), at every lane of a run."""
+        if counts:
+            # a count never falls: the last end's is the largest so far
+            last = blocked_cummax(jnp.where(self.out_sel, x,
+                                            jnp.zeros((), x.dtype)))
+        else:
+            # a signed or float sum rises and falls: _at_last_marked
+            (last,), _any = _at_last_marked(self.out_sel, [x])
+        return jnp.concatenate([jnp.zeros((1,), x.dtype), last[:-1]])
+
+    def readers(self, lanes):
+        """-> (at_end(i), diff(i)) as _SortedView.readers, every group at
+        its run's last live lane (other lanes hold no answer: out_sel)."""
+        before: dict = {}
+
+        def at_end(i):
+            return lanes[i][0]
+
+        def diff(i):
+            if i not in before:
+                before[i] = self._before_run(*lanes[i])
+            return lanes[i][0] - before[i]
+
+        return at_end, diff
+
 
 def _eval_aggs(aggs: Sequence[AggSpec], batch: Batch,
-               view: _SortedView,
+               view,
                group_keys: Sequence[str] = ()) -> dict:
-    """Evaluate EVERY aggregate AND the group-key output columns with ONE
-    batched row-gather.
+    """Evaluate EVERY aggregate AND the group-key output columns over a
+    view of the batch in which a group's rows are one contiguous run.
 
     Phase 1 builds the per-agg prefix arrays (cumsums / segmented scans —
     sequential-access, cheap) plus one lane per group-key column (its
     sorted values: the value at a run's END equals the value at its
-    leader). Phase 2 stacks them into one (cap, L) int64 matrix and
-    gathers whole rows at run ends — a 1-D gather moves ~0.2 GB/s on v5e
-    while the (cap, L) row gather moves every lane for the same cost
-    (profiled r4: per-column gathers dominated Q3's device time). The
-    prefix row BEFORE each group needs no second gather: runs are
-    contiguous among live lanes (dead lanes contribute zero to every
-    masked prefix), so prefix-before-group-g IS end_rows[g-1], a shift."""
+    leader). Phase 2 is the view's (`readers`): each lane's value at the
+    run ends, and for the running sums that value less the one before the
+    run. A _SortedView stacks the lanes into one (cap, L) int64 matrix and
+    gathers whole rows at run ends, group g to lane g — a 1-D gather
+    moves ~0.2 GB/s on v5e while the (cap, L) row gather moves every lane
+    for the same cost (profiled r4: per-column gathers dominated Q3's
+    device time). A _RunEndsView leaves every group at its run's last
+    live lane and gathers nothing."""
     if not aggs and not group_keys:
         return {}  # DISTINCT with no keys: nothing to emit
     lanes: list = []
-    dec: list = []
 
-    def add_lane(arr) -> int:
-        dt = arr.dtype
-        if jnp.issubdtype(dt, jnp.floating):
-            lanes.append(arr.astype(jnp.float32).view(jnp.uint32)
-                         .astype(jnp.int64))
-            dec.append("f32")
-        elif dt == jnp.bool_:
-            lanes.append(arr.astype(jnp.int64))
-            dec.append("bool")
-        else:
-            lanes.append(arr.astype(jnp.int64))
-            dec.append("i64" if dt != jnp.int32 else "i32")
+    def add_lane(arr, counts: bool = False) -> int:
+        lanes.append(view.lane(arr, counts))
         return len(lanes) - 1
 
     cnt_lane: dict = {}  # col name (or None=sel) -> live-count lane index
@@ -326,7 +464,8 @@ def _eval_aggs(aggs: Sequence[AggSpec], batch: Batch,
         if col not in cnt_lane:
             live = (view.sel_sorted if col is None
                     else view.sorted_col(batch, col)[1])
-            cnt_lane[col] = add_lane(blocked_cumsum(live.astype(jnp.int64)))
+            cnt_lane[col] = add_lane(blocked_cumsum(live.astype(jnp.int64)),
+                                     counts=True)
         return cnt_lane[col]
 
     specs = []  # (agg, kind, lane indices...)
@@ -381,29 +520,7 @@ def _eval_aggs(aggs: Sequence[AggSpec], batch: Batch,
         else:
             key_specs.append((name, vi, None))
 
-    P = jnp.stack(lanes, axis=1)                      # (cap, L) int64
-    end_rows = P[view.ends]
-    # prefix row before group g == end row of group g-1 (runs are
-    # contiguous among live lanes; dead lanes add zero to every prefix)
-    prev_rows = jnp.concatenate(
-        [jnp.zeros((1, P.shape[1]), P.dtype), end_rows[:-1]], axis=0)
-    has_prev = view.starts > 0
-
-    def at_end(i):
-        v = end_rows[:, i]
-        if dec[i] == "f32":
-            return v.astype(jnp.uint32).view(jnp.float32)
-        if dec[i] == "bool":
-            return v != 0
-        return v.astype(jnp.int32) if dec[i] == "i32" else v
-
-    def diff(i):
-        e, b = at_end(i), prev_rows[:, i]
-        if dec[i] == "f32":
-            b = b.astype(jnp.uint32).view(jnp.float32)
-        elif dec[i] == "i32":
-            b = b.astype(jnp.int32)
-        return e - jnp.where(has_prev, b, jnp.zeros((), e.dtype))
+    at_end, diff = view.readers(lanes)
 
     out: dict = {}
     for name, vi, validi in key_specs:
@@ -884,16 +1001,45 @@ def dense_merge(a: Batch, b: Batch, group_by: Sequence[str],
     return Batch(out_cols, sel, jnp.sum(sel).astype(jnp.int32))
 
 
+def run_ends_aggregate(batch: Batch, group_by: Sequence[str],
+                       aggs: Sequence[AggSpec],
+                       dense: bool = False) -> Batch:
+    """GROUP BY over input ALREADY grouped in contiguous runs, in place
+    (reference orderedAggregator, colexec/ordered_aggregator.go): no hash
+    (so no collision flag), no sort, no gather and no scatter over the
+    batch's lanes. Run boundaries come from comparing each live lane's
+    keys with the next live lane's, and every aggregate of `SUPPORTED`
+    from the prefix arrays hash_aggregate builds (_eval_aggs: the same
+    code, so the same semantics: NULL inputs, the wide sum's halves,
+    avg's parts, an all-dead batch).
+
+    Output: the UNCOMPACTED run-ends view, the form
+    ops/groupjoin.int_key_aggregate emits with out_capacity=0: a batch at
+    the input's capacity with each group ONCE, at its run's last live
+    lane (`sel` marks those lanes, `length` counts them), groups in input
+    run order. top_k_batch, ShrinkOp, MapOp and a join's build take a
+    sparse `sel`; Batch.compact() gives group g at lane g.
+
+    Precondition (the caller's to prove): equal group keys are adjacent
+    among the live lanes. Dead lanes may lie anywhere, inside runs too,
+    unless the caller passes `dense` (live lanes first, as a compacting
+    join leaves them: exec/fused._Tracer._ordered_input), which saves
+    the scans that look past them."""
+    view = _RunEndsView(batch, group_by, dense)
+    out_cols = mask_padding(
+        dict(_eval_aggs(aggs, batch, view, group_keys=group_by)),
+        view.out_sel)
+    return Batch(out_cols, view.out_sel, view.sg.num_groups)
+
+
 def ordered_aggregate(batch: Batch, group_by: Sequence[str],
                       aggs: Sequence[AggSpec]) -> Batch:
-    """Aggregation over input already grouped in contiguous runs
-    (reference orderedAggregator, colexec/ordered_aggregator.go): no sort
-    at all — run boundaries come from adjacent key comparison in place.
-    Output contract matches hash_aggregate (group g at lane g, live lanes
-    [0, num_groups)); groups keep input run order.
+    """run_ends_aggregate with hash_aggregate's output contract (group g
+    at lane g, live lanes [0, num_groups)), groups in input run order:
+    what the streaming OrderedAggOp folds, chunk by chunk.
 
     Precondition: equal group keys are adjacent among selected rows
-    (SortOp output, PK-ordered scans). A caller whose input is only
-    PARTIALLY grouped still gets correct results from the flow layer's
-    merge fold — split runs re-merge by key there."""
-    return hash_aggregate(batch, group_by, aggs, method="ordered")
+    (SortOp output, PK-ordered scans), dead lanes anywhere. A caller
+    whose input is only PARTIALLY grouped still gets correct results from
+    the flow layer's merge fold — split runs re-merge by key there."""
+    return run_ends_aggregate(batch, group_by, aggs).compact()

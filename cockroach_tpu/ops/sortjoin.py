@@ -422,8 +422,22 @@ def probe_unique_compact(probe: Batch, ub: UniqueBuild,
     fetches its build columns the same way). A semi join emits nothing
     of the build: its compaction carries the probe lane index itself and
     gathers probe rows only. Inner and semi joins over a build that
-    `compacts` only; the lane order of the result is the key order,
-    which no consumer of a compacted batch may rely on."""
+    `compacts` only.
+
+    The lane order of an INNER join's result is a guarantee: live rows
+    first (`sel` = lane < `length`), ascending in the join key, so rows
+    of equal keys are adjacent. The matches are taken from the key
+    sort's own domain in position order (_first_matches over its
+    positions), and `compacts` admits only the narrow packing (`key << 1
+    | tag`: exact and monotone in the key; a key outside [0, 2^30)
+    raises `fallback`). An aggregate grouped by the key (and columns of
+    the unique build, functions of it) reads its groups off this order
+    with no sort (exec/fused._Tracer._ordered_input,
+    ops/agg.run_ends_aggregate). A raised `fallback` or `overflow`
+    discards the result with the whole program's answer; the guarantee
+    is asked of the rerun's join anew. A SEMI join's result is in
+    probe-lane order (its compaction carries the lane index) and
+    guarantees no key order."""
     from cockroach_tpu.coldata.batch import mask_padding
 
     if not compacts(ub, probe.capacity, how):

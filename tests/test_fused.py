@@ -8,6 +8,8 @@ single-program path and the streaming operator tree, and every query must
 produce identical results through both.
 """
 
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -19,6 +21,7 @@ from cockroach_tpu.exec.operators import (
 )
 from cockroach_tpu.coldata.batch import Field, INT, Schema
 from cockroach_tpu.ops.agg import AggSpec
+from cockroach_tpu.ops.expr import Col
 from cockroach_tpu.ops.sort import SortKey
 from cockroach_tpu.workload.tpch import TPCH
 from cockroach_tpu.workload import tpch_queries as Q
@@ -248,15 +251,22 @@ def test_q3_sql_compacts_both_joins_and_matches_oracle():
     assert n == 0
 
 
-def _eqns(jaxpr):
-    """Every equation of `jaxpr`, those of its sub-jaxprs included."""
+def _scoped_eqns(jaxpr, stack=""):
+    """(name stack with the enclosing equations', equation) of every
+    equation of `jaxpr`, those of its sub-jaxprs included."""
     for eqn in jaxpr.eqns:
-        yield eqn
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        yield here, eqn
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else (v,)):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    yield from _eqns(inner)
+                    yield from _scoped_eqns(inner, here)
+
+
+def _eqns(jaxpr):
+    """Every equation of `jaxpr`, those of its sub-jaxprs included."""
+    return (eqn for _stack, eqn in _scoped_eqns(jaxpr))
 
 
 def _sorts(jaxpr):
@@ -418,6 +428,229 @@ def test_q3_program_sorts_per_join(program, monkeypatch):
         assert cummaxes.count(lcap + rcap) == 2
 
 
+def test_q3_aggregate_sorts_gathers_and_scatters_nothing():
+    """Beside test_q3_program_sorts_per_join[compact]: under Q3's
+    aggregate (its `crdb.op<N>.HashAggOp` scope) the program holds no
+    sort, no gather and no scatter at all: the join below it left the
+    Shrink's lanes grouped, and the aggregate reads them in place with
+    scans over those lanes (ISSUE 36). The join's own C-row gathers are
+    the join's."""
+    from cockroach_tpu.exec.operators import ScanOp, walk_operators
+    from cockroach_tpu.sql.bind import plan_sql
+    from cockroach_tpu.sql.plan_compile import compile_plan
+    from tests.test_sql import Q3_SQL
+
+    _gen, cat = _sql_catalog()
+    cp = compile_plan(plan_sql(Q3_SQL, cat), cat, 1 << 14, sql=Q3_SQL,
+                      setting="tpu")
+    _prog, args = cp.runner._prepare()
+    scans = [n for n in walk_operators(cp.op) if isinstance(n, ScanOp)]
+    prog, _box = cp.runner._make_prog([id(s) for s in scans])
+    jaxpr, branches = _agg_branches(lambda: jax.make_jaxpr(prog)(*args))
+    assert branches == {"ordered": 1}
+    (shrink,) = [op for op in walk_operators(cp.op)
+                 if type(op).__name__ == "ShrinkOp"
+                 and type(op.child).__name__ == "JoinOp"
+                 and op.child.how == "inner"]
+    under = [(eqn.primitive.name, [v.aval.shape for v in eqn.invars])
+             for stack, eqn in _scoped_eqns(jaxpr.jaxpr)
+             # the innermost operator scope is the equation's owner (a
+             # child is lowered inside its parent's scope)
+             if re.findall(r"crdb\.op\d+\.(\w+)", stack)[-1:]
+             == ["HashAggOp"]]
+    names = {n for n, _shapes in under}
+    banned = names & {"sort", "gather", "scatter", "scatter-add",
+                      "scatter_add", "argsort"}
+    assert not banned, banned
+    # what it does hold: running sums and maxima over the Shrink's lanes
+    # (in blocks: ops/prefix.py), and nothing over any wider array
+    assert {"cumsum", "cummax"} <= names
+    lanes = shrink.capacity
+    assert all(int(np.prod(shape)) <= lanes
+               for _n, shapes in under for shape in shapes)
+
+
+# -- an aggregate over a compacting join reads the order the join left ------
+
+_GROUPED = [("key", "fk"), ("dd", "d"), ("ee", "e"), ("w", "w")]
+
+
+def _agg_over_shrunk_join(group_by, how="inner", build_mode="unique",
+                          steps=None, capacity=512, dup_build=False):
+    """-> HashAggOp(group_by) / MapOp(steps) / ShrinkOp(capacity) /
+    JoinOp(how) of 256 probe rows (fk, v, w) against 64 build rows
+    (k unique unless dup_build, d = k % 7, e = 3k). The default steps
+    rename fk to `key`, d to `dd`, e to `ee`, keep w and compute x = 2v;
+    a semi join emits no build column."""
+    from cockroach_tpu.exec.operators import ShrinkOp
+
+    rng = np.random.default_rng(36)
+    pk = rng.integers(0, 400, 256)
+    bk = (rng.integers(0, 40, 64) if dup_build
+          else rng.permutation(400)[:64])
+    probe = _int_scan({"fk": pk, "v": rng.integers(-50, 90, 256),
+                       "w": rng.integers(0, 5, 256)}, 64)
+    build = _int_scan({"k": bk, "d": bk % 7, "e": bk * 3}, 64)
+    join = JoinOp(probe, build, ["fk"], ["k"], how=how,
+                  build_mode=build_mode)
+    if steps is None:
+        steps = [("project", [(n, Col(c)) for n, c in _GROUPED
+                              if how != "semi" or c in ("fk", "w")]
+                  + [("x", Col("v") * 2)])]
+    return HashAggOp(MapOp(ShrinkOp(join, capacity), steps), group_by,
+                     [AggSpec("sum", "x", "s"),
+                      AggSpec("count_star", None, "n"),
+                      AggSpec("min", "x", "lo")])
+
+
+def _agg_answers(make):
+    """-> (rows of make() through the fused runner, the lowerings it
+    counted, rows of a second make() through the streaming runtime)."""
+    agg = make()
+    names = list(agg.group_by) + ["s", "n", "lo"]
+    got, branches = _agg_branches(lambda: collect(agg, fuse=True))
+    want = collect(make(), fuse=False)
+    return _sorted_rows(got, names), branches, _sorted_rows(want, names)
+
+
+def _steps_filter_then_project():
+    return [("filter", Col("w") > 1),
+            ("project", [("key", Col("fk")), ("dd", Col("d")),
+                         ("x", Col("v") * 2)])]
+
+
+def _steps_project_then_filter():
+    return [("project", [("key", Col("k")), ("dd", Col("d")),
+                         ("x", Col("v") * 2), ("w", Col("w"))]),
+            ("filter", Col("w") < 3)]
+
+
+def _steps_computed_key():
+    return [("project", [("key", Col("fk") + 0), ("dd", Col("d")),
+                         ("x", Col("v") * 2)])]
+
+
+@pytest.mark.parametrize("group_by,kw", [
+    (["key", "dd"], {}),                       # renamed probe key + build
+    (["ee", "key", "dd"], {}),                 # the key anywhere among them
+    (["key", "dd"], {"steps": _steps_filter_then_project}),   # holes
+    (["dd", "key"], {"steps": _steps_project_then_filter}),   # build's key
+], ids=["renamed", "key_in_the_middle", "filter_below", "filter_above"])
+def test_aggregate_over_a_compacting_join_aggregates_in_place(group_by, kw):
+    """The precondition of _Tracer._ordered_input holds: counted
+    `fused.agg_ordered`, nothing hashed, the streaming runtime's rows.
+    A filter between the join and the aggregate punches holes into the
+    runs and is grouped exactly (run ends against the next LIVE lane)."""
+    kw = dict(kw, steps=kw["steps"]()) if "steps" in kw else kw
+    got, branches, want = _agg_answers(
+        lambda: _agg_over_shrunk_join(group_by, **kw))
+    assert branches == {"ordered": 1}
+    assert got == want and len(got) >= 10
+
+
+@pytest.mark.parametrize("group_by,kw", [
+    (["dd", "ee"], {}),                        # the join key is not there
+    (["key", "w"], {}),                        # w is a PROBE column
+    (["key", "dd"], {"steps": _steps_computed_key}),  # not a bare column
+    (["key", "dd"], {"build_mode": "expand"}),         # build not unique
+    (["key", "w"], {"how": "semi"}),           # probe-lane order, no key's
+    (["key", "dd"], {"two_step": True}),       # the join did not compact
+], ids=["no_join_key", "probe_column", "computed_key", "expand_build",
+        "semi_join", "two_step"])
+def test_aggregate_keeps_the_hash_path_where_the_order_is_not_proved(
+        group_by, kw, monkeypatch):
+    kw = dict(kw)
+    if "steps" in kw:
+        kw["steps"] = kw["steps"]()
+    if kw.pop("two_step", False):
+        monkeypatch.setattr(fused._Tracer, "_compactable",
+                            lambda self, op: False)
+    got, branches, want = _agg_answers(
+        lambda: _agg_over_shrunk_join(group_by, **kw))
+    assert branches == {"materialized": 1}
+    assert got == want and len(got) > 5
+
+
+def test_shrink_overflow_under_the_ordered_aggregate_restarts_exactly():
+    """More matches than the Shrink holds: the compacted lanes are a cut
+    of the key order, the overflow flag discards the program's answer,
+    widen() grows the Shrink and the NEW trace decides the precondition
+    again: ordered both times, the rows exact."""
+    made = []
+
+    def make():
+        made.append(_agg_over_shrunk_join(["key", "dd"], capacity=16))
+        return made[-1]
+
+    got, branches, want = _agg_answers(make)
+    assert made[0].child.child.capacity == 16 * 16
+    assert branches == {"ordered": 2}       # one a traced program
+    assert got == want and len(got) >= 10
+
+
+def test_join_fallback_under_the_ordered_aggregate_rehashes_exactly():
+    """Duplicate build keys: the first trace compacts the join and
+    aggregates in place, the join's fallback flag discards that answer,
+    JoinOp.widen() leaves the unique path, the rerun's join does not
+    compact and its aggregate hashes (twice: the row-matrix rung flags
+    the duplicates too, then the general expansion answers): the order
+    is never assumed."""
+    got, branches, want = _agg_answers(
+        lambda: _agg_over_shrunk_join(["key", "dd"], dup_build=True))
+    assert branches == {"ordered": 1, "materialized": 2}
+    assert got == want and len(got) >= 10
+
+
+def test_mesh_q3_aggregates_by_hash():
+    """On the four-shard mesh Q3's BY_HASH join takes the two steps
+    (_DistTracer._compactable), so its local aggregate proves no order
+    and hashes; the merge hashes an all_gather'ed concatenation."""
+    from cockroach_tpu.sql.bind import plan_sql
+    from cockroach_tpu.sql.plan_compile import compile_plan
+    from tests.test_sql import Q3_SQL
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four virtual CPU devices")
+    _gen, cat = _sql_catalog()
+    cp = compile_plan(plan_sql(Q3_SQL, cat), cat, 1 << 14, sql=Q3_SQL,
+                      setting="tpu")
+    _out, branches = _agg_branches(lambda: _mesh_jaxpr(cp.op, 4, 1 << 14))
+    assert branches == {"materialized": 1}
+
+
+@pytest.mark.parametrize("query", ["q3", "q18"])
+def test_q3_and_q18_sql_aggregate_in_place_and_match_oracles(query):
+    """Q3's one aggregate and Q18's last (five keys: the last join's key
+    and columns of its unique build) read the order their compacting
+    join left: `fused.agg_ordered` 1 a traced program; Q18's first, over
+    the scan, stays `fused.agg_int_key`. Oracle rows."""
+    from cockroach_tpu.sql.session import Session
+    from tests.test_sql import Q18_SQL, Q3_SQL
+
+    gen, cat = _sql_catalog()
+    sess = Session(cat, capacity=1 << 14)
+    sess.execute("set vectorize = tpu")
+    sql = Q3_SQL if query == "q3" else Q18_SQL.format(threshold=150)
+    (_k, got, _s), events = _stage_events(lambda: sess.execute(sql),
+                                          "fused.")
+    traced = events["fused.compile"]
+    branches = {name[len("fused.agg_"):]: n for name, n in events.items()
+                if name.startswith("fused.agg_")}
+    if query == "q3":
+        assert branches == {"ordered": traced}
+        rows = [(int(got["l_orderkey"][i]), int(got["revenue"][i]),
+                 int(got["o_orderdate"][i]))
+                for i in range(len(got["l_orderkey"]))]
+        assert rows == Q.q3_oracle(gen)
+    else:
+        assert branches == {"ordered": traced, "int_key": traced}
+        rows = [(int(got["c_name"][i]), int(got["c_custkey"][i]),
+                 int(got["o_orderkey"][i]), int(got["o_orderdate"][i]),
+                 int(got["o_totalprice"][i]), int(got["sum_qty"][i]))
+                for i in range(len(got["c_name"]))]
+        assert rows and rows == Q.q18_oracle(gen, 150)
+
+
 def _shrunk_join(how, capacity=512, second_parent=False):
     """-> (root, probe keys, matched mask): a ShrinkOp over a JoinOp of
     256 probe rows against 64 unique build keys; with `second_parent`
@@ -556,8 +789,8 @@ def _agg_session(cat, *setup):
 
 
 def _agg_branches(fn):
-    """-> (fn(), {"materialized" | "folded": events} of the counters
-    `fused.agg_materialized` / `fused.agg_folded`: one event per
+    """-> (fn(), {"materialized" | "folded" | "ordered" | "int_key":
+    events} of the counters `fused.agg_<lowering>`: one event per
     HashAggOp per traced program)."""
     out, events = _stage_events(fn, "fused.agg_")
     return out, {name[len("fused.agg_"):]: n for name, n in events.items()}

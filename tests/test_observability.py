@@ -256,9 +256,12 @@ def test_explain_analyze_q3_renders_span_tree():
     compacts = [ln.split() for ln in lines
                 if ln.startswith("fused.join_compact")]
     assert compacts and compacts[0][-2:] == ["2", "ev"]
-    # and names the lowering its aggregate took (exec/fused._mat_agg)
-    assert any(ln.startswith("fused.agg_materialized") for ln in lines)
-    assert not any(ln.startswith("fused.agg_folded") for ln in lines)
+    # and names the lowering its aggregate took (exec/fused._mat_agg):
+    # in place, over the order its compacting join left
+    assert any(ln.startswith("fused.agg_ordered") for ln in lines)
+    assert not any(ln.startswith(("fused.agg_folded",
+                                  "fused.agg_materialized"))
+                   for ln in lines)
 
 
 def test_explain_analyze_trace_shows_retry_on_armed_fault():

@@ -493,6 +493,119 @@ def test_ordered_aggregate_matches_hash(rng):
     assert rows(oa) == rows(ha)
 
 
+_EVERY_AGG = [AggSpec("sum", "v", "s"), AggSpec("sum", "w", "sw"),
+              AggSpec("count", "v", "c"), AggSpec("count_star", None, "n"),
+              AggSpec("min", "v", "mn"), AggSpec("max", "f", "mx"),
+              AggSpec("sum_hi32", "v", "hi"), AggSpec("sum_lo32", "v", "lo"),
+              AggSpec("bool_and", "bl", "ba"), AggSpec("bool_or", "bl", "bo"),
+              AggSpec("any_not_null", "v", "an"), AggSpec("sum", "f", "sf"),
+              # avg's parts, as HashAggOp decomposes it
+              AggSpec("sum", "v", "avs"), AggSpec("count", "v", "avc")]
+
+
+def _grouped_batch(rng, cap, n_keys, sel, null_key=None):
+    """Keys (k1 nullable where `null_key` names a value, k2 a function of
+    k1) in contiguous runs over ALL lanes; every input type with NULLs."""
+    k1 = np.sort(rng.integers(0, n_keys, cap))
+    v = rng.integers(-(1 << 40), 1 << 40, cap)
+    null = rng.random(cap) < 0.3
+    return make_batch({
+        "k1": (k1, None if null_key is None else k1 != null_key),
+        "k2": ((k1 * 3) % 2, None),
+        "v": (v, ~null),
+        "w": ((v % 1000).astype(np.int32), None),
+        "f": (rng.normal(size=cap).astype(np.float32), None),
+        "bl": (rng.random(cap) < 0.5, ~null)}, sel)
+
+
+def _agg_rows(out, names):
+    rows = []
+    for i in np.nonzero(np.asarray(out.sel))[0]:
+        row = []
+        for n in names:
+            c = out.col(n)
+            ok = c.validity is None or bool(c.validity[i])
+            row.append((ok, float(c.values[i]) if ok else 0.0))
+        rows.append(tuple(row))
+    return sorted(rows)
+
+
+_RUN_ENDS_CASES = {
+    # name: (capacity, distinct keys, selection, NULL key value)
+    "dense_prefix": (600, 40, lambda r, c: np.arange(c) < 417, None),
+    "every_lane_live": (64, 9, lambda r, c: np.ones(c, bool), None),
+    "runs_of_one": (64, 1 << 30, lambda r, c: np.arange(c) < 50, None),
+    "one_run_the_whole_batch": (700, 1, lambda r, c: np.ones(c, bool), None),
+    "one_lane": (1, 1, lambda r, c: np.ones(c, bool), None),
+    "dead_tail": (1500, 30, lambda r, c: np.arange(c) < 3, None),
+    "all_dead": (600, 7, lambda r, c: np.zeros(c, bool), None),
+    "null_key_run": (600, 12, lambda r, c: np.arange(c) < 500, 1),
+    "holes_in_runs": (600, 9, lambda r, c: r.random(c) < 0.6, None),
+    "sparse_holes": (1500, 200, lambda r, c: r.random(c) < 0.1, 3),
+    # every run's FIRST lane dead, and every run's last
+    "first_lane_of_every_run_dead": (600, 15, "first", None),
+    "last_lane_of_every_run_dead": (600, 15, "last", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RUN_ENDS_CASES))
+def test_run_ends_aggregate_matches_lex(case, rng):
+    """The in-place grouped aggregate against the exact lexsort path:
+    every supported function with NULL inputs, the same groups and the
+    same values, each group once at its run's last live lane; `dense`
+    (the caller's word that live lanes come first) changes nothing where
+    it is true; ordered_aggregate is its compacted form."""
+    from cockroach_tpu.ops.agg import ordered_aggregate, run_ends_aggregate
+
+    cap, n_keys, sel_of, null_key = _RUN_ENDS_CASES[case]
+    b = _grouped_batch(rng, cap, n_keys, np.ones(cap, bool), null_key)
+    k1 = np.asarray(b.col("k1").values)
+    if sel_of in ("first", "last"):
+        edge = np.r_[True, k1[1:] != k1[:-1]]
+        sel = ~(edge if sel_of == "first" else np.r_[edge[1:], True])
+    else:
+        sel = sel_of(rng, cap)
+    b = Batch(b.columns, jnp.asarray(sel), jnp.int32(sel.sum()))
+    gb = ["k1", "k2"]
+    exact = gb + [a.out for a in _EVERY_AGG if a.out not in ("sf", "an")]
+    want = hash_aggregate(b, gb, _EVERY_AGG, method="lex")
+    live_first = not sel.any() or sel[:sel.sum()].all()
+    for dense in ([False, True] if live_first else [False]):
+        got = run_ends_aggregate(b, gb, _EVERY_AGG, dense=dense)
+        assert int(got.length) == int(want.length) == int(
+            np.asarray(got.sel).sum())
+        assert _agg_rows(got, exact) == _agg_rows(want, exact)
+        # one lane a group, and it is a live lane of the input
+        assert not (np.asarray(got.sel) & ~sel).any()
+        for (gk, gs), (wk, ws) in zip(_agg_rows(got, ["k1", "sf"]),
+                                      _agg_rows(want, ["k1", "sf"])):
+            assert gk == wk and gs[0] == ws[0]
+            assert abs(gs[1] - ws[1]) < 1e-3
+        # any_not_null: SOME non-NULL input of the group
+        vs = np.asarray(b.col("v").values)
+        for (k, an) in _agg_rows(got, ["k1", "an"]):
+            live = sel & np.asarray(b.col("v").validity) & (
+                (k1 == k[1]) if k[0] else (k1 == null_key))
+            assert an[0] == bool(live.any())
+            assert not an[0] or an[1] in vs[live].astype(float)
+    compacted = ordered_aggregate(b, gb, _EVERY_AGG)
+    assert _agg_rows(compacted, exact) == _agg_rows(want, exact)
+    assert (np.asarray(compacted.sel)
+            == (np.arange(cap) < int(want.length))).all()
+
+
+def test_blocked_cummax_matches_numpy(rng):
+    import jax
+    from cockroach_tpu.ops.prefix import blocked_cummax
+
+    for n in (1, 5, 64, 65, 3000, 70001):
+        for dt in (np.int64, np.int32, np.float32):
+            x = rng.integers(-(1 << 30), 1 << 30, n).astype(dt)
+            got = np.asarray(jax.jit(lambda v: blocked_cummax(v, block=64))(
+                jnp.asarray(x)))
+            np.testing.assert_array_equal(got, np.maximum.accumulate(x))
+
+
 def test_ordered_agg_op_streaming(rng):
     from cockroach_tpu.exec import collect
     from cockroach_tpu.exec.operators import OrderedAggOp, ScanOp

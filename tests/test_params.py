@@ -344,9 +344,14 @@ def test_every_binding_runs_the_one_program_and_answers_exactly(
     # every program traced here (the one with parameters and a literal
     # text a binding) aggregated ONCE over its materialized input: Q6 over
     # lineitem's flat-unpacked chunks with no lax.scan (ISSUE 32); a PR
-    # that moves it back under the chunk fold fails here, not on the chip
+    # that moves it back under the chunk fold fails here, not on the chip.
+    # Q3's aggregate reads the order its compacting join left, in place
+    # (ISSUE 36), in every one of them; Q6's has no join under it
     assert "fused.agg_folded" not in col.stages
-    assert col.stages["fused.agg_materialized"].events >= 1
+    taken, other = (("fused.agg_ordered", "fused.agg_materialized")
+                    if name == "q3" else
+                    ("fused.agg_materialized", "fused.agg_ordered"))
+    assert col.stages[taken].events >= 1 and other not in col.stages
     assert col.stages["sql.prepared_hit"].events == 2 * len(bindings) - 1
     assert col.stages["sql.bind_params"].rows == 2 * len(bindings) * len(kinds)
     assert _counter("sql_bind_textual_total") == textual
@@ -568,12 +573,13 @@ _LOC = re.compile(r"\s*loc\([^)]*\)|^#loc.*$", re.M)
 # of the accepted cells' literal statements at SF 0.01, seed 7, capacity
 # 131,072, `vectorize = tpu`, CPU. Q1's was computed on the tree BEFORE
 # PR 31 (5b4835b) and has held since: a PR that leaves it alone loads the
-# cache entry its parent compiled. Q3's is PR 34's, which changed the
-# compacting join on purpose (the build's row index rides the key sort;
-# 97c906df3a7b592e until then): a PR that moves it recompiles both Q3
-# cells and measures them.
+# cache entry its parent compiled. Q3's is PR 36's, which changed its
+# aggregate on purpose (in place over the order the compacting join left:
+# no hash, sort or gather at the Shrink's lanes; 3a343d0cc3806b81 from
+# PR 34 until then): a PR that moves it recompiles both Q3 cells and
+# measures them.
 LITERAL_PROGRAMS = {"tpch-sf1.q1-2streams": "43752e10756b36c7",
-                    "tpch-sf1.q3-1stream": "3a343d0cc3806b81"}
+                    "tpch-sf1.q3-1stream": "6f17850c22770fad"}
 
 
 @pytest.mark.parametrize("cell", sorted(LITERAL_PROGRAMS))

@@ -321,27 +321,31 @@ def test_explain_prints_the_having_parameter_and_the_lowerings(served):
                  if ln.startswith("fused.") and "ev" in ln.split()}
     finally:
         client.close()
-    # one int-key and one materialised aggregate a traced program
+    # a traced program: one int-key aggregate (the HAVING's, over the
+    # scan) and one in place over the last join's key order (ISSUE 36)
     traced = table.get("fused.compile", 0)
     if traced:
         assert table["fused.agg_int_key"] == traced
-        assert table["fused.agg_materialized"] == traced
+        assert table["fused.agg_ordered"] == traced
+        assert "fused.agg_materialized" not in table
         assert "fused.agg_folded" not in table
     assert table["fused.sort_lanes"] == table["fused.exec"]
 
 
 # ------------------------------------ the lowerings and the sorted lanes ---
 
-@pytest.mark.parametrize("sql,values,lanes,int_key,materialized", [
+@pytest.mark.parametrize("sql,values,lanes,int_key,ordered", [
     # the int-key aggregate's input, orders + customer, orders + the
-    # HAVING's Shrink (4,096), lineitem + the semi join's Shrink (16,384)
+    # HAVING's Shrink (4,096), lineitem + the semi join's Shrink (16,384);
+    # the last aggregate (five keys: the last join's key and four columns
+    # of its build) reads that join's order and sorts nothing
     (Q18, ("312",), [131072, 131072 + 131072, 131072 + 4096,
                      131072 + 16384], 1, 1),
     # lineitem + the orders Shrink (4,096 at SF 0.01), orders + customer
     (Q3, None, [131072 + 4096, 131072 + 131072], 0, 1),
 ], ids=["q18", "q3"])
 def test_the_aggregates_lowering_and_the_sorted_lanes_are_counted(
-        served, sql, values, lanes, int_key, materialized):
+        served, sql, values, lanes, int_key, ordered):
     sess = _session(served)
     sess._prepared = type(sess._prepared)()
     col = stats.enable()
@@ -356,10 +360,11 @@ def test_the_aggregates_lowering_and_the_sorted_lanes_are_counted(
         stats.disable()
     traced = col.stages["fused.compile"].events
     counted = {n: col.stages[n].events if n in col.stages else 0
-               for n in ("fused.agg_int_key", "fused.agg_materialized",
-                         "fused.agg_folded")}
+               for n in ("fused.agg_int_key", "fused.agg_ordered",
+                         "fused.agg_materialized", "fused.agg_folded")}
     assert counted == {"fused.agg_int_key": int_key * traced,
-                       "fused.agg_materialized": materialized * traced,
+                       "fused.agg_ordered": ordered * traced,
+                       "fused.agg_materialized": 0,
                        "fused.agg_folded": 0}
     # one event a dispatch, the program's lanes at each
     sort = col.stages["fused.sort_lanes"]

@@ -362,6 +362,32 @@ def test_compacting_probe_matches_probe_then_shrink(case, how):
     assert _batch_rows(got.batch, names) == _batch_rows(want, names)
 
 
+@pytest.mark.parametrize("case", sorted(
+    c for c, v in _COMPACT_CASES.items() if not v[5]))
+def test_compacting_inner_join_leaves_its_lanes_in_key_order(case):
+    """The guarantee an aggregate above relies on (ISSUE 36): an INNER
+    join's compacted result has its live rows first, ascending in the
+    join key (equal keys adjacent), the build's key equal to the probe's
+    on every live lane; a cut at C lanes (overflow) is a prefix of that
+    order. A semi join's is in probe-lane order."""
+    from cockroach_tpu.ops.join import prepare_build
+    from cockroach_tpu.ops.sortjoin import probe_unique_compact
+
+    pcols, psel, bcols, bsel, C, _fallback, _overflow = _COMPACT_CASES[case]
+    probe, build = _batch(pcols, psel), _batch(bcols, bsel)
+    ub = prepare_build(build, ("bk",), mode="unique")
+    got = probe_unique_compact(probe, ub, ("pk",), "inner", C).batch
+    n = int(got.length)
+    sel = np.asarray(got.sel)
+    assert sel[:n].all() and not sel[n:].any()
+    pk = np.asarray(got.col("pk").values)[:n]
+    assert (np.diff(pk) >= 0).all()
+    assert (np.asarray(got.col("bk").values)[:n] == pk).all()
+    semi = probe_unique_compact(probe, ub, ("pk",), "semi", C).batch
+    pv = np.asarray(semi.col("pv").values)[:int(semi.length)]
+    assert (np.diff(pv) > 0).all()      # pv is the probe's lane index
+
+
 @pytest.mark.parametrize("how", ["left", "anti", "right", "outer"])
 def test_compacting_probe_refuses_other_join_types(how):
     from cockroach_tpu.ops.join import prepare_build
