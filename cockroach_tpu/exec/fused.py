@@ -340,6 +340,10 @@ class _Tracer:
         # input's capacity): what FusedRunner counts a dispatch as stage
         # fused.sort_lanes
         self.sort_lanes = 0
+        # ... and those of them under the hashed u64 key (a join on more
+        # than one column, or on a key that is no integer): stage
+        # fused.hash_key_lanes
+        self.hash_key_lanes = 0
         # ids of the ShrinkOps that lowered with their join as ONE step in
         # THIS trace (_mat_join returned compacted=True): what leaves
         # their lanes in key order for _ordered_input
@@ -596,6 +600,14 @@ class _Tracer:
             guard = self._route_guard(op)
             self.flag_ops.extend(_flag_targets(guard, op))
             self.sort_lanes += probe.capacity + build.capacity
+            # the packing the key took (ops/sortjoin.prepare_unique): one
+            # integer column rides the sorts as itself, anything else as
+            # a 62-bit hash in a u64 operand, verified by a row gather
+            if getattr(bt, "key_kind", "hash") == "int":
+                stats.add("fused.join_key_int")
+            else:
+                stats.add("fused.join_key_hash")
+                self.hash_key_lanes += probe.capacity + build.capacity
             if shrink is not None and compacts(bt, probe.capacity, op.how):
                 res = probe_unique_compact(probe, bt, probe_on, op.how,
                                            shrink.capacity)
@@ -1066,7 +1078,8 @@ class FusedRunner:
     def __init__(self, root: Operator):
         self.root = root
         self.schema = root.schema
-        # config key -> (program, flag_ops, result_cap, sort_lanes), or
+        # config key -> (program, flag_ops, result_cap, sort_lanes,
+        # hash_key_lanes), or
         # None for a config that proved unsupported
         self._progs: Dict[tuple, Optional[tuple]] = {}
         # vkey (per-scan content-identity tuple) -> (args, chunks): lets a
@@ -1081,10 +1094,11 @@ class FusedRunner:
         self._mu = threading.RLock()
         self._served_once = False
         # a tree that reads bound parameters: the program takes them as
-        # one more argument (the statement's int64 vector of (value,
-        # valid) pairs, the same shape at every binding), so program,
-        # config key and persistent-cache entry belong to the statement
-        # and not to a binding
+        # its last arguments (the statement's int64 vector of (value,
+        # valid) pairs and a bool table for each LIKE pattern among them,
+        # the same shapes at every binding), so program, config key and
+        # persistent-cache entry belong to the statement and not to a
+        # binding
         self._takes_params = takes_params(root)
         # what the last dispatch passed after the images (_bound_args):
         # device_profile() runs the program at that binding; None until
@@ -1093,7 +1107,7 @@ class FusedRunner:
 
     def _bound_args(self) -> tuple:
         """The bound values of the statement this thread is running
-        (ops/expr.bound_args), as the program's trailing argument; () for
+        (ops/expr.bound_args), as the program's trailing arguments; () for
         a tree without parameters."""
         if not self._takes_params:
             return ()
@@ -1101,7 +1115,7 @@ class FusedRunner:
         if bound is None:
             raise _expr.ParamOutsideProgram(
                 "a parameterised plan was run with no values bound")
-        return (bound,)
+        return tuple(bound)
 
     @staticmethod
     def _warm_key(scans) -> Optional[tuple]:
@@ -1208,16 +1222,16 @@ class FusedRunner:
 
         def prog(*stacked_args):
             t = _Tracer(dict(zip(scan_ids, stacked_args)), self.root)
-            # a parameterised tree: the bound values are the argument
+            # a parameterised tree: the bound values are the arguments
             # after the images, and its filters read them while traced
-            bound = stacked_args[n_scans] if self._takes_params else None
-            with _expr.traced_params(bound):
+            with _expr.traced_params(stacked_args[n_scans:]):
                 out = t._mat(self.root)
             tracer_box["flag_ops"] = list(t.flag_ops)
             # the packed window never exceeds the result's own static
             # capacity — a 12-lane aggregate reads back ~1 KB, not MBs
             tracer_box["result_cap"] = min(RESULT_CAP, out.capacity)
             tracer_box["sort_lanes"] = t.sort_lanes
+            tracer_box["hash_key_lanes"] = t.hash_key_lanes
             with scope(RESULT_SCOPE):
                 return _pack_result(out, tuple(t.flags), schema,
                                     tracer_box["result_cap"])
@@ -1229,7 +1243,7 @@ class FusedRunner:
         """What _progs keeps of a compiled config: the program and what
         its trace left in the side-box."""
         return (compiled, tracer_box["flag_ops"], tracer_box["result_cap"],
-                tracer_box["sort_lanes"])
+                tracer_box["sort_lanes"], tracer_box["hash_key_lanes"])
 
     def _prepare(self):
         # one sessions-shared critical section covering the warm-key
@@ -1385,8 +1399,8 @@ class FusedRunner:
         t_first = _time.perf_counter()
         try:
             with stats.timed("fused.prepare"):
-                (prog, flag_ops, result_cap, sort_lanes), args = \
-                    self._prepare()
+                (prog, flag_ops, result_cap, sort_lanes,
+                 hash_key_lanes), args = self._prepare()
         except Unsupported as e:
             # this run's volume (or shape) is outside the fusion grammar:
             # delegate wholesale to the streaming runtime
@@ -1418,6 +1432,7 @@ class FusedRunner:
                 out = jax.block_until_ready(out)
             # one event a dispatch; the lanes are the traced shapes'
             stats.add("fused.sort_lanes", rows=sort_lanes)
+            stats.add("fused.hash_key_lanes", rows=hash_key_lanes)
             return out
 
         try:
@@ -1483,8 +1498,8 @@ class FusedRunner:
         if bound is None:
             return None
         try:
-            with _expr.bound_args(bound[0] if bound else None):
-                (prog, _ops, _cap, _lanes), args = self._prepare()
+            with _expr.bound_args(bound or None):
+                (prog, *_trace_facts), args = self._prepare()
         except Unsupported:
             return None
         stages = ("fused.dispatch", "fused.wait")

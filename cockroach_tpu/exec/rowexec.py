@@ -260,7 +260,7 @@ def eval_datum(e: Expr, row: Dict[str, object], schema: Schema):
         if v is None:
             return None
         return v in e.values
-    if isinstance(e, Like):
+    if isinstance(e, Like) and isinstance(e.pattern, str):
         v = eval_datum(e.arg, row, schema)
         if v is None:
             return None

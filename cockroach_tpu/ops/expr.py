@@ -16,10 +16,11 @@ Semantics follow SQL:
   host-side through the schema's dictionary (equality -> code compare,
   LIKE -> boolean lookup table indexed by code);
 - a bound parameter (`Param`) is an ARGUMENT of the program that
-  evaluates it (an entry of the statement's one packed vector), never a
-  constant of it: the program that takes the
-  arguments opens `traced_params` around its trace, and outside one a
-  `Param` refuses to evaluate (ParamOutsideProgram).
+  evaluates it (an entry of the statement's one packed vector; a LIKE
+  pattern's boolean table over its column's dictionary is an argument of
+  its own beside the vector), never a constant of it: the program that
+  takes the arguments opens `traced_params` around its trace, and outside
+  one a `Param` refuses to evaluate (ParamOutsideProgram).
 
 Dates are int32 days since epoch; EXTRACT uses the standard civil-calendar
 integer algorithm so it stays on device.
@@ -111,11 +112,15 @@ class Param(Expr):
     representation of `ty` (days, scaled integer, dictionary code).
     `sample` is the slot's value at the binding the plan was made at, in
     a Lit's units: the planner's estimates read it (sql/stats.py) and no
-    program ever does."""
+    program ever does. A LIKE pattern's slot (`Like.pattern`) has no
+    scalar: `table` says which of the statement's table arguments holds
+    its membership table over the column's dictionary, and `sample` is
+    (dictionary entries that match, dictionary entries)."""
 
     index: int
     ty: ColType
     sample: object = None
+    table: Optional[int] = None
 
     def type(self, schema):
         return self.ty
@@ -143,10 +148,12 @@ def _frame(slot: str, values):
 
 
 def bound_args(values):
-    """The statement's bound arguments for the runs inside the block: the
-    int64 vector of (value, valid) pairs, two entries a slot, that
-    sql/params.evaluate packs; what a program that takes them
-    (exec/fused.FusedRunner) is called with."""
+    """The statement's bound arguments for the runs inside the block, as
+    sql/params.evaluate makes them: a tuple of the int64 vector of (value,
+    valid) pairs, two entries a slot, and after it one boolean table over
+    a dictionary for each LIKE pattern among the slots; what a program
+    that takes them (exec/fused.FusedRunner) is called with, after its
+    scan images."""
     return _frame("args", values)
 
 
@@ -155,9 +162,18 @@ def current_args():
 
 
 def traced_params(values):
-    """Inside the trace of a program whose argument it is: the packed
-    vector eval_expr unpacks a Param from."""
+    """Inside the trace of a program whose arguments they are: the tuple
+    (packed vector, pattern tables...) eval_expr reads a Param from."""
     return _frame("traced", values)
+
+
+def _traced(expr: "Param"):
+    traced = getattr(_params, "traced", None)
+    if not traced:
+        raise ParamOutsideProgram(
+            f"parameter slot {expr.index} evaluated outside a program "
+            "that takes the bound values as arguments")
+    return traced
 
 
 def has_params(e) -> bool:
@@ -263,10 +279,13 @@ class InList(Expr):
 @dataclass(frozen=True, eq=False)
 class Like(Expr):
     """SQL LIKE over a dictionary-encoded string column (%/_ wildcards).
-    Resolved host-side: pattern -> bool table over the dictionary."""
+    Resolved host-side: pattern -> bool table over the dictionary, a
+    constant of the program for a literal pattern; for a bound one
+    (`pattern` a Param with a `table`) sql/params.py evaluates the table
+    once a Bind and the program takes it as an argument."""
 
     arg: Expr  # must be a STRING Col
-    pattern: str
+    pattern: object  # str | Param
     negate: bool = False
 
     def type(self, schema):
@@ -373,23 +392,30 @@ def _decimal_to_float(values, scale: int):
     return values.astype(jnp.float32) / jnp.float32(10 ** scale)
 
 
-# id(dictionary array) -> (weakref to it, {string: first code}): a string
-# bound against a served catalog's dictionary (sql/params.slot_value, once
-# a Bind) is one dict probe, not a walk of the dictionary
+def _per_dictionary(cache: dict, d: np.ndarray, build):
+    """`build(d)`, made once for a dictionary array and kept in `cache`
+    (id(d) -> (weakref to it, what was built)) for as long as it lives: a
+    served catalog's dictionaries are asked about at every Bind."""
+    hit = cache.get(id(d))
+    if hit is not None and hit[0]() is d:
+        return hit[1]
+    key = id(d)
+    made = build(d)
+    cache[key] = (weakref.ref(d, lambda _r: cache.pop(key, None)), made)
+    return made
+
+
+# {string: first code}: a string bound against a dictionary
+# (sql/params.slot_value) is one dict probe, not a walk of the dictionary
 _CODE_INDEX: Dict[int, Tuple[weakref.ref, Dict[str, int]]] = {}
 
 
 def _code_index(d: np.ndarray) -> Dict[str, int]:
-    hit = _CODE_INDEX.get(id(d))
-    if hit is not None and hit[0]() is d:
-        return hit[1]
     # filled from the back, so a string that occurs twice keeps its
     # first code (what np.nonzero(d == s)[0][0] gave)
-    index = dict(zip(d[::-1].tolist(), range(len(d) - 1, -1, -1)))
-    key = id(d)
-    _CODE_INDEX[key] = (weakref.ref(d, lambda _r: _CODE_INDEX.pop(key, None)),
-                        index)
-    return index
+    return _per_dictionary(
+        _CODE_INDEX, d,
+        lambda d: dict(zip(d[::-1].tolist(), range(len(d) - 1, -1, -1))))
 
 
 def _string_code(schema: Schema, col: str, s: str) -> int:
@@ -425,14 +451,10 @@ def eval_expr(expr: Expr, batch: Batch, schema: Schema) -> Column:
         return Column(jnp.full((cap,), v, dtype=ty.dtype))
 
     if isinstance(expr, Param):
-        traced = getattr(_params, "traced", None)
-        if traced is None:
-            raise ParamOutsideProgram(
-                f"parameter slot {expr.index} evaluated outside a program "
-                "that takes the bound values as arguments")
         # the statement's one int64 vector of (value, valid) pairs
         # (sql/params.evaluate); a float32 rides as its bit pattern
-        raw, valid = traced[2 * expr.index], traced[2 * expr.index + 1] != 0
+        packed = _traced(expr)[0]
+        raw, valid = packed[2 * expr.index], packed[2 * expr.index + 1] != 0
         if expr.ty.kind is Kind.FLOAT:
             import jax as _jax
 
@@ -703,15 +725,25 @@ def eval_expr(expr: Expr, batch: Batch, schema: Schema) -> Column:
     if isinstance(expr, Like):
         col = _find_string_col(expr.arg)
         d = schema.dictionary(col)
-        rx = re.compile(_like_to_regex(expr.pattern), re.S)
-        table = jnp.asarray(
-            np.array([bool(rx.fullmatch(s)) for s in d], dtype=np.bool_))
         c = eval_expr(expr.arg, batch, schema)
-        hit = table[jnp.clip(c.values, 0, len(d) - 1)]
+        validity = c.validity
+        if isinstance(expr.pattern, Param):
+            # a bound pattern: its table is this binding's argument, and a
+            # NULL pattern (the slot's valid entry) makes every row NULL
+            traced = _traced(expr.pattern)
+            table = traced[1 + expr.pattern.table]
+            bound = traced[0][2 * expr.pattern.index + 1] != 0
+            validity = _and_validity(validity,
+                                     jnp.full((cap,), bound, jnp.bool_))
+        else:
+            table = jnp.asarray(like_table(d, expr.pattern))
+        # (a bound table is as long as sql/params.table_lanes makes it, not
+        # as the dictionary: nothing of the program is the dictionary's size)
+        hit = table[jnp.clip(c.values, 0, table.shape[0] - 1)]
         hit &= c.values >= 0
         if expr.negate:
             hit = ~hit
-        return Column(hit, c.validity)
+        return Column(hit, validity)
 
     if isinstance(expr, Extract):
         c = eval_expr(expr.arg, batch, schema)
@@ -733,6 +765,51 @@ def eval_expr(expr: Expr, batch: Batch, schema: Schema) -> Column:
         return Column(fn(lc.values, rc.values), validity)
 
     raise TypeError(f"cannot evaluate {type(expr).__name__}")
+
+
+# (every entry joined by NUL, the offset each entry starts at): what
+# like_table searches for a pattern's longest literal
+_DICT_TEXT: Dict[int, Tuple[weakref.ref, Tuple[str, np.ndarray]]] = {}
+_SEP = "\x00"
+
+
+def _joined(d: np.ndarray) -> Tuple[str, np.ndarray]:
+    entries = [str(x) for x in d]
+    starts = np.zeros(len(entries), np.int64)
+    np.cumsum([len(x) + 1 for x in entries[:-1]], out=starts[1:])
+    return _SEP.join(entries), starts
+
+
+def like_table(dictionary: np.ndarray, pattern: str) -> np.ndarray:
+    """Which entries of `dictionary` match the LIKE `pattern` (`%` any
+    run, `_` any one character): a bool array over the dictionary's codes.
+    Every entry that matches holds the pattern's longest literal run, so
+    one search of the joined dictionary for it leaves the regular
+    expression only the entries that do (`%green%` over 200,000 part
+    names: 11,000), and nothing where the pattern is that literal between
+    two `%`: holding it is matching; a pattern without a literal is
+    matched entry by entry."""
+    n = len(dictionary)
+    if pattern and not pattern.strip("%"):
+        return np.ones(n, np.bool_)
+    rx = re.compile(_like_to_regex(pattern), re.S)
+    literal = max(re.split("[%_]", pattern), key=len)
+    if len(literal) < 3 or _SEP in literal or n < 64:
+        # (a literal of a character or two is in most entries)
+        return np.fromiter((rx.fullmatch(x) is not None
+                            for x in dictionary), np.bool_, n)
+    text, starts = _per_dictionary(_DICT_TEXT, dictionary, _joined)
+    found = np.fromiter((m.start() for m in
+                         re.finditer(re.escape(literal), text)), np.int64)
+    holds = np.searchsorted(starts, found, side="right") - 1
+    out = np.zeros(n, np.bool_)
+    if pattern == "%" + literal + "%":
+        # (no find spans two entries: the separator is not in the literal)
+        out[holds] = True
+        return out
+    for i in np.unique(holds):
+        out[i] = rx.fullmatch(dictionary[i]) is not None
+    return out
 
 
 def _like_to_regex(pattern: str) -> str:

@@ -27,10 +27,18 @@ Parameters: a constant subexpression over `$n` (`$1`, `$1 + interval '1'
 year`, `$2 - 0.01`) takes its type where a literal would, from the operand
 beside it in a comparison, BETWEEN or + - * arithmetic, and becomes a
 `Param` naming a slot of `Binder.param_slots` (sql/params.py evaluates the
-slots per Bind). One that finds no typed operand (a projection, `$1 =
-$2`, IN, LIKE, string ordering) raises ParamOutOfScope: the session then
-binds that statement as text. The values the binder is given are the
-binding its estimates are taken at; no value enters the plan's programs.
+slots per Bind). `col LIKE $n` over a dictionary-coded column makes a
+slot whose bound value is a table over the dictionary (`_pattern_slot`).
+One that finds no typed operand (a projection, `$1 = $2`, IN, string
+ordering) raises ParamOutOfScope: the session then binds that statement
+as text. The values the binder is given are the binding its estimates are
+taken at; no value enters the plan's programs.
+
+Derived tables: `FROM (SELECT ...) AS alias` as the statement's only FROM
+item is merged into the statement that reads it before anything is bound
+(`_merge_derived`: the specification's text of TPC-H Q9), so it binds to
+the plan of the flattened text and a `$n` inside it is a slot of the
+statement's one list.
 """
 
 from __future__ import annotations
@@ -198,6 +206,7 @@ class Binder:
         return plan
 
     def _bind_select(self, stmt: P.SelectStmt) -> Plan:
+        stmt = _merge_derived(stmt)
         # -- resolve FROM tables ------------------------------------------
         rels: Dict[str, _Rel] = {}
         schemas: Dict[str, Schema] = {}
@@ -422,7 +431,10 @@ class Binder:
             return Not(e) if node.negate else e
         if isinstance(node, P.LikeAst):
             arg = self._bx(node.arg, refs, allow_agg, aggs)
-            return Like(arg, node.pattern, node.negate)
+            pattern = node.pattern
+            if isinstance(pattern, P.Placeholder):
+                pattern = self._pattern_slot(pattern, arg)
+            return Like(arg, pattern, node.negate)
         if isinstance(node, P.IsNullAst):
             arg = self._bx(node.arg, refs, allow_agg, aggs)
             return IsNull(arg, node.negate)
@@ -594,6 +606,35 @@ class Binder:
         else:
             self.param_slots.append(slot)
         return Param(slot.index, ty, sample_of(slot, self.params))
+
+    def _pattern_slot(self, node: P.Placeholder, arg: Expr) -> Param:
+        """`col LIKE $n`: a slot whose bound value is the pattern's bool
+        table over `col`'s dictionary, made once a Bind on the host
+        (sql/params.evaluate) and passed to the program as an argument of
+        its own. One dictionary-coded column only: the table is indexed
+        by that column's codes."""
+        if self.params is None:
+            raise BindError("the statement has parameters and no values "
+                            "were bound")
+        if not (isinstance(arg, Col)
+                and arg.type(self._global).kind is Kind.STRING
+                and self._global.dictionary(arg.name) is not None):
+            raise ParamOutOfScope("a LIKE pattern parameter over anything "
+                                  "but one dictionary-coded column")
+        tables = [s for s in self.param_slots if s.table is not None]
+        for seen in tables:
+            if (seen.column, seen.node) == (arg.name, node):
+                slot = seen
+                break
+        else:
+            rel = self._col_to_rel.get(arg.name)
+            slot = ParamSlot(len(self.param_slots), node,
+                             arg.type(self._global), arg.name,
+                             self._global, table=len(tables),
+                             relation=self._alias_tables.get(rel, rel))
+            self.param_slots.append(slot)
+        return Param(slot.index, slot.ty, sample_of(slot, self.params),
+                     table=slot.table)
 
     def _agg_result_type(self, e: Expr):
         """The type of an aggregate's result that the HAVING being bound
@@ -1357,6 +1398,116 @@ class _AggCollector:
             fields.append(Field(
                 spec.out, FLOAT if spec.func == "avg" else in_ty))
         return Schema(fields)
+
+
+# ------------------------------------------------------- derived tables
+
+def _merge_derived(stmt: P.SelectStmt) -> P.SelectStmt:
+    """`SELECT ... FROM (SELECT <items> FROM <tables> WHERE ...) AS alias
+    [WHERE ...] GROUP BY ... ORDER BY ...` -> the one SELECT it means: the
+    inner FROM and WHERE, and every outer reference to an inner output
+    column replaced by the expression that computes it. The derived table
+    is the statement's only FROM item and is itself a plain
+    select-project-join: no DISTINCT, GROUP BY, HAVING, aggregate, window,
+    ORDER BY or LIMIT of its own (those need the sub-plan kept apart; a
+    BindError here)."""
+    if not any(t.subquery is not None for t in stmt.tables):
+        return stmt
+    if len(stmt.tables) != 1:
+        raise BindError("a derived table joined to other FROM items is "
+                        "not supported")
+    tref = stmt.tables[0]
+    inner = _merge_derived(tref.subquery)
+    if (inner.distinct or inner.group_by or inner.having is not None
+            or inner.order_by or inner.limit is not None or inner.offset):
+        raise BindError("a derived table with DISTINCT, GROUP BY, HAVING, "
+                        "ORDER BY or LIMIT is not supported")
+    exported: Dict[str, P.Node] = {}
+    for ast, alias in inner.items:
+        name = alias or (ast.name if isinstance(ast, P.ColRef) else None)
+        if name is None or name == "*" or name in exported:
+            raise BindError("every column of a derived table needs one "
+                            "name of its own")
+        if any(isinstance(n, P.WindowCall)
+               or (isinstance(n, P.FuncCall) and n.name in _AGG_FUNCS)
+               for n in _ast_nodes(ast)):
+            raise BindError("a derived table with aggregates or window "
+                            "functions is not supported")
+        exported[name] = ast
+
+    def inline(node, keep=()):
+        """`node` with the derived table's columns written out. A bare
+        name in `keep` (an outer select alias, in GROUP BY or ORDER BY)
+        stays: it names the outer item."""
+        if isinstance(node, P.ColRef):
+            if node.qualifier not in (None, tref.alias):
+                raise BindError(f"unknown table/alias {node.qualifier!r}")
+            if node.qualifier is None and node.name in keep:
+                return node
+            if node.name not in exported:
+                raise BindError(f"column {node.name!r} is not a column of "
+                                f"the derived table {tref.alias!r}")
+            return exported[node.name]
+        return _map_children(node, lambda n: inline(n, keep))
+
+    items = []
+    for ast, alias in stmt.items:
+        if alias is None and isinstance(ast, P.ColRef):
+            alias = ast.name    # the output keeps the inner column's name
+        items.append((inline(ast), alias))
+    named = {alias for _ast, alias in items if alias}
+    where = inner.where
+    if stmt.where is not None:
+        where = P.Parser._conjoin(where, inline(stmt.where))
+    return P.SelectStmt(
+        items=items, distinct=stmt.distinct, tables=list(inner.tables),
+        where=where,
+        group_by=[inline(g, named) for g in stmt.group_by],
+        having=None if stmt.having is None else inline(stmt.having),
+        order_by=[(inline(o, named), desc) for o, desc in stmt.order_by],
+        limit=stmt.limit, offset=stmt.offset)
+
+
+def _map_children(node, fn):
+    """A copy of the AST node with `fn` applied to every child node (in
+    lists and in (node, ...) tuples too); a subquery keeps its own
+    scope."""
+    import dataclasses
+
+    if isinstance(node, P.SelectStmt) or not dataclasses.is_dataclass(node):
+        return node
+
+    def walk(v):
+        if isinstance(v, P.SelectStmt):
+            return v
+        if isinstance(v, P.Node):
+            return fn(v)
+        if isinstance(v, list):
+            return [walk(x) for x in v]
+        if isinstance(v, tuple):
+            return tuple(walk(x) for x in v)
+        return v
+
+    return dataclasses.replace(node, **{
+        f.name: walk(getattr(node, f.name))
+        for f in dataclasses.fields(node)})
+
+
+def _ast_nodes(node):
+    """`node` and every AST node under it (a subquery keeps its own
+    scope)."""
+    import dataclasses
+
+    yield node
+    if isinstance(node, P.SelectStmt) or not dataclasses.is_dataclass(node):
+        return
+    pending = [getattr(node, f.name) for f in dataclasses.fields(node)]
+    while pending:
+        v = pending.pop()
+        if isinstance(v, P.Node):
+            yield from _ast_nodes(v)
+        elif isinstance(v, (list, tuple)):
+            pending.extend(v)
 
 
 def plan_sql(sql: str, catalog: Catalog) -> Plan:

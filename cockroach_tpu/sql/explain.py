@@ -268,7 +268,7 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
         lines.append("parameters: "
                      + ", ".join(s.describe() for s in slots))
         lines.append("estimates taken at: "
-                     + _params.describe_binding(params.values))
+                     + _params.describe_binding(params.values, slots))
     if analyze:
         from cockroach_tpu.util.tracing import summarize
 

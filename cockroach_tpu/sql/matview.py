@@ -116,7 +116,8 @@ class _Shape:
 
     def __init__(self, stmt: P.SelectStmt, desc):
         if len(stmt.tables) != 1 or stmt.tables[0].how != "inner" \
-                or stmt.tables[0].on is not None:
+                or stmt.tables[0].on is not None \
+                or stmt.tables[0].subquery is not None:
             raise BindError("materialized views take exactly one table")
         if stmt.having is not None or stmt.order_by or stmt.distinct \
                 or stmt.limit is not None or stmt.offset:

@@ -14,7 +14,13 @@ of one Bind into the slots' device scalars, once, on the host:
 - `$1 + interval '1' year`, `$2 - 0.01`: arithmetic over parameters and
   literals only is folded here, per binding, and reaches the program as
   one argument;
-- NULL binds as NULL (the slot's `valid` is False).
+- NULL binds as NULL (the slot's `valid` is False);
+- a LIKE pattern over a dictionary-coded column (`p_name like $1`) has no
+  scalar to send: the pattern is matched against the column's dictionary
+  here, once a Bind (stage `sql.bind_like`), and the boolean table over
+  the dictionary's codes (padded to a power of two: `table_lanes`) is an
+  argument of the program beside the scalars' vector, where a literal
+  pattern's table is a constant of it.
 
 A value the slot's type cannot hold exactly (0.055 against a DECIMAL(2)
 column, 'abc' against a date) raises ValueOutOfScope: that binding is
@@ -146,15 +152,22 @@ class ParamSlot:
     """One program argument of a parameterised statement: the constant
     subexpression `node` (a `$n`, or arithmetic over `$n` and literals),
     typed `ty` by the binder from the operand beside it. `column` and
-    `schema` name the dictionary a STRING slot is looked up in."""
+    `schema` name the dictionary a STRING slot is looked up in. A LIKE
+    pattern's slot has a `table`: its place among the statement's table
+    arguments (`relation` is the column's table, for EXPLAIN)."""
 
     index: int
     node: P.Node
     ty: ColType
     column: Optional[str] = None
     schema: object = None
+    table: Optional[int] = None
+    relation: Optional[str] = None
 
     def describe(self) -> str:
+        if self.table is not None:
+            return (f"{render(self.node)} "
+                    f"pattern({self.relation}.{self.column})")
         kind = {Kind.STRING: "string(code)", Kind.DATE: "date",
                 Kind.INT: "int", Kind.FLOAT: "float"}.get(
             self.ty.kind, f"decimal({self.ty.scale})")
@@ -315,10 +328,30 @@ def slot_value(slot: ParamSlot, values: Sequence) -> Tuple:
     return out, np.bool_(True)
 
 
+def pattern_table(slot: ParamSlot, values: Sequence):
+    """A LIKE pattern slot at one binding -> the bool table over its
+    column's dictionary (ops/expr.like_table), or None for a NULL
+    pattern."""
+    from cockroach_tpu.ops.expr import like_table
+
+    try:
+        pattern = _fold(slot.node, values, Kind.STRING)
+    except _Null:
+        return None
+    return like_table(slot.schema.dictionary(slot.column), pattern)
+
+
 def sample_of(slot: ParamSlot, values: Sequence):
     """The slot's value at this binding as a Lit would hold it (the
     planner's estimates read a Param's `sample` where they read a Lit's
-    value: days, an unscaled number; a string has none)."""
+    value: days, an unscaled number; a string has none). A pattern's is
+    (dictionary entries it matches, dictionary entries)."""
+    if slot.table is not None:
+        try:
+            table = pattern_table(slot, values)
+        except ValueError:
+            return None
+        return None if table is None else (int(table.sum()), len(table))
     if slot.ty.kind is Kind.STRING:
         return None
     try:
@@ -328,24 +361,57 @@ def sample_of(slot: ParamSlot, values: Sequence):
     return v if isinstance(v, int) else float(v)
 
 
-def evaluate(slots: Sequence[ParamSlot], values: Sequence) -> np.ndarray:
-    """One Bind's values -> the program's ONE trailing argument: an int64
-    vector of (value, valid) pairs, slot i at [2i, 2i + 1] (a float32's bit
-    pattern rides as an integer; ops/expr.eval_expr unpacks a Param by its
-    type). One small array is one transfer to the device a statement,
-    however many parameters it has. Raises ValueOutOfScope for a binding
-    the slots cannot hold."""
+def table_lanes(entries: int) -> int:
+    """The length of a pattern slot's table argument: the dictionary's
+    entries rounded up to a power of two, as a capacity is. The program's
+    shape then belongs to the statement and not to the data: TPC-H's part
+    names are 199,995 to 199,999 distinct strings by the seed (a handful of
+    200,000 rows share a name), and each would be a module of its own."""
+    return 1 << max(entries - 1, 0).bit_length()
+
+
+def _bind_pattern(slot: ParamSlot, values: Sequence):
+    """-> (entries matched, valid, table): a pattern slot's entry of the
+    packed vector and its table argument (`table_lanes` long; no code
+    reaches the entries past the dictionary's, which are False). One
+    `sql.bind_like` event, its `rows` the dictionary entries the pattern
+    was matched against."""
+    from cockroach_tpu.exec import stats
+
+    size = len(slot.schema.dictionary(slot.column))
+    out = np.zeros(table_lanes(size), np.bool_)
+    with stats.timed("sql.bind_like", rows=size):
+        table = pattern_table(slot, values)
+    if table is None:
+        return np.int64(0), np.bool_(False), out
+    out[:size] = table
+    return np.int64(table.sum()), np.bool_(True), out
+
+
+def evaluate(slots: Sequence[ParamSlot], values: Sequence) -> tuple:
+    """One Bind's values -> the program's trailing arguments: first an
+    int64 vector of (value, valid) pairs, slot i at [2i, 2i + 1] (a
+    float32's bit pattern rides as an integer; ops/expr.eval_expr unpacks
+    a Param by its type), then one bool table over a dictionary for each
+    pattern slot, in the order of `ParamSlot.table` (most statements have
+    none). One small array is one transfer to the device a statement,
+    however many scalar parameters it has. Raises ValueOutOfScope for a
+    binding the slots cannot hold."""
     packed = np.zeros(2 * len(slots), np.int64)
+    tables = [None] * sum(s.table is not None for s in slots)
     try:
         for s in slots:
-            value, valid = slot_value(s, values)
+            if s.table is not None:
+                value, valid, tables[s.table] = _bind_pattern(s, values)
+            else:
+                value, valid = slot_value(s, values)
             if value.dtype == np.float32:
                 value = value.view(np.int32)
             packed[2 * s.index] = value
             packed[2 * s.index + 1] = valid
     except (ValueError, ArithmeticError) as e:
         raise ValueOutOfScope(str(e)) from e
-    return packed
+    return (packed, *tables)
 
 
 @dataclass
@@ -356,12 +422,22 @@ class BoundParams:
     it)."""
 
     values: tuple
-    args: Optional[np.ndarray] = None
+    args: Optional[tuple] = None
 
     def __len__(self):
         return len(self.values)
 
 
-def describe_binding(values: Sequence) -> str:
-    return ", ".join(f"${i + 1} = {_as_literal(v)}"
+def describe_binding(values: Sequence, slots: Sequence[ParamSlot] = ()
+                     ) -> str:
+    """`$1 = 312, $2 = '%green%' (108 of 1997 part.p_name values)`: the
+    binding EXPLAIN's estimates were taken at; a pattern with the share of
+    its dictionary it matches, which is what the estimate read."""
+    matched = {}
+    for s in slots:
+        sample = sample_of(s, values) if s.table is not None else None
+        if sample and isinstance(s.node, P.Placeholder):
+            matched[s.node.index] = (f" ({sample[0]} of {sample[1]} "
+                                     f"{s.relation}.{s.column} values)")
+    return ", ".join(f"${i + 1} = {_as_literal(v)}{matched.get(i + 1, '')}"
                      for i, v in enumerate(values))

@@ -178,7 +178,7 @@ class ExistsAst(Node):
 @dataclass
 class LikeAst(Node):
     arg: Node
-    pattern: str
+    pattern: object  # the pattern's text, or the Placeholder that binds it
     negate: bool = False
 
 
@@ -224,10 +224,12 @@ class ExtractAst(Node):
 
 @dataclass
 class TableRef(Node):
-    name: str
+    name: str                      # a derived table's is its alias
     alias: Optional[str] = None
     how: str = "inner"             # join type joining THIS table
     on: Optional[Node] = None      # outer joins: ON condition (equi)
+    # FROM (SELECT ...) AS alias: the derived table's query
+    subquery: Optional["SelectStmt"] = None
 
 
 @dataclass
@@ -875,6 +877,13 @@ class Parser:
             stmt.tables.append(t)
 
     def _one_table(self) -> TableRef:
+        if self.accept("op", "("):
+            # a derived table: FROM (SELECT ...) [AS] alias
+            query = self.parse_select()
+            self.expect("op", ")")
+            self.accept_kw("as")
+            alias = self.expect("name").text
+            return TableRef(alias, alias, subquery=query)
         # schema-qualified names (crdb_internal.cluster_queries) fold
         # into one dotted table name; the binder/catalog treat the
         # dotted string as the table's full name
@@ -933,6 +942,8 @@ class Parser:
             self.expect("op", ")")
             return InListAst(e, values, negate)
         if self.accept_kw("like"):
+            if self.peek().kind == "param":
+                return LikeAst(e, self.primary(), negate)
             pat = self.expect("str").text
             return LikeAst(e, pat[1:-1].replace("''", "'"), negate)
         if negate:

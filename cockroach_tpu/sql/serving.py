@@ -398,7 +398,8 @@ def match_batchable(ast, catalog, capacity: int) -> Optional[BatchSpec]:
     if (ast.distinct or ast.group_by or ast.having is not None
             or ast.offset):
         return None
-    if len(ast.tables) != 1 or ast.tables[0].on is not None:
+    if (len(ast.tables) != 1 or ast.tables[0].on is not None
+            or ast.tables[0].subquery is not None):
         return None
     table = ast.tables[0].name
     try:

@@ -1198,10 +1198,12 @@ class Session:
         statement with the values written in) where the statement is
         outside the typed scope: not a SELECT whose parameters all stand
         beside a typed operand in a comparison, BETWEEN or + - *
-        arithmetic (sql/bind.py). Stage `sql.bind_params`: typing (a
-        parse and a bind of the parameterised text, once a statement),
-        the dictionary lookup of a string, the host folding of `$1 +
-        interval '1' year`; `rows` counts the values."""
+        arithmetic, or as LIKE's pattern over a dictionary-coded column
+        (sql/bind.py). Stage `sql.bind_params`: typing (a parse and a
+        bind of the parameterised text, once a statement), the dictionary
+        lookup of a string, the host folding of `$1 + interval '1' year`,
+        a LIKE pattern matched against its dictionary (stage
+        `sql.bind_like` inside this one); `rows` counts the values."""
         from cockroach_tpu.exec import stats
         from cockroach_tpu.util.metric import default_registry
 
@@ -1486,6 +1488,7 @@ class Session:
             raise BindError("DDL inside a transaction is not supported "
                             "(descriptors are not transactional yet)")
         if isinstance(ast, P.SelectStmt) and len(ast.tables) == 1 \
+                and ast.tables[0].subquery is None \
                 and isinstance(self.catalog, SessionCatalog) \
                 and self._matviews().get(ast.tables[0].name) is not None:
             return self._select_matview(ast)

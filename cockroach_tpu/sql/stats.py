@@ -212,6 +212,13 @@ def conjunct_selectivity(e, stats: Optional[TableStats]) -> float:
             return _DEFAULT_SEL
         return min(1.0, len(e.values) / cs.distinct)
     if isinstance(e, Like):
+        sample = getattr(e.pattern, "sample", None)
+        if sample:
+            # a bound pattern: the share of the column's dictionary that
+            # the binding the plan is made at matches
+            hits, size = sample
+            sel = max(hits / max(size, 1), _MIN_SEL)
+            return 1.0 - sel if e.negate else sel
         return 0.1
     return _DEFAULT_SEL
 

@@ -69,7 +69,7 @@ def _served(loader, stmt, params=None, capacity=131072):
 
 
 def _program_text(prep):
-    (prog, _ops, _cap, _lanes), _args = prep.op._fused_runner._prepare()
+    (prog, *_trace_facts), _args = prep.op._fused_runner._prepare()
     return prog.as_text()
 
 
@@ -477,7 +477,7 @@ def test_profile_prepared_runs_each_statement_at_its_last_binding(
     literal, bound_calls = spies[JOIN_AGG].calls, spies[bound_sql].calls
     assert len(literal) == 2 and len(bound_calls) == 2
     slots = sess._prepared[bound_sql].slots
-    want = np.asarray(P_.evaluate(slots, ("11",)))
+    (want,) = P_.evaluate(slots, ("11",))
     for call in bound_calls:
         np.testing.assert_array_equal(np.asarray(call[-1]), want)
     # the literal statement's program takes its images and nothing else

@@ -480,7 +480,7 @@ def test_a_float_parameter_rides_as_its_bit_pattern(tpch, client):
     prep = _prepared(tpch, sql)
     assert [s.ty.kind.value for s in prep.slots] == ["float"]
     assert len(prep.op._fused_runner._progs) == 1
-    args = P_.evaluate(prep.slots, ("1.5",))
+    (args,) = P_.evaluate(prep.slots, ("1.5",))
     assert args.dtype == np.int64 and args.tolist() == [
         int(np.float32(1.5).view(np.int32)), 1]
 

@@ -196,8 +196,8 @@ def test_a_parameter_beside_an_aggregates_result_is_typed_from_it(served):
     assert (slot.ty.kind, slot.ty.scale) == (Kind.DECIMAL, 2)
     assert slot.describe() == "$1 decimal(2)"
     # '312' is 31200 exactly at the sum's scale; no float on the way
-    assert P_.evaluate([slot], ("312",)).tolist() == [31200, 1]
-    assert P_.evaluate([slot], ("312.25",)).tolist() == [31225, 1]
+    assert P_.evaluate([slot], ("312",))[0].tolist() == [31200, 1]
+    assert P_.evaluate([slot], ("312.25",))[0].tolist() == [31225, 1]
     with pytest.raises(P_.ValueOutOfScope):
         P_.evaluate([slot], ("312.005",))
 
@@ -275,8 +275,8 @@ def test_q18_program_sorts_per_join(served, monkeypatch):
     prog, _box = runner._make_prog([id(sc) for sc in scans])
     col = stats.enable()
     try:
-        jaxpr = jax.make_jaxpr(prog)(*args, P_.evaluate(prep.slots,
-                                                        ("312",)))
+        jaxpr = jax.make_jaxpr(prog)(
+            *args, *P_.evaluate(prep.slots, ("312",)))
     finally:
         stats.disable()
     assert col.stages["fused.join_compact"].events == 2
@@ -475,8 +475,8 @@ def test_the_manifest_holds_the_new_entries():
             "fused_wait_ms", "device_idle_pct",
             "window_restarts"} <= reported
     (lanes,) = [m for m in bench["per_layer"] if m["name"] == "sort_lanes_m"]
-    assert lanes["workloads"] == [CELL, "tpch-sf1.q3-1stream",
-                                  "tpch-sf1-qgen.q3-1stream"]
+    assert lanes["workloads"][:3] == [CELL, "tpch-sf1.q3-1stream",
+                                      "tpch-sf1-qgen.q3-1stream"]
     # 6 + 14 + 8 bytes a row of the three images: about 58 MB at SF1
     from benchmark import bytes_model
 
