@@ -5,11 +5,19 @@ Reference seams:
 - optbuilder (pkg/sql/opt/optbuilder/builder.go:242): AST -> relational
   expression with resolved columns — this file's job.
 - join ordering (pkg/sql/opt/xform join reordering rules): the reference
-  runs Cascades exploration with stats costing; this binder uses the
-  classic greedy heuristic — start from the largest (fact) relation and
-  repeatedly attach the smallest-estimate connected relation, letting
-  each dimension first absorb its own satellites (so customer joins
-  orders before orders joins lineitem, Q3's shape).
+  runs Cascades exploration with stats costing; this binder uses a
+  greedy heuristic — start from the largest (fact) relation and
+  repeatedly attach the connected relation whose join KEEPS the smallest
+  share of the tree's rows, by estimate (sql/plan.keep_share, the share
+  that sizes a Shrink: a join here costs its lanes, which are static, so
+  a dimension that filters nothing costs the same wherever it sits
+  unless a Shrink below it has cut the lanes; Q9's filtered part goes
+  under supplier, partsupp and orders), and among equal shares the
+  smallest, letting each dimension first absorb its own satellites (so
+  customer joins orders before orders joins lineitem, Q3's shape) and
+  counting their filters for it. Stage `sql.join_rank`: one event a
+  tree that had a choice, `rows` = the steps the share decided against
+  the size.
 - semi-join conversion (norm rules ConvertSemiToInnerJoin reversed):
   an inner join whose right side contributes no downstream columns and is
   unique on its join keys is executed as `semi` — the shape every
@@ -63,7 +71,7 @@ from cockroach_tpu.sql.params import (
 )
 from cockroach_tpu.sql.plan import (
     Aggregate, Catalog, Distinct, Filter, Join, Limit, OrderBy, Plan,
-    Project, Scan, VectorTopK, _plan_columns,
+    Project, Scan, VectorTopK, _plan_columns, keep_share,
 )
 
 
@@ -194,6 +202,11 @@ class Binder:
         self.param_slots: List[ParamSlot] = []
         self._open_params = 0
         self._having_aggs: Optional["_AggCollector"] = None
+        # one entry a join tree whose orderer had a choice: the steps at
+        # which the share a join keeps chose another relation than the
+        # size would have (stage `sql.join_rank`; a subquery's binder
+        # appends to the statement's list)
+        self.join_ranks: List[int] = []
 
     # ---------------------------------------------------------------- bind
 
@@ -266,6 +279,7 @@ class Binder:
                 # subquery is an argument of the same program
                 sub_binder = Binder(self.catalog, params=self.params)
                 sub_binder.param_slots = self.param_slots
+                sub_binder.join_ranks = self.join_ranks
                 sub = sub_binder.bind(ast.query)
                 sub_cols = _plan_columns(sub, self.catalog)
                 key = f"__sub{sub_n}"
@@ -737,16 +751,23 @@ class Binder:
         # cost-ranked estimates: ANALYZE stats give per-conjunct
         # selectivities (histograms + distinct counts, sql/stats.py);
         # without stats, the flat 0.2 filter discount stands in
+        from cockroach_tpu.exec import stats as exec_stats
         from cockroach_tpu.sql.stats import estimate_rows
 
         est = {}
+        share = {}  # of a probe's rows, what a join to `k` alone keeps
         for k, r in rels.items():
             stats = (self.catalog.table_stats(r.table)
                      if r.table else None)
             if stats is not None:
                 est[k] = estimate_rows(stats, r.est, r.filters)
+                base = float(stats.row_count)
             else:
                 est[k] = r.est * (0.2 if r.filters else 1.0)
+                base = r.est
+            # 1.0 for every relation with no filter of its own, an
+            # IN (subquery) among them (no filter list, no base table)
+            share[k] = keep_share(est[k], base)
         fact = max((k for k in rels if rels[k].forced_semi is None),
                    key=lambda k: est[k])
 
@@ -754,6 +775,40 @@ class Binder:
         plan = self._rel_plan(remaining.pop(fact), stmt)
         joined = {fact}
         pending = list(edges)
+
+        def reach(start, within: Set[str]) -> Set[str]:
+            """`start` and what it reaches over pending edges inside
+            `within`."""
+            seen = set(start)
+            todo = list(seen)
+            while todo:
+                at = todo.pop()
+                for e in pending:
+                    for mine, other in ((e.a, e.b), (e.b, e.a)):
+                        if mine == at and other in within \
+                                and other not in seen:
+                            seen.add(other)
+                            todo.append(other)
+            return seen
+
+        def rank(k: str, cands) -> Tuple[float, float]:
+            """(the share of the tree's rows that attaching `k` keeps, by
+            estimate; `k`'s size). A join costs its LANES here, and only
+            a Shrink cuts lanes: the relation that removes most goes
+            under the others, whatever its size, and among equal shares
+            the smallest goes first. `k` brings its satellites (what
+            reaches the tree only through it: nation behind supplier), so
+            a filter on one of them counts for `k`."""
+            left = set(remaining) - {k}
+            satellites = reach([k], left) - reach(set(cands) - {k}, left)
+            keeps = 1.0
+            for sat in sorted(satellites):  # one product in every process
+                keeps *= share[sat]
+            return keeps, est[k]
+
+        # one entry a step that had a choice: did the share decide it
+        # against the size?
+        choices: List[bool] = []
 
         def attach_to(plan: Plan, joined: Set[str]) -> Plan:
             while True:
@@ -764,7 +819,9 @@ class Binder:
                             cands.setdefault(other, []).append(e)
                 if not cands:
                     return plan
-                key = min(cands, key=lambda k: est[k])
+                key = min(cands, key=lambda k: rank(k, cands))
+                if len(cands) > 1:
+                    choices.append(est[key] > min(est[k] for k in cands))
                 rel = remaining.pop(key)
                 # satellites: relations connected to `key` but not to the
                 # current tree join into `key` first (Q3: customer->orders)
@@ -801,6 +858,9 @@ class Binder:
             raise BindError(
                 f"cross join required for {sorted(remaining)} "
                 "(no join predicate connects them)")
+        if choices:
+            exec_stats.add("sql.join_rank", rows=sum(choices))
+            self.join_ranks.append(sum(choices))
         return plan
 
     def _join_kind(self, rel: _Rel, sub_joined: Set[str],

@@ -15,7 +15,8 @@ from cockroach_tpu.sql import parser as P
 from cockroach_tpu.sql.bind import Binder
 from cockroach_tpu.sql.plan import (
     Aggregate, Catalog, Distinct, Filter, IndexScan, Join, Limit,
-    OrderBy, Plan, Project, Scan, VectorTopK, Window, normalize,
+    OrderBy, Plan, Project, Scan, VectorTopK, Window, join_keeps,
+    normalize,
 )
 
 
@@ -58,7 +59,12 @@ def render_plan(p: Plan, catalog: Catalog) -> List[str]:
         if isinstance(node, Join):
             keys = ", ".join(f"{a}={b}"
                              for a, b in zip(node.left_on, node.right_on))
-            return f"{node.how} join on {keys}"
+            # the share of its probe's rows the join keeps, by estimate:
+            # what the join orderer ranked its build by (sql/bind.py) and
+            # what sizes a Shrink above it
+            keeps = join_keeps(node, catalog)
+            return f"{node.how} join on {keys}" + (
+                "" if keeps is None else f" (keeps ~{100 * keeps:.1f}%)")
         if isinstance(node, Aggregate):
             aggs = ", ".join(f"{a.func}({a.col or '*'}) as {a.out}"
                              for a in node.aggs)
@@ -274,6 +280,10 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
 
         st = stats.enable()
         try:
+            # the statement was bound before this table began: what its
+            # join orderer counted goes on the page with the order
+            for steps in binder.join_ranks:
+                st.add("sql.join_rank", rows=steps)
             with tracer().span("query", sql=sql[:60]) as sp:
                 t0 = time.perf_counter()
                 built: List[object] = []

@@ -687,6 +687,32 @@ def _base_rows(p: Plan, catalog: Catalog) -> float:
     return best
 
 
+def keep_share(est_rows: float, base_rows: float) -> float:
+    """The share of a probe's rows that a join to a unique build keeps, by
+    estimate (FK->PK: each probe row matches at most one build row, and
+    the build's filters have left `est_rows` of its `base_rows`). The ONE
+    definition: `estimate_cardinality` sizes a join's output, and through
+    it a Shrink, by it; the binder's join orderer (sql/bind.py) ranks the
+    relations it may attach by it, so the relation that goes first is the
+    one `insert_shrinks` compacts above."""
+    return min(est_rows / max(base_rows, 1.0), 1.0)
+
+
+def join_keeps(p: Join, catalog: Catalog) -> Optional[float]:
+    """The estimated share of its probe's rows that join `p` keeps (what
+    EXPLAIN prints beside it), or None for a join that keeps both sides
+    whole."""
+    frac = keep_share(estimate_cardinality(p.right, catalog),
+                      _base_rows(p.right, catalog))
+    if p.how in ("inner", "semi"):
+        return frac
+    if p.how == "anti":
+        return 1.0 - frac
+    if p.how == "left":
+        return 1.0
+    return None
+
+
 def estimate_cardinality(p: Plan, catalog: Catalog) -> float:
     """Stats-based output-row estimate (the coster's cardinality model:
     histogram/selectivity per conjunct, FK->PK fraction per join —
@@ -707,17 +733,10 @@ def estimate_cardinality(p: Plan, catalog: Catalog) -> float:
         return max(base * sel, 1.0)
     if isinstance(p, Join):
         le = estimate_cardinality(p.left, catalog)
-        re_ = estimate_cardinality(p.right, catalog)
-        rbase = _base_rows(p.right, catalog)
-        frac = min(re_ / max(rbase, 1.0), 1.0)
-        if p.how == "semi":
-            return max(le * frac, 1.0)
-        if p.how == "anti":
-            return max(le * (1.0 - frac), 1.0)
-        if p.how in ("inner", "left"):
-            # FK->PK (unique build): each probe row matches <=1 build row
-            return max(le * (frac if p.how == "inner" else 1.0), 1.0)
-        return max(le + re_, 1.0)
+        keeps = join_keeps(p, catalog)
+        if keeps is None:
+            return max(le + estimate_cardinality(p.right, catalog), 1.0)
+        return max(le * keeps, 1.0)
     if isinstance(p, Aggregate):
         ce = estimate_cardinality(p.input, catalog)
         return max(ce / 2.0, 1.0) if p.group_by else 1.0
@@ -741,7 +760,9 @@ def insert_shrinks(p: Plan, catalog: Optional[Catalog] = None) -> Plan:
     sorts should not pay full-capacity lanes; (3) round 5, STATS-driven:
     above any selective join whose estimated output is a small fraction
     of its probe input (Q9: the 5% green-parts semi join collapses the
-    remaining 4 joins + aggregation from 6M lanes to a ~1M compaction).
+    remaining 4 joins + aggregation from 8M lanes to a 524,288-lane
+    compaction: all four, because the binder's join orderer ranks by the
+    same `keep_share` and so attaches that semi join first).
     Smallness propagates through row-preserving nodes; the deferred
     overflow flag + 16x capacity growth keep the optimism safe (a stale
     estimate costs one recompile, never a wrong answer)."""

@@ -374,11 +374,21 @@ def test_explain_prints_the_pattern_slot_and_the_key_packings(one):
         assert "parameters: $1 pattern(part.p_name)" in lines
         assert (f"estimates taken at: $1 = '%green%' ({hits} of "
                 f"{len(one['ref'].names)} part.p_name values)") in lines
+        # the order's reason beside the order: the semi join keeps the
+        # pattern's share of part, the other four joins everything
+        share = 100 * hits / len(one["ref"].names)
+        assert [ln.split(" (keeps ~")[1].split("%)")[0]
+                for ln in lines if " join on " in ln] == [
+            "100.0", "100.0", "100.0", f"{share:.1f}", "100.0"]
         rows, code = client.bound("explain analyze " + Q9, ("%green%",))
         assert code is None
         table = {ln.split()[0]: int(ln.split()[ln.split().index("ev") - 1])
                  for (ln,) in rows
                  if ln.startswith("fused.") and "ev" in ln.split()}
+        # one join tree with a choice, one step the share decided
+        assert [ln.split()[3:] for (ln,) in rows
+                if ln.startswith("sql.join_rank")] == [
+            ["1", "ev", "1", "rows"]]
     finally:
         client.close()
     traced = table.get("fused.compile", 0)
@@ -560,14 +570,18 @@ def test_q9_counts_its_join_keys_and_its_hashed_lanes(one):
     joins = [j for j in walk_operators(prep.op) if isinstance(j, JoinOp)]
     (shrink,) = [s for s in walk_operators(prep.op)
                  if isinstance(s, ShrinkOp)]
-    # orders, partsupp on the two-column key, part (semi, compacting under
-    # the Shrink), supplier x nation, and that against lineitem
+    # orders, partsupp on the two-column key and supplier x nation, all
+    # three over the Shrink; under it the part semi join, compacting: the
+    # orderer attaches the relation that REMOVES most first (ISSUE 39),
+    # so the one join at lineitem's full width is the one that keeps 5%
     assert [(j.how, tuple(j.probe_on)) for j in joins] == [
         ("inner", ("l_orderkey",)),
         ("inner", ("l_suppkey", "l_partkey")),
-        ("semi", ("l_partkey",)),
         ("inner", ("l_suppkey",)),
+        ("semi", ("l_partkey",)),
         ("inner", ("s_nationkey",))]
+    supplier, semi = joins[2], joins[3]
+    assert supplier.probe is shrink and shrink.child is semi
     # one trace: four integer keys, one hashed; every table is one chunk
     # of CAP lanes at SF 0.01, and the Shrink's capacity is the probe of
     # the two joins above it
@@ -580,7 +594,7 @@ def test_q9_counts_its_join_keys_and_its_hashed_lanes(one):
     assert (lanes.events, lanes.rows) == (2, 2 * hashed)
     sort = col.stages["fused.sort_lanes"]
     assert (sort.events, sort.rows) == (
-        2, 2 * (3 * 2 * CAP + 2 * (shrink.capacity + CAP)))
+        2, 2 * (2 * 2 * CAP + 3 * (shrink.capacity + CAP)))
     ctx = {"window": {"stages": {"fused.hash_key_lanes": {
         "events": lanes.events, "rows": lanes.rows}}}}
     assert hash_key_lanes_m.read(ctx) == hashed / 1e6
