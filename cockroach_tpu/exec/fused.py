@@ -277,11 +277,27 @@ def _keyed_on_join(join: JoinOp, group_by: Sequence[str]) -> Optional[str]:
 def takes_params(root: Operator) -> bool:
     """Does a filter or projection under `root` read a bound parameter
     (ops/expr.Param)? Such a tree's program takes the statement's bound
-    values as arguments, after the scan images."""
+    values as arguments, after the scan images: FusedRunner's program on
+    one chip, and DistFusedRunner's shard_map program on a mesh, where
+    they are replicated (`P()`) beside the images."""
     return any(_expr.has_params([payload if kind == "filter"
                                  else [e for _n, e in payload]
                                  for kind, payload in op.steps])
                for op in walk_operators(root) if isinstance(op, MapOp))
+
+
+def bound_program_args(takes: bool) -> tuple:
+    """The bound values of the statement this thread is running
+    (ops/expr.bound_args), as the trailing arguments of a whole-query
+    program whose tree `takes` them (takes_params; this module's runner
+    and parallel/dist_flow.py's); () for a tree without parameters."""
+    if not takes:
+        return ()
+    bound = _expr.current_args()
+    if bound is None:
+        raise _expr.ParamOutsideProgram(
+            "a parameterised plan was run with no values bound")
+    return tuple(bound)
 
 
 SCOPE_PREFIX = "crdb."
@@ -1100,22 +1116,10 @@ class FusedRunner:
         # persistent-cache entry belong to the statement and not to a
         # binding
         self._takes_params = takes_params(root)
-        # what the last dispatch passed after the images (_bound_args):
+        # what the last dispatch passed after the images (bound_program_args):
         # device_profile() runs the program at that binding; None until
         # the runner has dispatched
         self._last_bound: Optional[tuple] = None
-
-    def _bound_args(self) -> tuple:
-        """The bound values of the statement this thread is running
-        (ops/expr.bound_args), as the program's trailing arguments; () for
-        a tree without parameters."""
-        if not self._takes_params:
-            return ()
-        bound = _expr.current_args()
-        if bound is None:
-            raise _expr.ParamOutsideProgram(
-                "a parameterised plan was run with no values bound")
-        return tuple(bound)
 
     @staticmethod
     def _warm_key(scans) -> Optional[tuple]:
@@ -1309,7 +1313,7 @@ class FusedRunner:
             return self._progs[key], args
         if key not in self._progs:
             prog, tracer_box = self._make_prog(scan_ids)
-            bound = self._bound_args()
+            bound = bound_program_args(self._takes_params)
 
             def build():
                 maybe_fail("fused.compile")
@@ -1366,7 +1370,7 @@ class FusedRunner:
                         (chunks[sid],) + tuple(a[1].shape[1:]),
                         a[1].dtype))
                     for sid, a in zip(scan_ids, args)) \
-                    + self._bound_args()
+                    + bound_program_args(self._takes_params)
 
                 def build(prog=prog, sds=sds):
                     maybe_fail("fused.compile")
@@ -1415,7 +1419,7 @@ class FusedRunner:
                 "fused fallback -> streaming (unsupported: {})", e)
             yield from self.root.batches()
             return
-        bound = self._last_bound = self._bound_args()
+        bound = self._last_bound = bound_program_args(self._takes_params)
 
         def dispatch():
             _cancel.checkpoint()

@@ -245,11 +245,13 @@ _EXEC_PREFIXES = ("scan", "agg", "join", "sort", "fused", "serving",
 _NON_EXEC_STAGES = ("compile", "vault", "image_build", "prime",
                     "prewarm")
 # stages that split or surround one already counted (fused.exec holds
-# fused.dispatch and fused.wait, dist.exec its own two), or that are host
-# work of the session, or set-up (dist.ingest: a scan's first placement)
+# fused.dispatch and fused.wait, dist.exec its own two, dist.dispatch the
+# placing of the bound values: dist.args), or that are host work of the
+# session, or set-up (dist.ingest: a scan's first placement)
 _NOT_EXEC = frozenset(("fused.dispatch", "fused.wait", "fused.prepare",
                        "fused.unpack", "dist.dispatch", "dist.wait",
-                       "dist.prepare", "dist.unpack", "dist.ingest",
+                       "dist.args", "dist.prepare", "dist.unpack",
+                       "dist.ingest",
                        "sql.lookup", "sql.parse", "sql.bind", "sql.plan"))
 
 

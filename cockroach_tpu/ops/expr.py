@@ -128,10 +128,10 @@ class Param(Expr):
 
 class ParamOutsideProgram(Exception):
     """A Param was evaluated by a program that does not take the bound
-    values as arguments (the streaming operators' own jits, the
-    distributed runner): tracing on would bake this binding's value into
-    a program that serves every binding. The session answers by binding
-    the statement as text (sql/session.py)."""
+    values as arguments (the streaming operators' own jits): tracing on
+    would bake this binding's value into a program that serves every
+    binding. The session answers by binding the statement as text
+    (sql/session.py)."""
 
 
 _params = threading.local()

@@ -22,7 +22,14 @@ shard_map'd XLA program runs the ENTIRE query on every device —
   where the tree carries none (built by hand), from the side's lanes;
   never more than the lanes give (`_classify`, `side_bucket`). The
   join behind the exchange runs at n_dev x (probe + build bucket)
-  lanes, so the estimate is what keeps it near the rows there are;
+  lanes, so the estimate is what keeps it near the rows there are.
+  A tree may route SEVERAL joins on one probe spine, each on its own
+  key (Q9: lineitem's survivors on (l_suppkey, l_partkey) to partsupp,
+  hashed over both columns, and again on l_orderkey to orders): the
+  later join's probe is the earlier one's routed output (n_dev x bucket
+  lanes, which give its lanes' bucket), every join has its own bucket
+  pair in the config key, its own `dist.bucket` events and its own
+  _BucketGuard, which widens that join alone;
 - P9 two-stage aggregation: per-device partial fold -> all_gather ->
   replicated merge -> finalize (partial aggregators on data nodes, final
   on the gateway);
@@ -32,14 +39,26 @@ shard_map'd XLA program runs the ENTIRE query on every device —
   From lanes it is ORed into the join's; from an estimate it is a flag
   of its own whose target (`_BucketGuard`) sends that join back to the
   lanes' buckets in ONE restart (`sql_distsql_bucket_restarts_total`):
-  a low estimate is slower once, never wrong.
+  a low estimate is slower once, never wrong;
+- bound values (ops/expr.Param: a prepared statement's `$n`) are
+  ARGUMENTS of the program, after the scan images and replicated
+  (`in_specs` P()): the packed int64 vector of the scalar slots, then
+  one bool table a bound LIKE pattern (sql/params.py), placed by one
+  `device_put` to NamedSharding(mesh, P()) an argument at every
+  dispatch (stage `dist.args`) and read under ops/expr.traced_params
+  while the tree is traced, the way exec/fused.py's runner takes them
+  (takes_params and bound_program_args are shared with it). The plan
+  fingerprint reads a Param by slot, type and table, never by the value
+  the plan was made at, and the config key adds the arguments' shapes:
+  every binding of a statement runs ONE program, and a FlowRestart
+  re-dispatches at the same binding.
 
 Warm path: compiled programs live in a process-wide cache keyed by
 (plan fingerprint, config key) where the config key carries the mesh
 identity, the broadcast limit, every scan's (role, pow2 bucket) and
-the bucket pair an estimate gave each BY_HASH join (the fingerprint
-skips `est_rows`; the power of two keeps drifting statistics on one
-program) — the distributed analog of exec/fused.py's exec cache. A warm
+the bucket pair an estimate gave each BY_HASH join and the shapes of
+the bound values (the fingerprint skips `est_rows` and a Param's
+sample; the power of two keeps drifting statistics on one program) — the distributed analog of exec/fused.py's exec cache. A warm
 re-run of a distributed query is ONE dispatch: cached ingest-sharded
 images (per-shard-refreshed against their resident MVCC source when the
 table took writes), cached executable, no trace, no transfer.
@@ -62,9 +81,16 @@ always` and the node's mesh, Catalog.mesh; sql/session.py), or from
 `run_sql(mesh=)`. Stages, in the one seam (exec/stats.timed = stage =
 span = annotation), under the `flow.dist` span: `dist.prepare` (cold:
 `dist.prime` per scan, `dist.ingest` per image, `dist.compile`),
-`dist.exec` = `dist.dispatch` + `dist.wait`, `dist.readback`,
-`dist.unpack`; `dist.a2a` counts one event a dispatch, with the bytes
-one device sends through the exchanges (empty bucket lanes included);
+`dist.exec` = `dist.dispatch` (holding `dist.args` where the program
+takes bound values: `bytes` placed, `rows` = arguments) + `dist.wait`,
+`dist.readback`, `dist.unpack`; one event a dispatch each, reckoned from
+the traced shapes when the program compiled: `dist.a2a` (the bytes one
+device sends through the exchanges, empty bucket lanes included),
+`dist.sort_lanes` (the lanes one device passes through key sorts:
+`fused.sort_lanes`' reckoning of the joins and sort-based aggregates as
+that device sees them, PLUS the routers' destination sorts, one over
+every lane of a routed side) and `dist.hash_key_lanes` (the joins' of
+them under the hashed u64 key: a key of two columns, or no integer);
 `dist.compile` carries one `dist.bucket` event a routed side: the
 estimate, the bucket traced, the bucket the lanes give.
 """
@@ -73,7 +99,7 @@ from __future__ import annotations
 
 import threading
 from collections import OrderedDict
-from dataclasses import is_dataclass
+from dataclasses import fields, is_dataclass, replace
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -88,12 +114,14 @@ from cockroach_tpu.exec import stats
 from cockroach_tpu.exec.fused import (
     EXCHANGE, MERGE, RESULT_CAP, RESULT_SCOPE, HBMExceeded, Unsupported,
     _ModeBumpGuard, _Tracer, _pack_result, _unpack_result,
-    compile_via_vault, lower_program, scope, takes_params,
+    bound_program_args, compile_via_vault, lower_program, scope,
+    takes_params,
 )
 from cockroach_tpu.exec.operators import (
     FlowRestart, HashAggOp, JoinOp, Operator, ScanOp, ShrinkOp, SortOp, TopKOp,
     _pow2_at_least, walk_operators,
 )
+from cockroach_tpu.ops import expr as _expr
 from cockroach_tpu.ops.agg import hash_aggregate
 from cockroach_tpu.parallel import ingest
 from cockroach_tpu.parallel.mesh import mesh_key, shrink_mesh
@@ -140,6 +168,12 @@ class _Program(NamedTuple):
     result_cap: int
     a2a_bytes: int         # what one device sends through the exchanges
     #                        of ONE dispatch (stage dist.a2a)
+    sort_lanes: int        # lanes one device passes through key sorts a
+    #                        dispatch: the joins' and the sort-based
+    #                        aggregates' (fused.sort_lanes' reckoning) plus
+    #                        the routers' destination sorts (dist.sort_lanes)
+    hash_key_lanes: int    # the joins' of them under the hashed u64 key
+    #                        (dist.hash_key_lanes)
 
     def flag_ops(self, ops: list) -> Optional[list]:
         """The restart targets of this program's flags over `ops` (a
@@ -243,11 +277,30 @@ def _fp_value(v, depth: int = 0):
     if isinstance(v, Schema):
         return ("S",) + tuple(repr(f) for f in v.fields)
     if is_dataclass(v) and not isinstance(v, type):
-        r = repr(v)
+        # a bound parameter is its slot, its type and its table argument,
+        # never the value the plan was made at: every binding of a
+        # statement has ONE fingerprint (the table's padded length is a
+        # shape, and rides the config key with the other arguments')
+        r = repr(_without_samples(v))
         if " at 0x" not in r:
             return ("C", r)
     r = repr(v)
     return ("R", r) if " at 0x" not in r else ("?",)
+
+
+def _without_samples(e):
+    """The expression with every Param's `sample` (the value of the
+    binding the plan was made at, which only the planner's estimates
+    read) taken out; an expression without a Param is returned as it
+    is."""
+    if not _expr.has_params(e):
+        return e
+    if isinstance(e, _expr.Param):
+        return replace(e, sample=None)
+    if isinstance(e, (tuple, list)):
+        return type(e)(_without_samples(x) for x in e)
+    return replace(e, **{f.name: _without_samples(getattr(e, f.name))
+                         for f in fields(e)})
 
 
 def _plan_fingerprint(root: Operator) -> tuple:
@@ -294,11 +347,15 @@ class _DistTracer(_Tracer):
         self.a2a: Dict[tuple, int] = {}
         # (side, id(join)) -> (bucket, the bucket its lanes give)
         self.buckets: Dict[tuple, Tuple[int, int]] = {}
+        # (side, id(join)) -> lanes through that side's destination sort
+        # in one dispatch (the router sorts every lane of what it routes)
+        self.route_lanes: Dict[tuple, int] = {}
 
     def _note_exchange(self, side: str, op: JoinOp, batch: Batch,
                        bucket: int, times: int = 1) -> None:
         self.a2a[(side, id(op))] = times * exchange_bytes(
             batch, self.n_dev, bucket)
+        self.route_lanes[(side, id(op))] = times * batch.capacity
 
     def _bucket(self, side: str, op: JoinOp, by_lanes: int,
                 by_est: Optional[int], parts: int = 1) -> int:
@@ -461,6 +518,15 @@ class DistFusedRunner:
         self.axis = axis
         self.n_dev = mesh.shape[axis]
         self._warm = False  # last _prepare was a zero-work warm probe
+        # a tree that reads bound parameters (exec/fused.takes_params):
+        # the program takes them after the images, replicated, the same
+        # shapes at every binding
+        self._takes_params = takes_params(root)
+        self._replicated = NamedSharding(mesh, P())
+        # the bound values of the last dispatch (() for a tree without
+        # parameters): device_profile() runs the program at that binding;
+        # None until the runner has dispatched
+        self._last_bound: Optional[tuple] = None
 
     # chunk-shard the scans on the probe spine (and on a repartitioned
     # build's own probe spine); replicate the (small) broadcast builds.
@@ -468,18 +534,7 @@ class DistFusedRunner:
     # correct sharded result; a sharded build is only correct through the
     # explicit repartition path — nested repartition inside a build is
     # rejected (falls back to single-chip).
-    def _refuse_params(self) -> None:
-        """The shard_map program has no argument for a statement's bound
-        values yet (replicated scalars beside the images), and tracing
-        one in would bake a binding into a program that serves all: the
-        single-chip ladder answers, whose fused program takes them (0A000
-        under distsql = always). Asked before any scan is primed."""
-        if takes_params(self.root):
-            raise Unsupported("bound parameters are not arguments of the "
-                              "distributed program")
-
     def _classify(self, chunks: Dict[int, int]):
-        self._refuse_params()
         limit = Settings().get(BROADCAST_LIMIT)
         sharded: set = set()
         repart: dict = {}
@@ -659,7 +714,7 @@ class DistFusedRunner:
     # ---------------------------------------------------------- compile --
 
     def _config_key(self, layout: Dict[int, Tuple[str, int]],
-                    repart: Dict[int, _Exchange]):
+                    repart: Dict[int, _Exchange], bound: tuple = ()):
         """Shape identity of one compiled program: mesh, broadcast limit,
         and per-op pow2 buckets. `layout` maps scan id -> (role, bucket);
         `repart` is _classify's. A BY_HASH join whose buckets come from
@@ -667,9 +722,15 @@ class DistFusedRunner:
         bucket of its program follows from the pair and the layout): two
         states of the statistics share a program until they round to
         different powers of two. Without an estimate, or sent back to its
-        lanes, it adds nothing."""
+        lanes, it adds nothing; each join adds its own pair, or nothing.
+        `bound` (the statement's bound values) adds each argument's
+        shape and type, never a value: the packed vector's length and a
+        pattern table's padded length are the statement's."""
         out: list = [("mesh",) + mesh_key(self.mesh, self.axis),
                      ("bl", int(Settings().get(BROADCAST_LIMIT)))]
+        if bound:
+            out.append(("params",) + tuple(
+                (tuple(a.shape), str(a.dtype)) for a in bound))
         for op in walk_operators(self.root):
             if isinstance(op, ScanOp):
                 role, bucket = layout[id(op)]
@@ -701,11 +762,16 @@ class DistFusedRunner:
         def step(*stacked_args):
             local = dict(zip([id(s) for s in scans], stacked_args))
             t = _DistTracer(local, root, axis, n_dev, sharded, repart)
-            out = t._mat(root)
+            # a parameterised tree: the bound values are the arguments
+            # after the images, replicated, read while it is traced
+            with _expr.traced_params(stacked_args[len(scans):]):
+                out = t._mat(root)
             box["flag_ops"] = list(t.flag_ops)
             box["result_cap"] = min(RESULT_CAP, out.capacity)
             box["a2a_bytes"] = sum(t.a2a.values())
             box["buckets"] = dict(t.buckets)
+            box["sort_lanes"] = t.sort_lanes + sum(t.route_lanes.values())
+            box["hash_key_lanes"] = t.hash_key_lanes
             with scope(RESULT_SCOPE):
                 flags = tuple(
                     lax.psum(f.astype(jnp.int32), axis) > 0
@@ -716,12 +782,13 @@ class DistFusedRunner:
 
     def _lower(self, scans, sharded, repart, args, box):
         """Trace + lower the sharded step program (`box` receives the
-        tracer's flag_ops / result_cap)."""
+        tracer's flag_ops / result_cap). `args` are the scans' images
+        and then the statement's bound values, if the tree takes any."""
         step = self._make_step(scans, sharded, repart, box)
         in_specs = tuple(
             (P(self.axis), P(self.axis)) if id(sc) in sharded
             else (P(), P())
-            for sc in scans)
+            for sc in scans) + (P(),) * (len(args) - len(scans))
         fn = shard_map(step, mesh=self.mesh, in_specs=in_specs,
                        out_specs=P(), check_rep=False)
         return lower_program(fn, args)
@@ -754,7 +821,8 @@ class DistFusedRunner:
             for f in box["flag_ops"])
         flag_types = tuple(type(f).__name__ for f in box["flag_ops"])
         entry = _Program(compiled, flag_idx, flag_types, box["result_cap"],
-                         box["a2a_bytes"])
+                         box["a2a_bytes"], box["sort_lanes"],
+                         box["hash_key_lanes"])
         _PROGS[pkey] = entry
         _trim_progs()
         return entry
@@ -785,8 +853,9 @@ class DistFusedRunner:
         sharded, repart, images = self._materialize(scans, sources, chunks)
         layout = {id(sc): (images[id(sc)].role, images[id(sc)].bucket)
                   for sc in scans}
+        bound = self._bound_shapes()
         pkey = (_plan_fingerprint(self.root),
-                self._config_key(layout, repart))
+                self._config_key(layout, repart, bound))
         ops = list(walk_operators(self.root))
         entry = _PROGS.get(pkey, _MISS)
         if entry is None:
@@ -797,8 +866,8 @@ class DistFusedRunner:
             self._warm = False
             args = tuple((images[id(sc)].bufs, images[id(sc)].ms)
                          for sc in scans)
-            entry = self._compile(pkey, scans, sharded, repart, args,
-                                  layout, ops)
+            entry = self._compile(pkey, scans, sharded, repart,
+                                  args + bound, layout, ops)
             flag_ops = entry.flag_ops(ops)
         else:
             _PROGS.move_to_end(pkey)
@@ -809,6 +878,15 @@ class DistFusedRunner:
         args = tuple((images[id(sc)].bufs, images[id(sc)].ms)
                      for sc in scans)
         return entry, flag_ops, args
+
+    def _bound_shapes(self) -> tuple:
+        """The statement's bound values as the program is lowered with
+        them: each argument's shape and type, replicated over the mesh
+        (exec/fused.bound_program_args; () for a tree without
+        parameters)."""
+        return tuple(
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=self._replicated)
+            for a in bound_program_args(self._takes_params))
 
     # -------------------------------------------------------------- aot --
 
@@ -830,13 +908,14 @@ class DistFusedRunner:
             ops = list(walk_operators(self.root))
             layout = {id(sc): (images[id(sc)].role, images[id(sc)].bucket)
                       for sc in scans}
-            pkey = (fp, self._config_key(layout, repart))
+            bound = self._bound_shapes()
+            pkey = (fp, self._config_key(layout, repart, bound))
             if _PROGS.get(pkey, _MISS) is _MISS:
                 args = tuple((images[id(sc)].bufs, images[id(sc)].ms)
                              for sc in scans)
                 try:
-                    self._compile(pkey, scans, sharded, repart, args,
-                                  layout, ops)
+                    self._compile(pkey, scans, sharded, repart,
+                                  args + bound, layout, ops)
                     done += 1
                 except Unsupported:
                     return done
@@ -867,12 +946,12 @@ class DistFusedRunner:
                                              jnp.uint8, sharding=sh),
                         jax.ShapeDtypeStruct((rows,), jnp.int32,
                                              sharding=sh)))
-                pkey2 = (fp, self._config_key(layout2, repart2))
+                pkey2 = (fp, self._config_key(layout2, repart2, bound))
                 if _PROGS.get(pkey2, _MISS) is not _MISS:
                     continue
                 try:
                     self._compile(pkey2, scans, sharded2, repart2,
-                                  tuple(sds_args), layout2, ops)
+                                  tuple(sds_args) + bound, layout2, ops)
                     done += 1
                 except Unsupported:
                     continue
@@ -886,10 +965,12 @@ class DistFusedRunner:
         outer on the sharded spine, a repartition nested in a build, an
         empty scan, more rows than the packed result window): the caller
         decides what answers instead (collect_distributed)."""
-        self._refuse_params()
         with stats.timed("dist.prepare"):
             prog, flag_ops, args = self._prepare()
         compiled, a2a_bytes = prog.compiled, prog.a2a_bytes
+        # a restart (_run_dist) comes back here inside the statement's
+        # bound_args block: the same binding
+        bound = self._last_bound = bound_program_args(self._takes_params)
 
         def dispatch():
             _cancel.checkpoint()
@@ -900,11 +981,14 @@ class DistFusedRunner:
             # the program call returns the host is enqueueing; after that
             # it waits for the mesh (readback below is the transfer only)
             with stats.timed("dist.dispatch"):
-                out = compiled(*args)
+                out = compiled(*args, *self._place_bound(bound))
             with stats.timed("dist.wait"):
                 out = jax.block_until_ready(out)
-            # one event a dispatch; the bytes are the traced shapes'
+            # one event a dispatch; the bytes and lanes are the traced
+            # shapes'
             stats.add("dist.a2a", bytes=a2a_bytes)
+            stats.add("dist.sort_lanes", rows=prog.sort_lanes)
+            stats.add("dist.hash_key_lanes", rows=prog.hash_key_lanes)
             default_registry().counter(
                 "sql_distsql_exchange_bytes_total",
                 "bytes one device sent through the BY_HASH exchanges of "
@@ -926,17 +1010,36 @@ class DistFusedRunner:
             raise Unsupported("result exceeds the packed window")
         yield batch
 
+    def _place_bound(self, bound: tuple) -> tuple:
+        """The statement's bound values on the mesh, replicated: one
+        device_put to NamedSharding(mesh, P()) an argument (the runtime
+        copies to every chip; no loop over chips here). Stage
+        `dist.args`, inside dist.dispatch: one event a dispatch of a
+        program that takes bound values, `bytes` what is placed (once,
+        not a chip), `rows` the arguments."""
+        if not bound:
+            return ()
+        with stats.timed("dist.args", rows=len(bound),
+                         bytes=sum(a.nbytes for a in bound)):
+            return tuple(jax.device_put(bound, self._replicated))
+
     def device_profile(self, repeats: int = 5):
-        """FusedRunner.device_profile for the mesh's program: per
-        operator the mean over the chips with the largest chip beside
-        it, the exchanges and merges apart from the operators behind
-        them. None where the tree is Unsupported now."""
+        """FusedRunner.device_profile for the mesh's program, at the
+        runner's last binding: per operator the mean over the chips with
+        the largest chip beside it, the exchanges and merges apart from
+        the operators behind them. None for a runner that has not
+        dispatched, or whose tree is Unsupported now."""
         from cockroach_tpu.exec import device_profile as _dp
 
+        bound = self._last_bound
+        if bound is None:
+            return None
         try:
-            prog, _flag_ops, args = self._prepare()
+            with _expr.bound_args(bound or None):
+                prog, _flag_ops, args = self._prepare()
         except Unsupported:
             return None
+        args += self._place_bound(bound)
         stages = ("dist.dispatch", "dist.wait")
         return _dp.profile(
             _dp.run_annotated(lambda: prog.compiled(*args), stages),
@@ -978,6 +1081,11 @@ def _run_dist(runner: DistFusedRunner, reset, consume,
     opts = _retry.options_from_settings()
     backoffs = opts.backoffs()
     restarts = 0
+    # registered with the first distributed flow, as operators._run_flow
+    # registers it with the first local one: a reader of the registry can
+    # tell "no restart yet" (0) from "no such counter"
+    restart_counter = default_registry().counter(
+        "sql_flow_restarts_total", "deferred-flag flow restarts")
     span_cm = (_tracing.tracer().from_carrier(
         trace_info, "flow.dist", shards=runner.n_dev)
         if trace_info is not None else nullcontext())
@@ -992,9 +1100,7 @@ def _run_dist(runner: DistFusedRunner, reset, consume,
                 if restarts == max_restarts:
                     raise
                 restarts += 1
-                default_registry().counter(
-                    "sql_flow_restarts_total",
-                    "deferred-flag flow restarts").inc()
+                restart_counter.inc()
                 _tracing.record("flow.restart", n=restarts,
                                 op=type(fr.op).__name__)
                 widen = getattr(fr.op, "widen", None)

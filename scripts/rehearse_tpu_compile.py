@@ -4,7 +4,7 @@ on-chip-measurement guide. What the chip's compiler refuses it refuses
 here, at no chip time; each compile's seconds are the smoke's cold budget.
 
     JAX_PLATFORMS=cpu python scripts/rehearse_tpu_compile.py [--sf 1] \
-        [--only kernel,q1,q6,q3,q3_dist4]
+        [--only kernel,q1,q6,q3,q3_dist4,q9_dist4]
 
 Plans are built from SF1 data (TPCH.mvcc_load, so the planner sees SF1's
 statistics and every inner capacity is SF1's), lowered exactly as
@@ -12,6 +12,10 @@ exec/fused.py and parallel/dist_flow.py lower them, from abstract shapes
 placed on the described devices, and compiled with fused.TPU_COMPILE_OPTIONS.
 tests/test_tpu_compile.py keeps the quick compiles (and the sort-join's);
 Q3's whole programs (minutes) stay here. One JSON line per program.
+`q9_dist4` (not in the default list: a quarter of an hour and more) is the
+benchmark cell tpch-sf1-q9-mesh4.q9-1stream's program: Q9 prepared, from
+that cell's loader and text, its bound pattern's table a replicated
+argument.
 """
 
 from __future__ import annotations
@@ -21,6 +25,7 @@ import json
 import os
 import sys
 import time
+from types import SimpleNamespace
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -111,6 +116,8 @@ def main():
                                      sharding=one_chip))
             report(f"limb_kernel({rows},{limbs},{lanes})", lowered, tl)
 
+    if "q9_dist4" in only:
+        q9_dist4(topo, args)
     fused_names = [q for q in ("q1", "q6", "q3") if q in only]
     if not fused_names and "q3_dist4" not in only:
         return
@@ -132,9 +139,6 @@ def main():
     sqls = {"q1": chip_smoke.Q1_SQL, "q6": chip_smoke.Q6_SQL,
             "q3": chip_smoke.Q3_SQL}
 
-    def pow2(n):
-        return 1 << max(0, (n - 1).bit_length())
-
     for name in fused_names:
         cp = compile_plan(plan_sql(sqls[name], catalog), catalog,
                           chip_smoke.CAPACITY, sql=sqls[name],
@@ -142,7 +146,7 @@ def main():
         assert cp.runner is not None, f"{name} is outside the fusion grammar"
         scans = [n for n in walk_operators(cp.op) if isinstance(n, ScanOp)]
         prog, _box = cp.runner._make_prog([id(s) for s in scans])
-        sds = scan_shapes(scans, gen, lambda sc, n: (pow2(n), one_chip))
+        sds = scan_shapes(scans, gen, lambda sc, n: (_pow2(n), one_chip))
         lowered, tl = timed_lower(prog, *sds)
         report(name + "_fused", lowered, tl,
                chunks={sc.table: int(a[0].shape[0])
@@ -167,8 +171,8 @@ def main():
 
         def lead(sc, n):
             if id(sc) in sharded:
-                return 4 * pow2(-(-n // 4)), NamedSharding(mesh, P("x"))
-            return pow2(n), NamedSharding(mesh, P())
+                return 4 * _pow2(-(-n // 4)), NamedSharding(mesh, P("x"))
+            return _pow2(n), NamedSharding(mesh, P())
 
         sds = scan_shapes(scans, gen, lead)
         t0 = time.perf_counter()
@@ -181,6 +185,74 @@ def main():
                               if id(sc) in sharded),
                roles={sc.table: (ingest.SHARDED if id(sc) in sharded
                                  else ingest.REPLICATED) for sc in scans})
+
+
+def _pow2(n):
+    return 1 << max(0, (n - 1).bit_length())
+
+
+def q9_dist4(topo, args):
+    """The mesh cell's Q9 as DistFusedRunner lowers it for four described
+    chips: layout by _classify at the default broadcast limit, the plan
+    made at '%green%', the bound values' shapes after the images."""
+    from benchmark import manifest
+    from benchmark.loaders import tpch_pname
+    from cockroach_tpu.exec.operators import ScanOp, walk_operators
+    from cockroach_tpu.ops.expr import bound_args
+    from cockroach_tpu.parallel import dist_flow, ingest
+    from cockroach_tpu.sql import params as _params
+    from cockroach_tpu.sql import parser
+    from cockroach_tpu.sql.bind import Binder
+    from cockroach_tpu.sql.plan_compile import compile_plan
+    from cockroach_tpu.storage.mvcc import MVCCStore
+    from cockroach_tpu.util.settings import PALLAS, Settings
+
+    stmt = manifest.cell("tpch-sf1-q9-mesh4.q9-1stream")["statements"][0]
+    t0 = time.perf_counter()
+    loaded = tpch_pname.load(MVCCStore(), {"sf": args.sf}, stmt["tables"],
+                             args.seed)
+    print(json.dumps({"loaded_sf": args.sf, "loader": "tpch_pname",
+                      "seconds": round(time.perf_counter() - t0, 1)}),
+          flush=True)
+    Settings().set(PALLAS, "on")
+    catalog, values = loaded["catalog"], ("%green%",)
+    binder = Binder(catalog, params=values)
+    plan = binder.bind(parser.parse(stmt["sql"]))
+    bound = _params.evaluate(binder.param_slots, values)
+    cp = compile_plan(plan, catalog, chip_smoke.CAPACITY, sql=stmt["sql"],
+                      setting="tpu")
+    mesh = Mesh(np.array(topo.devices[:4]), ("x",))
+    runner = dist_flow.DistFusedRunner(cp.op, mesh, "x")
+    scans = [n for n in walk_operators(cp.op) if isinstance(n, ScanOp)]
+    # scan_shapes' generator: the row counts the benchmark's loader gave
+    gen = SimpleNamespace(num_rows=loaded["rows"].__getitem__)
+    chunks = {id(sc): -(-gen.num_rows(sc.table) // sc.capacity)
+              for sc in scans}
+    sharded, repart = runner._classify(chunks)
+    assert len(repart) == 2, "Q9 at SF1 routes partsupp and orders"
+
+    def lead(sc, n):
+        if id(sc) in sharded:
+            return 4 * _pow2(-(-n // 4)), NamedSharding(mesh, P("x"))
+        return _pow2(n), NamedSharding(mesh, P())
+
+    sds = scan_shapes(scans, gen, lead)
+    with bound_args(bound):
+        sds += runner._bound_shapes()
+    t0 = time.perf_counter()
+    box = {}
+    lowered = runner._lower(scans, sharded, repart, sds, box)
+    by_join = {id(op): dist_flow._join_keys(op)
+               for op in walk_operators(cp.op) if id(op) in repart}
+    report("q9_dist4", lowered, time.perf_counter() - t0,
+           buckets={f"{side} {by_join[j]}": b
+                    for (side, j), b in box["buckets"].items()},
+           a2a_mb=round(box["a2a_bytes"] / 1e6, 2),
+           sort_lanes=box["sort_lanes"],
+           hash_key_lanes=box["hash_key_lanes"],
+           params=[list(a.shape) for a in bound],
+           roles={sc.table: (ingest.SHARDED if id(sc) in sharded
+                             else ingest.REPLICATED) for sc in scans})
 
 
 if __name__ == "__main__":

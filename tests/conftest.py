@@ -67,6 +67,25 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture
+def one_traced_rehearsal():
+    """`benchmark/run.py --trace 1` writes its profile under ONE directory
+    (benchmark/.trace) and clears it first, so two traced rehearsals at
+    once, on two xdist workers, lose each other's trace. A test that runs
+    one holds this lock meanwhile: an flock on the benchmark's directory
+    itself, which every worker of the checkout sees and which leaves no
+    file behind."""
+    import fcntl
+
+    fd = os.open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark"), os.O_RDONLY)
+    try:
+        fcntl.flock(fd, fcntl.LOCK_EX)
+        yield
+    finally:
+        os.close(fd)    # closing the descriptor drops the lock
+
+
 @pytest.fixture(autouse=True)
 def _resilience_hygiene():
     """Disarm every fault point and close every circuit breaker after each

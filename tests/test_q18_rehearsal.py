@@ -13,7 +13,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL = "tpch-sf1-q18.q18-1stream"
 
 
-def test_the_q18_cell_rehearses_correct_and_its_control_does_not():
+def test_the_q18_cell_rehearses_correct_and_its_control_does_not(
+        one_traced_rehearsal):
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["JAX_PLATFORMS"] = "cpu"
     p = subprocess.run(

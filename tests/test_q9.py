@@ -545,7 +545,10 @@ def test_the_manifest_holds_the_new_entries():
         and q18 <= mine
     for name in mine - q18:
         (m,) = [m for m in bench["per_layer"] if m["name"] == name]
-        assert m["workloads"] == [CELL] and m["moves"] == "stmt_p50_ms"
+        # Q9's cells alone: this one, and since ISSUE 40 its twin on the
+        # mesh wherever that one's program emits the stage (bind_like_ms)
+        assert m["workloads"][0] == CELL and m["moves"] == "stmt_p50_ms"
+        assert set(m["workloads"][1:]) <= {"tpch-sf1-q9-mesh4.q9-1stream"}
     # 19 + 12 + 6 + 8 + 5 bytes a row of five images and 16 of nation's
     # (no narrow wire is declared for its columns): 134 MB at SF1
     from benchmark import bytes_model
