@@ -34,11 +34,14 @@ materialized output (lanes x row bytes) fits the operator's workmem, the
 aggregate, grouped or scalar, takes the Chain as a Mat (a multi-chunk scan
 unpacks flat off the stacked image) and aggregates ONCE; over the budget
 it folds chunk by chunk, the out-of-core answer (_Tracer._agg_stream;
-_mat_agg counts fused.agg_materialized / fused.agg_folded, or
+_agg_partial counts fused.agg_materialized / fused.agg_folded, or
 fused.agg_int_key where ops/groupjoin.int_key_aggregate took a single
 integer key through ONE sort, or fused.agg_ordered where a compacting join
 left the input grouped and ops/agg.run_ends_aggregate aggregates it in
-place: _Tracer._ordered_input). A range-dense
+place: _Tracer._ordered_input, or fused.agg_dense where every key has a
+small static domain, a dictionary's, a bool's or a range the planner read
+off the statistics, and ops/agg.dense_aggregate aggregates by slot, D
+lanes out, folded or not). A range-dense
 aggregate folds any Chain, and a TopKOp over a Chain always folds.
 
 Overflow posture matches streaming: joins and generic agg folds carry
@@ -782,7 +785,7 @@ class _Tracer:
         self.sort_lanes += m.capacity
         self.flag_ops.append(_GroupJoinGuard(op, "_ia_wide", "_ia_ok"))
         self.flags.append(res.fallback)
-        return op._final_project(res.batch)
+        return res.batch
 
     def _agg_stream(self, op: HashAggOp) -> Optional[_Stream]:
         """The chunk stream `op` folds over, or None: it aggregates ONCE
@@ -852,17 +855,31 @@ class _Tracer:
 
     def _mat_agg(self, op: HashAggOp) -> Batch:
         out = self._try_groupjoin(op)
-        lowering = None
-        if out is None and op.group_by:
+        if out is not None:
+            stats.add("fused.agg_materialized")
+            return out
+        acc, dense = self._agg_partial(op)
+        return op._final_project(acc.compact() if dense else acc)
+
+    def _agg_partial(self, op: HashAggOp) -> Tuple[Batch, bool]:
+        """-> (`op`'s internal accumulator over this trace's input, before
+        _final_project; is it DENSE). A dense accumulator holds group g at
+        LANE g of the D lanes its keys' static domains span
+        (op._dense_sizes), the same layout whatever rows came in: partials
+        of it merge lane-wise (dense_merge; the mesh's shards do), and
+        compact() gives the D-lane batch every later operator runs at.
+        Counts the lowering taken, one event a traced HashAggOp."""
+        out = lowering = None
+        if op.group_by:
             dense = self._ordered_input(op)
             if dense is not None:
                 # in place: nothing hashed, so no collision flag and no
                 # re-seeded restart; the run-ends view at the Shrink's
                 # lanes is what top_k_batch, a Shrink, a MapOp and
                 # _pack_result take (Q18's first aggregate feeds them it)
-                out = op._final_project(run_ends_aggregate(
+                out = run_ends_aggregate(
                     self._mat(op.child), tuple(op.group_by),
-                    tuple(op.internal), dense=dense))
+                    tuple(op.internal), dense=dense)
                 lowering = "fused.agg_ordered"
         if out is None:
             out = self._try_int_agg(op)
@@ -870,11 +887,12 @@ class _Tracer:
                 lowering = "fused.agg_int_key"
         # every fast path aggregates over the materialized input
         s = self._agg_stream(op) if out is None else None
-        # the lowering taken, one event a traced HashAggOp
+        if out is None and op._dense_sizes is not None:
+            lowering = "fused.agg_dense"
         stats.add(lowering or ("fused.agg_folded" if s is not None
                                else "fused.agg_materialized"))
         if out is not None:
-            return out
+            return out, False
         group_by, internal = tuple(op.group_by), tuple(op.internal)
         if op._range_dense is not None:
             from cockroach_tpu.ops.agg import range_dense_aggregate
@@ -895,28 +913,39 @@ class _Tracer:
                 (acc, fl), chain_fl = self._fold(s, init, step)
                 self.flag_ops.extend(s.flag_ops + [op])
                 self.flags.extend(list(chain_fl) + [fl])
-                return op._final_project(acc)
+                return acc, False
             m2 = self._mat(op.child)
             out, fl = range_dense_aggregate(m2, group_by[0], lo, span,
                                             internal)
             self.flag_ops.append(op)
             self.flags.append(fl)
-            return op._final_project(out)
-        if s is not None and op._dense_sizes is not None:
-            sizes = tuple(op._dense_sizes)
+            return out, False
+        if op._dense_sizes is not None:
+            # no hash and a statically complete key space: no collision,
+            # no overflow. Ranged keys (op.key_domains) carry the ONE flag,
+            # a live key outside its range, answered by op.widen(); keys of
+            # dictionaries and bools alone add no flag to the program
+            sizes, doms = tuple(op._dense_sizes), op.key_domains
 
-            def init(b):
-                return dense_aggregate(b, group_by, internal, sizes)
+            def partial(b):
+                return dense_aggregate(b, group_by, internal, sizes, doms,
+                                       with_flag=True)
 
-            def step(acc, b):
-                return dense_merge(
-                    acc, dense_aggregate(b, group_by, internal, sizes),
-                    group_by, internal)
+            if s is not None:
+                def step(carry, b):
+                    part, fl = partial(b)
+                    return dense_merge(carry[0], part, group_by,
+                                       internal), carry[1] | fl
 
-            acc, fl = self._fold(s, init, step)
-            self.flag_ops.extend(s.flag_ops)
-            self.flags.extend(fl)
-            return op._final_project(acc.compact())
+                (acc, outside), fl = self._fold(s, partial, step)
+                self.flag_ops.extend(s.flag_ops)
+                self.flags.extend(fl)
+            else:
+                acc, outside = partial(self._mat(op.child))
+            if doms:
+                self.flag_ops.append(op)
+                self.flags.append(outside)
+            return acc, True
         if s is not None:
             part_cap = s.cap if group_by else 1
             acc_cap = _pow2_at_least(part_cap * op.expansion)
@@ -943,22 +972,18 @@ class _Tracer:
             (acc, ovf), fl = self._fold(s, init, step)
             self.flag_ops.extend(s.flag_ops + ([op] if group_by else []))
             self.flags.extend(list(fl) + ([ovf] if group_by else []))
-            return op._final_project(acc)
-        m = self._mat(op.child)
-        if op._dense_sizes is not None:
-            out = dense_aggregate(m, group_by, internal,
-                                  tuple(op._dense_sizes))
-            return op._final_project(out.compact())
+            return acc, False
         # materialized aggregate: output capacity == input capacity, which
         # by construction holds every group — no overflow is possible, but
         # a hash-grouping collision still forces a re-seeded rerun (a
         # scalar aggregate hashes nothing: no flag, no restart target)
-        out, coll = hash_aggregate(m, group_by, internal, seed=op.seed,
-                                   method="hash", with_flag=True)
+        out, coll = hash_aggregate(self._mat(op.child), group_by, internal,
+                                   seed=op.seed, method="hash",
+                                   with_flag=True)
         if group_by:
             self.flag_ops.append(op)
             self.flags.append(coll)
-        return op._final_project(out)
+        return out, False
 
 
 # Result rows the fused program packs for the single-transfer readback.
@@ -1173,12 +1198,15 @@ class FusedRunner:
         if isinstance(op, (JoinOp, HashAggOp)):
             # expansion (FlowRestart doubles it), workmem (gates the
             # Unsupported/fallback decision), build mode (restart drops
-            # unique->expand) and the hash-grouping seed (restart
-            # re-seeds) all shape the program
+            # unique->expand), the hash-grouping seed (restart
+            # re-seeds) and an aggregate's ranged keys (restart drops
+            # them) all shape the program
             out.append((type(op).__name__, op.expansion, op.workmem,
                         getattr(op, "seed", 0),
                         getattr(op, "build_mode", ""),
                         getattr(op, "_range_dense", None),
+                        tuple(sorted(
+                            (getattr(op, "key_domains", None) or {}).items())),
                         getattr(op, "_gj_bump", 0),
                         getattr(op, "_ia_ok", True),
                         getattr(op, "_ia_wide", False)))

@@ -581,9 +581,17 @@ class HashAggOp(Operator):
     def __init__(self, child: Operator, group_by: Sequence[str],
                  aggs: Sequence[AggSpec], expansion: int = 1,
                  workmem: Optional[int] = None,
-                 dense_range: Optional[Tuple[int, int]] = None):
+                 dense_range: Optional[Tuple[int, int]] = None,
+                 key_domains: Optional[Dict[str, Tuple[int, int]]] = None):
         self.child = child
         self.group_by = list(group_by)
+        # planner hint (stats-derived, sql/plan.key_domains): group key ->
+        # its value range [lo, hi]. Beside the dictionary and bool keys it
+        # makes the key space small and static (ops/agg.dense_key_sizes):
+        # the aggregate then lowers dense, group g at lane g. A live key
+        # outside its range (a row written after ANALYZE) raises the
+        # deferred flag and widen() drops the ranges.
+        self.key_domains = dict(key_domains) if key_domains else None
         # planner hint (stats-derived): the single int group key's value
         # range [lo, hi] — enables the scatter-based direct-address
         # aggregation (ops/agg.py range_dense_aggregate). A stale range
@@ -632,24 +640,7 @@ class HashAggOp(Operator):
                                  for a in self.internal)
         self._finalize = jax.jit(self._final_project)
         self._make_kernels()
-        # dense (sort-free) path for small static key domains — see
-        # ops/agg.py dense_aggregate; partials fold lane-wise so the whole
-        # streaming aggregation compiles without a single sort HLO
-        from cockroach_tpu.ops.agg import dense_key_sizes, dense_aggregate, \
-            dense_merge
-        self._dense_sizes = (dense_key_sizes(child.schema, self.group_by)
-                             if self.group_by else None)
-        if self._dense_sizes is not None:
-            sizes = tuple(self._dense_sizes)
-            gb, internal = tuple(self.group_by), tuple(self.internal)
-            self._dense_partial = jax.jit(
-                lambda item: dense_aggregate(f(item), gb, internal, sizes))
-            self._dense_fold = jax.jit(
-                lambda acc, item: dense_merge(
-                    acc, dense_aggregate(f(item), gb, internal, sizes),
-                    gb, internal))
-            self._dense_final = jax.jit(
-                lambda acc: self._final_project(acc.compact()))
+        self._make_dense()
         self._range_dense = None
         if (self._dense_sizes is None and dense_range is not None
                 and len(self.group_by) == 1):
@@ -664,6 +655,41 @@ class HashAggOp(Operator):
                     and _jnp.issubdtype(key_dtype, _jnp.integer)):
                 self._range_dense = (int(lo), int(span))
                 self._make_rd_kernels()
+
+    def _make_dense(self):
+        """The dense (sort-free) path for small static key domains — see
+        ops/agg.py dense_aggregate; partials fold lane-wise so the whole
+        streaming aggregation compiles without a single sort HLO. ONE
+        decision (`_dense_sizes`) for this operator's own fold, the fused
+        tracer and the distributed one; with ranged keys every partial
+        carries the out-of-range flag. Called at construction and again by
+        widen() once the ranges are dropped."""
+        from cockroach_tpu.ops.agg import (
+            dense_aggregate, dense_key_sizes, dense_merge,
+        )
+        self._dense_sizes = (
+            dense_key_sizes(self.child.schema, self.group_by,
+                            self.key_domains)
+            if self.group_by else None)
+        if self._dense_sizes is None:
+            self.key_domains = None  # a range no dense lowering reads
+            return
+        sizes, doms = tuple(self._dense_sizes), self.key_domains
+        gb, internal = tuple(self.group_by), tuple(self.internal)
+        f = self._chunk_fn
+
+        def partial(item):
+            return dense_aggregate(f(item), gb, internal, sizes, doms,
+                                   with_flag=True)
+
+        def fold(carry, item):
+            part, fl = partial(item)
+            return dense_merge(carry[0], part, gb, internal), carry[1] | fl
+
+        self._dense_partial = jax.jit(partial)
+        self._dense_fold = jax.jit(fold)
+        self._dense_final = jax.jit(
+            lambda acc: self._final_project(acc.compact()))
 
     def _make_rd_kernels(self):
         """Jitted direct-address partial/fold — built ONCE (jit caches by
@@ -707,11 +733,19 @@ class HashAggOp(Operator):
 
     def widen(self):
         """FlowRestart remedy: a tripped range-dense flag (stale stats)
-        disables that path; otherwise double the accumulator expansion
-        (group overflow) AND re-seed the key hash (collision)."""
+        disables that path, and a dense aggregate's (a key outside its
+        range: the only flag it raises) drops the ranges, so that the
+        keys without a static domain hash again; otherwise double the
+        accumulator expansion (group overflow) AND re-seed the key hash
+        (collision)."""
         if self._range_dense is not None:
             self._range_dense = None
             self.dense_range = None
+            return
+        if self.key_domains:
+            self.key_domains = None
+            self._make_dense()
+            self._stacked_jit.clear()
             return
         self.expansion *= 2
         self.seed += 1
@@ -829,13 +863,13 @@ class HashAggOp(Operator):
                 dfinal = self._dense_final
 
                 def dense_prog(bufs, ms):
-                    acc = dpartial((bufs[0], ms[0]))
+                    carry = dpartial((bufs[0], ms[0]))
                     if bufs.shape[0] > 1:
-                        def body(acc, x):
-                            return dfold(acc, x), None
-                        acc, _ = jax.lax.scan(body, acc,
-                                              (bufs[1:], ms[1:]))
-                    return dfinal(acc)
+                        def body(carry, x):
+                            return dfold(carry, x), None
+                        carry, _ = jax.lax.scan(body, carry,
+                                                (bufs[1:], ms[1:]))
+                    return dfinal(carry[0]), carry[1]
 
                 # AOT-compile OUTSIDE the fold bucket: agg.fold tracks
                 # the recurring per-query cost; the once-per-shape XLA
@@ -844,9 +878,11 @@ class HashAggOp(Operator):
                     prog = jax.jit(dense_prog).lower(bufs, ms).compile()
                 self._stacked_jit[("dense", bufs.shape)] = prog
             with stats.timed("agg.fold"):
-                out = prog(bufs, ms)
+                out, outside = prog(bufs, ms)
             stats.add("agg.fold_stacked")
-            return [out], False
+            # the key space is statically complete (no overflow); only a
+            # ranged key can ask for a restart, ONE readback, and only then
+            return [out], bool(self.key_domains) and bool(outside)
 
         acc_cap = _pow2_at_least(sc.capacity * self.expansion)
         row_bytes = _spill.estimate_row_bytes(self._internal_schema)
@@ -895,14 +931,18 @@ class HashAggOp(Operator):
             return
 
         if self._dense_sizes is not None:
-            acc = None
+            carry = None
             for item in self._stream():
                 with stats.timed("agg.fold"):
-                    acc = (self._dense_partial(item) if acc is None
-                           else self._dense_fold(acc, item))
-            if acc is not None:
-                yield self._dense_final(acc)
-            # dense key space is statically complete: no overflow possible
+                    carry = (self._dense_partial(item) if carry is None
+                             else self._dense_fold(carry, item))
+            if carry is not None:
+                yield self._dense_final(carry[0])
+            # dense key space is statically complete: no overflow possible;
+            # a ranged key's stale range is the one deferred flag (ONE
+            # end-of-stream readback, as the hash fold's below)
+            if carry is not None and self.key_domains and bool(carry[1]):
+                raise FlowRestart(self)  # widen() drops the ranges
             return
 
         if self._range_dense is not None:

@@ -631,9 +631,14 @@ def hash_aggregate(batch: Batch, group_by: Sequence[str],
 # Dense (sort-free) aggregation for low-cardinality keys.
 #
 # When every GROUP BY column has a statically known small domain (dictionary
-# codes, bools), the group space is a fixed D = prod(sizes) lanes and every
+# codes, bools, or an integer or date key whose range [lo, hi] the planner
+# proved from the table statistics: the year of o_orderdate, 1992..1998),
+# the group space is a fixed D = prod(sizes) lanes and every
 # aggregate is a masked reduction over a (cap, D) broadcast — no sort, no
-# scatter, no data-dependent shapes. Two wins on TPU: the kernel is pure
+# scatter, no data-dependent shapes. A ranged key's slot is `value - lo`;
+# statistics go stale, so a live value outside [lo, hi] raises a deferred
+# flag (dense_aggregate's with_flag) and the flow restarts without the
+# range: never a dropped or clamped row. Two wins on TPU: the kernel is pure
 # VPU-friendly elementwise+reduce (a 1M-row batch aggregates in ~HBM-read
 # time), and the compiled program contains NO sort HLO — big sorts are what
 # makes a whole-query program slow to compile for the TPU (Q1 at SF1: 5 s;
@@ -647,11 +652,15 @@ def hash_aggregate(batch: Batch, group_by: Sequence[str],
 DENSE_MAX_GROUPS = 256  # (cap x D) broadcast traffic bound
 
 
-def dense_key_sizes(schema, group_by: Sequence[str]):
+def dense_key_sizes(schema, group_by: Sequence[str], domains=None):
     """Per-key domain sizes (incl. a NULL slot) if every group column has a
-    statically known small domain; None otherwise."""
+    statically known small domain; None otherwise. `domains` (name ->
+    (lo, hi), inclusive: the planner's, sql/plan.key_domains) gives an
+    INT or DATE key its range; a key of those kinds without one has no
+    static domain."""
     from cockroach_tpu.coldata.batch import Kind as _Kind
 
+    domains = domains or {}
     sizes = []
     for n in group_by:
         f = schema.field(n)
@@ -662,6 +671,13 @@ def dense_key_sizes(schema, group_by: Sequence[str]):
             sizes.append(len(d) + 1)  # +1 = NULL slot
         elif f.type.kind is _Kind.BOOL:
             sizes.append(3)  # false, true, NULL
+        elif f.type.kind in (_Kind.INT, _Kind.DATE) and n in domains:
+            lo, hi = domains[n]
+            if hi < lo:
+                return None
+            # the NULL slot always, as a dictionary key has it: an outer
+            # join NULL-extends a column its table calls NOT NULL
+            sizes.append(hi - lo + 2)
         else:
             return None
     prod = 1
@@ -673,35 +689,53 @@ def dense_key_sizes(schema, group_by: Sequence[str]):
 
 
 def _dense_packed(batch: Batch, group_by: Sequence[str],
-                  sizes: Sequence[int]):
+                  sizes: Sequence[int], domains=None):
     """(cap,) packed group code in [0, D); D for dead lanes. NULL keys
-    take the last slot of their column's domain."""
+    take the last slot of their column's domain. A key in `domains`
+    (ranged, see dense_key_sizes) takes slot `value - lo`; the third
+    result is whether a live row's key lies outside its range (False
+    without ranged keys)."""
+    domains = domains or {}
     D = 1
     for s in sizes:
         D *= s
     packed = jnp.zeros(batch.capacity, dtype=jnp.int32)
+    outside = jnp.bool_(False)
     for n, size in zip(group_by, sizes):
         c = batch.col(n)
-        code = c.values.astype(jnp.int32)
+        if n in domains:
+            lo, hi = domains[n]
+            v = c.values.astype(jnp.int64)
+            inside = (v >= lo) & (v <= hi)
+            outside |= jnp.any(batch.sel & c.valid_mask() & ~inside)
+            code = jnp.where(inside, v - lo, 0).astype(jnp.int32)
+        else:
+            code = c.values.astype(jnp.int32)
         if c.validity is not None:
             code = jnp.where(c.validity, code, jnp.int32(size - 1))
         packed = packed * size + code
-    return jnp.where(batch.sel, packed, jnp.int32(D)), D
+    return jnp.where(batch.sel, packed, jnp.int32(D)), D, outside
 
 
 def dense_aggregate(batch: Batch, group_by: Sequence[str],
-                    aggs: Sequence[AggSpec], sizes: Sequence[int]) -> Batch:
+                    aggs: Sequence[AggSpec], sizes: Sequence[int],
+                    domains=None, with_flag: bool = False):
     """GROUP BY over the dense key space. Output: capacity D, group with
     packed code g at LANE g (a fixed global layout — partials from
     different batches merge lane-wise with dense_merge). sel marks groups
-    with >= 1 selected row.
+    with >= 1 selected row. With ranged keys (`domains`, as
+    dense_key_sizes took them) the caller asks `with_flag` and gets
+    (Batch, a live key lay outside its range): the statistics were stale,
+    the batch is not the answer, and the flow runtime restarts without
+    the range (HashAggOp.widen).
 
     Two lowering paths: the Pallas MXU kernel (ops/pallas_kernels.py)
     computes all integer sum/count aggregates in ONE pass via byte-limb
     matmuls when sql.tpu.pallas enables it; everything else (and the
     fallback) uses per-aggregate masked broadcasts."""
-    group_by = list(group_by)
-    packed, D = _dense_packed(batch, group_by, sizes)
+    group_by, domains = list(group_by), domains or {}
+    assert with_flag or not domains, "a ranged key needs its flag read"
+    packed, D, outside = _dense_packed(batch, group_by, sizes, domains)
 
     interp = _pallas_mode()
     kernel_cols: dict = {}
@@ -728,6 +762,8 @@ def dense_aggregate(batch: Batch, group_by: Sequence[str],
     for n, size, code in zip(group_by, sizes, codes):
         c = batch.col(n)
         is_null = code == (size - 1) if c.validity is not None else None
+        if n in domains:
+            code = code.astype(jnp.int64) + domains[n][0]
         if c.validity is None:
             out_cols[n] = Column(code.astype(c.values.dtype))
         else:
@@ -739,7 +775,8 @@ def dense_aggregate(batch: Batch, group_by: Sequence[str],
     out_cols.update(kernel_cols)
     sel = counts > 0
     out_cols = mask_padding(out_cols, sel)
-    return Batch(out_cols, sel, jnp.sum(sel).astype(jnp.int32))
+    out = Batch(out_cols, sel, jnp.sum(sel).astype(jnp.int32))
+    return (out, outside) if with_flag else out
 
 
 def _pallas_mode():

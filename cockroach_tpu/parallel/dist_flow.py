@@ -32,7 +32,9 @@ shard_map'd XLA program runs the ENTIRE query on every device —
   _BucketGuard, which widens that join alone;
 - P9 two-stage aggregation: per-device partial fold -> all_gather ->
   replicated merge -> finalize (partial aggregators on data nodes, final
-  on the gateway);
+  on the gateway); a DENSE partial (group g at lane g of the keys' static
+  domains, exec/fused._Tracer._agg_partial) is gathered at its D lanes
+  and merged lane-wise, with no second hash aggregate;
 - deferred overflow/collision flags are psum-reduced across the axis and
   answered by the same FlowRestart widen/re-seed retry as single-chip.
   A full bucket drops no row silently: the router raises its flag.
@@ -97,6 +99,7 @@ estimate, the bucket traced, the bucket the lanes give.
 
 from __future__ import annotations
 
+import functools
 import threading
 from collections import OrderedDict
 from dataclasses import fields, is_dataclass, replace
@@ -122,7 +125,7 @@ from cockroach_tpu.exec.operators import (
     _pow2_at_least, walk_operators,
 )
 from cockroach_tpu.ops import expr as _expr
-from cockroach_tpu.ops.agg import hash_aggregate
+from cockroach_tpu.ops.agg import dense_merge, hash_aggregate
 from cockroach_tpu.parallel import ingest
 from cockroach_tpu.parallel.mesh import mesh_key, shrink_mesh
 from cockroach_tpu.parallel.repartition import (
@@ -441,23 +444,30 @@ class _DistTracer(_Tracer):
             # fully replicated input: every device computes the identical
             # complete aggregate — gathering would multiply every count
             return super()._mat_agg(op)
-        group_by, internal = tuple(op.group_by), tuple(op.internal)
-        # local partial: run the single-chip logic WITHOUT finalization
-        final = op._final_project
-        op._final_project = lambda b: b  # capture internal accumulator
-        try:
-            local = super()._mat_agg(op)
-        finally:
-            op._final_project = final
+        group_by = tuple(op.group_by)
+        # local partial: the single-chip lowering's accumulator, before
+        # finalization (a group-join would emit final groups: _try_groupjoin)
+        local, dense = self._agg_partial(op)
         with self._scope(op, MERGE):
-            gathered = _all_gather_batch(local.compact(), self.axis)
-            merged, coll = hash_aggregate(
-                gathered, group_by, op._merge_aggs, seed=op.seed + 7,
-                method="hash", with_flag=True)
-        if group_by:
-            self.flag_ops.append(op)
-            self.flags.append(coll)
-        return final(merged)
+            if dense:
+                # group g sits at lane g on every shard: the partials
+                # merge lane-wise at D lanes. Nothing is hashed, so no
+                # collision flag and no re-seeded restart
+                parts = jax.tree_util.tree_map(
+                    lambda x: lax.all_gather(x, self.axis), local)
+                merged = functools.reduce(
+                    lambda a, b: dense_merge(a, b, group_by, op.internal),
+                    [jax.tree_util.tree_map(lambda x: x[i], parts)
+                     for i in range(self.n_dev)]).compact()
+            else:
+                gathered = _all_gather_batch(local.compact(), self.axis)
+                merged, coll = hash_aggregate(
+                    gathered, group_by, op._merge_aggs, seed=op.seed + 7,
+                    method="hash", with_flag=True)
+                if group_by:
+                    self.flag_ops.append(op)
+                    self.flags.append(coll)
+        return op._final_project(merged)
 
     def _is_sharded(self, op: Operator) -> bool:
         """Does this subtree's materialization hold only device-LOCAL rows?

@@ -109,6 +109,25 @@ def render_plan(p: Plan, catalog: Catalog) -> List[str]:
     return lines
 
 
+def _ranged_key_lines(op) -> List[str]:
+    """One line an aggregate that lowers by slot BECAUSE the statistics
+    bound a key (HashAggOp.key_domains): the slots, and each range it
+    stands on (stale the moment a row is written outside it; the flow
+    then restarts on the hash aggregate)."""
+    import math
+
+    from cockroach_tpu.exec.operators import HashAggOp, walk_operators
+
+    return [
+        f"aggregate by slot: group by {', '.join(agg.group_by)} in "
+        f"{math.prod(agg._dense_sizes)} slots ("
+        + ", ".join(f"{k} in [{lo}, {hi}]"
+                    for k, (lo, hi) in agg.key_domains.items())
+        + " by the statistics)"
+        for agg in walk_operators(op)
+        if isinstance(agg, HashAggOp) and agg.key_domains]
+
+
 def _distribution_lines(op, mesh, catalog: Catalog) -> List[str]:
     """How the distributed runner would place this tree on `mesh`
     (DistFusedRunner.describe), from the catalog's row counts: no scan
@@ -265,6 +284,8 @@ def execute_with_plan(sql: str, catalog: Catalog, capacity: int = 1 << 17,
         lines.append(f"engine: {engine} (est {est} scan rows, "
                      f"crossover ~{crossover_rows()} rows; tpu dispatch "
                      f"floor {1000 * est_tpu_seconds(0):.0f}ms)")
+    if explained is not None:
+        lines.extend(_ranged_key_lines(explained.op))
     if mesh is not None and explained is not None:
         lines.extend(_distribution_lines(explained.op, mesh, catalog))
     if slots:

@@ -126,12 +126,13 @@ class TPCHCatalog(Catalog):
             # bounded sample: the FIRST 4 x 16K chunks only (draining the
             # generator would materialize the whole table at plan time);
             # the exact row count comes from the generator. Bounds are
-            # therefore prefix-biased — fine for selectivities, and the
-            # range-dense hint that needed exact bounds is off.
+            # therefore prefix-biased — fine for selectivities, and said
+            # so, for what needs them exact (key_domains)
             st = sample_stats(
                 itertools.islice(self.gen.chunks(name, 1 << 14), 4),
                 self.gen.schema(name))
             st.row_count = self.gen.num_rows(name)
+            st.exact_bounds = False
             self._stats_cache[name] = st
         return self._stats_cache[name]
 
@@ -1001,8 +1002,8 @@ def build(p: Plan, catalog: Catalog, capacity: int = 1 << 17,
             if agg_cls is HashAggOp:
                 return HashAggOp(child, list(node.group_by),
                                  list(node.aggs),
-                                 dense_range=_dense_range_hint(
-                                     node, catalog))
+                                 key_domains=key_domains(
+                                     node, catalog, child.schema))
             return agg_cls(child, list(node.group_by), list(node.aggs))
         if isinstance(node, OrderBy):
             return SortOp(rec(node.input), list(node.keys))
@@ -1051,43 +1052,80 @@ def build(p: Plan, catalog: Catalog, capacity: int = 1 << 17,
     return rec(p)
 
 
-ENABLE_RANGE_DENSE_HINT = False  # see the measured counter-result below
+def _year_of(days: int) -> int:
+    """The civil year of a DATE (days since the unix epoch): what
+    ops/expr evaluates Extract('year', ...) to, on the host."""
+    return int(np.datetime64(int(days), "D").astype("datetime64[Y]")
+               .astype(np.int64)) + 1970
 
 
-def _dense_range_hint(node: "Aggregate", catalog: Catalog):
-    """Stats-derived [lo, hi] of a single integer group key (the
-    direct-address aggregation hint; sql/stats histograms supply the
-    bounds). MEASURED COUNTER-RESULT (r4, v5e): int64 scatter-adds over
-    multi-M inputs cost MORE than the sort-view aggregation they replace
-    (Q18 first agg: 0.88s -> 1.23s warm), so the automatic hint is off —
-    TPU scatters are input-sized and slow regardless of the group span.
-    The kernel (ops/agg.py range_dense_aggregate) remains available via
-    an explicit HashAggOp dense_range for small-input OLTP shapes."""
-    if True:
-        return None
-    if len(node.group_by) != 1:
-        return None
-    col = node.group_by[0]
-    for sub in _walk_plan(node.input):
-        if not isinstance(sub, (Scan, IndexScan)):
-            continue
-        try:
-            schema = catalog.table_schema(sub.table)
-        except Exception:
-            continue
-        if col not in schema.names():
-            continue
-        stats = catalog.table_stats(sub.table)
-        if stats is None:
+def _column_range(p: Plan, name: str, catalog: Catalog):
+    """-> (lo, hi, Kind) that every non-NULL value of output column `name`
+    of `p` lies in, by the statistics of the table it is scanned from, or
+    None. Followed only through what keeps a column's values a SUBSET of
+    the table's: filters, shrinks, sorts, limits, DISTINCT, joins (an
+    outer join adds NULLs only), an inner GROUP BY's keys, and projections
+    that rename it. The one computed form is Extract('year', <such a DATE>),
+    monotone in the day: year(lo)..year(hi)."""
+    from cockroach_tpu.coldata.batch import Kind
+    from cockroach_tpu.ops.expr import Extract
+
+    if isinstance(p, (Scan, IndexScan)):
+        schema = catalog.table_schema(p.table)
+        if name not in (p.columns or schema.names()):
             return None
-        cs = stats.columns.get(col)
-        if cs is None or cs.lo is None or cs.hi is None:
+        st = catalog.table_stats(p.table)
+        cs = st.columns.get(name) if st is not None else None
+        kind = schema.field(name).type.kind
+        if (cs is None or cs.lo is None or cs.hi is None
+                or not st.exact_bounds
+                or kind not in (Kind.INT, Kind.DATE)):
             return None
-        span = cs.hi - cs.lo + 1
-        if 0 < span <= (1 << 22):
-            return (cs.lo, cs.hi)
+        return int(cs.lo), int(cs.hi), kind
+    if isinstance(p, Project):
+        e = dict(p.outputs).get(name)
+        if isinstance(e, Col):
+            return _column_range(p.input, e.name, catalog)
+        if (isinstance(e, Extract) and e.part == "year"
+                and isinstance(e.arg, Col)):
+            r = _column_range(p.input, e.arg.name, catalog)
+            if r is not None and r[2] is Kind.DATE:
+                return _year_of(r[0]), _year_of(r[1]), Kind.INT
         return None
+    if isinstance(p, (Filter, Shrink, OrderBy, Limit, Distinct)):
+        return _column_range(p.input, name, catalog)
+    if isinstance(p, Aggregate):
+        return (_column_range(p.input, name, catalog)
+                if name in p.group_by else None)
+    if isinstance(p, Join):
+        sides = (p.left,) if p.how in ("semi", "anti") else (p.left, p.right)
+        for side in sides:
+            if name in _plan_columns(side, catalog):
+                return _column_range(side, name, catalog)
     return None
+
+
+def key_domains(node: "Aggregate", catalog: Catalog, schema: Schema):
+    """Stats-derived {key: (lo, hi)} of `node`'s integer and date GROUP BY
+    keys (HashAggOp's `key_domains`), or None: given only where, WITH
+    them, every key has a small static domain (ops/agg.dense_key_sizes
+    over `schema`, the aggregate's input's: dictionaries and bools count
+    as they are, the product is held to its DENSE_MAX_GROUPS), so that
+    the aggregate lowers by slot: no hash, no sort, D lanes out. A key
+    whose range cannot be proved (_column_range) gives none, and the
+    aggregate lowers as without statistics. Statistics go stale: the
+    lowering flags a live key outside its range and the flow restarts
+    without (HashAggOp.widen)."""
+    from cockroach_tpu.ops.agg import dense_key_sizes
+
+    doms = {}
+    for key in node.group_by:
+        r = _column_range(node.input, key, catalog)
+        if r is not None:
+            doms[key] = r[:2]
+    if not doms or dense_key_sizes(schema, node.group_by, doms) is None:
+        return None
+    return doms
 
 
 def _walk_plan(p: Plan):

@@ -42,6 +42,10 @@ class ColumnStats:
 class TableStats:
     row_count: int
     columns: Dict[str, ColumnStats] = field(default_factory=dict)
+    # lo/hi cover EVERY row the statistics were taken over (sample_stats'
+    # contract); a catalog that samples a prefix of the table says False,
+    # and nothing then reads a bound as a domain (sql/plan.key_domains)
+    exact_bounds: bool = True
 
     def encode(self) -> bytes:
         return json.dumps({

@@ -396,7 +396,7 @@ def test_explain_prints_the_pattern_slot_and_the_key_packings(one):
         assert table["fused.join_key_int"] == 4 * traced
         assert table["fused.join_key_hash"] == traced
         assert table["fused.join_compact"] == traced
-        assert table["fused.agg_materialized"] == traced
+        assert table["fused.agg_dense"] == traced
     assert table["fused.hash_key_lanes"] == table["fused.sort_lanes"] >= 1
 
 

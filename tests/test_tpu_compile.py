@@ -175,3 +175,69 @@ def test_four_device_repartition_has_all_to_all(topo):
     text = compiled.as_text()
     assert "all-to-all" in text
     assert " scatter(" not in text
+
+
+@pytest.mark.parametrize("n_dev,lanes", [(1, 524288), (4, 262144)])
+def test_q9s_aggregate_by_slot_compiles_with_the_limb_kernel(topo, n_dev,
+                                                              lanes):
+    """ops/agg.dense_aggregate over (a dictionary of 25, a year ranged
+    1992..1998) at the lanes Q9's aggregate sees at SF1, with the Pallas
+    limb kernel as `sql.tpu.pallas = auto` picks it on a TPU: on one chip,
+    and under shard_map on four with the lane-wise merge of the gathered
+    208-lane partials (ISSUE 42): the kernel lowers inside shard_map, and
+    nothing sorts the input's lanes."""
+    import functools
+
+    from jax import lax
+
+    from cockroach_tpu.coldata.batch import Batch, Column
+    from cockroach_tpu.ops.agg import AggSpec, dense_aggregate, dense_merge
+    from cockroach_tpu.parallel.repartition import shard_map
+    from cockroach_tpu.util.settings import PALLAS, Settings
+
+    gb, aggs = ("n", "y"), (AggSpec("sum", "v", "sv"),)
+    sizes, doms = (26, 8), {"y": (1992, 1998)}
+
+    def step(n, y, v, sel):
+        b = Batch({"n": Column(n), "y": Column(y), "v": Column(v)}, sel,
+                  jnp.sum(sel).astype(jnp.int32))
+        part, outside = dense_aggregate(b, gb, aggs, sizes, doms,
+                                        with_flag=True)
+        if n_dev > 1:
+            parts = jax.tree_util.tree_map(
+                lambda x: lax.all_gather(x, "x"), part)
+            part = functools.reduce(
+                lambda a, c: dense_merge(a, c, gb, aggs),
+                [jax.tree_util.tree_map(lambda x: x[i], parts)
+                 for i in range(n_dev)])
+            outside = lax.psum(outside.astype(jnp.int32), "x") > 0
+        out = part.compact()
+        return out.col("n").values, out.col("y").values, \
+            out.col("sv").values, out.sel, outside
+
+    if n_dev == 1:
+        fn, sh = step, SingleDeviceSharding(topo.devices[0])
+    else:
+        mesh = Mesh(np.array(topo.devices[:n_dev]), ("x",))
+        fn = shard_map(step, mesh=mesh, in_specs=(P("x"),) * 4,
+                       out_specs=(P(),) * 5, check_rep=False)
+        sh = NamedSharding(mesh, P("x"))
+    s = Settings()
+    old = s.get(PALLAS)
+    s.set(PALLAS, "on")     # `auto` asks jax.default_backend(): the CPU here
+    try:
+        compiled = _compile(
+            fn,
+            jax.ShapeDtypeStruct((n_dev * lanes,), jnp.int32, sharding=sh),
+            jax.ShapeDtypeStruct((n_dev * lanes,), jnp.int64, sharding=sh),
+            jax.ShapeDtypeStruct((n_dev * lanes,), jnp.int64, sharding=sh),
+            jax.ShapeDtypeStruct((n_dev * lanes,), jnp.bool_, sharding=sh))
+    finally:
+        s.set(PALLAS, old)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the one sort left is compact()'s, over the domain's 208 lanes
+    sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+    assert sorts and all("[208]" in ln and str(lanes) not in ln
+                         for ln in sorts)
+    assert ("all-gather" in text) == (n_dev > 1)
