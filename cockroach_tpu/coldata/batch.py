@@ -292,8 +292,7 @@ class Batch:
         zero-filled and deselected. The shuffle-boundary materialization the
         reference does eagerly per-op via selection vectors."""
         cap = self.capacity
-        order = jnp.argsort(~self.sel, stable=True)  # selected rows first
-        out = self.gather(order)
+        out = self.gather(first_selected(self.sel, cap))
         new_sel = jnp.arange(cap) < self.length
         return Batch(mask_padding(out.columns, new_sel), new_sel,
                      self.length)
@@ -337,6 +336,38 @@ class Batch:
 
 def full_sel(capacity: int):
     return jnp.ones(capacity, dtype=jnp.bool_)
+
+
+_TOP32 = np.uint32(1 << 31)
+
+
+def first_matches(match, carry, C: int):
+    """-> (C,) int32: `carry` (uint32 under 2^31) of the first C lanes
+    where `match`, in lane order, then of unmatched lanes in lane order
+    (zeros past the lanes there are). ONE sort of a single u32 operand:
+    the miss bit rides above each lane's carry, so no key needs a value
+    operand beside it. Where `carry` rises with the lane (the lane index
+    itself, a position) the key is unique and the result is what
+    `argsort(~match, stable=True)[:C]` gathers, lane for lane, from one
+    operand where that sort moves two and a tie-break: on v5e at
+    8,388,608 lanes the argsort costs 26.4 ms standing alone and this
+    sort 8.7 (scripts/price_sort_operands.py; PERF.md section 6). Every
+    compaction of the served programs is this function: the compacting
+    joins (ops/sortjoin.py), the aggregates' run ends (ops/groupjoin.py),
+    ShrinkOp and Batch.compact."""
+    key = jax.lax.sort(jnp.where(match, carry, carry | _TOP32),
+                       is_stable=False)
+    n = key.shape[0]
+    key = key[:C] if n >= C else jnp.concatenate(
+        [key, jnp.full((C - n,), _TOP32)])
+    return (key & ~_TOP32).astype(jnp.int32)
+
+
+def first_selected(sel, C: int):
+    """-> (C,) int32: the lanes where `sel`, in lane order, then the
+    others in lane order: `argsort(~sel, stable=True)[:C]` (lanes under
+    2^31, which every batch has)."""
+    return first_matches(sel, jnp.arange(sel.shape[0], dtype=jnp.uint32), C)
 
 
 def mask_padding(columns: Dict[str, Column], sel) -> Dict[str, Column]:

@@ -27,7 +27,7 @@ import numpy as np
 from cockroach_tpu.coldata.arrow import numpy_to_batch
 from cockroach_tpu.coldata.batch import (
     BOOL, Batch, ColType, Column, Field, FLOAT, INT, Kind, Schema,
-    concat_batches, mask_padding,
+    concat_batches, first_selected, mask_padding,
 )
 from cockroach_tpu.ops.agg import AggSpec, hash_aggregate
 from cockroach_tpu.ops.expr import Expr, Col, eval_expr, filter_mask
@@ -1767,9 +1767,11 @@ class ShrinkOp(Operator):
     join-expansion and hash-collision retries (disk_spiller.go:208's
     optimistic/general pairing).
 
-    What it costs: a stable (pred, i32) argsort of the child's full
-    capacity plus a (C, W) row gather of the child's columns. Over a
-    unique-build inner or semi join that nothing else reads, the fused
+    What it costs: ONE single-operand u32 sort of the child's full
+    capacity (coldata/batch.first_selected: the miss bit above the lane
+    index, what the compacting joins run) plus a (C, W) row gather of
+    the child's columns. Over a unique-build inner or semi join that
+    nothing else reads, the fused
     runner lowers the pair as ONE step (fused._Tracer._mat_join,
     sortjoin.probe_unique_compact): the join compacts its matches in key
     order, its resort to probe order never runs, and the row gather packs
@@ -1793,17 +1795,13 @@ class ShrinkOp(Operator):
 
     def shrink_traceable(self, m: Batch):
         """-> (shrunk batch, overflow flag). Gathers ONLY the C winning
-        rows (argsort selected-first, then a (C, W) row gather) — a full
-        compact() would row-gather every capacity lane just to slice C
-        of them (~150 ms per 6M-lane shrink on v5e)."""
+        rows (the selected lanes first, in lane order, then a (C, W) row
+        gather) — a full compact() would row-gather every capacity lane
+        just to slice C of them (~150 ms per 6M-lane shrink on v5e)."""
         C = self.capacity
-        cap = m.capacity
-        order = jnp.argsort(~m.sel, stable=True)  # selected rows first
-        kidx = (order[:C] if cap >= C else jnp.concatenate(
-            [order, jnp.zeros((C - cap,), order.dtype)]))
         length = jnp.minimum(m.length, C).astype(jnp.int32)
         sel = jnp.arange(C) < length
-        out = m.gather(kidx.astype(jnp.int32), sel=sel, length=length)
+        out = m.gather(first_selected(m.sel, C), sel=sel, length=length)
         return (Batch(mask_padding(out.columns, sel), sel, length),
                 m.length > C)
 

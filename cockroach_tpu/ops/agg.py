@@ -159,9 +159,13 @@ class _SortedView:
             # sort operand count, ~30s each at 1M) + ONE row-gather of all
             # referenced columns stacked into an int64 matrix (a (cap, C)
             # row gather costs what a single 1-D gather costs; C separate
-            # gathers cost C times that)
+            # gathers cost C times that). (hash, position) is a total key
+            # and IS the stable order, so both operands are keys and the
+            # sort is unstable: the default's tie-break would be a third
+            # operand (scripts/price_sort_operands.py)
             h_sorted, perm = lax.sort(
-                (h, jnp.arange(cap, dtype=jnp.int32)), num_keys=1)
+                (h, jnp.arange(cap, dtype=jnp.int32)), num_keys=2,
+                is_stable=False)
             self.perm = perm
 
             from cockroach_tpu.ops.rowmat import pack_rows, unpack_rows

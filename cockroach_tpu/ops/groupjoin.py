@@ -16,14 +16,17 @@ Pipeline (all native cum-ops; no scatters, no probe-side row gathers):
   1. pack (key - min_key) << 1 | side into ONE u32 (u64 on retry) sort
      key; dead/NULL-key lanes get top-region sentinels tagged as probe
      so they can never look like duplicate build keys;
-  2. lax.sort [(key, value)] — build lanes carry their ROW INDEX as the
+  2. lax.sort [(key, value)], unstable (integer sums and counts never
+     read the order of a run's probe lanes, so XLA's tie-break operand
+     is not paid for) — build lanes carry their ROW INDEX as the
      value, probe lanes their packed aggregate inputs (disjoint lane
      sets share the operand; ops/bitpack.py);
   3. runid = cumsum(new-run); ONE narrow cummax broadcasts (has_build,
      build row index) to each run — a row index always fits 31 bits,
      so no payload-width ladder exists;
   4. per aggregate: extract input bits, segmented sums via cumsum;
-  5. one (u32 lane, i32 iota) sort compacts matched run-END lanes to
+  5. one single-operand u32 sort (coldata/batch.first_selected: the
+     miss bit above the lane index) compacts matched run-END lanes to
      the group capacity; adjacent-end cumsum differences yield exact
      group sums/counts (between two matched ends every contribution is
      zero), and build GROUP COLUMNS gather from the build batch at just
@@ -45,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from cockroach_tpu.coldata.batch import Batch, Column
+from cockroach_tpu.coldata.batch import Batch, Column, first_selected
 from cockroach_tpu.ops.agg import AggSpec
 from cockroach_tpu.ops.bitpack import pack_lanes, plan_pack
 
@@ -122,7 +125,14 @@ def int_key_aggregate(
     apayv = pack_lanes(batch, aplan)
     agg_flag = aplan.total_bits > jnp.int32(63)
 
-    sgk, sgv = jax.lax.sort((gk, apayv), num_keys=1)
+    # unstable: ties are the lanes of one group (or dead lanes), and
+    # their order reaches no output. GJ_FUNCS are sums and counts in
+    # int64 over the packed inputs' bits, which commute exactly, read at
+    # run ENDS only (every other lane of every output column is zeroed
+    # and deselected below); the stable sort's tie-break would be a
+    # third operand at the input's lanes (7.4 ms of 29.7 at 8,388,608
+    # lanes on a v5e: scripts/price_sort_operands.py)
+    sgk, sgv = jax.lax.sort((gk, apayv), num_keys=1, is_stable=False)
     prev = jnp.concatenate([~sgk[:1], sgk[:-1]])
     newrun = sgk != prev
     newrun = newrun.at[0].set(True)
@@ -193,13 +203,9 @@ def int_key_aggregate(
     if not out_capacity:
         out = Batch(cols, is_end, n_groups.astype(jnp.int32))
         return GroupJoinResult(out, fallback, jnp.bool_(False))
-    # compacted variant: one (u32 lane, i32 iota) sort + tiny gathers
-    lane = jnp.arange(cap, dtype=jnp.uint32)
-    csort = jnp.where(is_end, lane, np.uint32(0xFFFFFFFF))
-    _, cidx = jax.lax.sort((csort, lane.astype(jnp.int32)), num_keys=1)
+    # compacted variant: one single-operand u32 sort + tiny gathers
     C = out_capacity
-    top = (cidx[:C] if cap >= C else jnp.concatenate(
-        [cidx, jnp.zeros((C - cap,), cidx.dtype)]))
+    top = first_selected(is_end, C)
     valid = jnp.arange(C) < n_groups
     ccols = {}
     for nme, col in cols.items():
@@ -276,7 +282,11 @@ def group_join_aggregate(
     gk = jnp.concatenate([gk_b, gk_p])
     gv = jnp.concatenate([jnp.arange(rcap, dtype=jnp.uint32).astype(vdt),
                           apayv.astype(vdt)])
-    sgk, sgv = jax.lax.sort((gk, gv), num_keys=1)
+    # unstable: the tag bit, part of the key, leads each run with its
+    # build lane; ties are probe lanes of one key (int64 sums and
+    # counts read at run ends: their order reaches no output), dead
+    # lanes, or duplicate build keys (`dup_flag`: the result is discarded)
+    sgk, sgv = jax.lax.sort((gk, gv), num_keys=1, is_stable=False)
     sgv = sgv.astype(jnp.uint64)
 
     # ---- runs + broadcast of the build ROW INDEX ----------------------
@@ -330,12 +340,8 @@ def group_join_aggregate(
     # ---- compact matched run-END lanes ---------------------------------
     nxt = jnp.concatenate([newrun[1:], jnp.ones((1,), jnp.bool_)])
     is_end = nxt & matched
-    lane = jnp.arange(n, dtype=jnp.uint32)
-    csort = jnp.where(is_end, lane, np.uint32(0xFFFFFFFF))
-    _, cidx = jax.lax.sort((csort, lane.astype(jnp.int32)), num_keys=1)
     C = out_capacity
-    top = (cidx[:C] if n >= C else jnp.concatenate(
-        [cidx, jnp.zeros((C - n,), cidx.dtype)]))
+    top = first_selected(is_end, C)
     n_ends = jnp.sum(is_end)
     valid = jnp.arange(C) < n_ends
     overflow = n_ends > C

@@ -15,8 +15,13 @@ key + one i32 iota) and moves whole rows exactly once:
   1. pack each row's join key and a build/probe tag bit into ONE uint64
      sort operand (raw biased value for single integer keys — exact, no
      collisions; 62-bit hash otherwise);
-  2. lax.sort [build ++ probe] keyed on packed, carrying only iota.
-     Equal keys become adjacent with the build row FIRST (tag bit);
+  2. lax.sort [build ++ probe] keyed on packed, carrying one value
+     operand (a build lane's payload or row index, a probe lane's lane
+     index). Equal keys become adjacent with the build row FIRST: the
+     tag bit is part of the key, so the sort need not be stable for it,
+     and none here is (PR 43: XLA's stable sort carries a tie-break
+     operand of its own; where the order of a key's probe lanes is read,
+     the value operand is the second key);
   3. one 3-leaf segmented scan broadcasts each run head's (is_build,
      source index) to the run ("take right if right starts a run" — the
      carry resets at every head, so no segment ids are needed). A probe
@@ -29,12 +34,13 @@ key + one i32 iota) and moves whole rows exactly once:
   5. back out of the sorted (key) domain, in one of two forms. The
      consumer decides which, and the plan shows the consumer:
      a. RESORT by each lane's DESTINATION index (probe lanes -> their own
-        probe position), carrying (matched-build-row << 1 | match) as one
-        i32 — lanes [0:lcap] land in probe order, probe columns never
-        move. For every consumer that reads the probe's lane layout;
+        probe position; a permutation, so no ties), carrying
+        (matched-build-row << 1 | match) as one i32 — lanes [0:lcap] land
+        in probe order, probe columns never move. For every consumer
+        that reads the probe's lane layout;
      b. COMPACT the matched probe lanes where they stand, in key order
         (probe_unique_compact): one single-operand u32 sort of lcap +
-        rcap lanes puts the matches first (_first_matches), and C-row
+        rcap lanes puts the matches first (first_matches), and C-row
         gathers fetch their probe lane index and build ROW INDEX and
         (step 6) the rows of both sides. For an inner or semi join
         whose only reader is a ShrinkOp of capacity C
@@ -69,7 +75,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from cockroach_tpu.coldata.batch import Batch, Column
+from cockroach_tpu.coldata.batch import (
+    Batch, Column, first_matches, first_selected,
+)
 from cockroach_tpu.ops.rowmat import RowPlan, pack_rows, unpack_rows
 
 # numpy scalars, NOT jnp: a module-level jax.Array closure constant gets
@@ -249,7 +257,7 @@ class _CarrySorted(NamedTuple):
 
 
 def _carry_sort(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
-                rows: bool = False) -> _CarrySorted:
+                rows: bool = False, ordered: bool = False) -> _CarrySorted:
     """Steps 1-4 of the carry join: probe key packing, key sort, run
     detection, the deferred `fallback` flag and the broadcast of each
     run's build payload (ONE copy, whatever step 5 does with it). What a
@@ -263,7 +271,11 @@ def _carry_sort(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
       build's columns): the form the compaction takes, which fetches
       the columns from `ub.batch` at the C surviving lanes only. No
       width to overflow, and ONE cummax of (runid << 32 | row + 1)
-      broadcasts it. A semi join ignores the row and reads the match."""
+      broadcasts it. A semi join ignores the row and reads the match.
+
+    `ordered`: the caller reads the sorted domain's lane ORDER (the
+    compacting inner join, whose result is in it), so lanes of equal
+    keys must stand as a stable sort leaves them."""
     lcap, rcap = probe.capacity, ub.batch.capacity
     n = lcap + rcap
     p_packed, p_range = _pack_keys(
@@ -277,7 +289,22 @@ def _carry_sort(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
         val = jnp.concatenate([jnp.arange(rcap, dtype=jnp.uint32), lane])
     else:
         val = jnp.concatenate([ub.payv, lane.astype(jnp.uint64)])
-    s_packed, s_val = jax.lax.sort((packed, val), num_keys=1)
+    # The sort is unstable: XLA's stable sort carries a tie-break operand
+    # of its own (7.8 ms of 22.9 at 8,388,608 lanes on a v5e:
+    # scripts/price_sort_operands.py, PR 43), and nothing here needs it.
+    # Ties are probe lanes of one key (or duplicate build keys:
+    # `fallback`); the tag, part of the key, puts a key's build lane
+    # first whatever the sort does with them. Their order reaches an
+    # output only where the caller reads the sorted domain's lane order
+    # (`ordered`): there (packed, val) is sorted as TWO keys, a total
+    # key that IS the stable order (val rises with the row among build
+    # lanes and with the lane among probe lanes), for 1.3-2.3 ms more
+    # than one key. Otherwise every lane of a run receives the run's one
+    # build payload (a cummax over ALL its build lanes) and each probe
+    # lane carries its own lane index, so any order of a run will do.
+    assert rows or not ordered  # a packed payload is no tie-break
+    s_packed, s_val = jax.lax.sort(
+        (packed, val), num_keys=2 if ordered else 1, is_stable=False)
 
     one = s_packed.dtype.type(1)  # u32 (narrow carry keys) or u64
     pos = jnp.arange(n, dtype=jnp.int32)
@@ -335,7 +362,8 @@ def _probe_carry(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
     dest = jnp.where(cs.is_build, jnp.int32(lcap) + pos,
                      cs.s_val.astype(jnp.int32))
     res = (cs.bpay << np.uint64(1)) | cs.match_sorted.astype(jnp.uint64)
-    _d, o_res = jax.lax.sort((dest, res), num_keys=1)
+    # dest is a permutation: no ties, so no stable tie-break to pay for
+    _d, o_res = jax.lax.sort((dest, res), num_keys=1, is_stable=False)
     o_match = (o_res[:lcap] & np.uint64(1)) != 0
     o_bpay = o_res[:lcap] >> np.uint64(1)
 
@@ -389,25 +417,6 @@ def compacts(ub: UniqueBuild, probe_capacity: int, how: str) -> bool:
             and probe_capacity + ub.batch.capacity < (1 << 30))
 
 
-_TOP32 = np.uint32(1 << 31)
-
-
-def _first_matches(match, carry, C: int):
-    """-> (C,) int32: `carry` (uint32 under 2^31) of the first C lanes
-    where `match`, in lane order, then of unmatched lanes. ONE sort of a
-    single u32 operand: the miss bit rides above each lane's carry, so
-    no key needs a value operand beside it and none needs the stability
-    operand XLA adds to a `(pred, i32)` argsort. On v5e at 8,650,752
-    lanes that argsort costs 24.6 ms and this sort 8.1 (PERF.md section
-    6, PR 26)."""
-    key = jax.lax.sort(jnp.where(match, carry, carry | _TOP32),
-                       is_stable=False)
-    n = key.shape[0]
-    key = key[:C] if n >= C else jnp.concatenate(
-        [key, jnp.full((C - n,), _TOP32)])
-    return (key & ~_TOP32).astype(jnp.int32)
-
-
 def probe_unique_compact(probe: Batch, ub: UniqueBuild,
                          probe_on: Sequence[str], how: str,
                          capacity: int) -> CompactJoin:
@@ -415,7 +424,7 @@ def probe_unique_compact(probe: Batch, ub: UniqueBuild,
     ShrinkOp over the join computes), as ONE step that never restores
     probe order: the matched probe lanes are known in the sorted domain,
     so the destination resort is replaced by the compaction's own sort
-    there (_first_matches), one C-row gather of each match's probe lane
+    there (first_matches), one C-row gather of each match's probe lane
     index and build row index, and one C-row gather a side of the
     columns themselves. The build's columns never ride a sort, so their
     number and width are free (ops/groupjoin.group_join_aggregate
@@ -427,7 +436,7 @@ def probe_unique_compact(probe: Batch, ub: UniqueBuild,
     The lane order of an INNER join's result is a guarantee: live rows
     first (`sel` = lane < `length`), ascending in the join key, so rows
     of equal keys are adjacent. The matches are taken from the key
-    sort's own domain in position order (_first_matches over its
+    sort's own domain in position order (first_matches over its
     positions), and `compacts` admits only the narrow packing (`key << 1
     | tag`: exact and monotone in the key; a key outside [0, 2^30)
     raises `fallback`). An aggregate grouped by the key (and columns of
@@ -444,7 +453,7 @@ def probe_unique_compact(probe: Batch, ub: UniqueBuild,
         raise ValueError(f"no compacting probe for a {how} join of this "
                          f"build")
     C = capacity
-    cs = _carry_sort(probe, ub, probe_on, rows=True)
+    cs = _carry_sort(probe, ub, probe_on, rows=True, ordered=how == "inner")
     # a sentinel probe lane (dead lane or NULL key: top bit) pairs with
     # the same-index build sentinel and is no match: the key-liveness
     # guard of the resorting form, taken in the sorted domain
@@ -455,11 +464,10 @@ def probe_unique_compact(probe: Batch, ub: UniqueBuild,
     length = jnp.minimum(n_match, C).astype(jnp.int32)
     sel = jnp.arange(C) < length
     if how == "semi":
-        lane = _first_matches(match, cs.s_val, C)  # lane index < 2^30
+        lane = first_matches(match, cs.s_val, C)  # lane index < 2^30
     else:
         # one (C, 2) row gather: two 1-D gathers cost twice it
-        kidx = _first_matches(
-            match, jnp.arange(match.shape[0], dtype=jnp.uint32), C)
+        kidx = first_selected(match, C)
         got = jnp.stack([cs.s_val.astype(jnp.int32), cs.bpay],
                         axis=1)[kidx]
         lane = got[:, 0]
@@ -505,7 +513,11 @@ def probe_unique(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
 
     packed = jnp.concatenate([ub.packed, p_packed])
     iota = jnp.arange(n, dtype=jnp.int32)
-    s_packed, perm = jax.lax.sort((packed, iota), num_keys=1)
+    # unstable, as _carry_sort's: ties are probe lanes of one key (a
+    # second build lane of it raises `fallback`), each carrying its own
+    # position, and every lane of a run receives the run's one build row
+    s_packed, perm = jax.lax.sort((packed, iota), num_keys=1,
+                                  is_stable=False)
 
     pos = iota
     prev_packed = jnp.concatenate([s_packed[:1], s_packed[:-1]])
@@ -528,7 +540,9 @@ def probe_unique(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
     brow_sorted = jnp.clip(build_perm, 0, rcap - 1)
     res_payload = (brow_sorted << jnp.int32(1)) | match_sorted.astype(
         jnp.int32)
-    _d, o_payload = jax.lax.sort((dest, res_payload), num_keys=1)
+    # dest is a permutation: no ties
+    _d, o_payload = jax.lax.sort((dest, res_payload), num_keys=1,
+                                 is_stable=False)
     o_match = (o_payload[:lcap] & jnp.int32(1)).astype(jnp.bool_)
     o_brow = o_payload[:lcap] >> jnp.int32(1)
 

@@ -191,23 +191,36 @@ def exchange_bytes(batch: Batch, n_dev: int, bucket_cap: int) -> int:
 def _route_and_exchange(batch: Batch, dest: jnp.ndarray, axis_name: str,
                         n_dev: int, bucket_cap: int
                         ) -> Tuple[Batch, jnp.ndarray]:
-    """Shared router tail: ONE stable sort by destination carries every
-    lane along as an operand; each destination's rows are then a
-    contiguous run, cut out as one slice of bucket_cap rows; an
+    """Shared router tail: ONE sort by destination carries every lane
+    along as an operand; each destination's rows are then a contiguous
+    run in lane order, cut out as one slice of bucket_cap rows; an
     all_to_all over ICI for each lane. No gather and no scatter: on a
     v5e the sort costs a fourteenth of gathering the lanes by an argsort
     and a seventieth of placing them element by element (PERF.md
-    section 6, PR 28)."""
+    section 6, PR 28). The sort's key is `dest * cap + lane` as one u32:
+    unique, so the sort is unstable and XLA adds no tie-break operand
+    to the columns it carries, and a run keeps its lane order (an
+    overflowing run its first bucket_cap rows) as the stable sort by
+    `dest` alone kept it. A shard too long for that key,
+    (n_dev + 1) * cap >= 2^32, sorts by `dest`, stable."""
     dest = jnp.where(batch.sel, dest, n_dev)          # dead rows sort last
+    cap = batch.capacity
 
     # every column's values, and its validity where it has one (a tuple:
     # a dict would come back in sorted order, not the batch's)
     lanes, columns = jax.tree_util.tree_flatten(
         tuple(batch.columns.values()))
-    sorted_dest, *lanes = lax.sort((dest, *lanes), num_keys=1,
-                                   is_stable=True)
-    starts = jnp.searchsorted(sorted_dest, jnp.arange(n_dev + 1)
-                              ).astype(jnp.int32)
+    cuts = jnp.arange(n_dev + 1)
+    if (n_dev + 1) * cap < (1 << 32):
+        key = (dest.astype(jnp.uint32) * np.uint32(cap)
+               + jnp.arange(cap, dtype=jnp.uint32))
+        sorted_dest, *lanes = lax.sort((key, *lanes), num_keys=1,
+                                       is_stable=False)
+        cuts = cuts.astype(jnp.uint32) * np.uint32(cap)
+    else:
+        sorted_dest, *lanes = lax.sort((dest, *lanes), num_keys=1,
+                                       is_stable=True)
+    starts = jnp.searchsorted(sorted_dest, cuts).astype(jnp.int32)
     count = starts[1:] - starts[:-1]                   # rows per destination
     overflow = jnp.any(count > bucket_cap)
     # row j of bucket d is the j-th row of d's run: live while the run

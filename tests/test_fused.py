@@ -270,10 +270,10 @@ def _eqns(jaxpr):
 
 
 def _sorts(jaxpr):
-    """(lanes, dtype of the first operand, operands) of every sort
-    equation."""
+    """(lanes, dtype of the first operand, operands, is_stable) of every
+    sort equation."""
     return [(eqn.invars[0].aval.shape[0], str(eqn.invars[0].aval.dtype),
-             len(eqn.invars))
+             len(eqn.invars), eqn.params["is_stable"])
             for eqn in _eqns(jaxpr) if eqn.primitive.name == "sort"]
 
 
@@ -337,21 +337,15 @@ def _mesh_jaxpr(root, n_dev, limit):
         s.set(dist_flow.BROADCAST_LIMIT, old)
 
 
-@pytest.mark.parametrize("program", ["compact", "two_step", "mesh",
-                                     "mesh_lanes"])
-def test_q3_program_sorts_per_join(program, monkeypatch):
-    """Per join of Q3's fused program: exactly two sorts at lcap + rcap
-    lanes, the key sort and the compaction's single-operand sort, none
-    by destination and no argsort at lcap; with the one-step lowering
-    switched off (PR 25's program): key sort and resort at lcap + rcap,
-    and the Shrink's `(pred, i32)` argsort at lcap. On a four-shard mesh
-    (the mesh cell's program at this scale) the local semi join compacts
-    and the BY_HASH inner join takes the two steps
-    (_DistTracer._compactable), behind the router's two destination
-    sorts, which carry a side's lanes as operands; the join's lanes
-    follow the buckets, which the planner's estimates size (ISSUE 30);
-    without estimates (`mesh_lanes`: a tree built by hand carries none)
-    they are the buckets the lanes give, and the program the parent's."""
+Q3_PROGRAMS = ["compact", "two_step", "mesh", "mesh_lanes"]
+
+
+def _q3_program(program, monkeypatch):
+    """-> (jaxpr, flag_ops, compacted, two_step) of Q3's program at this
+    scale: `compact` is the served one-chip program, `two_step` the same
+    with the one-step lowering switched off (PR 25's program), `mesh`
+    the mesh cell's on four shards, `mesh_lanes` that without the
+    planner's estimates (a tree built by hand carries none)."""
     from cockroach_tpu.exec.operators import ScanOp, walk_operators
     from cockroach_tpu.sql.bind import plan_sql
     from cockroach_tpu.sql.plan_compile import compile_plan
@@ -374,38 +368,58 @@ def test_q3_program_sorts_per_join(program, monkeypatch):
         # lineitem's four chunks shard one to a device; orders + customer
         # (two chunks) are over the limit, so the inner join goes BY_HASH
         jaxpr, flag_ops = _mesh_jaxpr(cp.op, 4, 1 << 14)
-    else:
-        _prog, args = cp.runner._prepare()
-        compacted.clear()
-        two_step.clear()
-        scans = [n for n in walk_operators(cp.op) if isinstance(n, ScanOp)]
-        prog, _box = cp.runner._make_prog([id(s) for s in scans])
-        jaxpr = jax.make_jaxpr(prog)(*args)
+        return jaxpr, flag_ops, compacted, two_step
+    _prog, args = cp.runner._prepare()
+    compacted.clear()
+    two_step.clear()
+    scans = [n for n in walk_operators(cp.op) if isinstance(n, ScanOp)]
+    prog, _box = cp.runner._make_prog([id(s) for s in scans])
+    return jax.make_jaxpr(prog)(*args), None, compacted, two_step
+
+
+@pytest.mark.parametrize("program", Q3_PROGRAMS)
+def test_q3_program_sorts_per_join(program, monkeypatch):
+    """Per join of Q3's fused program: exactly two sorts at lcap + rcap
+    lanes, the key sort and the compaction's single-operand sort, none
+    by destination and no sort at lcap; with the one-step lowering
+    switched off (PR 25's program): key sort and resort at lcap + rcap,
+    and the Shrink's one-operand u32 sort at lcap (a `(pred, i32)`
+    argsort until PR 43). On a four-shard mesh (the mesh cell's program
+    at this scale) the local semi join compacts and the BY_HASH inner
+    join takes the two steps (_DistTracer._compactable), behind the
+    router's two destination sorts, which carry a side's lanes as
+    operands under ONE u32 key (destination x lanes + lane); the join's
+    lanes follow the buckets, which the planner's estimates size (ISSUE
+    30); without estimates (`mesh_lanes`) they are the buckets the lanes
+    give. No sort of any of them is stable (the fourth member of a
+    signature; PR 43): each key is total, or its ties reach no output."""
+    jaxpr, flag_ops, compacted, two_step = _q3_program(program, monkeypatch)
     sorts = _sorts(jaxpr.jaxpr)
     if program.startswith("mesh"):
         # the semi join orders x customer is local to a shard (16,384
         # lanes a side) and compacts: key sort and compaction sort
         assert compacted == [(16384, 16384, "semi")]
-        assert sorts.count((32768, "uint32", 2)) == 1
-        assert sorts.count((32768, "uint32", 1)) == 1
-        # the router: one stable sort by destination a side, carrying the
+        assert sorts.count((32768, "uint32", 2, False)) == 1
+        # the router: one sort by (destination, lane) a side, carrying the
         # side's four column lanes as operands: a shard's 16,384 lineitem
         # lanes into buckets of 8,192, its 4,096 shrunk orders lanes into
         # buckets of 4,096 by their lanes. The planner expects 32,851
         # lineitem rows to pass the date (8,212 a shard, 2,053 a
         # destination: still 8,192) and 1,439 orders (359 a shard, 89 a
         # destination): buckets of 256
-        assert sorts.count((16384, "int32", 5)) == 1
-        assert sorts.count((4096, "int32", 5)) == 1
+        assert sorts.count((16384, "uint32", 5, False)) == 1
+        assert sorts.count((4096, "uint32", 5, False)) == 1
         build_bucket = {"mesh": 256, "mesh_lanes": 4096}[program]
         # so the inner join sees 4 x 8,192 probe and 4 x 256 (or 4,096)
         # build lanes, and takes the two steps: key sort, resort to probe
-        # order, and the Shrink's argsort over the probe lanes
+        # order, and the Shrink's one-operand sort over the probe lanes,
+        # which is also what the semi join's compaction sorts
         n = 4 * 8192 + 4 * build_bucket
         assert two_step == [(32768, 4 * build_bucket, "inner")]
-        assert sorts.count((n, "uint32", 2)) == 1
-        assert sorts.count((n, "int32", 2)) == 1
-        assert sorts.count((32768, "bool", 2)) == 1
+        assert sorts.count((n, "uint32", 2, False)) == 1
+        assert sorts.count((n, "int32", 2, False)) == 1
+        assert sorts.count((32768, "uint32", 1, False)) == 2
+        assert not [s for s in sorts if s[1] == "bool"]
         # estimated buckets give the router's flag a restart target of its
         # own, ahead of the join's
         guards = [type(f).__name__ for f in flag_ops].count("_BucketGuard")
@@ -416,15 +430,15 @@ def test_q3_program_sorts_per_join(program, monkeypatch):
     cummaxes = _cummaxes(jaxpr.jaxpr)
     for lcap, rcap, _how in compacted:
         at_n = sorted(s[1:] for s in sorts if s[0] == lcap + rcap)
-        assert at_n == [("uint32", 1), ("uint32", 2)]
-        assert (lcap, "bool", 2) not in sorts
+        assert at_n == [("uint32", 1, False), ("uint32", 2, False)]
+        assert (lcap, "uint32", 1, False) not in sorts
         # the build's row index under the run id: one scan, where the
         # resorting form's 62-bit payload takes two
         assert cummaxes.count(lcap + rcap) == 1
     for lcap, rcap, _how in two_step:
         at_n = sorted(s[1:] for s in sorts if s[0] == lcap + rcap)
-        assert at_n == [("int32", 2), ("uint32", 2)]
-        assert sorts.count((lcap, "bool", 2)) == 1
+        assert at_n == [("int32", 2, False), ("uint32", 2, False)]
+        assert sorts.count((lcap, "uint32", 1, False)) == 1
         assert cummaxes.count(lcap + rcap) == 2
 
 

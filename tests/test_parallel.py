@@ -225,6 +225,15 @@ def _router_case(name):
         cols["b"] = (rng.random(n) > 0.5, None)
         # equal low words: only the high words tell the rows apart
         cols["h"] = ((np.arange(n, dtype=np.int64) << 32) | 7, None)
+    elif name == "every_destination_overflows":
+        # 16 live rows a destination a device into buckets of 8, dead
+        # rows between them: each run keeps its FIRST 8 rows in lane
+        # order, which is what the (destination, lane) key sorts by
+        sel[:] = np.arange(n) % 5 != 0
+        dest[:] = np.arange(n) % n_dev
+        bucket_cap = 8
+    elif name == "one_live_row":
+        sel[:] = np.arange(n) == 77
     elif name == "empty_range":
         cols["v"] = (rng.integers(0, 400, n).astype(np.int64), None)
         dest = None
@@ -236,8 +245,12 @@ def _router_case(name):
 @pytest.mark.parametrize("case", [
     "uniform", "one_destination_overflows",
     "run_starts_past_cap_minus_bucket", "all_dead", "validity_lane",
-    "bool_and_int64_high_words", "empty_range"])
+    "bool_and_int64_high_words", "every_destination_overflows",
+    "one_live_row", "empty_range"])
 def test_router_matches_numpy_router_bit_for_bit(case):
+    """The router's one sort is keyed on `destination x lanes + lane`, a
+    unique u32, and unstable (PR 43); the plain router walks the rows in
+    order, which is what the stable sort by destination alone gave."""
     from jax.sharding import PartitionSpec as P
     from cockroach_tpu.parallel.repartition import (
         _batch_pspecs, _route_and_exchange, range_repartition_local,
@@ -272,7 +285,8 @@ def test_router_matches_numpy_router_bit_for_bit(case):
     want, want_sel, want_ovf = _numpy_router(flat, sel, dest, n_dev,
                                              bucket_cap)
     assert np.array_equal(np.asarray(got_ovf), want_ovf)
-    assert want_ovf.any() == (case == "one_destination_overflows")
+    assert want_ovf.any() == (case in ("one_destination_overflows",
+                                       "every_destination_overflows"))
     assert np.array_equal(np.asarray(got_sel), want_sel)
     for n, (_, val) in cols.items():
         values = np.asarray(got[n].values)

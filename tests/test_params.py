@@ -571,15 +571,16 @@ def test_the_bound_statement_is_one_trace_with_its_params_tagged(tpch):
 _LOC = re.compile(r"\s*loc\([^)]*\)|^#loc.*$", re.M)
 # sha256 (first 16 hex digits) of the StableHLO text with `loc` stripped,
 # of the accepted cells' literal statements at SF 0.01, seed 7, capacity
-# 131,072, `vectorize = tpu`, CPU. Q1's was computed on the tree BEFORE
-# PR 31 (5b4835b) and has held since: a PR that leaves it alone loads the
-# cache entry its parent compiled. Q3's is PR 36's, which changed its
-# aggregate on purpose (in place over the order the compacting join left:
-# no hash, sort or gather at the Shrink's lanes; 3a343d0cc3806b81 from
-# PR 34 until then): a PR that moves it recompiles both Q3 cells and
-# measures them.
-LITERAL_PROGRAMS = {"tpch-sf1.q1-2streams": "43752e10756b36c7",
-                    "tpch-sf1.q3-1stream": "6f17850c22770fad"}
+# 131,072, `vectorize = tpu`, CPU. Both are PR 43's, which changed every
+# sort but ORDER BY's on purpose (no stable sort: tests/
+# test_sort_sites.py). Q1's moved with `Batch.compact` alone, which packs
+# its 12 result lanes by one u32 sort where a `(pred, i32)` argsort stood
+# (43752e10756b36c7 from before PR 31 until then: a PR that leaves it
+# alone loads the cache entry its parent compiled); Q3's with its joins'
+# key sorts (6f17850c22770fad from PR 36, 3a343d0cc3806b81 from PR 34): a
+# PR that moves it recompiles both Q3 cells and measures them.
+LITERAL_PROGRAMS = {"tpch-sf1.q1-2streams": "33fe0d227358292c",
+                    "tpch-sf1.q3-1stream": "8b40d3229b955834"}
 
 
 @pytest.mark.parametrize("cell", sorted(LITERAL_PROGRAMS))

@@ -257,10 +257,14 @@ def test_q18_program_sorts_per_join(served, monkeypatch):
     c_name (no u64 holds them at SF1; the row index rides the sort
     instead). Per compacted join: the key sort and the compaction's
     one-operand sort at lcap + rcap lanes, no resort by destination, ONE
-    cummax (the row index under the run id), and no argsort of its own
-    at lcap: the one `(pred, i32)` argsort left at 131,072 lanes is the
-    HAVING's Shrink over the aggregate's run-ends view. The customer
-    join has no Shrink above it and resorts, with the split cummax."""
+    cummax (the row index under the run id), and no sort of its own at
+    lcap: the sorts at 131,072 lanes are the int-key aggregate's (key,
+    packed inputs), its compaction of the run ends (at this scale; SF1
+    hands on the uncompacted view) and the HAVING's Shrink, one u32
+    operand each (`(u32, i32)` and a `(pred, i32)` argsort until PR
+    43). The customer
+    join has no Shrink above it and resorts, with the split cummax. No
+    sort is stable (a signature's last member)."""
     from tests.test_fused import _cummaxes, _record_joins, _sorts
 
     sess = _session(served)
@@ -286,12 +290,14 @@ def test_q18_program_sorts_per_join(served, monkeypatch):
     cummaxes = _cummaxes(jaxpr.jaxpr)
     for lcap, rcap, _how in compacted:
         at_n = sorted(s[1:] for s in sorts if s[0] == lcap + rcap)
-        assert at_n == [("uint32", 1), ("uint32", 2)]
+        assert at_n == [("uint32", 1, False), ("uint32", 2, False)]
         assert cummaxes.count(lcap + rcap) == 1
     assert sorted(s[1:] for s in sorts if s[0] == 2 * CAP) == [
-        ("int32", 2), ("uint32", 2)]
+        ("int32", 2, False), ("uint32", 2, False)]
     assert cummaxes.count(2 * CAP) == 2
-    assert sorts.count((CAP, "bool", 2)) == 1
+    assert sorted(s[1:] for s in sorts if s[0] == CAP) == [
+        ("uint32", 1, False), ("uint32", 1, False), ("uint32", 2, False)]
+    assert not [s for s in sorts if s[1] == "bool"]
 
 
 @pytest.mark.parametrize("value", ["312.005", "many"])

@@ -958,9 +958,11 @@ def test_dist_stages_and_device_seconds():
 
 @pytest.mark.parametrize("cell,seconds,mine", [
     ("tpch-sf1-mesh4.q3-1stream", "3", {"dist_sort_lanes_m"}),
-    # a statement of Q9 takes 0.2 to 0.5 s on the CPU backend: six
-    # seconds for the ten that `correct` wants (PERF.md section 7 (k))
-    ("tpch-sf1-q9-mesh4.q9-1stream", "6",
+    # a statement of Q9 takes 0.4 s on the CPU backend (0.25 until PR 43:
+    # XLA:CPU's unstable sort is the slower one there), more beside five
+    # workers: ten seconds for the ten that `correct` wants (PERF.md
+    # section 7 (k))
+    ("tpch-sf1-q9-mesh4.q9-1stream", "10",
      {"dist_sort_lanes_m", "dist_args_ms", "bind_ms", "bind_like_ms",
       "window_restarts", "prepared_hit_pct"}),
 ])
