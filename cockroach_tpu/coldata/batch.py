@@ -353,7 +353,7 @@ def first_matches(match, carry, C: int):
     8,388,608 lanes the argsort costs 26.4 ms standing alone and this
     sort 8.7 (scripts/price_sort_operands.py; PERF.md section 6). Every
     compaction of the served programs is this function: the compacting
-    joins (ops/sortjoin.py), the aggregates' run ends (ops/groupjoin.py),
+    joins (ops/sortjoin.py), the int-key aggregate's run ends (ops/agg.py),
     ShrinkOp and Batch.compact."""
     key = jax.lax.sort(jnp.where(match, carry, carry | _TOP32),
                        is_stable=False)

@@ -33,16 +33,17 @@ A HashAggOp over a Chain is a Fold only when it must be: while the Chain's
 materialized output (lanes x row bytes) fits the operator's workmem, the
 aggregate, grouped or scalar, takes the Chain as a Mat (a multi-chunk scan
 unpacks flat off the stacked image) and aggregates ONCE; over the budget
-it folds chunk by chunk, the out-of-core answer (_Tracer._agg_stream;
-_agg_partial counts fused.agg_materialized / fused.agg_folded, or
-fused.agg_int_key where ops/groupjoin.int_key_aggregate took a single
-integer key through ONE sort, or fused.agg_ordered where a compacting join
-left the input grouped and ops/agg.run_ends_aggregate aggregates it in
-place: _Tracer._ordered_input, or fused.agg_dense where every key has a
-small static domain, a dictionary's, a bool's or a range the planner read
-off the statistics, and ops/agg.dense_aggregate aggregates by slot, D
-lanes out, folded or not). A range-dense
-aggregate folds any Chain, and a TopKOp over a Chain always folds.
+it folds chunk by chunk, the out-of-core answer (_Tracer._agg_stream).
+_Tracer._agg_partial is the one place an aggregate's lowering is decided,
+and counts it, one event a traced HashAggOp: fused.agg_ordered where a
+compacting join left the input grouped and ops/agg.run_ends_aggregate
+aggregates it in place (_Tracer._ordered_input); fused.agg_int_key where
+ops/agg.int_key_aggregate takes a single integer key through ONE sort;
+fused.agg_dense where every key has a small static domain, a
+dictionary's, a bool's or a range the planner read off the statistics,
+and ops/agg.dense_aggregate aggregates by slot, D lanes out, folded or
+not; else the hash aggregate, fused.agg_folded over the budget and
+fused.agg_materialized within it. A TopKOp over a Chain always folds.
 
 Overflow posture matches streaming: joins and generic agg folds carry
 deferred overflow flags through the scan; the runner checks them once after
@@ -76,8 +77,8 @@ from cockroach_tpu.exec.operators import (
     child_operators, walk_operators,
 )
 from cockroach_tpu.ops.agg import (
-    _identity as _agg_identity, dense_aggregate, dense_merge,
-    hash_aggregate, run_ends_aggregate,
+    INT_KEY_AGG_FUNCS, _identity as _agg_identity, dense_aggregate,
+    dense_merge, hash_aggregate, int_key_aggregate, run_ends_aggregate,
 )
 from cockroach_tpu.ops import expr as _expr
 from cockroach_tpu.ops.sort import _sortable_int
@@ -191,36 +192,21 @@ def _validate(op: Operator) -> None:
     raise Unsupported(f"operator {type(op).__name__}")
 
 
-class _ModeBumpGuard:
-    """FlowRestart target that advances a fast path one level down its
-    config ladder (the attr rides the fused config key)."""
+class _IntKeyAggGuard:
+    """FlowRestart target of the int-key aggregate's FALLBACK flag (the
+    key's range or the packed inputs outgrew the sort's operands): the
+    first trip retries with a 64-bit key operand, the second turns the
+    kernel off for this operator, and the rerun hashes. Both attributes
+    ride the fused config key, so each state compiles its own program."""
 
-    def __init__(self, op, attr: str):
-        self.op = op
-        self.attr = attr
-
-    def widen(self):
-        setattr(self.op, self.attr, getattr(self.op, self.attr, 0) + 1)
-
-
-class _GroupJoinGuard:
-    """FlowRestart target for the group-join / int-key-aggregate
-    FALLBACK flags: first trip retries with wide keys/payloads (u64 +
-    split-cummax broadcast); second trip disables the fast path so the
-    rerun takes the general route. Both attributes ride the fused
-    config key, so each state compiles its own program."""
-
-    def __init__(self, agg: HashAggOp, wide_attr: str = "_gj_wide",
-                 ok_attr: str = "_gj_ok"):
+    def __init__(self, agg: HashAggOp):
         self.agg = agg
-        self.wide_attr = wide_attr
-        self.ok_attr = ok_attr
 
     def widen(self):
-        if not getattr(self.agg, self.wide_attr, False):
-            setattr(self.agg, self.wide_attr, True)
+        if not getattr(self.agg, "_ia_wide", False):
+            self.agg._ia_wide = True
         else:
-            setattr(self.agg, self.ok_attr, False)
+            self.agg._ia_ok = False
 
 
 class _Stream:
@@ -642,121 +628,25 @@ class _Tracer:
             self.flags.extend(_join_flags(guard, b_ovf, p_ovf, res.overflow))
             return res.batch, False
 
-    def _try_groupjoin(self, op: HashAggOp) -> Optional[Batch]:
-        """Aggregate-over-join collapse (ops/groupjoin.py): when the
-        GROUP BY keys on the join column (+ build columns a unique build
-        makes functionally dependent on it), ONE sort joins AND groups —
-        no destination resort, no row gather, no separate aggregation
-        sort. Returns None when the pattern or dtypes don't fit; deferred
-        flags rerun wider configs or the general path."""
-        from cockroach_tpu.ops.groupjoin import (
-            GJ_FUNCS, group_join_aggregate,
-        )
-
-        child = op.child
-        if isinstance(child, ShrinkOp):
-            # a planner shrink between agg and join is subsumed: the
-            # collapse compacts its own output
-            child = child.child
-        if not (isinstance(child, JoinOp) and child.how == "inner"
-                and child.grace_level == 0):
-            return None
-        if not op.group_by:
-            return None
-        if len(child.probe_on) != 1 or len(child.build_on) != 1:
-            return None
-        if _build_mode(child) != "unique":
-            return None
-        pon, bon = child.probe_on[0], child.build_on[0]
-        key_out = _keyed_on_join(child, op.group_by)
-        if key_out is None:
-            return None
-        probe_names = child.probe.schema.names()
-        rest = [g for g in op.group_by if g != key_out]
-        for a in op.internal:
-            if a.func not in GJ_FUNCS:
-                return None
-            if a.col is not None and a.col not in probe_names:
-                return None
-        for side, col in ((child.probe.schema, pon),
-                          (child.build.schema, bon)):
-            if not jnp.issubdtype(side.field(col).type.dtype, jnp.integer):
-                return None
-
-        def _packable(schema, names):
-            for nm in names:
-                dt = schema.field(nm).type.dtype
-                if dt == jnp.bool_ or jnp.issubdtype(dt, jnp.integer):
-                    continue
-                if jnp.issubdtype(dt, jnp.floating) and dt.itemsize <= 4:
-                    continue
-                return None
-            return True
-
-        agg_cols = [a.col for a in op.internal if a.col is not None]
-        if not _packable(child.probe.schema, agg_cols):
-            return None
-        # build columns gather at the compacted ends (row-index
-        # payload): no packability or width constraint on them. The
-        # ladder only widens the KEY + aggregate-input operand, then
-        # gives up to the general path.
-        mode = getattr(op, "_gj_bump", 0)
-        if mode > 1:
-            return None
-
-        # the collapse materializes the probe side whole: respect the
-        # operator budget (the streaming fold remains the bounded path)
-
-        est_rows = 0
-        for sub in walk_operators(child.probe):
-            if isinstance(sub, ScanOp):
-                est_rows = max(est_rows,
-                               self.stacked[id(sub)][0].shape[0]
-                               * sub.capacity)
-        if est_rows * self._row_bytes(child.probe.schema) > op.workmem:
-            return None
-        probe = self._mat(child.probe)
-        build = self._mat(child.build)
-        if (build.capacity * self._row_bytes(child.build.schema)
-                > child.workmem):
-            raise Unsupported("join build exceeds workmem")
-        ccap = min(
-            _pow2_at_least(max(16, min(probe.capacity, build.capacity))),
-            (1 << 16) * op.expansion)
-        self.sort_lanes += probe.capacity + build.capacity
-        res = group_join_aggregate(
-            probe, build, pon, bon, key_out,
-            probe.col(pon).values.dtype if key_out == pon
-            else build.col(bon).values.dtype,
-            rest, list(op.internal), ccap,
-            key64=mode >= 1, wide_payload=mode >= 1)
-        self.flag_ops.append(_ModeBumpGuard(op, "_gj_bump"))
-        self.flags.append(res.fallback)
-        self.flag_ops.append(op)
-        self.flags.append(res.overflow)
-        return op._final_project(res.batch)
-
     def _try_int_agg(self, op: HashAggOp) -> Optional[Batch]:
-        """Single-int-key GROUP BY via ops/groupjoin.int_key_aggregate:
+        """Single-int-key GROUP BY via ops/agg.int_key_aggregate:
         the key and the packed aggregate inputs ride ONE sort — no
         hashing, no argsort(perm) pair, no random gathers (those cost
         Q18's first aggregation ~400ms at 6M rows on v5e). Used when the
         materialized input fits the operator budget; emits the
         uncompacted run-ends view for large group counts (a downstream
         filter/shrink compacts far cheaper than per-group gathers)."""
-        from cockroach_tpu.ops.groupjoin import GJ_FUNCS, int_key_aggregate
-
         if not getattr(op, "_ia_ok", True) or len(op.group_by) != 1:
             return None
-        if op._dense_sizes is not None or op._range_dense is not None:
-            return None  # small static domains: the MXU dense path wins
+        if op._dense_sizes is not None:
+            return None  # a small static domain: by slot, no sort at all
         child_schema = op.child.schema
         key = op.group_by[0]
         if not jnp.issubdtype(child_schema.field(key).type.dtype,
                               jnp.integer):
             return None
         for a in op.internal:
-            if a.func not in GJ_FUNCS:
+            if a.func not in INT_KEY_AGG_FUNCS:
                 return None
             if a.col is not None:
                 dt = child_schema.field(a.col).type.dtype
@@ -783,7 +673,7 @@ class _Tracer:
             m, key, list(op.internal), out_capacity=out_cap,
             key64=getattr(op, "_ia_wide", False))
         self.sort_lanes += m.capacity
-        self.flag_ops.append(_GroupJoinGuard(op, "_ia_wide", "_ia_ok"))
+        self.flag_ops.append(_IntKeyAggGuard(op))
         self.flags.append(res.fallback)
         return res.batch
 
@@ -795,11 +685,9 @@ class _Tracer:
         its own, where the flat unpack of the stacked image fuses into the
         filter and the reduction, and a grouped step re-sorts acc + chunk
         (N chunks cost ~2N sorted-agg passes vs ONE at N times the lanes).
-        An input over the budget keeps the fold, the out-of-core answer;
-        the range-dense accumulator is its static domain and folds any
-        stream."""
+        An input over the budget keeps the fold, the out-of-core answer."""
         s = self._stream(op.child)
-        if s is not None and op._range_dense is None:
+        if s is not None:
             n_chunks = self.stacked[id(s.scan)][0].shape[0]
             mat_rows = s.cap * n_chunks
             if mat_rows * self._row_bytes(op.child.schema) <= op.workmem:
@@ -821,15 +709,15 @@ class _Tracer:
           over a unique build, one key a side;
         - every GROUP BY column is, through the MapOps' projections, a
           bare column of the join's output (renames followed), and
-          together they pass the group-join's test (_keyed_on_join): the
+          together they are keyed on the join (_keyed_on_join): the
           join key, and beside it only columns of the build side.
 
         -> None: not so. Else whether the live lanes are still the
         batch's first (`dense` of ops/agg.run_ends_aggregate): a filter
         on the way punches holes into the runs, which the aggregate then
         looks past with a few scans more; it groups exactly either way.
-        Materializes op.child (what every lowering but the group-join
-        starts with)."""
+        Materializes op.child: whether the Shrink lowered with its join
+        is known only after."""
         names, dense, node = list(op.group_by), True, op.child
         while isinstance(node, MapOp):
             for kind, payload in reversed(node.steps):
@@ -854,10 +742,6 @@ class _Tracer:
         return dense if id(node) in self._compacted else None
 
     def _mat_agg(self, op: HashAggOp) -> Batch:
-        out = self._try_groupjoin(op)
-        if out is not None:
-            stats.add("fused.agg_materialized")
-            return out
         acc, dense = self._agg_partial(op)
         return op._final_project(acc.compact() if dense else acc)
 
@@ -868,63 +752,32 @@ class _Tracer:
         (op._dense_sizes), the same layout whatever rows came in: partials
         of it merge lane-wise (dense_merge; the mesh's shards do), and
         compact() gives the D-lane batch every later operator runs at.
-        Counts the lowering taken, one event a traced HashAggOp."""
-        out = lowering = None
-        if op.group_by:
-            dense = self._ordered_input(op)
-            if dense is not None:
-                # in place: nothing hashed, so no collision flag and no
-                # re-seeded restart; the run-ends view at the Shrink's
-                # lanes is what top_k_batch, a Shrink, a MapOp and
-                # _pack_result take (Q18's first aggregate feeds them it)
-                out = run_ends_aggregate(
-                    self._mat(op.child), tuple(op.group_by),
-                    tuple(op.internal), dense=dense)
-                lowering = "fused.agg_ordered"
-        if out is None:
-            out = self._try_int_agg(op)
-            if out is not None:
-                lowering = "fused.agg_int_key"
-        # every fast path aggregates over the materialized input
-        s = self._agg_stream(op) if out is None else None
-        if out is None and op._dense_sizes is not None:
-            lowering = "fused.agg_dense"
-        stats.add(lowering or ("fused.agg_folded" if s is not None
-                               else "fused.agg_materialized"))
-        if out is not None:
-            return out, False
+        The ONE place the lowering is chosen, first that applies: in
+        place, the int-key sort, by slot, hash; counts it, one event a
+        traced HashAggOp."""
         group_by, internal = tuple(op.group_by), tuple(op.internal)
-        if op._range_dense is not None:
-            from cockroach_tpu.ops.agg import range_dense_aggregate
-
-            lo, span = op._range_dense
-            if s is not None:
-                def init(b):
-                    return range_dense_aggregate(b, group_by[0], lo,
-                                                 span, internal)
-
-                def step(carry, b):
-                    acc, fl = carry
-                    part, fl2 = range_dense_aggregate(
-                        b, group_by[0], lo, span, internal)
-                    return dense_merge(acc, part, group_by,
-                                       internal), fl | fl2
-
-                (acc, fl), chain_fl = self._fold(s, init, step)
-                self.flag_ops.extend(s.flag_ops + [op])
-                self.flags.extend(list(chain_fl) + [fl])
-                return acc, False
-            m2 = self._mat(op.child)
-            out, fl = range_dense_aggregate(m2, group_by[0], lo, span,
-                                            internal)
-            self.flag_ops.append(op)
-            self.flags.append(fl)
+        ordered = self._ordered_input(op) if group_by else None
+        if ordered is not None:
+            # in place: nothing hashed, so no collision flag and no
+            # re-seeded restart; the run-ends view at the Shrink's
+            # lanes is what top_k_batch, a Shrink, a MapOp and
+            # _pack_result take (Q18's first aggregate feeds them it)
+            stats.add("fused.agg_ordered")
+            return run_ends_aggregate(self._mat(op.child), group_by,
+                                      internal, dense=ordered), False
+        out = self._try_int_agg(op)
+        if out is not None:
+            stats.add("fused.agg_int_key")
             return out, False
+        # by slot and by hash aggregate once over the materialized input
+        # within the budget, and fold the chunk stream `s` over it
+        s = self._agg_stream(op)
         if op._dense_sizes is not None:
             # no hash and a statically complete key space: no collision,
             # no overflow. Ranged keys (op.key_domains) carry the ONE flag,
             # a live key outside its range, answered by op.widen(); keys of
             # dictionaries and bools alone add no flag to the program
+            stats.add("fused.agg_dense")
             sizes, doms = tuple(op._dense_sizes), op.key_domains
 
             def partial(b):
@@ -946,6 +799,8 @@ class _Tracer:
                 self.flag_ops.append(op)
                 self.flags.append(outside)
             return acc, True
+        stats.add("fused.agg_folded" if s is not None
+                  else "fused.agg_materialized")
         if s is not None:
             part_cap = s.cap if group_by else 1
             acc_cap = _pow2_at_least(part_cap * op.expansion)
@@ -1204,10 +1059,8 @@ class FusedRunner:
             out.append((type(op).__name__, op.expansion, op.workmem,
                         getattr(op, "seed", 0),
                         getattr(op, "build_mode", ""),
-                        getattr(op, "_range_dense", None),
                         tuple(sorted(
                             (getattr(op, "key_domains", None) or {}).items())),
-                        getattr(op, "_gj_bump", 0),
                         getattr(op, "_ia_ok", True),
                         getattr(op, "_ia_wide", False)))
         elif isinstance(op, SortOp):

@@ -581,7 +581,6 @@ class HashAggOp(Operator):
     def __init__(self, child: Operator, group_by: Sequence[str],
                  aggs: Sequence[AggSpec], expansion: int = 1,
                  workmem: Optional[int] = None,
-                 dense_range: Optional[Tuple[int, int]] = None,
                  key_domains: Optional[Dict[str, Tuple[int, int]]] = None):
         self.child = child
         self.group_by = list(group_by)
@@ -592,11 +591,6 @@ class HashAggOp(Operator):
         # outside its range (a row written after ANALYZE) raises the
         # deferred flag and widen() drops the ranges.
         self.key_domains = dict(key_domains) if key_domains else None
-        # planner hint (stats-derived): the single int group key's value
-        # range [lo, hi] — enables the scatter-based direct-address
-        # aggregation (ops/agg.py range_dense_aggregate). A stale range
-        # raises the deferred flag and widen() disables the path.
-        self.dense_range = dense_range
         self.user_aggs = list(aggs)
         self.expansion = expansion  # acc capacity multiplier (restart doubles)
         self.seed = 0  # hash-grouping seed (restart re-seeds)
@@ -641,20 +635,6 @@ class HashAggOp(Operator):
         self._finalize = jax.jit(self._final_project)
         self._make_kernels()
         self._make_dense()
-        self._range_dense = None
-        if (self._dense_sizes is None and dense_range is not None
-                and len(self.group_by) == 1):
-            import jax.numpy as _jnp
-
-            from cockroach_tpu.ops.agg import RANGE_DENSE_FUNCS
-            lo, hi = dense_range
-            span = hi - lo + 1
-            key_dtype = child.schema.field(self.group_by[0]).type.dtype
-            if (all(a.func in RANGE_DENSE_FUNCS for a in self.internal)
-                    and 0 < span <= (1 << 22)
-                    and _jnp.issubdtype(key_dtype, _jnp.integer)):
-                self._range_dense = (int(lo), int(span))
-                self._make_rd_kernels()
 
     def _make_dense(self):
         """The dense (sort-free) path for small static key domains — see
@@ -691,30 +671,6 @@ class HashAggOp(Operator):
         self._dense_final = jax.jit(
             lambda acc: self._final_project(acc.compact()))
 
-    def _make_rd_kernels(self):
-        """Jitted direct-address partial/fold — built ONCE (jit caches by
-        function identity; per-call closures would retrace every run)."""
-        from cockroach_tpu.ops.agg import (
-            dense_merge as _dm, range_dense_aggregate,
-        )
-
-        lo, span = self._range_dense
-        gb, internal = tuple(self.group_by), tuple(self.internal)
-        f = self._chunk_fn
-
-        @jax.jit
-        def rd_partial(item):
-            return range_dense_aggregate(f(item), gb[0], lo, span,
-                                         internal)
-
-        @jax.jit
-        def rd_fold(acc, item):
-            part, fl = range_dense_aggregate(f(item), gb[0], lo, span,
-                                             internal)
-            return _dm(acc, part, gb, internal), fl
-
-        self._rd_partial, self._rd_fold = rd_partial, rd_fold
-
     def _make_kernels(self):
         """(Re)build the jitted partial/merge kernels for the CURRENT seed
         — called at construction and again by widen() after a re-seed."""
@@ -732,16 +688,11 @@ class HashAggOp(Operator):
         self._stacked_jit: Dict[tuple, Callable] = {}
 
     def widen(self):
-        """FlowRestart remedy: a tripped range-dense flag (stale stats)
-        disables that path, and a dense aggregate's (a key outside its
-        range: the only flag it raises) drops the ranges, so that the
-        keys without a static domain hash again; otherwise double the
-        accumulator expansion (group overflow) AND re-seed the key hash
-        (collision)."""
-        if self._range_dense is not None:
-            self._range_dense = None
-            self.dense_range = None
-            return
+        """FlowRestart remedy: a dense aggregate's flag (a key outside
+        its range, stale statistics: the only flag it raises) drops the
+        ranges, so that the keys without a static domain hash again;
+        otherwise double the accumulator expansion (group overflow) AND
+        re-seed the key hash (collision)."""
         if self.key_domains:
             self.key_domains = None
             self._make_dense()
@@ -842,13 +793,10 @@ class HashAggOp(Operator):
         per-chunk partial+merge over the stacked scan image (the same
         machinery fused._Tracer._fold uses inside whole-query programs).
         Returns ([result batches], restart?) or None when the input isn't
-        a resident stacked scan, the accumulator would blow workmem (the
-        grace path needs the chunk stream), or the path is range-dense
-        (its stale-stats flag plumbing stays on the loop)."""
+        a resident stacked scan or the accumulator would blow workmem (the
+        grace path needs the chunk stream)."""
         from cockroach_tpu.exec import spill as _spill
 
-        if self._range_dense is not None:
-            return None
         sc = self._stacked_scan()
         if sc is None:
             return None
@@ -943,24 +891,6 @@ class HashAggOp(Operator):
             # end-of-stream readback, as the hash fold's below)
             if carry is not None and self.key_domains and bool(carry[1]):
                 raise FlowRestart(self)  # widen() drops the ranges
-            return
-
-        if self._range_dense is not None:
-            acc = None
-            flag = jnp.bool_(False)
-            for item in self._stream():
-                with stats.timed("agg.fold"):
-                    if acc is None:
-                        acc, fl = self._rd_partial(item)
-                    else:
-                        acc, fl = self._rd_fold(acc, item)
-                    flag = flag | fl
-            if acc is not None:
-                yield self._finalize(acc)
-            # deferred: ONE end-of-stream readback (restart discards the
-            # sink's output, same posture as the hash fold below)
-            if bool(flag):
-                raise FlowRestart(self)  # stale range: widen() disables
             return
 
         acc: Optional[Batch] = None

@@ -12,6 +12,11 @@ view**, reading each run's result at its last position:
 - min/max/bool: segmented associative scan (reset at run boundaries);
 - any_not_null: segmented "first live value" scan.
 
+Beside it: `dense_aggregate` (every key has a small static domain: group
+g at slot g, no sort), `run_ends_aggregate` (input already grouped: in
+place) and `int_key_aggregate` (one integer key, sums and counts: the key
+and the packed inputs ride ONE sort).
+
 No scatter appears anywhere on this path; XLA lowers sorts + scans +
 gathers to fast vector code. Group ids come out key-sorted, which also
 makes a downstream ORDER BY on the group keys a no-op.
@@ -27,13 +32,17 @@ answer to the reference's datum-backed decimal fallback (col/coldataext).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from cockroach_tpu.coldata.batch import Batch, Column, mask_padding
+from cockroach_tpu.coldata.batch import (
+    Batch, Column, first_selected, mask_padding,
+)
+from cockroach_tpu.ops.bitpack import pack_lanes, plan_pack
 from cockroach_tpu.ops.hashtable import SortedGroups, sorted_groups
 from cockroach_tpu.ops.prefix import (
     blocked_assoc_scan, blocked_cummax, blocked_cumsum,
@@ -912,85 +921,6 @@ _DENSE_MERGE = {
 }
 
 
-RANGE_DENSE_FUNCS = ("sum", "count", "count_star", "min", "max",
-                     "sum_hi32", "sum_lo32")
-
-
-def range_dense_aggregate(batch: Batch, key_name: str, lo: int, span: int,
-                          aggs: Sequence[AggSpec]):
-    """GROUP BY over ONE integer key with a statically known value range
-    [lo, lo+span): group (key-lo) lives at LANE (key-lo) — a pure
-    SCATTER aggregation, no sort, no gathers, no hashing (the classic
-    direct-address aggregation; stats supply the range, sql/stats.py).
-
-    -> (Batch, out_of_range flag). Rows whose key falls outside the
-    range raise the deferred flag; the restart disables this path (the
-    stats were stale). Output merges lane-wise with dense_merge. A v5e
-    6M-row scatter costs ~55 ms/lane-array — the sorted-agg path pays
-    ~3x that in sort-view and extraction row-gathers alone."""
-    c = batch.col(key_name)
-    key = c.values.astype(jnp.int64)
-    live = batch.sel if c.validity is None else (batch.sel & c.validity)
-    idx = key - jnp.int64(lo)
-    in_range = (idx >= 0) & (idx < span)
-    flag = jnp.any(live & ~in_range)
-    if c.validity is not None:
-        # SQL groups NULL keys as their own group; the direct-address
-        # space has no NULL slot — a live NULL key disables this path
-        flag = flag | jnp.any(batch.sel & ~c.validity)
-    ok = live & in_range
-    # mode="drop": deselected / out-of-range rows scatter nowhere
-    at = jnp.where(ok, idx, jnp.int64(span)).astype(jnp.int32)
-
-    present = jnp.zeros((span,), jnp.bool_).at[at].max(True, mode="drop")
-    out_cols: dict = {}
-    out_cols[key_name] = Column(
-        (jnp.arange(span, dtype=jnp.int64) + lo).astype(c.values.dtype))
-    counts_cache: dict = {}
-
-    def live_count(col: Optional[str]):
-        if col not in counts_cache:
-            src = ok if col is None else (
-                ok & batch.col(col).valid_mask())
-            counts_cache[col] = jnp.zeros((span,), jnp.int64).at[
-                jnp.where(src, at, span)].add(1, mode="drop")
-        return counts_cache[col]
-
-    for a in aggs:
-        if a.func not in RANGE_DENSE_FUNCS:
-            raise AssertionError(f"range-dense unsupported: {a.func}")
-        if a.func == "count_star":
-            out_cols[a.out] = Column(live_count(None))
-            continue
-        vc = batch.col(a.col)
-        vlive = ok & vc.valid_mask()
-        any_live = live_count(a.col) > 0
-        if a.func == "count":
-            out_cols[a.out] = Column(live_count(a.col))
-        elif a.func in ("sum", "sum_hi32", "sum_lo32"):
-            v = vc.values
-            if a.func != "sum":
-                v = _wide_half(a.func, v)
-            acc = (v.dtype if jnp.issubdtype(v.dtype, jnp.integer)
-                   else jnp.float32)
-            vv = jnp.where(vlive, v, jnp.zeros((), v.dtype)).astype(acc)
-            out_cols[a.out] = Column(
-                jnp.zeros((span,), acc).at[
-                    jnp.where(vlive, at, span)].add(vv, mode="drop"),
-                any_live)
-        else:  # min / max
-            ident = _identity(a.func, vc.values.dtype)
-            init = jnp.full((span,), ident, vc.values.dtype)
-            vv = jnp.where(vlive, vc.values, ident)
-            sat = jnp.where(vlive, at, span)
-            acc = (init.at[sat].min(vv, mode="drop") if a.func == "min"
-                   else init.at[sat].max(vv, mode="drop"))
-            out_cols[a.out] = Column(acc, any_live)
-    out_cols = mask_padding(out_cols, present)
-    out = Batch(out_cols, present, jnp.sum(present).astype(jnp.int32))
-    return out, flag
-
-
 def dense_merge(a: Batch, b: Batch, group_by: Sequence[str],
                 aggs: Sequence[AggSpec]) -> Batch:
     """Lane-aligned merge of two dense_aggregate outputs (same key space):
@@ -1001,8 +931,8 @@ def dense_merge(a: Batch, b: Batch, group_by: Sequence[str],
         ca, cb = a.col(n), b.col(n)
         # the per-lane key decode is identical in both partials, but
         # mask_padding ZEROES key values on lanes dead in that partial —
-        # a lane live only in b must take b's values (latent until a
-        # partial missed a group entirely; exposed by range-dense folds)
+        # a lane live only in b must take b's values (a partial may
+        # miss a group entirely)
         if ca.validity is None:
             out_cols[n] = Column(jnp.where(a.sel, ca.values, cb.values))
         else:
@@ -1055,7 +985,7 @@ def run_ends_aggregate(batch: Batch, group_by: Sequence[str],
     avg's parts, an all-dead batch).
 
     Output: the UNCOMPACTED run-ends view, the form
-    ops/groupjoin.int_key_aggregate emits with out_capacity=0: a batch at
+    int_key_aggregate emits with out_capacity=0: a batch at
     the input's capacity with each group ONCE, at its run's last live
     lane (`sel` marks those lanes, `length` counts them), groups in input
     run order. top_k_batch, ShrinkOp, MapOp and a join's build take a
@@ -1084,3 +1014,160 @@ def ordered_aggregate(batch: Batch, group_by: Sequence[str],
     whose input is only PARTIALLY grouped still gets correct results from
     the flow layer's merge fold — split runs re-merge by key there."""
     return run_ends_aggregate(batch, group_by, aggs).compact()
+
+
+# What int_key_aggregate evaluates: int64 sums and counts over the packed
+# inputs' bits, which commute exactly (its sort is unstable).
+INT_KEY_AGG_FUNCS = ("sum", "count", "count_star")
+
+
+class IntKeyAggResult(NamedTuple):
+    batch: Batch           # group rows: compacted, or the run-ends view
+    fallback: jnp.ndarray  # bool: rerun with key64, then by hash
+    overflow: jnp.ndarray  # bool: more groups than out_capacity
+
+
+def int_key_aggregate(
+    batch: Batch, key_col: str, aggs: Sequence[AggSpec],
+    out_capacity: int = 0, key64: bool = False,
+) -> IntKeyAggResult:
+    """GROUP BY a single integer column without hashing, permutation
+    gathers, or an inverse sort: sort (biased key, packed agg inputs)
+    directly, then segmented sums as cumsum differences.
+
+    The general path (ops/hashtable.sorted_groups + hash_aggregate) pays
+    argsort(hash) + argsort(perm), two full random key gathers and one
+    gather per aggregate input. Here the key and the inputs (packed into
+    one u64 operand, ops/bitpack.py) RIDE the one sort; a key range or
+    packed inputs too wide for the operands raise `fallback`.
+
+    out_capacity == 0 returns the UNCOMPACTED run-ends view
+    (run_ends_aggregate's output form): a batch at input capacity whose
+    sel marks one lane per group — the right shape when a selective
+    filter/shrink follows (Q18's HAVING). Per-group totals use that
+    cumsums of bias-packed (non-negative) inputs are non-decreasing: the
+    previous group end's running value arrives via one cummax + lane
+    shift. A NULL key forms its own single group (SQL GROUP BY
+    semantics)."""
+    cap = batch.capacity
+    c = batch.col(key_col)
+    live = batch.sel
+    k = c.values.astype(jnp.int64)
+    valid_live = live if c.validity is None else (live & c.validity)
+    null_live = live & ~valid_live
+
+    big = np.int64((1 << 62) - 1)
+    klo = jnp.min(jnp.where(valid_live, k, big))
+    khi = jnp.max(jnp.where(valid_live, k, -big - 1))
+    anyv = jnp.any(valid_live)
+    klo = jnp.where(anyv, klo, 0)
+    key_budget = 62 if key64 else 30
+    key_flag = anyv & ((khi - klo) >= (jnp.int64(1) << key_budget))
+
+    kdt = jnp.uint64 if key64 else jnp.uint32
+    TOP = kdt(1) << (np.uint32(63) if key64 else np.uint32(31))
+    kb = jax.lax.bitcast_convert_type(
+        jnp.clip(k - klo, 0, jnp.int64(1) << key_budget),
+        jnp.uint64).astype(kdt)
+    # live NULL keys share ONE sentinel (one NULL group); dead lanes a
+    # different one — runs never mix liveness classes
+    gk = jnp.where(valid_live, kb, jnp.where(null_live, TOP, TOP | kdt(2)))
+
+    agg_cols: List[str] = []
+    for a in aggs:
+        if a.col is not None and a.col not in agg_cols:
+            agg_cols.append(a.col)
+    aplan = plan_pack(batch, agg_cols)
+    apayv = pack_lanes(batch, aplan)
+    agg_flag = aplan.total_bits > jnp.int32(63)
+
+    # unstable: ties are the lanes of one group (or dead lanes), and
+    # their order reaches no output. INT_KEY_AGG_FUNCS are sums and counts in
+    # int64 over the packed inputs' bits, which commute exactly, read at
+    # run ENDS only (every other lane of every output column is zeroed
+    # and deselected below); the stable sort's tie-break would be a
+    # third operand at the input's lanes (7.4 ms of 29.7 at 8,388,608
+    # lanes on a v5e: scripts/price_sort_operands.py)
+    sgk, sgv = jax.lax.sort((gk, apayv), num_keys=1, is_stable=False)
+    prev = jnp.concatenate([~sgk[:1], sgk[:-1]])
+    newrun = sgk != prev
+    newrun = newrun.at[0].set(True)
+    live_s = sgk != (TOP | kdt(2))
+    nxt = jnp.concatenate([newrun[1:], jnp.ones((1,), jnp.bool_)])
+    is_end = nxt & live_s
+
+    def extract(a: AggSpec):
+        """(values i64 biased, valid bool) per sorted lane."""
+        i = aplan.names.index(a.col)
+        off = aplan.offsets[i].astype(jnp.uint64)
+        raw = sgv >> off
+        avalid = live_s
+        if aplan.nullable[i]:
+            avalid = live_s & ((raw & np.uint64(1)) != 0)
+            raw = raw >> np.uint64(1)
+        mask = jnp.where(
+            aplan.widths[i] >= 64, np.uint64(0xFFFFFFFFFFFFFFFF),
+            (jnp.uint64(1) << aplan.widths[i].astype(jnp.uint64))
+            - np.uint64(1))
+        return jax.lax.bitcast_convert_type(raw & mask, jnp.int64), avalid
+
+    def seg_total(cum):
+        """Per-run totals at end lanes (uncompacted): cum is
+        NON-DECREASING, so the previous end's running value is
+        shift1(cummax(cum at ends))."""
+        t = jnp.where(is_end, cum, 0)
+        carry = jax.lax.cummax(t)
+        prev_end = jnp.concatenate([jnp.zeros((1,), cum.dtype),
+                                    carry[:-1]])
+        return jnp.where(is_end, cum - prev_end, 0)
+
+    cnt_all = jnp.cumsum(live_s.astype(jnp.int64))
+    cols: Dict[str, Column] = {}
+    kv = sgk.astype(jnp.int64) + klo  # un-bias (no tag bit here)
+    kv = jnp.where(live_s & (sgk < TOP), kv, 0)
+    key_validity = None
+    if c.validity is not None:
+        key_validity = is_end & (sgk < TOP)
+    cols[key_col] = Column(
+        jnp.where(is_end, kv, 0).astype(c.values.dtype), key_validity)
+
+    sums = []
+    for a in aggs:
+        if a.func == "count_star":
+            sums.append((a, seg_total(cnt_all), None, None))
+        else:
+            v, avalid = extract(a)
+            # non-nullable inputs: valid-count cumsum == cnt_all
+            i_n = aplan.names.index(a.col)
+            cum_valid = (jnp.cumsum(avalid.astype(jnp.int64))
+                         if aplan.nullable[i_n] else cnt_all)
+            nv = seg_total(cum_valid)
+            if a.func == "count":
+                sums.append((a, nv, None, None))
+            else:
+                i = aplan.names.index(a.col)
+                s = seg_total(jnp.cumsum(jnp.where(avalid, v, 0)))
+                sums.append((a, s + nv * aplan.los[i], nv, None))
+    for a, tot, nv, _ in sums:
+        if a.func == "sum":
+            cols[a.out] = Column(jnp.where(nv > 0, tot, 0), nv > 0)
+        else:
+            cols[a.out] = Column(tot, None)
+
+    n_groups = jnp.sum(is_end)
+    fallback = key_flag | agg_flag
+    if not out_capacity:
+        out = Batch(cols, is_end, n_groups.astype(jnp.int32))
+        return IntKeyAggResult(out, fallback, jnp.bool_(False))
+    # compacted variant: one single-operand u32 sort + tiny gathers
+    C = out_capacity
+    top = first_selected(is_end, C)
+    valid = jnp.arange(C) < n_groups
+    ccols = {}
+    for nme, col in cols.items():
+        v = jnp.where(valid, col.values[top], jnp.zeros((),
+                                                        col.values.dtype))
+        ccols[nme] = Column(v, None if col.validity is None
+                            else (col.validity[top] & valid))
+    out = Batch(ccols, valid, jnp.minimum(n_groups, C).astype(jnp.int32))
+    return IntKeyAggResult(out, fallback, n_groups > C)

@@ -427,8 +427,7 @@ def probe_unique_compact(probe: Batch, ub: UniqueBuild,
     there (first_matches), one C-row gather of each match's probe lane
     index and build row index, and one C-row gather a side of the
     columns themselves. The build's columns never ride a sort, so their
-    number and width are free (ops/groupjoin.group_join_aggregate
-    fetches its build columns the same way). A semi join emits nothing
+    number and width are free. A semi join emits nothing
     of the build: its compaction carries the probe lane index itself and
     gathers probe rows only. Inner and semi joins over a build that
     `compacts` only.

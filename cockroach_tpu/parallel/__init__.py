@@ -11,11 +11,9 @@ stays host-side (rpc/ in a later milestone).
 
 from cockroach_tpu.parallel.mesh import make_mesh, host_mesh
 from cockroach_tpu.parallel.repartition import (
-    hash_repartition_local, distributed_aggregate, distributed_hash_join,
-    shard_batch,
+    hash_repartition_local, shard_batch,
 )
 
 __all__ = [
-    "make_mesh", "host_mesh", "hash_repartition_local",
-    "distributed_aggregate", "distributed_hash_join", "shard_batch",
+    "make_mesh", "host_mesh", "hash_repartition_local", "shard_batch",
 ]

@@ -116,7 +116,7 @@ from cockroach_tpu.coldata.batch import Batch, Column, Schema, concat_batches
 from cockroach_tpu.exec import stats
 from cockroach_tpu.exec.fused import (
     EXCHANGE, MERGE, RESULT_CAP, RESULT_SCOPE, HBMExceeded, Unsupported,
-    _ModeBumpGuard, _Tracer, _pack_result, _unpack_result,
+    _Tracer, _pack_result, _unpack_result,
     bound_program_args, compile_via_vault, lower_program, scope,
     takes_params,
 )
@@ -225,7 +225,7 @@ def side_bucket(by_lanes: int, by_est: Optional[int], n_dev: int,
     return min(by_lanes, max(exchange_bucket(0, n_dev), by_est // parts))
 
 
-class _BucketGuard(_ModeBumpGuard):
+class _BucketGuard:
     """FlowRestart target of a BY_HASH join's router flags where its
     buckets were sized from the planner's estimate: a full bucket means
     the estimate was low, and widen() sends the join back to the buckets
@@ -236,10 +236,10 @@ class _BucketGuard(_ModeBumpGuard):
     ATTR = "_lanes_buckets"
 
     def __init__(self, op: JoinOp):
-        super().__init__(op, self.ATTR)
+        self.op = op
 
     def widen(self):
-        super().widen()
+        setattr(self.op, self.ATTR, getattr(self.op, self.ATTR, 0) + 1)
         default_registry().counter(
             "sql_distsql_bucket_restarts_total",
             "flow restarts that sent a BY_HASH join from buckets sized by "
@@ -366,18 +366,13 @@ class _DistTracer(_Tracer):
         self.buckets[(side, id(op))] = (bucket, by_lanes)
         return bucket
 
-    def _try_groupjoin(self, op):
-        """The single-chip aggregate-over-join collapse (exec/fused.py)
-        computes FINAL groups — inside shard_map the input is one shard,
-        so it would bypass the two-stage distributed aggregation and
-        emit shard-local sums as final. Disabled here; the distributed
-        protocol (partial agg + mesh merge) owns correctness. A
-        distributed collapse (a2a co-partition by group key, THEN local
-        group-join) is a future optimization."""
-        return None
-
     def _try_int_agg(self, op):
-        return None  # same two-stage reasoning as _try_groupjoin
+        # ops/agg.int_key_aggregate hands back the run-ends view of ONE
+        # shard's rows, at the input's lanes; no cell groups by an
+        # integer key on the mesh, so that view has never been gathered
+        # and merged across shards. Until one does (Q18 on four shards,
+        # ROADMAP R6), a shard's partial is the hash aggregate's.
+        return None
 
     # -- how a co-partitioned join's sides reach it ---------------------------
     #
@@ -446,7 +441,7 @@ class _DistTracer(_Tracer):
             return super()._mat_agg(op)
         group_by = tuple(op.group_by)
         # local partial: the single-chip lowering's accumulator, before
-        # finalization (a group-join would emit final groups: _try_groupjoin)
+        # finalization
         local, dense = self._agg_partial(op)
         with self._scope(op, MERGE):
             if dense:
@@ -749,8 +744,7 @@ class DistFusedRunner:
                 x = repart.get(id(op))
                 out.append((type(op).__name__, op.expansion, op.workmem,
                             getattr(op, "seed", 0),
-                            getattr(op, "build_mode", ""),
-                            getattr(op, "_range_dense", None))
+                            getattr(op, "build_mode", ""))
                            + ((x.probe_est, x.build_est)
                               if x is not None and x.estimated else ()))
             elif isinstance(op, SortOp):

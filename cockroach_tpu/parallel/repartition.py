@@ -1,20 +1,15 @@
-"""Collective repartitioning + distributed operators (shard_map kernels).
+"""Collective repartitioning (shard_map kernels) and mesh placement.
 
 Reference mapping (SURVEY.md §2.9):
 - P3 BY_HASH repartition (colflow/routers.go:442 HashRouter -> outbox ->
-  gRPC FlowStream -> inbox) ==> `hash_repartition_local`: one stable
-  on-chip sort by destination that carries every lane as an operand, each
+  gRPC FlowStream -> inbox) ==> `hash_repartition_local`: one on-chip
+  sort by destination that carries every lane as an operand, each
   destination's bucket cut out of the sorted lanes as ONE slice (no
   gather, no scatter), then `lax.all_to_all` over ICI, once per lane per
-  batch round.
-- P4 MIRROR broadcast ==> `all_gather` of the small side (used by
-  `distributed_aggregate`'s merge phase).
-- Two-stage distributed aggregation (partial aggregators on data nodes +
-  final on gateway, distsql_physical_planner.go) ==> partial per chip ->
-  all_gather -> replicated merge (group counts are post-agg small).
-- Distributed hash join (both sides routed BY_HASH on the join key so each
-  node joins one partition) ==> co-partition both sides with the same hash
-  -> local join per chip.
+  batch round. parallel/dist_flow.py routes both sides of a BY_HASH join
+  through it, so that each chip joins one partition.
+- P1/P2 placement: `shard_batch`, `put_sharded_blocks` (ingest-time, one
+  host-link crossing a replica), `put_replicated` (the P4 MIRROR side).
 
 Buckets are fixed-capacity (static shapes); overflow is detected and
 psum-reduced so the host can retry with a bigger factor — the collective
@@ -46,10 +41,8 @@ def shard_map(f, **kw):
     kw[_CHECK_KW] = kw.pop("check_rep", False)
     return _shard_map(f, **kw)
 
-from cockroach_tpu.coldata.batch import Batch, Column, mask_padding
-from cockroach_tpu.ops.agg import AggSpec, hash_aggregate
+from cockroach_tpu.coldata.batch import Batch
 from cockroach_tpu.ops.hash import hash_columns
-from cockroach_tpu.ops.join import hash_join
 
 
 def _batch_pspecs(batch: Batch, axis: Optional[str]):
@@ -120,11 +113,6 @@ def put_replicated(host, mesh: Mesh):
     """Place one host array fully replicated over the mesh (the P4
     MIRROR broadcast side): every device gets its own copy."""
     return jax.device_put(host, NamedSharding(mesh, P()))
-
-
-def _local_length(batch: Batch) -> Batch:
-    return Batch(batch.columns, batch.sel,
-                 jnp.sum(batch.sel).astype(jnp.int32))
 
 
 def hash_repartition_local(batch: Batch, key_names: Sequence[str],
@@ -248,105 +236,3 @@ def _route_and_exchange(batch: Batch, dest: jnp.ndarray, axis_name: str,
     sel = a2a(live)
     out = Batch(cols, sel, jnp.sum(sel).astype(jnp.int32))
     return out, overflow
-
-
-DEFAULT_PARTIAL_CAP = 4096  # gathered merge work = n_dev * partial_cap rows
-
-
-def distributed_aggregate(batch: Batch, mesh: Mesh, group_by: Sequence[str],
-                          aggs: Sequence[AggSpec], axis: str = "x",
-                          merge_aggs: Optional[Sequence[AggSpec]] = None,
-                          partial_cap: Optional[int] = None
-                          ) -> Tuple[Batch, jnp.ndarray]:
-    """Jittable two-stage distributed GROUP BY over a row-sharded batch:
-    per-chip partial agg -> all_gather partials -> replicated merge.
-
-    Partials are truncated to `partial_cap` live groups before the gather
-    (default DEFAULT_PARTIAL_CAP, capped at the input capacity) — the
-    reference's post-agg gather is small by construction for the same
-    reason. Returns (merged batch, overflow flag): overflow is True if any
-    chip had more than partial_cap live groups, in which case the result
-    dropped groups and the host must retry with a bigger cap (the same
-    retry contract as hash_repartition_local).
-
-    `aggs` must be mergeable as-is (avg decomposition is the flow layer's
-    job); `merge_aggs` defaults to the canonical merge of `aggs`.
-    """
-    from cockroach_tpu.exec.operators import _MERGE_FUNC
-
-    if merge_aggs is None:
-        merge_aggs = [AggSpec(_MERGE_FUNC[a.func], a.out, a.out) for a in aggs]
-    group_by = tuple(group_by)
-    aggs = tuple(aggs)
-    merge_aggs = tuple(merge_aggs)
-    if partial_cap is None:
-        partial_cap = min(DEFAULT_PARTIAL_CAP, batch.capacity)
-
-    def step(local: Batch):
-        local = _local_length(local)
-        part = hash_aggregate(local, group_by, aggs)
-        overflow = part.length > partial_cap
-        if partial_cap < part.capacity:
-            idx = jnp.arange(partial_cap, dtype=jnp.int32)
-            sel = idx < part.length
-            length = jnp.minimum(part.length, jnp.int32(partial_cap))
-            part = part.gather(idx, sel=sel, length=length)
-            part = Batch(mask_padding(part.columns, sel), sel, length)
-        ag = lambda x: lax.all_gather(x, axis, tiled=True)
-        cols = {n: Column(ag(c.values),
-                          None if c.validity is None else ag(c.validity))
-                for n, c in part.columns.items()}
-        sel = ag(part.sel)
-        gathered = Batch(cols, sel, jnp.sum(sel).astype(jnp.int32))
-        merged = hash_aggregate(gathered, group_by, merge_aggs)
-        return merged, lax.psum(overflow.astype(jnp.int32), axis) > 0
-
-    # a single spec broadcasts over the whole output pytree: every leaf of
-    # the merged result (including the scalar length) is replicated
-    fn = shard_map(step, mesh=mesh,
-                   in_specs=(_batch_pspecs(batch, axis),),
-                   out_specs=(P(), P()),
-                   check_rep=False)
-    return fn(batch)
-
-
-def distributed_hash_join(probe: Batch, build: Batch, mesh: Mesh,
-                          probe_on: Sequence[str], build_on: Sequence[str],
-                          how: str = "inner", axis: str = "x",
-                          bucket_cap: Optional[int] = None,
-                          out_capacity: Optional[int] = None,
-                          seed: int = 0) -> Tuple[Batch, jnp.ndarray]:
-    """Jittable distributed equi-join: co-partition both sides BY_HASH over
-    ICI, join each partition locally. Output stays row-sharded.
-
-    Returns (sharded result batch, overflow flag) — overflow set if any
-    bucket or local join capacity overflowed anywhere (host retries with
-    bigger factors; the skew path, SURVEY.md §7.4 item 5).
-    """
-    probe_on, build_on = tuple(probe_on), tuple(build_on)
-    n_dev = mesh.shape[axis]
-    p_bucket = bucket_cap or probe.capacity // n_dev * 2
-    b_bucket = bucket_cap or build.capacity // n_dev * 2
-
-    def step(lp: Batch, lb: Batch):
-        lp = _local_length(lp)
-        lb = _local_length(lb)
-        lp2, ovf1 = hash_repartition_local(
-            lp, probe_on, axis, n_dev, p_bucket, seed=seed)
-        lb2, ovf2 = hash_repartition_local(
-            lb, build_on, axis, n_dev, b_bucket, seed=seed)
-        res = hash_join(lp2, lb2, probe_on, build_on, how=how,
-                        out_capacity=out_capacity or lp2.capacity)
-        ovf = lax.psum((ovf1 | ovf2 | res.overflow).astype(jnp.int32), axis)
-        glen = lax.psum(res.batch.length, axis)
-        # the Batch's scalar length can't ride a row-sharded out_spec;
-        # return (columns, sel) sharded + replicated global length
-        return (res.batch.columns, res.batch.sel), glen, ovf > 0
-
-    fn = shard_map(step, mesh=mesh,
-                   in_specs=(_batch_pspecs(probe, axis),
-                             _batch_pspecs(build, axis)),
-                   out_specs=((P(axis)), P(), P()),
-                   check_rep=False)
-    (cols, sel), glen, ovf = fn(probe, build)
-    return Batch(cols, sel, glen), ovf

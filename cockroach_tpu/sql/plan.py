@@ -767,7 +767,7 @@ def insert_shrinks(p: Plan, catalog: Optional[Catalog] = None) -> Plan:
     Smallness propagates through row-preserving nodes; the deferred
     overflow flag + 16x capacity growth keep the optimism safe (a stale
     estimate costs one recompile, never a wrong answer)."""
-    node, _small = _shrink_rec(p, catalog, under_agg=False)
+    node, _small = _shrink_rec(p, catalog)
     return node
 
 
@@ -778,31 +778,24 @@ def _pow2_at_least(n: int) -> int:
     return c
 
 
-def _shrink_rec(p: Plan, catalog: Optional[Catalog], under_agg: bool):
+def _shrink_rec(p: Plan, catalog: Optional[Catalog]):
     if isinstance(p, Filter) and isinstance(p.input, Aggregate):
-        inner, _ = _shrink_rec(p.input, catalog, False)
+        inner, _ = _shrink_rec(p.input, catalog)
         return Shrink(Filter(inner, p.predicate)), True
     if not p.inputs():
         return p, False
-    kid_under = isinstance(p, Aggregate)
-    pairs = [_shrink_rec(k, catalog, kid_under) for k in p.inputs()]
+    pairs = [_shrink_rec(k, catalog) for k in p.inputs()]
     kids = tuple(n for n, _ in pairs)
     smalls = [sm for _, sm in pairs]
     out = _rebuild(p, kids)
     if isinstance(p, Shrink):
         return out, True
     if isinstance(p, Join):
-        if (p.how in ("inner", "semi") and smalls[1] and not smalls[0]
-                and not under_agg):
-            # (not directly under an Aggregate: the group-join collapse
-            # compacts itself and wants the raw Join child)
+        if p.how in ("inner", "semi") and smalls[1] and not smalls[0]:
             return Shrink(out, start_capacity=1 << 14), True
         # stats-driven: a selective join's output should not ride its
         # probe's multi-M lane capacity into the rest of the query.
-        # NOT directly under an Aggregate — the group-join collapse
-        # (exec/fused.py) wants the raw Join child and compacts itself.
-        if (catalog is not None and not under_agg
-                and p.how in ("inner", "semi", "anti")
+        if (catalog is not None and p.how in ("inner", "semi", "anti")
                 and not smalls[0]):
             est = estimate_cardinality(out, catalog)
             probe_est = estimate_cardinality(p.left, catalog)

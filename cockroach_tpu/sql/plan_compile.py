@@ -62,8 +62,8 @@ LOWERING_RULES: Dict[type, tuple] = {
     Shrink: ("shrink", "ShrinkOp", "compact-to-pow2 gather"),
     Join: ("join", "JoinOp", "ops/join.hash_join (inner/left/right/"
            "full/semi/anti)"),
-    Aggregate: ("aggregate", "HashAggOp", "ops/agg hash/sort-view/"
-                "groupjoin aggregation"),
+    Aggregate: ("aggregate", "HashAggOp", "ops/agg: in place over "
+                "grouped input, int-key sort, by slot, or hash"),
     Distinct: ("distinct", "DistinctOp", "hash aggregation on keys"),
     OrderBy: ("sort", "SortOp", "ops/sort bitonic/segmented sort"),
     Limit: ("limit", "LimitOp", "top-K when ordered, slice otherwise"),

@@ -4,8 +4,8 @@ whole-query compile: ms by lanes x operand form.
     chiprun -- python scripts/price_sort_operands.py
     JAX_PLATFORMS=cpu python scripts/price_sort_operands.py --rehearse
 
-Every sort of the served programs (ops/sortjoin.py, ops/groupjoin.py,
-ops/agg.py, coldata/batch.first_matches, parallel/repartition.py) is one
+Every sort of the served programs (ops/sortjoin.py, ops/agg.py,
+coldata/batch.first_matches, parallel/repartition.py) is one
 of the forms below. Each form is ONE jitted sort compiled with the fused
 runner's own options (exec/fused.TPU_COMPILE_OPTIONS), run `--reps` times
 after a warm-up, timed on the host clock around `block_until_ready`

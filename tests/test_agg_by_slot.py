@@ -427,34 +427,46 @@ def test_a_key_without_a_domain_still_merges_by_hash(mesh9, mesh_catalog,
 # ------------------------------------ the other cells' programs stay put ---
 
 # statement -> (loader, tables' statement, binding, lowering events a traced
-# program, each aggregate's _dense_sizes). The digests of the lowered texts
-# at the parent commit are in CHANGES.md (PR 42); `lowered()` prints them.
+# program, each aggregate's _dense_sizes): the six statements the one-chip
+# cells run. The digests of the lowered texts, parent beside change, are in
+# CHANGES.md (PR 44; PR 42 for the five it had); `python -m
+# tests.test_agg_by_slot` prints them.
 OTHERS = {
     "q1": ("tpch", "tpch-sf1.q1-2streams", None,
            {"fused.agg_dense": 1}, [[4, 3]]),
     "q3": ("tpch", "tpch-sf1.q3-1stream", None,
-           {"fused.agg_ordered": 1}, [None]),
+           {"fused.agg_ordered": 1, "fused.join_compact": 2,
+            "fused.join_key_int": 2}, [None]),
     "q3_qgen": ("tpch", "tpch-sf1-qgen.q3-1stream",
                 ("BUILDING", "1995-03-15"),
-                {"fused.agg_ordered": 1}, [None]),
+                {"fused.agg_ordered": 1, "fused.join_compact": 2,
+                 "fused.join_key_int": 2}, [None]),
     "q6_qgen": ("tpch", "tpch-sf1-qgen.q6-2streams",
                 ("1994-01-01", "0.06", "24"),
                 {"fused.agg_materialized": 1}, [None]),
     "q18_qgen": ("tpch_cname", "tpch-sf1-q18.q18-1stream", ("300",),
-                 {"fused.agg_int_key": 1, "fused.agg_ordered": 1},
+                 {"fused.agg_int_key": 1, "fused.agg_ordered": 1,
+                  "fused.join_compact": 2, "fused.join_key_int": 3},
                  [None, None]),
+    "q9_qgen": ("tpch_pname", "tpch-sf1-q9.q9-1stream", ("%green%",),
+                {"fused.agg_dense": 1, "fused.join_compact": 1,
+                 "fused.join_key_int": 4, "fused.join_key_hash": 1},
+                [[26, 8]]),
 }
-AGG_EVENTS = ("fused.agg_dense", "fused.agg_materialized",
-              "fused.agg_folded", "fused.agg_int_key", "fused.agg_ordered")
+LOWERING_EVENTS = (
+    "fused.agg_dense", "fused.agg_materialized", "fused.agg_folded",
+    "fused.agg_int_key", "fused.agg_ordered", "fused.join_compact",
+    "fused.join_key_int", "fused.join_key_hash")
 
 
 def lowered(name, monkeypatch=None):
     """-> (sha256 of each program exec/fused lowers for statement `name`
-    at SF 0.01, the aggregate lowerings a traced program counted, each
-    aggregate's _dense_sizes)."""
+    at SF 0.01, the aggregate and join lowerings a traced program counted,
+    each aggregate's _dense_sizes)."""
     loader, cell, binding = OTHERS[name][:3]
     stmt = manifest.cell(cell)["statements"][0]
-    module = {"tpch": tpch_loader, "tpch_cname": tpch_cname}[loader]
+    module = {"tpch": tpch_loader, "tpch_cname": tpch_cname,
+              "tpch_pname": tpch_pname}[loader]
     loaded = module.load(MVCCStore(), {"sf": 0.01}, stmt["tables"], SEED)
     texts, lower = [], fused.lower_program
 
@@ -475,7 +487,7 @@ def lowered(name, monkeypatch=None):
         stats.disable()
         fused.lower_program = lower
     traced = _events(col, "fused.compile")
-    counted = {e: _events(col, e) // traced for e in AGG_EVENTS
+    counted = {e: _events(col, e) // traced for e in LOWERING_EVENTS
                if _events(col, e)}
     return ([hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts],
             counted, [a._dense_sizes for a in _aggs(_prepared_op(sess))])

@@ -241,10 +241,11 @@ def test_config_key_carries_the_estimates_bucket_pair():
     # 3,000 and 4,000 rows both give buckets of 512; 5,000 gives 1,024
     assert joins[1] == joins[2] and a == b
     assert joins[3] != joins[2] and c != b
-    assert [j[0][6:] for j in joins] == [(), (512, None), (512, None),
+    assert [j[0][5:] for j in joins] == [(), (512, None), (512, None),
                                          (1024, None)]
-    # without an estimate: the six entries the key had before there were any
-    assert len(joins[0][0]) == 6 and len(bare) == len(a)
+    # without an estimate: the five entries the key has without any (the
+    # operator's name, expansion, workmem, seed, build mode)
+    assert len(joins[0][0]) == 5 and len(bare) == len(a)
     assert [e for e in bare if e[0] != "JoinOp"] == [
         e for e in a if e[0] != "JoinOp"]
 

@@ -45,8 +45,11 @@ def test_fused_matches_streaming_tpch(qn):
     assert _sorted_rows(rf, names) == _sorted_rows(rs, names)
 
 
-def _int_scan(data, capacity):
-    schema = Schema([Field(n, INT) for n in data])
+def _int_scan(data, capacity, nullable=()):
+    """One chunk source of integer columns; a column in `nullable` reads
+    its validity from data["<name>__valid"]."""
+    schema = Schema([Field(n, INT, nullable=n in nullable) for n in data
+                     if not n.endswith("__valid")])
 
     def chunks():
         yield data
@@ -122,11 +125,12 @@ def test_fused_empty_scan_falls_back():
     assert list(res["c"]) == [0]
 
 
-def test_groupjoin_collapse_matches_streaming():
-    """The aggregate-over-join collapse (ops/groupjoin.py) must be
-    invisible: same results as the streaming JoinOp+HashAggOp, group
-    keys on the probe OR the build join column, with build group
-    columns along."""
+def test_aggregate_directly_over_a_join_matches_streaming():
+    """A HashAggOp directly on a unique-build inner JoinOp, grouped by the
+    join key (no Shrink, no MapOp between: a tree only a hand builds):
+    the whole join and one aggregation over it, with the streaming
+    JoinOp+HashAggOp's rows, group keys on the probe OR the build join
+    column, with build group columns along."""
     rng = np.random.default_rng(7)
     nb, np_ = 32, 200
     bk = rng.permutation(500)[:nb]
@@ -157,9 +161,9 @@ def test_groupjoin_collapse_matches_streaming():
         assert _sorted_rows(rf, names) == _sorted_rows(rs, names)
 
 
-def test_groupjoin_duplicate_build_falls_back_correct():
-    """Duplicate build keys trip the deferred fallback: the rerun takes
-    the general path and the answer stays exact."""
+def test_aggregate_over_a_join_with_duplicate_build_keys_restarts_exactly():
+    """Duplicate build keys trip the unique join's deferred fallback: the
+    rerun joins by expansion and the answer stays exact."""
     rng = np.random.default_rng(9)
     bk = rng.integers(0, 20, 32)            # duplicates guaranteed
     bd = rng.integers(0, 100, 32)
@@ -178,6 +182,102 @@ def test_groupjoin_duplicate_build_falls_back_correct():
     rs = collect(agg2, fuse=False)
     assert _sorted_rows(rf, ["fk", "d", "s"]) \
         == _sorted_rows(rs, ["fk", "d", "s"])
+
+
+# -- an aggregate DIRECTLY on a join (the tree the group-join collapse took
+#    until PR 44): the join and the aggregate each lower as themselves ------
+
+def _null_rows(res):
+    """collect()'s columns as sorted rows, None where a value is NULL."""
+    names = [n for n in res if not n.endswith("__valid")]
+    rows = [tuple(None if not res[n + "__valid"][i] else res[n][i].item()
+                  for n in names) for i in range(len(res[names[0]]))]
+    return sorted(rows, key=repr)
+
+
+def _agg_on_join(case):
+    """-> HashAggOp / JoinOp(inner, probe fk = build k): 200 probe rows
+    (fk, v) in chunks of 64 against 32 build rows (k, d); `case` bends
+    one thing."""
+    seed = int(case[4]) if case.startswith("seed") else 44
+    rng = np.random.default_rng(seed)
+    nb, n = 32, 200
+    bk = rng.permutation(500)[:nb].astype(np.int64)
+    pk = rng.integers(0, 500, n).astype(np.int64)
+    pk[::3] = bk[rng.integers(0, nb, len(pk[::3]))]      # matches for sure
+    pv = rng.integers(-30, 90, n).astype(np.int64)
+    probe = {"fk": pk, "v": pv}
+    build = {"k": bk, "d": rng.integers(100, 4000, nb).astype(np.int64)}
+    nullable, group_by, kw = set(), ["fk", "d"], {}
+    if case.endswith("build_key"):
+        group_by = ["k", "d"]
+    elif case == "null_keys_and_inputs":
+        probe["fk__valid"] = (rng.random(n) > 0.2).astype(np.uint8)
+        probe["v__valid"] = (rng.random(n) > 0.3).astype(np.uint8)
+        build["k__valid"] = (np.arange(nb) != 5).astype(np.uint8)
+        nullable = {"fk", "v", "k"}
+    elif case == "a_group_of_null_inputs":
+        probe["fk"][:8] = bk[0]
+        probe["v__valid"] = (probe["fk"] != bk[0]).astype(np.uint8)
+        nullable = {"v"}
+    elif case == "duplicate_build_keys":
+        build["k"] = rng.integers(0, 20, nb).astype(np.int64)
+        probe["fk"] = rng.integers(0, 25, n).astype(np.int64)
+    elif case == "more_groups_than_the_accumulator":
+        # folded (the input is over the budget) into an accumulator of
+        # one chunk's lanes: every probe row its own group
+        build = {"k": np.arange(256, dtype=np.int64),
+                 "d": np.arange(256, dtype=np.int64) * 3}
+        probe = {"fk": np.arange(n, dtype=np.int64), "v": pv}
+        kw = {"workmem": 3000}
+    elif case == "wide_build_columns":
+        for i in range(6):
+            build[f"d{i}"] = rng.integers(-1 << 50, 1 << 50, nb)
+        group_by = ["fk", "d"] + [f"d{i}" for i in range(6)]
+    elif case == "inputs_past_31_bits":
+        probe["v"] = rng.integers(-1 << 45, 1 << 45, n)
+    elif case == "keys_spanning_more_than_2_32":
+        spread = bk * np.int64(1 << 33) - np.int64(1 << 41)
+        build["k"] = spread
+        probe["fk"] = np.where(np.arange(n) % 3 == 0,
+                               spread[rng.integers(0, nb, n)],
+                               rng.integers(-1 << 41, 1 << 41, n))
+
+    join = JoinOp(_int_scan(probe, 64, nullable),
+                  _int_scan(build, len(build["k"]), nullable),
+                  ["fk"], ["k"], how="inner")
+    return HashAggOp(join, group_by,
+                     [AggSpec("sum", "v", "s"), AggSpec("count", "v", "c"),
+                      AggSpec("count_star", None, "n")], **kw)
+
+
+@pytest.mark.parametrize("case", [
+    "seed1_probe_key", "seed2_probe_key", "seed3_probe_key",
+    "seed1_build_key", "seed2_build_key", "seed3_build_key",
+    "null_keys_and_inputs", "a_group_of_null_inputs",
+    "duplicate_build_keys", "more_groups_than_the_accumulator",
+    "wide_build_columns", "inputs_past_31_bits",
+    "keys_spanning_more_than_2_32"])
+def test_aggregate_on_a_raw_join_answers_as_the_streaming_runtime(case):
+    """What the group-join kernel's own tests held it to, now held by the
+    two operators that answer such a tree: fused against streaming, row
+    for row, NULLs as NULLs."""
+    agg = _agg_on_join(case)
+    assert fused.try_compile(agg) is not None
+    got, branches = _agg_branches(lambda: collect(agg, fuse=True))
+    want = collect(_agg_on_join(case), fuse=False)
+    assert _null_rows(got) == _null_rows(want) and len(want["n"]) >= 10
+    # one of the five lowerings, and never the in-place one: no Shrink
+    # compacted this join, so nothing proves an order
+    assert branches and set(branches) <= {"materialized", "folded"}
+    if case == "duplicate_build_keys":
+        assert agg.child.build_mode != "unique" and len(branches) == 1 \
+            and sum(branches.values()) >= 2      # a trace a restart
+    if case == "more_groups_than_the_accumulator":
+        assert "folded" in branches and agg.expansion > 1
+    if case == "a_group_of_null_inputs":
+        assert any(r[2] is None and r[3] == 0 and r[4] >= 8
+                   for r in _null_rows(got))
 
 
 def test_columnar_baselines_match_oracles():
@@ -583,6 +683,140 @@ def test_aggregate_keeps_the_hash_path_where_the_order_is_not_proved(
         lambda: _agg_over_shrunk_join(group_by, **kw))
     assert branches == {"materialized": 1}
     assert got == want and len(got) > 5
+
+
+# -- the one decision: which of the five lowerings an aggregate takes -------
+
+def _keyed_scan(n=200, capacity=64, keys=("k",), spread=10):
+    """`n` rows in chunks of `capacity`: integer keys in 0..spread-1 and
+    one summed column."""
+    rng = np.random.default_rng(44)
+    data = {k: rng.integers(0, spread, n).astype(np.int64) for k in keys}
+    data["v"] = rng.integers(-40, 90, n).astype(np.int64)
+    return _int_scan(data, capacity)
+
+
+def _coded_scan():
+    """A dictionary-coded string key and a bool key (static domains)."""
+    from cockroach_tpu.coldata.batch import BOOL, STRING
+
+    rng = np.random.default_rng(45)
+    schema = Schema([Field("mode", STRING, dict_ref="m"), Field("f", BOOL),
+                     Field("v", INT)],
+                    dicts={"m": np.array(["AIR", "MAIL", "SHIP"])})
+    data = {"mode": rng.integers(0, 3, 200).astype(np.int32),
+            "f": rng.random(200) > 0.5,
+            "v": rng.integers(-40, 90, 200).astype(np.int64)}
+
+    def chunks():
+        yield data
+
+    return ScanOp(schema, chunks, 64)
+
+
+_SUMS = [AggSpec("sum", "v", "s"), AggSpec("count_star", None, "n")]
+
+# row -> (the minimal tree, the lowering _agg_partial counts for it, the
+# out_capacity it hands ops/agg.int_key_aggregate if it does), in the
+# order _agg_partial asks
+_LOWERINGS = {
+    "ordered": (
+        lambda: _agg_over_shrunk_join(["key", "dd"]), "ordered", None),
+    "ordered_past_a_filter": (
+        lambda: _agg_over_shrunk_join(
+            ["key", "dd"], steps=_steps_filter_then_project()),
+        "ordered", None),
+    "int_key_compacted": (
+        lambda: HashAggOp(_keyed_scan(), ["k"], _SUMS), "int_key", 256),
+    "int_key_run_ends_view": (      # over 2^18 lanes: left to the reader
+        lambda: HashAggOp(_keyed_scan(1000, 1 << 19), ["k"], _SUMS),
+        "int_key", 0),
+    "int_key_off_for_a_min": (      # the kernel sums and counts only
+        lambda: HashAggOp(_keyed_scan(), ["k"],
+                          _SUMS + [AggSpec("min", "v", "lo")]),
+        "materialized", None),
+    "by_slot_dictionary_and_bool": (
+        lambda: HashAggOp(_coded_scan(), ["mode", "f"], _SUMS),
+        "dense", None),
+    "by_slot_ranged_key": (         # ahead of the int-key sort
+        lambda: HashAggOp(_keyed_scan(), ["k"], _SUMS,
+                          key_domains={"k": (0, 9)}), "dense", None),
+    "by_slot_folded": (
+        lambda: HashAggOp(_keyed_scan(), ["k"], _SUMS, workmem=-1,
+                          key_domains={"k": (0, 9)}), "dense", None),
+    "hash_folded": (
+        lambda: HashAggOp(_keyed_scan(keys=("k", "j"), spread=5),
+                          ["k", "j"], _SUMS, workmem=4000), "folded", None),
+    "hash_whole": (
+        lambda: HashAggOp(_keyed_scan(keys=("k", "j")), ["k", "j"], _SUMS),
+        "materialized", None),
+    "scalar": (
+        lambda: HashAggOp(_keyed_scan(), [], _SUMS), "materialized", None),
+}
+
+
+@pytest.mark.parametrize("row", list(_LOWERINGS))
+def test_agg_partial_counts_the_one_lowering_it_takes(row, monkeypatch):
+    """_Tracer._agg_partial is the one decision: in place, the int-key
+    sort, by slot, hash (folded over workmem, or whole). Each minimal
+    tree counts exactly its lowering's event, once, and answers as the
+    streaming runtime."""
+    make, lowering, out_capacity = _LOWERINGS[row]
+    handed = []
+    real = fused.int_key_aggregate
+
+    def spy(batch, key, aggs, **kw):
+        handed.append(kw["out_capacity"])
+        return real(batch, key, aggs, **kw)
+
+    monkeypatch.setattr(fused, "int_key_aggregate", spy)
+    got, branches = _agg_branches(lambda: collect(make(), fuse=True))
+    assert branches == {lowering: 1}
+    assert handed == ([] if out_capacity is None else [out_capacity])
+    want = collect(make(), fuse=False)
+    assert _null_rows(got) == _null_rows(want) and len(want["n"]) >= 1
+
+
+@pytest.mark.parametrize("row,lowering,merge", [
+    # the int-key sort is off inside shard_map: a shard's partial hashes,
+    # and the gathered partials merge by a second hash aggregate
+    ("int_key_compacted", "materialized", "hash"),
+    # group g at lane g on every shard: D lanes merged pair by pair
+    ("by_slot_ranged_key", "dense", "dense"),
+])
+def test_agg_partial_on_four_shards_merges_as_its_lowering_says(
+        row, lowering, merge, monkeypatch):
+    from cockroach_tpu.parallel import dist_flow, make_mesh
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four virtual CPU devices")
+    seen = []
+
+    def spy(name):
+        real = getattr(dist_flow, name)
+
+        def merge(first, *a, **kw):
+            seen.append((name, first.capacity))
+            return real(first, *a, **kw)
+
+        monkeypatch.setattr(dist_flow, name, merge)
+
+    spy("hash_aggregate")
+    spy("dense_merge")
+    make = _LOWERINGS[row][0]
+    progs = dict(dist_flow._PROGS)
+    dist_flow._PROGS.clear()    # another test's entry would skip the trace
+    try:
+        got, branches = _agg_branches(
+            lambda: dist_flow.collect_distributed(make(), make_mesh(4),
+                                                  strict=True))
+    finally:
+        dist_flow._PROGS.update(progs)
+    assert branches == {lowering: 1}
+    # 10 keys and the NULL slot; the gathered hash partials: 4 x a shard's
+    assert seen == {"hash": [("hash_aggregate", 4 * 64)],
+                    "dense": [("dense_merge", 11)] * 3}[merge]
+    assert _null_rows(got) == _null_rows(collect(make(), fuse=False))
 
 
 def test_shrink_overflow_under_the_ordered_aggregate_restarts_exactly():

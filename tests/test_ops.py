@@ -684,49 +684,3 @@ def test_wide_sum_exact_beyond_int64(rng):
         exp = {g: sum(int(v) for v in vals[keys == g]) for g in range(4)}
         assert got == exp, f"fuse={fuse}"
         assert max(exp.values()) > (1 << 63)  # the point of the test
-
-
-def test_range_dense_aggregate_matches_hash():
-    """Direct-address (scatter) aggregation == the sort-view path, incl.
-    the fold merge and the out-of-range / NULL-key fallback flags."""
-    import numpy as np
-    from cockroach_tpu.coldata.batch import Batch, Column
-    from cockroach_tpu.ops.agg import (
-        AggSpec, dense_merge, hash_aggregate, range_dense_aggregate,
-    )
-
-    rng = np.random.default_rng(3)
-    aggs = (AggSpec("sum", "v", "s"), AggSpec("count_star", None, "n"),
-            AggSpec("min", "v", "mn"), AggSpec("max", "v", "mx"))
-
-    def mk(n, seed):
-        r = np.random.default_rng(seed)
-        return Batch.from_columns({
-            "k": Column(jnp.asarray(r.integers(2, 70, n).astype(np.int64))),
-            "v": Column(jnp.asarray(
-                r.integers(-50, 50, n).astype(np.int64)))})
-
-    b1, b2 = mk(500, 1), mk(300, 2)
-    p1, f1 = range_dense_aggregate(b1, "k", 0, 128, aggs)
-    p2, f2 = range_dense_aggregate(b2, "k", 0, 128, aggs)
-    assert not bool(f1) and not bool(f2)
-    merged = dense_merge(p1, p2, ("k",), aggs)
-
-    from cockroach_tpu.coldata.batch import concat_batches
-    ref = hash_aggregate(concat_batches([b1, b2]), ("k",), aggs,
-                         method="lex")
-
-    def rows(b):
-        sel = np.asarray(b.sel)
-        return sorted(
-            (int(np.asarray(b.col("k").values)[i]),
-             int(np.asarray(b.col("s").values)[i]),
-             int(np.asarray(b.col("n").values)[i]),
-             int(np.asarray(b.col("mn").values)[i]),
-             int(np.asarray(b.col("mx").values)[i]))
-            for i in range(len(sel)) if sel[i])
-
-    assert rows(merged) == rows(ref)
-    # out-of-range keys raise the deferred fallback flag
-    _, flag = range_dense_aggregate(b1, "k", 0, 16, aggs)
-    assert bool(flag)

@@ -11,10 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from cockroach_tpu.coldata.batch import Batch, Column
-from cockroach_tpu.ops.agg import AggSpec
-from cockroach_tpu.parallel import (
-    distributed_aggregate, distributed_hash_join, make_mesh, shard_batch,
-)
+from cockroach_tpu.parallel import make_mesh, shard_batch
 
 
 def make_batch(cols, sel=None):
@@ -39,91 +36,132 @@ def test_shard_batch_layout():
     assert sb.length.sharding.is_fully_replicated
 
 
-def test_distributed_aggregate_matches_local():
-    mesh = make_mesh(8)
-    rng = np.random.default_rng(0)
-    n = 1024
-    k = rng.integers(0, 17, n).astype(np.int64)
-    v = rng.integers(0, 1000, n).astype(np.int64)
-    b = shard_batch(make_batch({"k": (k, None), "v": (v, None)}), mesh)
-    out, ovf = jax.jit(
-        lambda bb: distributed_aggregate(
-            bb, mesh, ["k"], [AggSpec("sum", "v", "s"),
-                              AggSpec("count_star", None, "n"),
-                              AggSpec("min", "v", "mn")])
-    )(b)
-    assert not bool(ovf)
-    ng = int(out.length)
-    assert ng == len(set(k.tolist()))
-    got = {}
-    kk = np.asarray(out.col("k").values)
-    for i in range(ng):
-        got[int(kk[i])] = (int(out.col("s").values[i]),
-                           int(out.col("n").values[i]),
-                           int(out.col("mn").values[i]))
-    for key in set(k.tolist()):
-        m = k == key
-        assert got[key] == (v[m].sum(), m.sum(), v[m].min())
+# ------------------------------------------- the BY_HASH router's contract --
+
+ROUTE_DEV = 4
 
 
-def test_distributed_aggregate_respects_sel():
-    mesh = make_mesh(8)
-    n = 64
-    k = np.zeros(n, dtype=np.int64)
-    v = np.ones(n, dtype=np.int64)
-    sel = np.arange(n) % 2 == 0
-    b = shard_batch(make_batch({"k": (k, None), "v": (v, None)}, sel=sel), mesh)
-    out, ovf = distributed_aggregate(b, mesh, ["k"],
-                                     [AggSpec("count_star", None, "n")])
-    assert not bool(ovf)
-    assert int(out.col("n").values[0]) == 32
+def _route(batch, keys, bucket_cap, seed=1):
+    """hash_repartition_local under shard_map on four virtual devices ->
+    ({column: (device, lanes) values}, (device, lanes) sel, (device,)
+    overflow): what each device RECEIVED."""
+    from jax.sharding import PartitionSpec as P
+    from cockroach_tpu.parallel.repartition import (
+        _batch_pspecs, hash_repartition_local, shard_map,
+    )
+
+    def local(b):
+        out, ovf = hash_repartition_local(b, tuple(keys), "x", ROUTE_DEV,
+                                          bucket_cap, seed=seed)
+        return ({n: c.values for n, c in out.columns.items()}, out.sel,
+                ovf[None])
+
+    cols, sel, ovf = jax.jit(shard_map(
+        local, mesh=make_mesh(ROUTE_DEV),
+        in_specs=(_batch_pspecs(batch, "x"),),
+        out_specs=(P("x"), P("x"), P("x")), check_rep=False))(batch)
+    return ({n: np.asarray(v).reshape(ROUTE_DEV, -1)
+             for n, v in cols.items()},
+            np.asarray(sel).reshape(ROUTE_DEV, -1), np.asarray(ovf))
 
 
-def test_distributed_aggregate_partial_cap_overflow():
-    """More live groups on a chip than partial_cap => overflow flag set
-    and result length clamped (no silent group drop)."""
-    mesh = make_mesh(8)
-    n = 512
-    k = np.arange(n, dtype=np.int64)  # 64 distinct groups per chip
-    v = np.ones(n, dtype=np.int64)
-    b = shard_batch(make_batch({"k": (k, None), "v": (v, None)}), mesh)
-    out, ovf = distributed_aggregate(
-        b, mesh, ["k"], [AggSpec("sum", "v", "s")], partial_cap=16)
-    assert bool(ovf)
-    assert int(out.length) <= 8 * 16
+def _named_device(batch, keys, seed=1):
+    """The device each row's key hash names (the router's own rule: the
+    hash's high bits, so the low ones stay free for the local join)."""
+    from cockroach_tpu.ops.hash import hash_columns
+
+    h = np.asarray(hash_columns(batch, tuple(keys), seed=seed))
+    return ((h >> np.uint64(42)) % np.uint64(ROUTE_DEV)).astype(np.int64)
 
 
-def test_distributed_hash_join_matches_oracle():
-    mesh = make_mesh(8)
-    rng = np.random.default_rng(1)
-    lk = rng.integers(0, 50, 512).astype(np.int64)
-    rk = rng.integers(0, 50, 256).astype(np.int64)
-    rv = np.arange(256, dtype=np.int64)
-    probe = shard_batch(make_batch({"lk": (lk, None)}), mesh)
-    build = shard_batch(make_batch({"rk": (rk, None), "rv": (rv, None)}), mesh)
-    out, ovf = jax.jit(
-        lambda p, b: distributed_hash_join(
-            p, b, mesh, ["lk"], ["rk"], bucket_cap=512, out_capacity=4096)
-    )(probe, build)
-    assert not bool(ovf)
-    want = sum(1 for a in lk for c in rk if a == c)
-    assert int(out.length) == want
-    # spot-check pairs
-    sel = np.asarray(out.sel)
-    got_l = np.asarray(out.col("lk").values)[sel]
-    got_r = np.asarray(out.col("rk").values)[sel]
-    np.testing.assert_array_equal(got_l, got_r)
+def test_hash_router_delivers_every_live_row_once_where_its_hash_names():
+    rng = np.random.default_rng(44)
+    n = ROUTE_DEV * 64
+    k = rng.integers(0, 1 << 40, n).astype(np.int64)
+    row = np.arange(n, dtype=np.int64)
+    b = make_batch({"k": (k, None), "row": (row, None)})
+    cols, sel, ovf = _route(b, ["k"], bucket_cap=64)
+    assert not ovf.any()
+    dest = _named_device(b, ["k"])
+    assert len(set(dest.tolist())) == ROUTE_DEV     # the case spreads
+    arrived = {}
+    for d in range(ROUTE_DEV):
+        for r, key in zip(cols["row"][d][sel[d]], cols["k"][d][sel[d]]):
+            assert int(r) not in arrived                 # once
+            arrived[int(r)] = d
+            assert int(key) == int(k[r])                 # with its columns
+    assert arrived == {i: int(dest[i]) for i in range(n)}
 
 
-def test_distributed_join_overflow_flag():
-    mesh = make_mesh(8)
-    lk = np.zeros(256, dtype=np.int64)  # all rows hash to one device
-    rk = np.zeros(256, dtype=np.int64)
-    probe = shard_batch(make_batch({"lk": (lk, None)}), mesh)
-    build = shard_batch(make_batch({"rk": (rk, None), "rv": (lk, None)}), mesh)
-    out, ovf = distributed_hash_join(
-        probe, build, mesh, ["lk"], ["rk"], bucket_cap=8, out_capacity=64)
-    assert bool(ovf)
+def test_hash_router_sends_no_dead_lane():
+    rng = np.random.default_rng(45)
+    n = ROUTE_DEV * 64
+    k = rng.integers(0, 1000, n).astype(np.int64)
+    live = rng.random(n) > 0.6
+    b = make_batch({"k": (k, None), "row": (np.arange(n), None)}, sel=live)
+    cols, sel, ovf = _route(b, ["k"], bucket_cap=64)
+    assert not ovf.any()
+    got = sorted(int(r) for d in range(ROUTE_DEV)
+                 for r in cols["row"][d][sel[d]])
+    assert got == np.flatnonzero(live).tolist()
+    # a lane that carries no row carries nothing of a dead one either
+    assert not cols["k"][~sel].any() and not cols["row"][~sel].any()
+
+
+def test_hash_router_full_bucket_raises_the_flag_and_keeps_the_first_rows():
+    rng = np.random.default_rng(46)
+    n, per_dev, cap = ROUTE_DEV * 64, 64, 8
+    k = rng.integers(0, 1 << 40, n).astype(np.int64)
+    b = make_batch({"k": (k, None), "row": (np.arange(n), None)})
+    cols, sel, ovf = _route(b, ["k"], bucket_cap=cap)
+    dest = _named_device(b, ["k"])
+    for src in range(ROUTE_DEV):
+        mine = np.arange(src * per_dev, (src + 1) * per_dev)
+        runs = [mine[dest[mine] == d] for d in range(ROUTE_DEV)]
+        # the flag is the sending device's, raised iff a run outgrew it
+        assert bool(ovf[src]) == any(len(r) > cap for r in runs)
+        for d, run in enumerate(runs):
+            # bucket `src` of device d: the run's FIRST `cap` rows, in
+            # lane order; the rest are dropped, which the flag says
+            lanes = slice(src * cap, (src + 1) * cap)
+            assert (cols["row"][d][lanes][sel[d][lanes]].tolist()
+                    == run[:cap].tolist())
+    assert ovf.any()
+
+
+def test_hash_router_one_hot_key_raises_the_flag():
+    n = ROUTE_DEV * 64
+    b = make_batch({"k": (np.full(n, 7, np.int64), None),
+                    "row": (np.arange(n), None)})
+    cols, sel, ovf = _route(b, ["k"], bucket_cap=16)
+    assert ovf.all()       # every device's 64 rows name one bucket of 16
+    (hot,) = set(_named_device(b, ["k"]).tolist())
+    assert sel[hot].sum() == ROUTE_DEV * 16
+    assert not np.delete(sel, hot, axis=0).any()
+    # with room for the skew, nothing is dropped and no flag is raised
+    cols, sel, ovf = _route(b, ["k"], bucket_cap=64)
+    assert not ovf.any() and sel[hot].sum() == n
+
+
+def test_hash_router_brings_both_sides_of_a_key_to_one_device():
+    """Routed with the same seed, a probe row and the build rows of its
+    key meet: the joins each device runs on what it received count, in
+    sum, the pairs of the whole join."""
+    rng = np.random.default_rng(47)
+    lk = rng.integers(0, 50, ROUTE_DEV * 128).astype(np.int64)
+    rk = rng.integers(0, 50, ROUTE_DEV * 64).astype(np.int64)
+    probe = make_batch({"lk": (lk, None)}, sel=rng.random(len(lk)) > 0.1)
+    build = make_batch({"rk": (rk, None)}, sel=rng.random(len(rk)) > 0.1)
+    pcols, psel, povf = _route(probe, ["lk"], bucket_cap=128)
+    bcols, bsel, bovf = _route(build, ["rk"], bucket_cap=64)
+    assert not povf.any() and not bovf.any()
+    local = sum(
+        int((pcols["lk"][d][psel[d]][:, None]
+             == bcols["rk"][d][bsel[d]][None, :]).sum())
+        for d in range(ROUTE_DEV))
+    want = int((lk[np.asarray(probe.sel)][:, None]
+                == rk[np.asarray(build.sel)][None, :]).sum())
+    assert local == want and want > 0
 
 
 def test_host_mesh_runs_distributed_query():

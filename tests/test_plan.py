@@ -149,3 +149,86 @@ def test_mvcc_catalog_serves_plans():
            for g in range(5)}
     got = dict(zip(res["g"].tolist(), res["s"].tolist()))
     assert got == {k: v for k, v in exp.items() if v}
+
+
+# -- a selective join directly under an Aggregate is shrunk like any other --
+
+def _having_shaped_build():
+    """orders joined to the orders whose lineitems sum past a bound: the
+    build is a HAVING-shaped filter, which rule (1) shrinks."""
+    big = Project(
+        Filter(Aggregate(Scan("lineitem", ("l_orderkey", "l_quantity")),
+                         ("l_orderkey",),
+                         (AggSpec("sum", "l_quantity", "qty"),)),
+               Cmp(">", Col("qty"), Lit(300, INT))),
+        (("big_okey", Col("l_orderkey")),))
+    return Join(Scan("orders", ("o_orderkey", "o_custkey")), big,
+                ("o_orderkey",), ("big_okey",), how="semi")
+
+
+def _selective_by_the_statistics():
+    """lineitem against one day's orders: the estimate keeps a sliver."""
+    day = Filter(Scan("orders", ("o_orderkey", "o_orderdate")),
+                 Cmp("==", Col("o_orderdate"), Lit(Q.Q3_DATE, INT)))
+    return Join(Scan("lineitem", ("l_orderkey", "l_quantity")), day,
+                ("l_orderkey",), ("o_orderkey",))
+
+
+@pytest.mark.parametrize("join,with_catalog,key,capacity", [
+    # rule (2): the build is already shrunk; no statistics asked
+    (_having_shaped_build, False, "o_custkey", 1 << 14),
+    # rule (3): the statistics' estimate is a sliver of the probe's
+    (_selective_by_the_statistics, True, "l_orderkey", None),
+], ids=["a_shrunk_build", "the_statistics_estimate"])
+def test_insert_shrinks_reaches_a_join_directly_under_an_aggregate(
+        join, with_catalog, key, capacity):
+    """Until PR 44 both rules stepped over a join whose parent was an
+    Aggregate (the group-join collapse wanted it raw): now it compacts
+    like every other selective join."""
+    from cockroach_tpu.sql.plan import Shrink, insert_shrinks
+
+    cat = TPCHCatalog(TPCH(sf=0.01)) if with_catalog else None
+    plan = Aggregate(join(), (key,), (AggSpec("count_star", None, "n"),))
+    out = insert_shrinks(plan, cat)
+    assert isinstance(out, Aggregate) and isinstance(out.input, Shrink)
+    assert isinstance(out.input.input, Join)
+    if capacity is not None:
+        assert out.input.start_capacity == capacity
+    else:       # from the estimate: a power of two under the probe's rows
+        cap = out.input.start_capacity
+        assert cap & (cap - 1) == 0 and 1 << 12 <= cap < 60175
+
+
+@pytest.mark.parametrize("qn", [3, 18])
+def test_hand_built_aggregate_over_join_is_shrunk_and_answers_its_oracle(qn):
+    """Q3 and Q18 built by hand are Aggregate(Join): normalized, the join
+    stands under a Shrink, compacts with it and leaves the aggregate its
+    input grouped, the program their SQL text runs; the answer is the
+    oracle's."""
+    from cockroach_tpu.exec import stats
+    from cockroach_tpu.sql.plan import Shrink
+
+    gen = TPCH(sf=0.01)
+    cat = TPCHCatalog(gen)
+    node = normalize(Q.PLANS[qn](gen), cat)
+    while not isinstance(node, Aggregate):
+        (node,) = node.inputs()
+    assert isinstance(node.input, Shrink)
+    assert isinstance(node.input.input, Join)
+    col = stats.enable()
+    try:
+        res = collect(Q.QUERIES[qn](gen, capacity=1 << 13), fuse=True)
+    finally:
+        stats.disable()
+    traced = col.stages["fused.compile"].events
+    assert col.stages["fused.agg_ordered"].events == traced
+    assert col.stages["fused.join_compact"].events == 2 * traced
+    if qn == 3:
+        got = sorted(zip(res["l_orderkey"].tolist(), res["revenue"].tolist(),
+                         res["o_orderdate"].tolist()))
+        assert got == sorted(Q.q3_oracle(gen))
+    else:
+        got = [tuple(int(res[n][i]) for n in (
+            "c_name", "c_custkey", "o_orderkey", "o_orderdate",
+            "o_totalprice", "sum_qty")) for i in range(len(res["c_name"]))]
+        assert got == Q.q18_oracle(gen)

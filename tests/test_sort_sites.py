@@ -24,8 +24,9 @@ import cockroach_tpu  # noqa: F401  (x64 config)
 from cockroach_tpu.coldata import batch as batch_mod
 from cockroach_tpu.coldata.batch import Batch, Column, first_selected
 from cockroach_tpu.exec import operators as operators_mod
-from cockroach_tpu.ops import groupjoin, sortjoin
-from cockroach_tpu.ops.agg import AggSpec, hash_aggregate
+from cockroach_tpu.ops import agg as agg_mod
+from cockroach_tpu.ops import sortjoin
+from cockroach_tpu.ops.agg import AggSpec, hash_aggregate, int_key_aggregate
 
 
 def _argsort_selected(sel, C):
@@ -49,7 +50,7 @@ def _until_pr43():
     with mock.patch.object(jax.lax, "sort", stable), \
             mock.patch.object(batch_mod, "first_selected",
                               _argsort_selected), \
-            mock.patch.object(groupjoin, "first_selected",
+            mock.patch.object(agg_mod, "first_selected",
                               _argsort_selected), \
             mock.patch.object(operators_mod, "first_selected",
                               _argsort_selected):
@@ -192,34 +193,12 @@ def test_int_key_aggregate_is_the_stable_forms_lane_for_lane(case,
     17 groups (18 with the NULL key's): capacities under, at and over
     them, over the lanes too, and the uncompacted view."""
     b = _agg_input(case)
-    got = groupjoin.int_key_aggregate(b, "pk", AGGS,
-                                      out_capacity=out_capacity)
+    got = int_key_aggregate(b, "pk", AGGS, out_capacity=out_capacity)
     with _until_pr43():
-        want = groupjoin.int_key_aggregate(b, "pk", AGGS,
-                                           out_capacity=out_capacity)
+        want = int_key_aggregate(b, "pk", AGGS, out_capacity=out_capacity)
     assert bool(got.overflow) == bool(want.overflow) == (
         out_capacity in (8, 16))
     _same(got, want)
-
-
-@pytest.mark.parametrize("case", CASES)
-@pytest.mark.parametrize("out_capacity", [8, 16, 32, 1024])
-def test_group_join_aggregate_is_the_stable_forms_lane_for_lane(
-        case, out_capacity):
-    """(key << 1 | side, row-or-inputs) unstable: the tag leads a run
-    with its build lane whatever the sort does with ties. Duplicate
-    build keys raise `fallback` on both."""
-    probe, build = _join_sides(case)
-    args = (probe, build, "pk", "bk", "pk", jnp.int64, ["bw"], AGGS,
-            out_capacity)
-    got = groupjoin.group_join_aggregate(*args)
-    with _until_pr43():
-        want = groupjoin.group_join_aggregate(*args)
-    assert bool(got.fallback) == bool(want.fallback) == (
-        case == "duplicate_build")
-    assert bool(got.overflow) == bool(want.overflow)
-    if case != "duplicate_build":
-        _same(got, want)
 
 
 @pytest.mark.parametrize("case", ["all_live", "dead_and_null"])
