@@ -199,14 +199,14 @@ class _IntKeyAggGuard:
     kernel off for this operator, and the rerun hashes. Both attributes
     ride the fused config key, so each state compiles its own program."""
 
-    def __init__(self, agg: HashAggOp):
-        self.agg = agg
+    def __init__(self, op: HashAggOp):
+        self.op = op
 
     def widen(self):
-        if not getattr(self.agg, "_ia_wide", False):
-            self.agg._ia_wide = True
+        if not getattr(self.op, "_ia_wide", False):
+            self.op._ia_wide = True
         else:
-            self.agg._ia_ok = False
+            self.op._ia_ok = False
 
 
 class _Stream:
@@ -636,24 +636,9 @@ class _Tracer:
         materialized input fits the operator budget; emits the
         uncompacted run-ends view for large group counts (a downstream
         filter/shrink compacts far cheaper than per-group gathers)."""
-        if not getattr(op, "_ia_ok", True) or len(op.group_by) != 1:
+        if not self._int_keyed(op):
             return None
-        if op._dense_sizes is not None:
-            return None  # a small static domain: by slot, no sort at all
         child_schema = op.child.schema
-        key = op.group_by[0]
-        if not jnp.issubdtype(child_schema.field(key).type.dtype,
-                              jnp.integer):
-            return None
-        for a in op.internal:
-            if a.func not in INT_KEY_AGG_FUNCS:
-                return None
-            if a.col is not None:
-                dt = child_schema.field(a.col).type.dtype
-                if not (dt == jnp.bool_
-                        or jnp.issubdtype(dt, jnp.integer)):
-                    return None
-
         est_rows = 0
         for sub in walk_operators(op.child):
             if isinstance(sub, ScanOp):
@@ -662,7 +647,36 @@ class _Tracer:
                                * sub.capacity)
         if est_rows * self._row_bytes(child_schema) > op.workmem:
             return None
-        m = self._mat(op.child)
+        return self._int_key_agg(op, self._mat(op.child), op.internal)
+
+    def _int_keyed(self, op: HashAggOp) -> bool:
+        """Is `op` a GROUP BY on ONE integer column whose aggregates
+        ops/agg.int_key_aggregate computes (sums and counts of integers
+        and bools), with the kernel not turned off for it
+        (_IntKeyAggGuard)? Then so are its partials' merge: the merging
+        functions of sums and counts are sums of int64 columns."""
+        if not getattr(op, "_ia_ok", True) or len(op.group_by) != 1:
+            return False
+        if op._dense_sizes is not None:
+            return False  # a small static domain: by slot, no sort at all
+        child_schema = op.child.schema
+        if not jnp.issubdtype(child_schema.field(op.group_by[0]).type.dtype,
+                              jnp.integer):
+            return False
+        for a in op.internal:
+            if a.func not in INT_KEY_AGG_FUNCS:
+                return False
+            if a.col is not None:
+                dt = child_schema.field(a.col).type.dtype
+                if not (dt == jnp.bool_
+                        or jnp.issubdtype(dt, jnp.integer)):
+                    return False
+        return True
+
+    def _int_key_agg(self, op: HashAggOp, m: Batch, aggs) -> Batch:
+        """ops/agg.int_key_aggregate of `m` by `op`'s one key: `aggs` are
+        op.internal over the operator's input, or op._merge_aggs over
+        partials of it (the mesh's final stage)."""
         # group count <= live rows: small inputs compact to their full
         # bound (overflow impossible); large ones return the run-ends
         # view — a downstream filter/shrink/top-K compacts far cheaper
@@ -670,7 +684,7 @@ class _Tracer:
         out_cap = (_pow2_at_least(m.capacity)
                    if m.capacity <= (1 << 18) else 0)
         res = int_key_aggregate(
-            m, key, list(op.internal), out_capacity=out_cap,
+            m, op.group_by[0], list(aggs), out_capacity=out_cap,
             key64=getattr(op, "_ia_wide", False))
         self.sort_lanes += m.capacity
         self.flag_ops.append(_IntKeyAggGuard(op))

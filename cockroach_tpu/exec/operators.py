@@ -1684,6 +1684,20 @@ class LimitOp(Operator):
             prev_carry = carry
 
 
+def shrink_batch(m: Batch, C: int):
+    """-> (`m`'s first C selected rows as a C-lane batch, did it hold
+    more). Gathers ONLY the C winning rows (the selected lanes first, in
+    lane order, then a (C, W) row gather) — a full compact() would
+    row-gather every capacity lane just to slice C of them (~150 ms per
+    6M-lane shrink on v5e). What ShrinkOp lowers to, and how the mesh
+    cuts a shard's rows to the result window before it gathers them."""
+    length = jnp.minimum(m.length, C).astype(jnp.int32)
+    sel = jnp.arange(C) < length
+    out = m.gather(first_selected(m.sel, C), sel=sel, length=length)
+    return (Batch(mask_padding(out.columns, sel), sel, length),
+            m.length > C)
+
+
 class ShrinkOp(Operator):
     """Adaptive capacity compaction: compact the child's (materialized)
     output into a SMALL static capacity, flagging overflow for the
@@ -1724,16 +1738,9 @@ class ShrinkOp(Operator):
         self.capacity *= self.GROWTH
 
     def shrink_traceable(self, m: Batch):
-        """-> (shrunk batch, overflow flag). Gathers ONLY the C winning
-        rows (the selected lanes first, in lane order, then a (C, W) row
-        gather) — a full compact() would row-gather every capacity lane
-        just to slice C of them (~150 ms per 6M-lane shrink on v5e)."""
-        C = self.capacity
-        length = jnp.minimum(m.length, C).astype(jnp.int32)
-        sel = jnp.arange(C) < length
-        out = m.gather(first_selected(m.sel, C), sel=sel, length=length)
-        return (Batch(mask_padding(out.columns, sel), sel, length),
-                m.length > C)
+        """-> (shrunk batch, overflow flag): shrink_batch at this
+        operator's capacity."""
+        return shrink_batch(m, self.capacity)
 
     def batches(self) -> Iterator[Batch]:
         parts = [b for b in self.child.batches()]

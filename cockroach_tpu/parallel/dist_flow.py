@@ -9,8 +9,16 @@ shard_map'd XLA program runs the ENTIRE query on every device —
   — the PartitionSpans analog, distsql_physical_planner.go:971, applied
   at load time so the host link is crossed once per replica, never
   full-image-then-scatter);
-- P4 broadcast joins: build sides under `sql.distsql.broadcast_limit_rows`
-  place replicated on every device (OutputRouterSpec_MIRROR);
+- P4 broadcast joins: build sides that SCAN no more rows than
+  `sql.distsql.broadcast_limit_rows` place replicated on every device
+  (OutputRouterSpec_MIRROR), and every shard computes them whole. A
+  build that scans more but EMITS little (a ShrinkOp on its spine keeps
+  a shard's lanes x shards under the same limit: Q18's few dozen order
+  keys behind the HAVING, 4,096 lanes a shard, and its joined orders x
+  customer rows, 16,384) has its own spine sharded: each shard computes
+  its part, ONE all_gather hands every shard the whole build, and the
+  join is local (_Gather; under the join's `.merge` scope; stage
+  `dist.gather_build`);
 - P3 BY_HASH repartition: larger build sides are co-partitioned by join-
   key hash with ONE `lax.all_to_all` per side, and every probe chunk is
   routed the same way before its local join (colflow/routers.go:442
@@ -30,11 +38,29 @@ shard_map'd XLA program runs the ENTIRE query on every device —
   lanes, which give its lanes' bucket), every join has its own bucket
   pair in the config key, its own `dist.bucket` events and its own
   _BucketGuard, which widens that join alone;
-- P9 two-stage aggregation: per-device partial fold -> all_gather ->
-  replicated merge -> finalize (partial aggregators on data nodes, final
-  on the gateway); a DENSE partial (group g at lane g of the keys' static
-  domains, exec/fused._Tracer._agg_partial) is gathered at its D lanes
-  and merged lane-wise, with no second hash aggregate;
+- P9 two-stage aggregation, three merges. Per-device partial fold ->
+  all_gather -> replicated merge -> finalize (partial aggregators on
+  data nodes, final on the gateway); a DENSE partial (group g at lane g
+  of the keys' static domains, exec/fused._Tracer._agg_partial) is
+  gathered at its D lanes and merged lane-wise, with no second hash
+  aggregate; and where a shard's partial can hold more groups than the
+  broadcast limit (its lanes do, and the planner expects as many:
+  Q18's GROUP BY l_orderkey, 1.5M groups over 2,097,152 lanes a shard)
+  the merge is BY_HASH (distsql_physical_planner.go planAggregators: a
+  local aggregator stage, BY_HASH routers on the group columns, a final
+  aggregator stage a node): the partial is routed on the group key
+  through the joins' router (an int-key partial's run-ends view as it
+  stands: dead lanes go nowhere), each shard merges what arrives with
+  the operator's merging functions (the int-key sort again for one
+  integer key), and the aggregate's OUTPUT IS SHARDED, each group on
+  exactly one shard (_AggRoute, _merge_by_hash; scope `.exchange`; stage
+  `dist.agg_route`, whose lanes and bytes `dist.sort_lanes` and
+  `dist.a2a` include). Its bucket comes from a shard's share of the
+  planner's groups, its router's flag is its own (_BucketGuard: one
+  restart onto the lanes' bucket). What stands above takes the output
+  as it takes any sharded rows: a filter and a Shrink a shard, a top-K
+  and a sort by gathering, a join's build by _Gather; a ROOT whose rows
+  are still sharded is gathered into the result (_gather_result);
 - deferred overflow/collision flags are psum-reduced across the axis and
   answered by the same FlowRestart widen/re-seed retry as single-chip.
   A full bucket drops no row silently: the router raises its flag.
@@ -58,8 +84,8 @@ shard_map'd XLA program runs the ENTIRE query on every device —
 Warm path: compiled programs live in a process-wide cache keyed by
 (plan fingerprint, config key) where the config key carries the mesh
 identity, the broadcast limit, every scan's (role, pow2 bucket) and
-the bucket pair an estimate gave each BY_HASH join and the shapes of
-the bound values (the fingerprint skips `est_rows` and a Param's
+the bucket pair an estimate gave each BY_HASH join (the one bucket a
+BY_HASH aggregate) and the shapes of the bound values (the fingerprint skips `est_rows` and a Param's
 sample; the power of two keeps drifting statistics on one program) — the distributed analog of exec/fused.py's exec cache. A warm
 re-run of a distributed query is ONE dispatch: cached ingest-sharded
 images (per-shard-refreshed against their resident MVCC source when the
@@ -93,6 +119,11 @@ device sends through the exchanges, empty bucket lanes included),
 that device sees them, PLUS the routers' destination sorts, one over
 every lane of a routed side) and `dist.hash_key_lanes` (the joins' of
 them under the hashed u64 key: a key of two columns, or no integer);
+`dist.agg_route` (of those two, what the BY_HASH aggregates' partials
+account for) and `dist.gather_build` (`rows` = lanes of the computed
+builds as gathered on every device, `bytes` = what one device sends for
+them); a traced program with a BY_HASH aggregate counts
+`dist.agg_partitioned` once, beside `fused.agg_int_key`;
 `dist.compile` carries one `dist.bucket` event a routed side: the
 estimate, the bucket traced, the bucket the lanes give.
 """
@@ -116,13 +147,13 @@ from cockroach_tpu.coldata.batch import Batch, Column, Schema, concat_batches
 from cockroach_tpu.exec import stats
 from cockroach_tpu.exec.fused import (
     EXCHANGE, MERGE, RESULT_CAP, RESULT_SCOPE, HBMExceeded, Unsupported,
-    _Tracer, _pack_result, _unpack_result,
+    _IntKeyAggGuard, _Tracer, _pack_result, _unpack_result,
     bound_program_args, compile_via_vault, lower_program, scope,
     takes_params,
 )
 from cockroach_tpu.exec.operators import (
     FlowRestart, HashAggOp, JoinOp, Operator, ScanOp, ShrinkOp, SortOp, TopKOp,
-    _pow2_at_least, walk_operators,
+    _pow2_at_least, child_operators, shrink_batch, walk_operators,
 )
 from cockroach_tpu.ops import expr as _expr
 from cockroach_tpu.ops.agg import dense_merge, hash_aggregate
@@ -154,6 +185,17 @@ def _all_gather_batch(b: Batch, axis: str) -> Batch:
     return Batch(cols, sel, jnp.sum(sel).astype(jnp.int32))
 
 
+def _gather_result(out: Batch, axis: str) -> Batch:
+    """The statement's rows on every device where the root's are
+    device-LOCAL (nothing above a BY_HASH aggregate or a sharded scan
+    merged them): each shard's first RESULT_CAP rows, gathered. The
+    length is the shards' true total, so a result over the packed window
+    is still seen to be (_pack_result)."""
+    head, _more = shrink_batch(out, min(RESULT_CAP, out.capacity))
+    rows = _all_gather_batch(head, axis)
+    return Batch(rows.columns, rows.sel, lax.psum(out.length, axis))
+
+
 # ------------------------------------------------------- program cache --
 #
 # Process-wide: a distributed query warmed by one DistFusedRunner stays
@@ -177,6 +219,14 @@ class _Program(NamedTuple):
     #                        the routers' destination sorts (dist.sort_lanes)
     hash_key_lanes: int    # the joins' of them under the hashed u64 key
     #                        (dist.hash_key_lanes)
+    agg_route: Tuple[int, int] = (0, 0)   # (lanes through the BY_HASH
+    #                        aggregates' destination sorts, bytes one device
+    #                        sends through their exchanges): the part of
+    #                        sort_lanes and a2a_bytes that is an
+    #                        aggregate's (dist.agg_route)
+    gather_build: Tuple[int, int] = (0, 0)  # (lanes of the computed
+    #                        builds as gathered on every device, bytes one
+    #                        device sends for them) (dist.gather_build)
 
     def flag_ops(self, ops: list) -> Optional[list]:
         """The restart targets of this program's flags over `ops` (a
@@ -186,8 +236,9 @@ class _Program(NamedTuple):
             if i >= len(ops):
                 return None
             op = ops[i]
-            if t == _BucketGuard.__name__ and isinstance(op, JoinOp):
-                op = _BucketGuard(op)
+            guard = _GUARDS.get(t)
+            if guard is not None and isinstance(op, (JoinOp, HashAggOp)):
+                op = guard(op)
             if type(op).__name__ != t:
                 return None
             out.append(op)
@@ -213,6 +264,41 @@ class _Exchange(NamedTuple):
     def estimated(self) -> bool:
         return self.probe_est is not None or self.build_est is not None
 
+    @property
+    def key(self) -> tuple:
+        """What the config key holds of it."""
+        return (self.probe_est, self.build_est) if self.estimated else ()
+
+
+class _AggRoute(NamedTuple):
+    """The bucket (groups a destination) of one aggregate that is merged
+    BY_HASH: every shard routes its partial on the group key and merges
+    what arrives, so each group ends on exactly one shard. `bucket` is
+    what the partial's lanes give as DistFusedRunner._classify reckons
+    them from the plan (the program takes the traced partial's), and
+    `bucket_est` what a shard's share of the planner's estimate of the
+    groups gives, None where the operator carries no estimate, the
+    estimate gives no less than the lanes, or a full bucket has sent the
+    aggregate back to its lanes (_BucketGuard)."""
+
+    bucket: int
+    bucket_est: Optional[int] = None
+
+    @property
+    def key(self) -> tuple:
+        return ("agg", self.bucket_est)
+
+
+class _Gather(NamedTuple):
+    """A join whose build is COMPUTED from sharded scans and small: each
+    shard computes its part at `lanes` lanes (a ShrinkOp bounds them),
+    an all_gather hands every shard the whole build, the join is
+    local."""
+
+    lanes: int
+
+    key = ("gather",)
+
 
 def side_bucket(by_lanes: int, by_est: Optional[int], n_dev: int,
                 parts: int = 1) -> int:
@@ -227,24 +313,57 @@ def side_bucket(by_lanes: int, by_est: Optional[int], n_dev: int,
 
 class _BucketGuard:
     """FlowRestart target of a BY_HASH join's router flags where its
-    buckets were sized from the planner's estimate: a full bucket means
-    the estimate was low, and widen() sends the join back to the buckets
-    its lanes give, in one step: the program every tree without an
-    estimate runs. The attribute rides the config key through
-    _classify (no estimate: no bucket pair in the key)."""
+    buckets were sized from the planner's estimate, and of a BY_HASH
+    aggregate's: a full bucket means the estimate was low, and widen()
+    sends the operator back to the buckets its lanes give, in one step:
+    the program every tree without an estimate runs. The attribute rides
+    the config key through _classify (no estimate: no bucket in the
+    key)."""
 
     ATTR = "_lanes_buckets"
 
-    def __init__(self, op: JoinOp):
+    def __init__(self, op: Operator):
         self.op = op
 
     def widen(self):
         setattr(self.op, self.ATTR, getattr(self.op, self.ATTR, 0) + 1)
         default_registry().counter(
             "sql_distsql_bucket_restarts_total",
-            "flow restarts that sent a BY_HASH join from buckets sized by "
-            "the planner's row estimate back to the buckets its lanes "
-            "give (the estimate was low: a bucket filled)").inc()
+            "flow restarts that sent a BY_HASH join or aggregate from "
+            "buckets sized by the planner's row estimate back to the "
+            "buckets its lanes give (the estimate was low: a bucket "
+            "filled)").inc()
+
+
+# restart targets that stand for an operator of the tree (`.op`): a
+# program keeps the operator's walk position and the guard's name
+_GUARDS = {g.__name__: g for g in (_BucketGuard, _IntKeyAggGuard)}
+
+# the "side" a BY_HASH aggregate's partial is booked under beside a
+# join's "probe" and "build" (_DistTracer.buckets, `dist.bucket` events)
+AGG_SIDE = "partial"
+
+
+def _is_sharded(op: Operator, sharded: set, placed: dict) -> bool:
+    """Does `op`'s materialization hold device-LOCAL rows, under
+    `sharded` (ids of the chunk-sharded scans) and `placed`
+    (DistFusedRunner._classify's)? A scan says so itself. A top-K and a
+    sort merge across the axis (replicated output), and so does an
+    aggregate, unless it merges BY_HASH (_AggRoute): then each group is
+    on one shard. A BY_HASH join's output is a partition; a local join's
+    is placed as its probe is (a sharded build is gathered first);
+    anything else as its child."""
+    if isinstance(op, ScanOp):
+        return id(op) in sharded
+    if isinstance(op, (TopKOp, SortOp)):
+        return False
+    if isinstance(op, HashAggOp):
+        return id(op) in placed and _is_sharded(op.child, sharded, placed)
+    if isinstance(op, JoinOp):
+        return (isinstance(placed.get(id(op)), _Exchange)
+                or _is_sharded(op.probe, sharded, placed))
+    return any(_is_sharded(c, sharded, placed)
+               for c in child_operators(op))
 
 
 _PROGS: "OrderedDict[tuple, Optional[_Program]]" = OrderedDict()
@@ -334,8 +453,10 @@ def _plan_fingerprint(root: Operator) -> tuple:
 class _DistTracer(_Tracer):
     """Trace-time program builder running INSIDE shard_map. Differences
     from the single-chip tracer: sharded scans see only their local chunk
-    slice; large join builds co-partition; aggregations and top-Ks merge
-    across the mesh axis before finalizing."""
+    slice; large join builds co-partition and small computed ones are
+    gathered; aggregations and top-Ks merge across the mesh axis before
+    finalizing, or, where a shard's partial is large, BY_HASH on the
+    group key, which leaves the aggregate's output sharded."""
 
     def __init__(self, stacked, root: Operator, axis: str, n_dev: int,
                  sharded_scans: set, repart_ops: dict):
@@ -343,7 +464,13 @@ class _DistTracer(_Tracer):
         self.axis = axis
         self.n_dev = n_dev
         self.sharded_scans = sharded_scans   # id(scan) of chunk-sharded
-        self.repart_ops = repart_ops         # id(join) -> _Exchange
+        self.placed = repart_ops             # _classify's, every kind
+        # id(join) -> _Exchange
+        self.repart_ops = {i: x for i, x in repart_ops.items()
+                           if isinstance(x, _Exchange)}
+        # id(join) -> (lanes of its computed build as gathered, bytes one
+        # device sends for it)
+        self.gathered: Dict[int, Tuple[int, int]] = {}
         # (side, id(join)) -> bytes one device sends through that side's
         # exchange in one dispatch; keyed, because a streamed probe's
         # chain is traced twice (chunk 0, then the scan body)
@@ -353,26 +480,20 @@ class _DistTracer(_Tracer):
         # (side, id(join)) -> lanes through that side's destination sort
         # in one dispatch (the router sorts every lane of what it routes)
         self.route_lanes: Dict[tuple, int] = {}
+        # the three above hold a BY_HASH aggregate's partial under the
+        # side AGG_SIDE and the aggregate's id
 
-    def _note_exchange(self, side: str, op: JoinOp, batch: Batch,
+    def _note_exchange(self, side: str, op: Operator, batch: Batch,
                        bucket: int, times: int = 1) -> None:
         self.a2a[(side, id(op))] = times * exchange_bytes(
             batch, self.n_dev, bucket)
         self.route_lanes[(side, id(op))] = times * batch.capacity
 
-    def _bucket(self, side: str, op: JoinOp, by_lanes: int,
+    def _bucket(self, side: str, op: Operator, by_lanes: int,
                 by_est: Optional[int], parts: int = 1) -> int:
         bucket = side_bucket(by_lanes, by_est, self.n_dev, parts)
         self.buckets[(side, id(op))] = (bucket, by_lanes)
         return bucket
-
-    def _try_int_agg(self, op):
-        # ops/agg.int_key_aggregate hands back the run-ends view of ONE
-        # shard's rows, at the input's lanes; no cell groups by an
-        # integer key on the mesh, so that view has never been gathered
-        # and merged across shards. Until one does (Q18 on four shards,
-        # ROADMAP R6), a shard's partial is the hash aggregate's.
-        return None
 
     # -- how a co-partitioned join's sides reach it ---------------------------
     #
@@ -399,7 +520,16 @@ class _DistTracer(_Tracer):
 
     def _join_build(self, op: JoinOp):
         if id(op) not in self.repart_ops:
-            return super()._join_build(op)
+            build, ovf = super()._join_build(op)
+            if self._is_sharded(op.build):
+                # a build computed from sharded scans (_classify admits
+                # one a ShrinkOp keeps small: _Gather): every shard takes
+                # the whole of it, and the join is local
+                sent = exchange_bytes(build, self.n_dev, build.capacity)
+                with self._scope(op, MERGE):
+                    build = _all_gather_batch(build, self.axis)
+                self.gathered[id(op)] = (build.capacity, sent)
+            return build, ovf
         # a routed partition is not held to op.workmem (ROADMAP D3)
         x = self.repart_ops[id(op)]
         bucket = self._bucket("build", op, x.build, x.build_est)
@@ -443,6 +573,9 @@ class _DistTracer(_Tracer):
         # local partial: the single-chip lowering's accumulator, before
         # finalization
         local, dense = self._agg_partial(op)
+        route = self.placed.get(id(op))
+        if route is not None and not dense:
+            return op._final_project(self._merge_by_hash(op, local, route))
         with self._scope(op, MERGE):
             if dense:
                 # group g sits at lane g on every shard: the partials
@@ -464,14 +597,39 @@ class _DistTracer(_Tracer):
                     self.flags.append(coll)
         return op._final_project(merged)
 
+    def _merge_by_hash(self, op: HashAggOp, local: Batch,
+                       route: _AggRoute) -> Batch:
+        """The BY_HASH merge (the reference's local aggregator stage,
+        HashRouters on the group columns, a final aggregator a node): the
+        shard's partial is routed on the group key as it stands (an
+        int-key partial's run-ends view: dead lanes go nowhere, nothing
+        compacts it first), and what arrives, n_dev x bucket lanes, is
+        merged by the operator's merging functions: each group on
+        exactly ONE shard, the accumulator before finalization. A full
+        bucket raises a flag of its own (_BucketGuard: back to the
+        lanes' bucket, once)."""
+        group_by = tuple(op.group_by)
+        bucket = self._bucket(
+            AGG_SIDE, op, exchange_bucket(local.capacity, self.n_dev),
+            route.bucket_est)
+        self._note_exchange(AGG_SIDE, op, local, bucket)
+        stats.add("dist.agg_partitioned")
+        with self._scope(op, EXCHANGE):
+            arrived, ovf = hash_repartition_local(
+                local, group_by, self.axis, self.n_dev, bucket, seed=1)
+        self.flag_ops.append(_BucketGuard(op))
+        self.flags.append(ovf)
+        if self._int_keyed(op):
+            return self._int_key_agg(op, arrived, op._merge_aggs)
+        merged, coll = hash_aggregate(
+            arrived, group_by, op._merge_aggs, seed=op.seed + 7,
+            method="hash", with_flag=True)
+        self.flag_ops.append(op)
+        self.flags.append(coll)
+        return merged
+
     def _is_sharded(self, op: Operator) -> bool:
-        """Does this subtree's materialization hold only device-LOCAL rows?
-        Aggregations and top-Ks merge across the axis (replicated output);
-        everything else is sharded iff it reads a sharded scan."""
-        if isinstance(op, (HashAggOp, TopKOp)):
-            return False
-        return any(isinstance(n, ScanOp) and id(n) in self.sharded_scans
-                   for n in walk_operators(op))
+        return _is_sharded(op, self.sharded_scans, self.placed)
 
     def _mat_inner(self, op: Operator) -> Batch:
         if isinstance(op, TopKOp):
@@ -533,12 +691,21 @@ class DistFusedRunner:
         # None until the runner has dispatched
         self._last_bound: Optional[tuple] = None
 
-    # chunk-shard the scans on the probe spine (and on a repartitioned
-    # build's own probe spine); replicate the (small) broadcast builds.
-    # A join materialized with sharded probe + replicated build is a
-    # correct sharded result; a sharded build is only correct through the
-    # explicit repartition path — nested repartition inside a build is
-    # rejected (falls back to single-chip).
+    # Chunk-shard the scans on the probe spine; a join's build is placed
+    # by what it scans and by what it EMITS, from the one limit
+    # (sql.distsql.broadcast_limit_rows):
+    # - it scans no more rows than the limit: its scans are replicated and
+    #   every shard computes it whole (MIRROR);
+    # - it scans more, but a ShrinkOp on its spine keeps what a shard
+    #   emits x shards under the limit: its own spine is sharded, each
+    #   shard computes its part and an all_gather hands every shard the
+    #   whole (_Gather), the join local;
+    # - otherwise both sides are co-partitioned BY_HASH (_Exchange), its
+    #   own spine sharded. A sharded build is only correct through one of
+    #   the two; a BY_HASH join nested inside a build is rejected (the
+    #   single-chip ladder, or 0A000 under `always`).
+    # An aggregate on a sharded spine whose partial can hold more groups
+    # a shard than the limit merges BY_HASH too (_AggRoute).
     def _classify(self, chunks: Dict[int, int]):
         limit = Settings().get(BROADCAST_LIMIT)
         sharded: set = set()
@@ -556,18 +723,74 @@ class DistFusedRunner:
                     raise Unsupported("right/outer join on sharded spine")
                 spine(op.probe, in_build)
                 rows = self._subtree_rows(op.build, chunks)
-                if rows > limit:
-                    if in_build:
-                        raise Unsupported(
-                            "repartitioned join nested inside a build")
+                if rows <= limit:
+                    return  # small build: scans stay replicated (broadcast)
+                lanes = self._shard_lanes(op.build, chunks)
+                if lanes * self.n_dev < limit:
+                    repart[id(op)] = _Gather(lanes)
+                elif in_build:
+                    raise Unsupported(
+                        "repartitioned join nested inside a build")
+                else:
                     repart[id(op)] = self._exchange(op, rows)
-                    spine(op.build, in_build=True)
-                return  # small build: scans stay replicated (broadcast)
+                spine(op.build, in_build=True)
+                return
             for c in _children(op):
                 spine(c, in_build)
+            if isinstance(op, HashAggOp):
+                route = self._agg_route(op, chunks, sharded, repart, limit)
+                if route is not None:
+                    repart[id(op)] = route
 
         spine(self.root)
         return sharded, repart
+
+    def _shard_lanes(self, op: Operator, chunks: Dict[int, int]) -> int:
+        """The lanes ONE shard materializes `op` at, reckoned from the
+        plan with the scans on `op`'s spine sharded: a scan's padded
+        share of its chunks, a ShrinkOp's capacity, a top-K's k, a dense
+        aggregate's slots, a join's probe (x expansion where it can
+        fan out); anything else its child's."""
+        if isinstance(op, ScanOp):
+            return op.capacity * _pow2_at_least(
+                -(-chunks[id(op)] // self.n_dev))
+        if isinstance(op, ShrinkOp):
+            return op.capacity
+        if isinstance(op, TopKOp):
+            return op.k
+        if isinstance(op, HashAggOp) and op._dense_sizes is not None:
+            return int(np.prod(op._dense_sizes))
+        if isinstance(op, JoinOp):
+            base = self._shard_lanes(op.probe, chunks)
+            return base if op.how in ("semi", "anti") else (
+                base * op.expansion)
+        return max(self._shard_lanes(c, chunks) for c in _children(op))
+
+    def _agg_route(self, op: HashAggOp, chunks, sharded: set, placed: dict,
+                   limit: int) -> Optional[_AggRoute]:
+        """How `op` merges across the shards, decided once its subtree is
+        placed: BY_HASH (-> its bucket) where its input is sharded and a
+        shard's partial can hold more groups than the limit: the planner
+        expects more than that at all (`est_rows`; a tree built by hand
+        carries none and merges as before) and the partial's lanes hold
+        more. None: by all_gather on every shard (a dense partial
+        lane-wise), its output replicated. The bucket from the estimate
+        is a shard's share of the groups (a table loaded in key order
+        shards them evenly, as _exchange says of rows), unless it gives
+        no less than the lanes, or a full bucket has sent the aggregate
+        back to its lanes'."""
+        est = _est_rows(op)
+        if (est is None or not op.group_by or op._dense_sizes is not None
+                or not _is_sharded(op.child, sharded, placed)):
+            return None
+        lanes, n = self._shard_lanes(op.child, chunks), self.n_dev
+        if min(lanes, est) <= limit:
+            return None
+        by_lanes, by_est = exchange_bucket(lanes, n), \
+            exchange_bucket(est // n, n)
+        if by_est >= by_lanes or getattr(op, _BucketGuard.ATTR, 0):
+            by_est = None
+        return _AggRoute(by_lanes, by_est)
 
     def _exchange(self, op: JoinOp, build_rows: int) -> _Exchange:
         """Size the buckets of a BY_HASH join whose build subtree scans
@@ -593,9 +816,11 @@ class DistFusedRunner:
 
     def describe(self, chunks: Dict[int, int]) -> List[str]:
         """EXPLAIN's distribution lines for `chunks` ({id(scan): chunk
-        count}): how many shards, each scan's placement, and each join's
-        router — BY_HASH (both sides through an all_to_all) or MIRROR
-        (the build replicated, the join local)."""
+        count}): how many shards, each scan's placement, each join's
+        router — BY_HASH (both sides through an all_to_all), GATHER (the
+        build computed a shard and gathered, the join local) or MIRROR
+        (the build replicated, the join local) — and each aggregate that
+        merges BY_HASH."""
         try:
             sharded, repart = self._classify(chunks)
         except Unsupported as e:
@@ -610,8 +835,12 @@ class DistFusedRunner:
                              f"{role} ({chunks[id(op)]} chunks of "
                              f"{op.capacity} rows)")
             elif isinstance(op, JoinOp):
-                if id(op) in repart:
-                    x = repart[id(op)]
+                x = repart.get(id(op))
+                if isinstance(x, _Gather):
+                    how = (f"GATHER (build computed a shard at {x.lanes} "
+                           f"lanes, all_gather of {self.n_dev * x.lanes} "
+                           f"to every shard, local join)")
+                elif x is not None:
                     # an estimated probe bucket is the whole side's (a
                     # streamed chunk takes its share of it), and no side's
                     # passes what its lanes give (side_bucket)
@@ -631,6 +860,15 @@ class DistFusedRunner:
                 else:
                     how = "replicated (every shard joins it whole)"
                 lines.append(f"  {op.how} join on {_join_keys(op)}: {how}")
+            elif id(op) in repart:
+                x = repart[id(op)]
+                rows = (f"{x.bucket} groups" if x.bucket_est is None else
+                        f"{x.bucket_est} groups (estimated "
+                        f"{_est_rows(op)} groups)")
+                lines.append(
+                    f"  aggregate by {', '.join(op.group_by)}: BY_HASH "
+                    f"(local partial, all_to_all on the group key, final "
+                    f"stage a shard; buckets of {rows} a shard)")
         return lines
 
     def _subtree_rows(self, op, chunks) -> int:
@@ -728,6 +966,10 @@ class DistFusedRunner:
         states of the statistics share a program until they round to
         different powers of two. Without an estimate, or sent back to its
         lanes, it adds nothing; each join adds its own pair, or nothing.
+        A gathered build and a BY_HASH aggregate say so (the aggregate
+        with its estimate's bucket), and an aggregate adds the int-key
+        kernel's state (exec/fused._IntKeyAggGuard), which its restarts
+        move.
         `bound` (the statement's bound values) adds each argument's
         shape and type, never a value: the packed vector's length and a
         pattern table's padded length are the statement's."""
@@ -745,8 +987,10 @@ class DistFusedRunner:
                 out.append((type(op).__name__, op.expansion, op.workmem,
                             getattr(op, "seed", 0),
                             getattr(op, "build_mode", ""))
-                           + ((x.probe_est, x.build_est)
-                              if x is not None and x.estimated else ()))
+                           + ((getattr(op, "_ia_ok", True),
+                               getattr(op, "_ia_wide", False))
+                              if isinstance(op, HashAggOp) else ())
+                           + (x.key if x is not None else ()))
             elif isinstance(op, SortOp):
                 out.append(("sort", op.workmem))
             elif isinstance(op, ShrinkOp):
@@ -770,12 +1014,20 @@ class DistFusedRunner:
             # after the images, replicated, read while it is traced
             with _expr.traced_params(stacked_args[len(scans):]):
                 out = t._mat(root)
+            if t._is_sharded(root):
+                with scope(RESULT_SCOPE):
+                    out = _gather_result(out, axis)
             box["flag_ops"] = list(t.flag_ops)
             box["result_cap"] = min(RESULT_CAP, out.capacity)
             box["a2a_bytes"] = sum(t.a2a.values())
             box["buckets"] = dict(t.buckets)
             box["sort_lanes"] = t.sort_lanes + sum(t.route_lanes.values())
             box["hash_key_lanes"] = t.hash_key_lanes
+            box["agg_route"] = tuple(
+                sum(v for (side, _), v in d.items() if side == AGG_SIDE)
+                for d in (t.route_lanes, t.a2a))
+            box["gather_build"] = tuple(
+                sum(v[i] for v in t.gathered.values()) for i in (0, 1))
             with scope(RESULT_SCOPE):
                 flags = tuple(
                     lax.psum(f.astype(jnp.int32), axis) > 0
@@ -821,30 +1073,39 @@ class DistFusedRunner:
             self._record_buckets(repart, box["buckets"])
         pos = {id(op): i for i, op in enumerate(ops)}
         flag_idx = tuple(
-            pos[id(f.op if isinstance(f, _BucketGuard) else f)]
+            pos[id(f.op if isinstance(f, tuple(_GUARDS.values())) else f)]
             for f in box["flag_ops"])
         flag_types = tuple(type(f).__name__ for f in box["flag_ops"])
         entry = _Program(compiled, flag_idx, flag_types, box["result_cap"],
                          box["a2a_bytes"], box["sort_lanes"],
-                         box["hash_key_lanes"])
+                         box["hash_key_lanes"], box["agg_route"],
+                         box["gather_build"])
         _PROGS[pkey] = entry
         _trim_progs()
         return entry
 
     def _record_buckets(self, repart, buckets) -> None:
-        """One `dist.bucket` event a routed side on the `dist.compile`
-        span: the planner's estimate (None: the operator carries none),
-        the bucket the program was traced with, and the one the side's
-        lanes give (the same two after a _BucketGuard restart)."""
+        """One `dist.bucket` event a routed side (a BY_HASH aggregate's
+        partial is one) on the `dist.compile` span: the planner's
+        estimate (None: the operator carries none), the bucket the
+        program was traced with, and the one the side's lanes give (the
+        same two after a _BucketGuard restart)."""
         for op in walk_operators(self.root):
-            if id(op) not in repart:
+            x = repart.get(id(op))
+            if isinstance(x, _AggRoute):
+                sides = ((AGG_SIDE, op),)
+                tag = {"agg": ", ".join(op.group_by)}
+            elif isinstance(x, _Exchange):
+                sides = (("probe", op.probe), ("build", op.build))
+                tag = {"join": _join_keys(op)}
+            else:
                 continue
-            for side, sub in (("probe", op.probe), ("build", op.build)):
+            for side, sub in sides:
                 if (side, id(op)) in buckets:
                     bucket, by_lanes = buckets[(side, id(op))]
-                    _tracing.record("dist.bucket", join=_join_keys(op),
-                                    side=side, est_rows=_est_rows(sub),
-                                    bucket=bucket, lanes_bucket=by_lanes)
+                    _tracing.record("dist.bucket", side=side,
+                                    est_rows=_est_rows(sub), bucket=bucket,
+                                    lanes_bucket=by_lanes, **tag)
 
     # ---------------------------------------------------------- prepare --
 
@@ -966,7 +1227,7 @@ class DistFusedRunner:
     def batches(self):
         """One dispatch of the whole query. Raises `Unsupported` for a
         plan, or an answer, the distributed runner does not take (right/
-        outer on the sharded spine, a repartition nested in a build, an
+        outer on the sharded spine, a BY_HASH join nested in a build, an
         empty scan, more rows than the packed result window): the caller
         decides what answers instead (collect_distributed)."""
         with stats.timed("dist.prepare"):
@@ -993,6 +1254,10 @@ class DistFusedRunner:
             stats.add("dist.a2a", bytes=a2a_bytes)
             stats.add("dist.sort_lanes", rows=prog.sort_lanes)
             stats.add("dist.hash_key_lanes", rows=prog.hash_key_lanes)
+            for stage, (lanes, sent) in (("dist.agg_route", prog.agg_route),
+                                         ("dist.gather_build",
+                                          prog.gather_build)):
+                stats.add(stage, rows=lanes, bytes=sent)
             default_registry().counter(
                 "sql_distsql_exchange_bytes_total",
                 "bytes one device sent through the BY_HASH exchanges of "

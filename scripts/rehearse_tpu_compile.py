@@ -4,7 +4,7 @@ on-chip-measurement guide. What the chip's compiler refuses it refuses
 here, at no chip time; each compile's seconds are the smoke's cold budget.
 
     JAX_PLATFORMS=cpu python scripts/rehearse_tpu_compile.py [--sf 1] \
-        [--only kernel,q1,q6,q3,q3_dist4,q9_dist4]
+        [--only kernel,q1,q6,q3,q3_dist4,q9_dist4,q18_dist4]
 
 Plans are built from SF1 data (TPCH.mvcc_load, so the planner sees SF1's
 statistics and every inner capacity is SF1's), lowered exactly as
@@ -15,7 +15,11 @@ Q3's whole programs (minutes) stay here. One JSON line per program.
 `q9_dist4` (not in the default list: a quarter of an hour and more) is the
 benchmark cell tpch-sf1-q9-mesh4.q9-1stream's program: Q9 prepared, from
 that cell's loader and text, its bound pattern's table a replicated
-argument.
+argument. `q18_dist4` (not in the default list either) is
+tpch-sf1-q18-mesh4.q18-1stream's: Q18 prepared, its aggregate merged
+BY_HASH and its two computed builds gathered; its line carries EXPLAIN's
+distribution lines, so the layout and the lanes are read here before a
+chip compile is paid.
 """
 
 from __future__ import annotations
@@ -118,6 +122,8 @@ def main():
 
     if "q9_dist4" in only:
         q9_dist4(topo, args)
+    if "q18_dist4" in only:
+        q18_dist4(topo, args)
     fused_names = [q for q in ("q1", "q6", "q3") if q in only]
     if not fused_names and "q3_dist4" not in only:
         return
@@ -195,8 +201,32 @@ def q9_dist4(topo, args):
     """The mesh cell's Q9 as DistFusedRunner lowers it for four described
     chips: layout by _classify at the default broadcast limit, the plan
     made at '%green%', the bound values' shapes after the images."""
-    from benchmark import manifest
     from benchmark.loaders import tpch_pname
+
+    repart = prepared_dist4(topo, args, "q9_dist4",
+                            "tpch-sf1-q9-mesh4.q9-1stream", tpch_pname,
+                            ("%green%",))
+    assert len(repart) == 2, "Q9 at SF1 routes partsupp and orders"
+
+
+def q18_dist4(topo, args):
+    """The mesh cell's Q18 likewise, the plan made at QUANTITY 313: the
+    aggregate on l_orderkey routed BY_HASH, both computed builds
+    gathered, no join routed."""
+    from benchmark.loaders import tpch_cname
+
+    repart = prepared_dist4(topo, args, "q18_dist4",
+                            "tpch-sf1-q18-mesh4.q18-1stream", tpch_cname,
+                            ("313",))
+    kinds = sorted(type(x).__name__ for x in repart.values())
+    assert kinds == ["_AggRoute", "_Gather", "_Gather"], kinds
+
+
+def prepared_dist4(topo, args, name, cell, loader, values) -> dict:
+    """Lower and compile `cell`'s one prepared statement as
+    DistFusedRunner does for four described chips, from `loader`'s SF
+    tables with `values` bound; -> _classify's placements."""
+    from benchmark import manifest
     from cockroach_tpu.exec.operators import ScanOp, walk_operators
     from cockroach_tpu.ops.expr import bound_args
     from cockroach_tpu.parallel import dist_flow, ingest
@@ -207,15 +237,15 @@ def q9_dist4(topo, args):
     from cockroach_tpu.storage.mvcc import MVCCStore
     from cockroach_tpu.util.settings import PALLAS, Settings
 
-    stmt = manifest.cell("tpch-sf1-q9-mesh4.q9-1stream")["statements"][0]
+    stmt = manifest.cell(cell)["statements"][0]
     t0 = time.perf_counter()
-    loaded = tpch_pname.load(MVCCStore(), {"sf": args.sf}, stmt["tables"],
-                             args.seed)
-    print(json.dumps({"loaded_sf": args.sf, "loader": "tpch_pname",
+    loaded = loader.load(MVCCStore(), {"sf": args.sf}, stmt["tables"],
+                         args.seed)
+    print(json.dumps({"loaded_sf": args.sf, "loader": loader.__name__,
                       "seconds": round(time.perf_counter() - t0, 1)}),
           flush=True)
     Settings().set(PALLAS, "on")
-    catalog, values = loaded["catalog"], ("%green%",)
+    catalog = loaded["catalog"]
     binder = Binder(catalog, params=values)
     plan = binder.bind(parser.parse(stmt["sql"]))
     bound = _params.evaluate(binder.param_slots, values)
@@ -229,7 +259,6 @@ def q9_dist4(topo, args):
     chunks = {id(sc): -(-gen.num_rows(sc.table) // sc.capacity)
               for sc in scans}
     sharded, repart = runner._classify(chunks)
-    assert len(repart) == 2, "Q9 at SF1 routes partsupp and orders"
 
     def lead(sc, n):
         if id(sc) in sharded:
@@ -242,17 +271,22 @@ def q9_dist4(topo, args):
     t0 = time.perf_counter()
     box = {}
     lowered = runner._lower(scans, sharded, repart, sds, box)
-    by_join = {id(op): dist_flow._join_keys(op)
-               for op in walk_operators(cp.op) if id(op) in repart}
-    report("q9_dist4", lowered, time.perf_counter() - t0,
-           buckets={f"{side} {by_join[j]}": b
+    by_op = {id(op): (dist_flow._join_keys(op) if hasattr(op, "probe_on")
+                      else ", ".join(op.group_by))
+             for op in walk_operators(cp.op) if id(op) in repart}
+    report(name, lowered, time.perf_counter() - t0,
+           buckets={f"{side} {by_op[j]}": b
                     for (side, j), b in box["buckets"].items()},
            a2a_mb=round(box["a2a_bytes"] / 1e6, 2),
            sort_lanes=box["sort_lanes"],
            hash_key_lanes=box["hash_key_lanes"],
+           agg_route=box.get("agg_route"),
+           gather_build=box.get("gather_build"),
            params=[list(a.shape) for a in bound],
+           distribution=runner.describe(chunks),
            roles={sc.table: (ingest.SHARDED if id(sc) in sharded
                              else ingest.REPLICATED) for sc in scans})
+    return repart
 
 
 if __name__ == "__main__":

@@ -807,6 +807,381 @@ def test_explain_under_always_prints_parameters_estimates_and_both_routers(
     assert not dist_flow._PROGS and not dist_flow.ingest._CACHE
 
 
+# ------------- an exchange UNDER an aggregate, gathered builds (ISSUE 45) ----
+
+Q18_STMT = manifest.cell("tpch-sf1-q18-mesh4.q18-1stream")["statements"][0]
+Q18 = Q18_STMT["sql"]
+# SF1's layout at SF 0.05 and 4,096 rows a chunk: lineitem 74 chunks, 19 a
+# shard padded to 32 = 131,072 lanes, over a limit of 98,304 with the
+# planner's 149,975 groups, so the GROUP BY on l_orderkey merges BY_HASH;
+# both computed builds scan more than the limit and a Shrink keeps what a
+# shard emits x 4 under it (4 x 4,096, 4 x 16,384): gathered; orders (19
+# chunks) sharded on its spine, customer (2 chunks) MIRROR
+Q18_SF = 0.05
+Q18_LIMIT = 3 * 32768
+Q18_EXPLAIN = [
+    "  inner join on l_orderkey=o_orderkey: GATHER (build computed a shard "
+    "at 16384 lanes, all_gather of 65536 to every shard, local join)",
+    "  scan lineitem: sharded (74 chunks of 4096 rows)",
+    "  semi join on o_orderkey=l_orderkey: GATHER (build computed a shard "
+    "at 4096 lanes, all_gather of 16384 to every shard, local join)",
+    "  inner join on o_custkey=c_custkey: MIRROR (build of 8192 rows "
+    "replicated, local join)",
+    "  scan orders: sharded (19 chunks of 4096 rows)",
+    "  scan customer: replicated (2 chunks of 4096 rows)",
+    "  aggregate by l_orderkey: BY_HASH (local partial, all_to_all on the "
+    "group key, final stage a shard; buckets of 32768 groups (estimated "
+    "149975 groups) a shard)",
+    "parameters: $1 decimal(2)",
+    "estimates taken at: $1 = 250"]
+
+
+def _load18(gen):
+    from benchmark.loaders import tpch_cname
+    from benchmark.reference import tpch_q18
+
+    loaded = tpch_cname.load_from(gen, MVCCStore(), Q18_STMT["tables"])
+    loaded["mesh"] = make_mesh(N_DEV)
+    loaded["ref"] = tpch_q18.Reference(loaded["data"], loaded["dicts"], {})
+    return loaded
+
+
+@pytest.fixture(scope="module")
+def tpch18():
+    from benchmark.loaders import tpch_cname
+
+    return _load18(tpch_cname.TPCHCName(sf=Q18_SF, seed=SEED))
+
+
+@pytest.fixture
+def limit18():
+    s = Settings()
+    old = s.get(dist_flow.BROADCAST_LIMIT)
+    s.set(dist_flow.BROADCAST_LIMIT, Q18_LIMIT)
+    dist_flow.progs_clear()
+    try:
+        yield
+    finally:
+        s.set(dist_flow.BROADCAST_LIMIT, old)
+        stats.disable()
+
+
+@pytest.fixture
+def catalog18(tpch18, limit18):
+    cat = tpch18["catalog"].with_mesh(tpch18["mesh"])
+    try:
+        yield cat
+    finally:
+        cat.with_mesh(None)
+
+
+def _q18_rows(payload, dicts):
+    from tests.test_q18 import _as_wire
+
+    return _as_wire(payload, dicts["c_name"])
+
+
+def _q18_bindings(ref):
+    """Four values of QUANTITY: over the LIMIT's 100 rows, a few rows, the
+    fewest there are, none."""
+    from tests.test_q18 import _bindings
+
+    many, few, none = _bindings(ref)
+    some = str((int(many[0]) + int(few[0])) // 2)
+    assert 0 < len(ref.answer((some,))) < 100
+    return [many, (some,), few, none]
+
+
+def _routed(op):
+    """(the aggregates merged BY_HASH, the joins with a gathered build) as
+    _classify places `op`'s tree now."""
+    from cockroach_tpu.exec.operators import ScanOp
+
+    runner = dist_flow.DistFusedRunner(op, make_mesh(N_DEV))
+    _scans, _sources, chunks = runner._prime()
+    _sharded, placed = runner._classify(chunks)
+    kinds = {i: type(x).__name__ for i, x in placed.items()}
+    ops = [o for o in walk_operators(op) if not isinstance(o, ScanOp)]
+    return ([o for o in ops if kinds.get(id(o)) == "_AggRoute"],
+            [o for o in ops if kinds.get(id(o)) == "_Gather"])
+
+
+def test_q18_always_is_exact_at_four_bindings_in_one_program(tpch18,
+                                                            catalog18):
+    """Q18 prepared on four shards (SF1's layout): exact against the
+    plain reference and equal to `distsql = off` at four bindings, ONE
+    prepared entry, ONE _PROGS entry, one compile, nothing bound as text,
+    no restart; the aggregate's exchange and both gathers are counted a
+    dispatch and the routed bytes are part of dist.a2a."""
+    ref, dicts = tpch18["ref"], tpch18["dicts"]
+    textual, restarts = (reg_value("sql_bind_textual_total"),
+                         reg_value("sql_flow_restarts_total"))
+    sess = _session(catalog18, "set distsql = always")
+    col = stats.enable()
+    answers = []
+    bindings = _q18_bindings(ref)
+    for values in bindings:
+        payload, root = _bound(sess, Q18, values)
+        assert root.tags["tier"] == "dist"
+        answers.append(_q18_rows(payload, dicts))
+        oks, compared = ref.check([(values, answers[-1])])
+        assert oks == [True], (values, compared)
+    sizes = [len(a) for a in answers]
+    assert sizes[0] == 100 and 0 < sizes[2] <= sizes[1] < 100 \
+        and sizes[3] == 0
+    assert _events(col, "dist.fallback_unsupported") == 0
+    assert _events(col, "dist.compile") == 1
+    assert len(dist_flow._PROGS) == 1
+    assert reg_value("sql_bind_textual_total") == textual
+    assert reg_value("sql_flow_restarts_total") == restarts
+    (prep,) = sess._prepared.values()
+    assert prep.dist and len(prep.slots) == 1
+    (prog,) = dist_flow._PROGS.values()
+    n = len(bindings)
+    # the aggregate's router sorts a shard's 131,072 partial lanes into
+    # buckets of 32,768 (a shard's share of the planner's 149,975 groups);
+    # a partial's row: l_orderkey 8, sum 8 + its validity lane, selection
+    row = 8 + 8 + 1 + 1
+    assert prog.agg_route == (131072, (N_DEV - 1) * 32768 * row)
+    assert prog.a2a_bytes == prog.agg_route[1]      # no join is routed
+    assert col.stages["dist.agg_route"].rows == n * 131072
+    assert col.stages["dist.agg_route"].bytes == n * prog.agg_route[1]
+    assert col.stages["dist.a2a"].bytes == n * prog.a2a_bytes
+    # both builds as gathered on every shard: 4 x 4,096 and 4 x 16,384
+    assert prog.gather_build[0] == 16384 + 65536
+    assert col.stages["dist.gather_build"].rows == n * (16384 + 65536)
+    assert _events(col, "dist.agg_partitioned") == 1    # one traced program
+    assert _events(col, "fused.agg_int_key") == 1
+    assert prog.flag_types.count("_BucketGuard") == 1
+    assert prog.flag_types.count("_IntKeyAggGuard") == 2    # partial, final
+    # the router's destination sort is in dist.sort_lanes, with the final
+    # stage's sort over what arrived (4 x 32,768)
+    assert col.stages["dist.sort_lanes"].rows == n * prog.sort_lanes
+    aggs, gathered = _routed(prep.op)
+    assert [a.group_by for a in aggs] == [["l_orderkey"]]
+    assert [j.how for j in gathered] == ["inner", "semi"]
+    # the same session with `distsql = off` (its SET drops the shared
+    # entry: after the mesh's checks): one chip, the same rows (ties on
+    # both sort keys may stand in another order)
+    assert sess.execute("set distsql = off")[0] == "ok"
+    assert sess.execute("set vectorize = tpu")[0] == "ok"
+    for values, got in zip(bindings, answers):
+        off, root = _bound(sess, Q18, values)
+        assert root.tags["tier"] == "fused"
+        assert sorted(_q18_rows(off, dicts)) == sorted(got)
+
+
+def test_q18s_program_gathers_no_partial_and_routes_no_join(tpch18,
+                                                            catalog18):
+    """The lowered program holds ONE all_to_all per lane of the partial
+    (under the aggregate's `.exchange` scope) and no all_gather at the
+    partial's 131,072 lanes: what is gathered is the two shrunk builds
+    and the last aggregate's 16,384-lane partial."""
+    import re
+
+    sess = _session(catalog18, "set distsql = always")
+    _bound(sess, Q18, ("300",))
+    (prep,) = sess._prepared.values()
+    text = _lowered_text(prep.op, tpch18["mesh"],
+                         P_.evaluate(prep.slots, ("300",)))
+    gathers = {int(m) for m in re.findall(
+        r"stablehlo.all_gather.*?-> tensor<(\d+)x", text)}
+    assert gathers and max(gathers) <= 65536
+    assert "all_to_all" in text
+
+
+def test_the_shares_of_q18s_aggregate_add_up(tpch18, catalog18):
+    """The final stage's outputs of the four shards are disjoint in
+    l_orderkey, and their union is the whole aggregate: every order once,
+    its quantity the sum over ALL its lines, wherever they were
+    scanned."""
+    from cockroach_tpu.exec.operators import ScanOp
+    from cockroach_tpu.parallel.repartition import shard_map
+
+    sess = _session(catalog18, "set distsql = always")
+    _bound(sess, Q18, ("300",))
+    (prep,) = sess._prepared.values()
+    (agg,), _gathered = _routed(prep.op)
+    runner = dist_flow.DistFusedRunner(prep.op, tpch18["mesh"])
+    scans, sources, chunks = runner._prime()
+    sharded, placed, images = runner._materialize(scans, sources, chunks)
+    mine = [sc for sc in scans
+            if any(n is sc for n in walk_operators(agg))]
+    assert [sc.table for sc in mine] == ["lineitem"]
+
+    def final_stage(*stacked):
+        t = dist_flow._DistTracer(dict(zip([id(s) for s in mine], stacked)),
+                                  prep.op, "x", N_DEV, sharded, placed)
+        out = t._mat(agg)
+        assert out.capacity == N_DEV * 32768     # what arrived, merged
+        return (out.col("l_orderkey").values, out.col("sum").values,
+                out.sel)
+
+    spec = dist_flow.P("x")
+    keys, sums, sel = jax.jit(shard_map(
+        final_stage, mesh=tpch18["mesh"],
+        in_specs=tuple((spec, spec) for _ in mine),
+        out_specs=(spec, spec, spec), check_rep=False))(
+            *((images[id(sc)].bufs, images[id(sc)].ms) for sc in mine))
+    keys, sums, sel = (np.asarray(a).reshape(N_DEV, -1)
+                       for a in (keys, sums, sel))
+    shares = [dict(zip(keys[d][sel[d]].tolist(), sums[d][sel[d]].tolist()))
+              for d in range(N_DEV)]
+    assert all(len(s) == int(sel[d].sum()) for d, s in enumerate(shares))
+    assert all(len(s) > 10000 for s in shares)       # an even spread
+    union = {}
+    for share in shares:
+        assert not union.keys() & share.keys()        # disjoint
+        union.update(share)
+    li = tpch18["data"]["lineitem"]
+    want = {}
+    for k, q in zip(li["l_orderkey"].tolist(), li["l_quantity"].tolist()):
+        want[k] = want.get(k, 0) + q
+    assert union == want and len(want) == 75000
+
+
+def test_q18_on_the_mesh_takes_ties_at_the_cut_as_a_set(limit18):
+    """tests/test_q18.py's tied data set (every row of the answer ties
+    with dozens on both sort keys, at the cut too) on four shards: 100
+    rows, any of the tied ones at the cut, as the reference takes them."""
+    from tests.test_q18 import _Tied, _bindings
+
+    loaded = _load18(_Tied(sf=Q18_SF, seed=7))
+    cat = loaded["catalog"].with_mesh(loaded["mesh"])
+    try:
+        ref = loaded["ref"]
+        values = _bindings(ref)[0]
+        passing = ref.answer(values)
+        cut = (passing[99][4], passing[99][3])
+        assert (passing[100][4], passing[100][3]) == cut
+        sess = _session(cat, "set distsql = always")
+        payload, root = _bound(sess, Q18, values)
+        assert root.tags["tier"] == "dist"
+        got = _q18_rows(payload, loaded["dicts"])
+        assert len(got) == 100
+        assert ref.check([(values, got)])[0] == [True]
+        assert ref.check([(values, got[1:] + [got[0]])])[0] == [False]
+        aggs, gathered = _routed(next(iter(sess._prepared.values())).op)
+        assert len(aggs) == 1 and len(gathered) == 2
+    finally:
+        cat.with_mesh(None)
+
+
+def test_explain_prints_the_aggregates_buckets_and_both_gathered_builds(
+        catalog18):
+    sess = _session(catalog18, "set distsql = always")
+    bound, text = sess.bind_params("explain " + Q18, ("250",))
+    lines = sess.execute(text, params=bound)[1]
+    at = lines.index("distribution: full (4 shards, mesh axis 'x')")
+    assert lines[at + 1:] == Q18_EXPLAIN
+    assert not dist_flow._PROGS and not dist_flow.ingest._CACHE
+
+
+GROUPS = ("select l_orderkey, sum(l_quantity) as q from lineitem "
+          "group by l_orderkey order by q desc, l_orderkey limit 10")
+
+
+def _top_groups(tpch):
+    li = tpch["data"]["lineitem"]
+    sums = {}
+    for k, q in zip(li["l_orderkey"].tolist(), li["l_quantity"].tolist()):
+        sums[k] = sums.get(k, 0) + q
+    return sorted(sums.items(), key=lambda kq: (-kq[1], kq[0]))
+
+
+def test_a_low_estimate_restarts_the_aggregates_exchange_once(
+        tpch, catalog, monkeypatch):
+    """The planner's estimate of the groups forced to 4,100 (just over the
+    limit, so the aggregate still merges BY_HASH): buckets of 512 where
+    about 940 groups go to each destination. The router's flag, a flag of
+    the aggregate's own, restarts the flow ONCE on the bucket the lanes
+    give (8,192), and the answer is exact; the top-K above takes the
+    aggregate's sharded output (a local top 10, then the gather)."""
+    from cockroach_tpu.sql import plan as plan_mod
+    from cockroach_tpu.sql import plan_compile
+
+    real = plan_compile.estimate_cardinality
+
+    def low(node, cat):
+        if isinstance(node, plan_mod.Aggregate) and node.group_by:
+            return 4100.0
+        return real(node, cat)
+
+    monkeypatch.setattr(plan_compile, "estimate_cardinality", low)
+    b0, f0 = (reg_value("sql_distsql_bucket_restarts_total"),
+              reg_value("sql_flow_restarts_total"))
+    sess = _session(catalog, "set distsql = always")
+    col = stats.enable()
+    dist, root = _run(sess, GROUPS)
+    assert root.tags["tier"] == "dist"
+    assert list(zip(dist["l_orderkey"].tolist(), dist["q"].tolist())) == \
+        _top_groups(tpch)[:10]
+    assert (reg_value("sql_distsql_bucket_restarts_total"),
+            reg_value("sql_flow_restarts_total")) == (b0 + 1, f0 + 1)
+    restarts = [(tags["n"], tags["op"]) for s in root.walk()
+                for _t, msg, tags in s.events if msg == "flow.restart"]
+    assert restarts == [(1, "_BucketGuard")]
+    chosen = [[(tags["agg"], tags["side"], tags["est_rows"], tags["bucket"],
+                tags["lanes_bucket"])
+               for _t, msg, tags in s.events if msg == "dist.bucket"]
+              for s in root.walk() if s.name == "dist.compile"]
+    assert chosen == [[("l_orderkey", "partial", 4100, 512, 8192)],
+                      [("l_orderkey", "partial", 4100, 8192, 8192)]]
+    assert _events(col, "dist.agg_partitioned") == 2    # one a program
+    # the prepared tree keeps the lanes' bucket: no restart, no compile
+    again, _root = _run(sess, GROUPS)
+    _same(dist, again)
+    assert reg_value("sql_flow_restarts_total") == f0 + 1
+    assert _events(col, "dist.compile") == 2
+
+
+def test_a_root_with_sharded_rows_is_gathered(tpch, catalog):
+    """Nothing above a BY_HASH aggregate merges its shards' rows where the
+    statement has no ORDER BY: the program gathers the root's rows, so the
+    answer holds every shard's groups; and a result over the packed window
+    is declined under `always`, never cut."""
+    sess = _session(catalog, "set distsql = always")
+    payload, root = _run(
+        sess, "select l_orderkey, sum(l_quantity) as q from lineitem "
+              "group by l_orderkey having sum(l_quantity) > 230")
+    assert root.tags["tier"] == "dist"
+    want = [(k, q) for k, q in _top_groups(tpch) if q > 23000]
+    assert len(want) > 20
+    assert sorted(zip(payload["l_orderkey"].tolist(),
+                      payload["q"].tolist()),
+                  key=lambda kq: (-kq[1], kq[0])) == want
+    (prog,) = dist_flow._PROGS.values()
+    assert prog.agg_route[0] == 16384
+    with pytest.raises(SQLError) as e:
+        sess.execute("select l_orderkey, sum(l_quantity) as q from lineitem "
+                     "group by l_orderkey")
+    assert e.value.pgcode == "0A000" and "packed window" in str(e.value)
+
+
+def test_q3_and_q9_are_placed_as_they_were(catalog, catalog9):
+    """No aggregate of the other mesh cells' statements is routed and no
+    build of theirs gathered (their EXPLAIN lines are held above): Q3's
+    and Q9's partials are small (a Shrink's lanes, 208 slots) and their
+    big builds are scans."""
+    col = stats.enable()
+    for cat, session, run in (
+            (catalog, _session, lambda s: _run(s, Q3)),
+            (catalog9, _session9, lambda s: _bound(s, Q9, ("%green%",)))):
+        dist_flow.progs_clear()
+        sess = session(cat, "set distsql = always")
+        _payload, root = run(sess)
+        assert root.tags["tier"] == "dist"
+        (prog,) = dist_flow._PROGS.values()
+        assert prog.agg_route == (0, 0) and prog.gather_build == (0, 0)
+        assert _routed(next(iter(sess._prepared.values())).op) == ([], [])
+    # the two stages count their event a dispatch, as dist.a2a does, and
+    # nothing in it
+    assert _events(col, "dist.agg_partitioned") == 0
+    for stage in ("dist.agg_route", "dist.gather_build"):
+        assert _events(col, stage) == 2
+        assert (col.stages[stage].rows, col.stages[stage].bytes) == (0, 0)
+
+
 # ---------------------------------------- a low estimate (ISSUE 30) ----
 
 def test_a_low_estimate_restarts_once_and_answers_exactly(
@@ -964,6 +1339,10 @@ def test_dist_stages_and_device_seconds():
     # section 7 (k))
     ("tpch-sf1-q9-mesh4.q9-1stream", "10",
      {"dist_sort_lanes_m", "dist_args_ms", "bind_ms", "bind_like_ms",
+      "window_restarts", "prepared_hit_pct"}),
+    # a statement of Q18 takes 0.25 s there
+    ("tpch-sf1-q18-mesh4.q18-1stream", "10",
+     {"agg_exchange_ms", "agg_a2a_mb", "dist_sort_lanes_m", "bind_ms",
       "window_restarts", "prepared_hit_pct"}),
 ])
 def test_the_mesh_cell_rehearses_on_the_cpu(cell, seconds, mine,
