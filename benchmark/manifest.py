@@ -142,4 +142,16 @@ def validate(bench: dict) -> list:
             for w in m.get("workloads", ()):
                 if w not in cells:
                     errors.append(f"metric {m['name']}: unknown cell {w}")
+    for w in sorted(cells):
+        judged = {m["name"] for m in metrics_for(bench, w, "end_to_end")}
+        if "setup_s" not in judged or len(judged) < 2:
+            errors.append(f"workload {w}: reports {sorted(judged)}, not "
+                          f"setup_s and one more end-to-end metric")
+        layer = metrics_for(bench, w, "per_layer")
+        if not layer:
+            errors.append(f"workload {w}: reports no per-layer metric")
+        for m in layer:
+            if m["moves"] in e2e - judged:
+                errors.append(f"metric {m['name']}: moves {m['moves']}, "
+                              f"which {w} does not report")
     return errors
