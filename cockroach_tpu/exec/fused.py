@@ -84,7 +84,7 @@ from cockroach_tpu.ops import expr as _expr
 from cockroach_tpu.ops.sort import _sortable_int
 from cockroach_tpu.ops.vector import distance_fn
 from cockroach_tpu.ops.join import (
-    effective_build_mode, hash_join_prepared, prepare_build,
+    effective_build_mode, hash_join_prepared, prepare_build, scan64_lanes,
 )
 from cockroach_tpu.ops.sortjoin import compacts, probe_unique_compact
 from cockroach_tpu.ops.sort import sort_batch, top_k_batch
@@ -349,6 +349,10 @@ class _Tracer:
         # than one column, or on a key that is no integer): stage
         # fused.hash_key_lanes
         self.hash_key_lanes = 0
+        # lanes through the 64-bit scans its joins still run
+        # (ops/join.scan64_lanes; none under a compacting join):
+        # stage fused.join_scan64_lanes
+        self.join_scan64_lanes = 0
         # ids of the ShrinkOps that lowered with their join as ONE step in
         # THIS trace (_mat_join returned compacted=True): what leaves
         # their lanes in key order for _ordered_input
@@ -625,6 +629,8 @@ class _Tracer:
             res = hash_join_prepared(
                 probe, bt, probe_on, build_on, how=op.how,
                 out_capacity=probe.capacity * op.expansion)
+            self.join_scan64_lanes += scan64_lanes(bt, probe.capacity,
+                                                   op.how)
             self.flags.extend(_join_flags(guard, b_ovf, p_ovf, res.overflow))
             return res.batch, False
 
@@ -989,7 +995,7 @@ class FusedRunner:
         self.root = root
         self.schema = root.schema
         # config key -> (program, flag_ops, result_cap, sort_lanes,
-        # hash_key_lanes), or
+        # hash_key_lanes, join_scan64_lanes), or
         # None for a config that proved unsupported
         self._progs: Dict[tuple, Optional[tuple]] = {}
         # vkey (per-scan content-identity tuple) -> (args, chunks): lets a
@@ -1131,6 +1137,7 @@ class FusedRunner:
             tracer_box["result_cap"] = min(RESULT_CAP, out.capacity)
             tracer_box["sort_lanes"] = t.sort_lanes
             tracer_box["hash_key_lanes"] = t.hash_key_lanes
+            tracer_box["join_scan64_lanes"] = t.join_scan64_lanes
             with scope(RESULT_SCOPE):
                 return _pack_result(out, tuple(t.flags), schema,
                                     tracer_box["result_cap"])
@@ -1142,7 +1149,8 @@ class FusedRunner:
         """What _progs keeps of a compiled config: the program and what
         its trace left in the side-box."""
         return (compiled, tracer_box["flag_ops"], tracer_box["result_cap"],
-                tracer_box["sort_lanes"], tracer_box["hash_key_lanes"])
+                tracer_box["sort_lanes"], tracer_box["hash_key_lanes"],
+                tracer_box["join_scan64_lanes"])
 
     def _prepare(self):
         # one sessions-shared critical section covering the warm-key
@@ -1298,8 +1306,8 @@ class FusedRunner:
         t_first = _time.perf_counter()
         try:
             with stats.timed("fused.prepare"):
-                (prog, flag_ops, result_cap, sort_lanes,
-                 hash_key_lanes), args = self._prepare()
+                (prog, flag_ops, result_cap, sort_lanes, hash_key_lanes,
+                 join_scan64_lanes), args = self._prepare()
         except Unsupported as e:
             # this run's volume (or shape) is outside the fusion grammar:
             # delegate wholesale to the streaming runtime
@@ -1332,6 +1340,7 @@ class FusedRunner:
             # one event a dispatch; the lanes are the traced shapes'
             stats.add("fused.sort_lanes", rows=sort_lanes)
             stats.add("fused.hash_key_lanes", rows=hash_key_lanes)
+            stats.add("fused.join_scan64_lanes", rows=join_scan64_lanes)
             return out
 
         try:

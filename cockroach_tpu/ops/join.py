@@ -191,6 +191,19 @@ def hash_join_prepared(left: Batch, build: BuildTable,
                          out_capacity, track_build)
 
 
+def scan64_lanes(build: BuildTable, probe_capacity: int, how: str) -> int:
+    """Lanes hash_join_prepared passes through scans over a 64-bit
+    operand (pairs of u32 on the chip) for this build: a unique build's
+    run broadcasts (ops/sortjoin.scan64_lanes), else the expansion's
+    int64 sum of match counts over the probe's lanes (_probe_sorted).
+    What exec/fused counts as stage fused.join_scan64_lanes."""
+    from cockroach_tpu.ops import sortjoin
+
+    if isinstance(build, sortjoin.UniqueBuild):
+        return sortjoin.scan64_lanes(build, probe_capacity, how)
+    return probe_capacity
+
+
 def _probe_sorted(left: Batch, right: Batch, order, key_sorted, run_end,
                   lq, left_on, right_on, how: str,
                   out_capacity: int | None,

@@ -42,7 +42,11 @@ key + one i32 iota) and moves whole rows exactly once:
         (probe_unique_compact): one single-operand u32 sort of lcap +
         rcap lanes puts the matches first (first_matches), and C-row
         gathers fetch their probe lane index and build ROW INDEX and
-        (step 6) the rows of both sides. For an inner or semi join
+        (step 6) the rows of both sides. Step 3's broadcast is not
+        needed here: the tag puts a run's build lane at its head, so one
+        32-bit running maximum over the heads' positions tells a lane
+        whether its run has one and where its row index stands
+        (_carry_sort, PR 49). For an inner or semi join
         whose only reader is a ShrinkOp of capacity C
         (exec/fused._Tracer._mat_join): a selective join keeps a sliver
         of its probe, and the second full-width sort of (a) would
@@ -78,6 +82,7 @@ import numpy as np
 from cockroach_tpu.coldata.batch import (
     Batch, Column, first_matches, first_selected,
 )
+from cockroach_tpu.ops.prefix import blocked_cummax
 from cockroach_tpu.ops.rowmat import RowPlan, pack_rows, unpack_rows
 
 # numpy scalars, NOT jnp: a module-level jax.Array closure constant gets
@@ -252,16 +257,18 @@ class _CarrySorted(NamedTuple):
     is_build: jnp.ndarray
     match_sorted: jnp.ndarray  # probe lane whose run starts with a build
     bpay: jnp.ndarray          # the run's build payload: uint64 packed
-    #                            columns, or (rows=True) int32 build row
+    #                            columns, or (rows=True) the int32 sorted
+    #                            position of the run's head, whose s_val
+    #                            is the build row where match_sorted
     fallback: jnp.ndarray
 
 
 def _carry_sort(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
                 rows: bool = False, ordered: bool = False) -> _CarrySorted:
     """Steps 1-4 of the carry join: probe key packing, key sort, run
-    detection, the deferred `fallback` flag and the broadcast of each
-    run's build payload (ONE copy, whatever step 5 does with it). What a
-    build lane carries beside its key is one of two things:
+    detection, the deferred `fallback` flag and what each lane learns
+    of its run's build lane (ONE copy, whatever step 5 does with it).
+    What a build lane carries beside its key is one of two things:
 
     - its non-key columns bit-packed into one u64 (`ub.payv`): the form
       the resort needs, where every probe lane receives the columns. 62
@@ -270,8 +277,11 @@ def _carry_sort(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
     - `rows`: its own ROW INDEX as a u32 (< rcap < 2^30, whatever the
       build's columns): the form the compaction takes, which fetches
       the columns from `ub.batch` at the C surviving lanes only. No
-      width to overflow, and ONE cummax of (runid << 32 | row + 1)
-      broadcasts it. A semi join ignores the row and reads the match.
+      width to overflow, and nothing to broadcast: ONE 32-bit running
+      maximum of (position << 1 | is_build) over the run heads gives
+      every lane its run's head, where the build lane stands if the run
+      has one, and the caller reads the row there (`s_val` of the head)
+      at its C lanes. A semi join reads the match alone.
 
     `ordered`: the caller reads the sorted domain's lane ORDER (the
     compacting inner join, whose result is in it), so lanes of equal
@@ -315,10 +325,18 @@ def _carry_sort(probe: Batch, ub: UniqueBuild, probe_on: Sequence[str],
     dup = jnp.any(is_build & ~newrun)
     fallback = dup | ub.range_flag | p_range
     if rows:
-        has_b, brow = _run_build_broadcast(newrun, is_build,
-                                           s_val.astype(jnp.int32))
+        # the tag is the key's low bit, so a run that has a build lane
+        # has it at its HEAD (a second one raises `dup`): each lane needs
+        # its run head's position and tag, and position << 1 | tag rises
+        # over the heads, so one 32-bit running maximum carries both
+        # (n < 2^30 by `compacts`). The chip has no 64-bit lanes: the
+        # (runid << 32 | row + 1) broadcast cost 15.7 ms at 8,650,752
+        # lanes on a v5e where this costs 1.2 (PERF.md section 6, PR 49)
+        head = blocked_cummax(jnp.where(
+            newrun, (pos << 1) | is_build.astype(jnp.int32), 0))
+        has_b = (head & 1) != 0
         return _CarrySorted(s_packed, s_val, is_build, ~is_build & has_b,
-                            brow, fallback)
+                            head >> 1, fallback)
     fallback = fallback | (ub.pay_plan.total_bits > jnp.int32(62))
 
     # broadcast the build payload to its run: split-cummax (62-bit
@@ -417,6 +435,17 @@ def compacts(ub: UniqueBuild, probe_capacity: int, how: str) -> bool:
             and probe_capacity + ub.batch.capacity < (1 << 30))
 
 
+def scan64_lanes(ub: UniqueBuild, probe_capacity: int, how: str) -> int:
+    """Lanes probe_unique passes through scans over a 64-bit operand
+    (pairs of u32 on the chip: PERF.md section 6, PR 49): the resorting
+    carry join broadcasts its payload with two s64 cummaxes
+    (_carry_sort), the row-matrix join its build row with one
+    (_run_build_broadcast), each over probe + build lanes. The
+    compacting join (probe_unique_compact) runs none."""
+    n = probe_capacity + ub.batch.capacity
+    return 2 * n if carries(ub, probe_capacity, how) else n
+
+
 def probe_unique_compact(probe: Batch, ub: UniqueBuild,
                          probe_on: Sequence[str], how: str,
                          capacity: int) -> CompactJoin:
@@ -425,8 +454,11 @@ def probe_unique_compact(probe: Batch, ub: UniqueBuild,
     probe order: the matched probe lanes are known in the sorted domain,
     so the destination resort is replaced by the compaction's own sort
     there (first_matches), one C-row gather of each match's probe lane
-    index and build row index, and one C-row gather a side of the
-    columns themselves. The build's columns never ride a sort, so their
+    index and run head, one C-lane gather of the build row index that
+    stands at the head, and one C-row gather a side of the columns
+    themselves. No scan of it has a 64-bit operand (the run heads come
+    from one s32 running maximum: _carry_sort), and the build's columns
+    never ride a sort, so their
     number and width are free. A semi join emits nothing
     of the build: its compaction carries the probe lane index itself and
     gathers probe rows only. Inner and semi joins over a build that
@@ -467,15 +499,18 @@ def probe_unique_compact(probe: Batch, ub: UniqueBuild,
     else:
         # one (C, 2) row gather: two 1-D gathers cost twice it
         kidx = first_selected(match, C)
-        got = jnp.stack([cs.s_val.astype(jnp.int32), cs.bpay],
-                        axis=1)[kidx]
+        sv = cs.s_val.astype(jnp.int32)
+        got = jnp.stack([sv, cs.bpay], axis=1)[kidx]
         lane = got[:, 0]
+        # a build lane's value IS its row index: a match's build row is
+        # the value at its run's head, read at the C lanes only
+        brow = sv[got[:, 1]]
     cols = dict(probe.gather(lane, sel=sel, length=length).columns)
     if how == "inner":
         # the build's columns, its key among them, from its own lanes
         # (a matched lane is live, and its key the probe's), under the
         # join's NULL-padding contract
-        for name, c in ub.batch.gather(got[:, 1]).columns.items():
+        for name, c in ub.batch.gather(brow).columns.items():
             valid = sel if c.validity is None else c.validity & sel
             cols[name] = Column(
                 jnp.where(valid, c.values, jnp.zeros((), c.values.dtype)),

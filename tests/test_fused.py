@@ -377,11 +377,22 @@ def _sorts(jaxpr):
             for eqn in _eqns(jaxpr) if eqn.primitive.name == "sort"]
 
 
-def _cummaxes(jaxpr):
-    """Lanes of every cummax equation (a carry join's run broadcast: one
-    for a row index, two for the 62-bit payload's halves)."""
-    return [eqn.invars[0].aval.shape[0] for eqn in _eqns(jaxpr)
-            if eqn.primitive.name == "cummax"]
+def _cummaxes(jaxpr, dtype="int64"):
+    """Lanes (the whole operand's) of every cummax equation over `dtype`:
+    at int64 a resorting carry join's run broadcast (two, the 62-bit
+    payload's halves), at int32 a compacting join's run heads (one, in
+    ops/prefix.blocked_cummax's rows of 512: the lanes padded to them)."""
+    return [int(np.prod(eqn.invars[0].aval.shape)) for eqn in _eqns(jaxpr)
+            if eqn.primitive.name == "cummax"
+            and str(eqn.invars[0].aval.dtype) == dtype]
+
+
+def _head_scans(jaxpr, n):
+    """How many s32 cummaxes of `jaxpr` run over `n` lanes padded to
+    blocked_cummax's rows: a compacting join's ONE scan (PR 49)."""
+    from cockroach_tpu.ops.prefix import _BLOCK
+
+    return _cummaxes(jaxpr, "int32").count(-(-n // _BLOCK) * _BLOCK)
 
 
 def _record_joins(monkeypatch):
@@ -532,9 +543,10 @@ def test_q3_program_sorts_per_join(program, monkeypatch):
         at_n = sorted(s[1:] for s in sorts if s[0] == lcap + rcap)
         assert at_n == [("uint32", 1, False), ("uint32", 2, False)]
         assert (lcap, "uint32", 1, False) not in sorts
-        # the build's row index under the run id: one scan, where the
-        # resorting form's 62-bit payload takes two
-        assert cummaxes.count(lcap + rcap) == 1
+        # the run heads' positions: one 32-bit scan and none of 64
+        # bits, where the resorting form's 62-bit payload takes two
+        assert cummaxes.count(lcap + rcap) == 0
+        assert _head_scans(jaxpr.jaxpr, lcap + rcap) == 1
     for lcap, rcap, _how in two_step:
         at_n = sorted(s[1:] for s in sorts if s[0] == lcap + rcap)
         assert at_n == [("int32", 2, False), ("uint32", 2, False)]
