@@ -605,7 +605,11 @@ def _label(op: Operator) -> str:
     if isinstance(op, ScanOp):
         return str(getattr(op, "table", None) or "")
     if isinstance(op, JoinOp):
-        return f"{op.how} " + ", ".join(
+        # a semi / anti join with a residual is named as the plan has
+        # it; it runs as the inner / left join that feeds the residual's
+        # filter (sql/plan.build)
+        of = getattr(op, "residual_of", None)
+        return (f"{of}+residual " if of else f"{op.how} ") + ", ".join(
             f"{a} = {b}" for a, b in zip(op.probe_on, op.build_on))
     if isinstance(op, HashAggOp):
         return ("group by " + ", ".join(op.group_by) if op.group_by

@@ -353,6 +353,10 @@ class _Tracer:
         # (ops/join.scan64_lanes; none under a compacting join):
         # stage fused.join_scan64_lanes
         self.join_scan64_lanes = 0
+        # probe + build lanes of its semi and anti joins that carry a
+        # residual (JoinOp.residual_of, sql/plan.build): stage
+        # fused.join_residual_lanes
+        self.join_residual_lanes = 0
         # ids of the ShrinkOps that lowered with their join as ONE step in
         # THIS trace (_mat_join returned compacted=True): what leaves
         # their lanes in key order for _ordered_input
@@ -609,6 +613,8 @@ class _Tracer:
             guard = self._route_guard(op)
             self.flag_ops.extend(_flag_targets(guard, op))
             self.sort_lanes += probe.capacity + build.capacity
+            if getattr(op, "residual_of", None):
+                self.join_residual_lanes += probe.capacity + build.capacity
             # the packing the key took (ops/sortjoin.prepare_unique): one
             # integer column rides the sorts as itself, anything else as
             # a 62-bit hash in a u64 operand, verified by a row gather
@@ -995,7 +1001,7 @@ class FusedRunner:
         self.root = root
         self.schema = root.schema
         # config key -> (program, flag_ops, result_cap, sort_lanes,
-        # hash_key_lanes, join_scan64_lanes), or
+        # hash_key_lanes, join_scan64_lanes, join_residual_lanes), or
         # None for a config that proved unsupported
         self._progs: Dict[tuple, Optional[tuple]] = {}
         # vkey (per-scan content-identity tuple) -> (args, chunks): lets a
@@ -1138,6 +1144,7 @@ class FusedRunner:
             tracer_box["sort_lanes"] = t.sort_lanes
             tracer_box["hash_key_lanes"] = t.hash_key_lanes
             tracer_box["join_scan64_lanes"] = t.join_scan64_lanes
+            tracer_box["join_residual_lanes"] = t.join_residual_lanes
             with scope(RESULT_SCOPE):
                 return _pack_result(out, tuple(t.flags), schema,
                                     tracer_box["result_cap"])
@@ -1150,7 +1157,8 @@ class FusedRunner:
         its trace left in the side-box."""
         return (compiled, tracer_box["flag_ops"], tracer_box["result_cap"],
                 tracer_box["sort_lanes"], tracer_box["hash_key_lanes"],
-                tracer_box["join_scan64_lanes"])
+                tracer_box["join_scan64_lanes"],
+                tracer_box["join_residual_lanes"])
 
     def _prepare(self):
         # one sessions-shared critical section covering the warm-key
@@ -1307,7 +1315,8 @@ class FusedRunner:
         try:
             with stats.timed("fused.prepare"):
                 (prog, flag_ops, result_cap, sort_lanes, hash_key_lanes,
-                 join_scan64_lanes), args = self._prepare()
+                 join_scan64_lanes, join_residual_lanes), args = \
+                    self._prepare()
         except Unsupported as e:
             # this run's volume (or shape) is outside the fusion grammar:
             # delegate wholesale to the streaming runtime
@@ -1341,6 +1350,7 @@ class FusedRunner:
             stats.add("fused.sort_lanes", rows=sort_lanes)
             stats.add("fused.hash_key_lanes", rows=hash_key_lanes)
             stats.add("fused.join_scan64_lanes", rows=join_scan64_lanes)
+            stats.add("fused.join_residual_lanes", rows=join_residual_lanes)
             return out
 
         try:

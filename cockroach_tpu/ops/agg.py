@@ -1017,8 +1017,8 @@ def ordered_aggregate(batch: Batch, group_by: Sequence[str],
 
 
 # What int_key_aggregate evaluates: int64 sums and counts over the packed
-# inputs' bits, which commute exactly (its sort is unstable).
-INT_KEY_AGG_FUNCS = ("sum", "count", "count_star")
+# inputs' bits, and their extremes: all commute exactly (its sort is unstable).
+INT_KEY_AGG_FUNCS = ("sum", "count", "count_star", "min", "max")
 
 
 class IntKeyAggResult(NamedTuple):
@@ -1048,7 +1048,10 @@ def int_key_aggregate(
     cumsums of bias-packed (non-negative) inputs are non-decreasing: the
     previous group end's running value arrives via one cummax + lane
     shift. A NULL key forms its own single group (SQL GROUP BY
-    semantics)."""
+    semantics). A run's MIN or MAX of an input rides one running maximum
+    with the run's number above bit 32 (no earlier run's value outlasts a
+    run's first lane), over the input's packed bits: 32 hold them or
+    `fallback` is raised."""
     cap = batch.capacity
     c = batch.col(key_col)
     live = batch.sel
@@ -1131,6 +1134,21 @@ def int_key_aggregate(
     cols[key_col] = Column(
         jnp.where(is_end, kv, 0).astype(c.values.dtype), key_validity)
 
+    M32 = np.int64(0xFFFFFFFF)
+    # the run's number above bit 32: min and max only
+    runid = (jnp.cumsum(newrun.astype(jnp.int32)).astype(jnp.int64)
+             << np.int64(32)
+             if any(a.func in ("min", "max") for a in aggs) else None)
+
+    def run_extreme(v, avalid, func):
+        """The run's min or max of the biased values `v` (< 2^32) over its
+        valid lanes so far, at every lane: the answer at run ends. NULLs
+        and, for min, the reversed value leave the low word 0, the least
+        there is."""
+        low = jnp.where(avalid, v if func == "max" else M32 - v, 0)
+        got = jax.lax.cummax(runid | low) & M32
+        return got if func == "max" else M32 - got
+
     sums = []
     for a in aggs:
         if a.func == "count_star":
@@ -1144,6 +1162,10 @@ def int_key_aggregate(
             nv = seg_total(cum_valid)
             if a.func == "count":
                 sums.append((a, nv, None, None))
+            elif a.func in ("min", "max"):
+                agg_flag = agg_flag | (aplan.widths[i_n] > jnp.int32(32))
+                ext = run_extreme(v, avalid, a.func) + aplan.los[i_n]
+                sums.append((a, jnp.where(is_end, ext, 0), nv, None))
             else:
                 i = aplan.names.index(a.col)
                 s = seg_total(jnp.cumsum(jnp.where(avalid, v, 0)))
@@ -1151,6 +1173,11 @@ def int_key_aggregate(
     for a, tot, nv, _ in sums:
         if a.func == "sum":
             cols[a.out] = Column(jnp.where(nv > 0, tot, 0), nv > 0)
+        elif a.func in ("min", "max"):
+            # the input's own type, as the other aggregates give it
+            cols[a.out] = Column(
+                jnp.where(nv > 0, tot, 0).astype(
+                    batch.col(a.col).values.dtype), nv > 0)
         else:
             cols[a.out] = Column(tot, None)
 

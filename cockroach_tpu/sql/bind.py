@@ -23,7 +23,25 @@ Reference seams:
   unique on its join keys is executed as `semi` — the shape every
   hand-written TPC-H plan here used.
 - IN (subquery) -> semi join, NOT IN -> anti join (decorrelation's
-  trivial case; correlated subqueries are rejected at bind time).
+  trivial case).
+- correlated [NOT] EXISTS (subquery) as a top-level WHERE conjunct ->
+  plan.Apply over the join tree (`_bind_exists`; plan.decorrelate turns
+  it into a semi / anti join, the first pass of normalize()): the
+  subquery binds in a sub-binder whose `outer` is this one, so a name it
+  cannot resolve itself resolves one level up; its `inner = outer`
+  conjuncts are the Apply's `correlation`, ONE other comparison of an
+  inner column with an outer one (`l2.l_suppkey <> l1.l_suppkey`, TPC-H
+  Q21) its `residual`. A correlated subquery anywhere else (under OR, in
+  a projection, IN), an uncorrelated EXISTS, a second residual, or any
+  other correlated predicate is a BindError.
+- alias scoping: a FROM item whose TABLE is named twice in its FROM list
+  (a self-join) or also in the enclosing query's (a correlated subquery
+  over the same table) has its columns named `<alias>.<column>` in every
+  plan node (`_resolve_from`; a renaming Project over its Scan), so that the
+  aliases stay apart below the binder; every other item keeps the
+  table's own names, and a statement without such an item binds to the
+  plan it always did. A bare name that two items could mean is a
+  BindError when it is used.
 
 Literal typing: SQL numeric literals are untyped; the binder retypes
 them against the other operand (DECIMAL(s) columns make `0.05` a
@@ -51,6 +69,7 @@ statement's one list.
 
 from __future__ import annotations
 
+import dataclasses
 import datetime
 from dataclasses import dataclass, field as dc_field
 from decimal import Decimal
@@ -70,8 +89,9 @@ from cockroach_tpu.sql.params import (
     ParamOutOfScope, ParamSlot, date_add, is_param_const, sample_of,
 )
 from cockroach_tpu.sql.plan import (
-    Aggregate, Catalog, Distinct, Filter, Join, Limit, OrderBy, Plan,
-    Project, Scan, VectorTopK, _plan_columns, keep_share,
+    Aggregate, Apply, Catalog, Distinct, Filter, Join, Limit, OrderBy, Plan,
+    Project, Scan, VectorTopK, _plan_columns, _rebuild, _walk_plan,
+    keep_share,
 )
 
 
@@ -192,10 +212,29 @@ class _OpenParam(Expr):
 
 _PARAM_KINDS = (Kind.DATE, Kind.INT, Kind.DECIMAL, Kind.STRING, Kind.FLOAT)
 
+# in a conjunct's `refs`: it names a column of the enclosing query
+_OUTER = "<outer>"
+
+# the comparisons a correlated subquery may make between one of its
+# columns and one of the outer query's, and each read from the other side
+_CORRELATED_OPS = {"=": "==", "<>": "!=", "!=": "!=", "<": "<", "<=": "<=",
+                   ">": ">", ">=": ">="}
+_MIRRORED = {"==": "==", "!=": "!=", "<": ">", "<=": ">=", ">": "<",
+             ">=": "<="}
+_RESIDUAL_KINDS = (Kind.INT, Kind.DATE, Kind.DECIMAL, Kind.FLOAT)
+
 
 class Binder:
-    def __init__(self, catalog: Catalog, params: Optional[Sequence] = None):
+    def __init__(self, catalog: Catalog, params: Optional[Sequence] = None,
+                 outer: Optional["Binder"] = None):
         self.catalog = catalog
+        # the binder of the enclosing query, for a correlated subquery's:
+        # names this one cannot resolve resolve there (one level up)
+        self.outer = outer
+        # plan names of THIS query's columns that its subqueries read
+        self._sub_refs: Set[str] = set()
+        # did any FROM item of the statement take alias-qualified names?
+        self._renamed_any = False
         # the values of the binding this plan is made at (estimates read
         # them through Param.sample), or None: a `$n` is then unbound
         self.params = params
@@ -216,35 +255,76 @@ class Binder:
             raise ParamOutOfScope(
                 "a parameter stands where no operand beside it gives its "
                 "type")
+        if self._renamed_any:
+            plan = _share_scan_columns(plan)
         return plan
 
-    def _bind_select(self, stmt: P.SelectStmt) -> Plan:
-        stmt = _merge_derived(stmt)
-        # -- resolve FROM tables ------------------------------------------
+    def scope_one_table(self, table: str, schema: Schema) -> None:
+        """The scope of a statement over ONE table under its own names
+        (UPDATE and DELETE bind their expressions in it)."""
+        self._schemas = {table: schema}
+        self._col_to_rel = {n: table for n in schema.names()}
+        self._global = schema
+        self._renames = {}
+        self._by_source = {n: [table] for n in schema.names()}
+        self._alias_tables = {table: table}
+
+    def _resolve_from(self, stmt: P.SelectStmt) -> Dict[str, _Rel]:
+        """The FROM list -> its relations, and this query's scope: the
+        schema of every item under its PLAN names (`<alias>.<column>` for
+        an item whose table is named twice here, or in the enclosing
+        query too; the table's own otherwise), which item a plan name
+        belongs to, and which items a bare source name could mean."""
+        tables = [tref.name for tref in stmt.tables]
+        enclosing = (set(self.outer._alias_tables.values())
+                     if self.outer is not None else set())
         rels: Dict[str, _Rel] = {}
         schemas: Dict[str, Schema] = {}
         col_to_rel: Dict[str, str] = {}
+        self._renames: Dict[str, Dict[str, str]] = {}
+        self._by_source: Dict[str, List[str]] = {}
         for tref in stmt.tables:
             key = tref.alias or tref.name
             if key in rels:
-                raise BindError(f"duplicate table/alias {key!r} "
-                                "(self-joins need distinct aliases; "
-                                "self-join support not implemented)")
-            schema = self.catalog.table_schema(tref.name)
+                raise BindError(f"duplicate table/alias {key!r} (each "
+                                "FROM item of a self-join needs an alias "
+                                "of its own)")
+            schema = source = self.catalog.table_schema(tref.name)
+            pk = self._pk(tref.name)
+            if tables.count(tref.name) > 1 or tref.name in enclosing:
+                ren = {n: f"{key}.{n}" for n in schema.names()}
+                self._renames[key] = ren
+                self._renamed_any = True
+                schema = Schema(
+                    [dataclasses.replace(f, name=ren[f.name])
+                     for f in schema.fields], schema.dicts)
+                pk = None if pk is None else tuple(ren[c] for c in pk)
             rels[key] = _Rel(key, table=tref.name,
                              est=float(self._rows(tref.name)),
-                             unique_cols=self._pk(tref.name))
+                             unique_cols=pk)
             schemas[key] = schema
             for name in schema.names():
                 if name in col_to_rel:
                     raise BindError(f"ambiguous column {name!r} "
                                     f"(in {col_to_rel[name]} and {key})")
                 col_to_rel[name] = key
+            for name in source.names():
+                self._by_source.setdefault(name, []).append(key)
         self._schemas = schemas
         self._col_to_rel = col_to_rel
-        self._global = self._merge_schemas(schemas.values())
+        # a correlated subquery types its outer references too; its own
+        # names come last and win
+        self._global = self._merge_schemas(
+            ([self.outer._global] if self.outer is not None else [])
+            + list(schemas.values()))
         self._alias_tables = {(tref.alias or tref.name): tref.name
                               for tref in stmt.tables}
+        return rels
+
+    def _bind_select(self, stmt: P.SelectStmt) -> Plan:
+        stmt = _merge_derived(stmt)
+        # -- resolve FROM tables ------------------------------------------
+        rels = self._resolve_from(stmt)
 
         # -- outer joins: linear (syntactic) join order -------------------
         # LEFT/RIGHT/FULL OUTER joins are not freely reorderable; they
@@ -268,19 +348,19 @@ class Binder:
         post_filters: List[Expr] = []
         conjuncts = self._split_and(stmt.where) if stmt.where else []
         sub_n = 0
+        applies: List[Apply] = []  # over the join tree, input filled in
         for ast in conjuncts:
             ast = _fold_dates(ast)
+            exists = _as_exists(ast)
+            if exists is not None:
+                applies.append(self._bind_exists(*exists))
+                continue
             if isinstance(ast, (P.InSubquery,)):
                 arg, refs = self._bind_scalar(ast.arg)
                 if not isinstance(arg, Col) or len(refs) != 1:
                     raise BindError("IN (subquery) needs a plain column "
                                     "on the left")
-                # one statement, one list of slots: a `$n` inside the
-                # subquery is an argument of the same program
-                sub_binder = Binder(self.catalog, params=self.params)
-                sub_binder.param_slots = self.param_slots
-                sub_binder.join_ranks = self.join_ranks
-                sub = sub_binder.bind(ast.query)
+                sub = self._sub_binder().bind(ast.query)
                 sub_cols = _plan_columns(sub, self.catalog)
                 key = f"__sub{sub_n}"
                 sub_n += 1
@@ -290,20 +370,15 @@ class Binder:
                 edges.append(_Edge(next(iter(refs)), key,
                                    [(arg.name, sub_cols[0])]))
                 continue
-            pair = self._as_join_pred(ast)
-            if pair is not None:
-                (ra, ca), (rb, cb) = pair
-                if ra != rb:
-                    self._add_edge(edges, ra, rb, ca, cb)
-                    continue
-            e, refs = self._bind_scalar(ast)
-            if len(refs) == 1:
-                rels[next(iter(refs))].filters.append(e)
-            else:
-                post_filters.append(e)
+            self._place_conjunct(ast, rels, edges, post_filters)
 
         # -- select-item / aggregate analysis -----------------------------
         plan = self._join_tree(rels, edges, stmt, post_filters)
+        # a correlated subquery filters the rows of the relation it names
+        # and commutes with that relation's inner joins: above them all it
+        # probes what the most selective of them left (a Shrink's lanes)
+        for ap in applies:
+            plan = dataclasses.replace(ap, input=plan)
         for f in post_filters:
             plan = Filter(plan, f)
         plan = self._select_and_aggregate(plan, stmt)
@@ -318,6 +393,27 @@ class Binder:
         # (scan passthroughs, ORDER BY-only refs, HAVING-only
         # aggregates) with a final projection above sort/limit
         return self._exact_shape(plan)
+
+    def _place_conjunct(self, ast: P.Node, rels: Dict[str, _Rel],
+                        edges: List[_Edge], post_filters: List[Expr]) -> bool:
+        """A WHERE conjunct over this query's own FROM items goes where
+        it binds: a join edge between two of them, a filter of the one it
+        names, or a filter above the joins. -> False, and nothing placed,
+        for a conjunct that names the enclosing query."""
+        pair = self._as_join_pred(ast)
+        if pair is not None:
+            (ra, ca), (rb, cb) = pair
+            if ra != rb:
+                self._add_edge(edges, ra, rb, ca, cb)
+                return True
+        e, refs = self._bind_scalar(ast)
+        if _OUTER in refs:
+            return False
+        if len(refs) == 1:
+            rels[next(iter(refs))].filters.append(e)
+        else:
+            post_filters.append(e)
+        return True
 
     def _exact_shape(self, plan: Plan) -> Plan:
         names = getattr(self, "_select_names", None)
@@ -530,23 +626,46 @@ class Binder:
             raise BindError(f"unknown function {node.name!r}")
         if isinstance(node, (P.InSubquery, P.ExistsAst)):
             raise BindError("subqueries are only supported as top-level "
-                            "WHERE conjuncts (col IN (SELECT ...))")
+                            "WHERE conjuncts (col [NOT] IN (SELECT ...), "
+                            "[NOT] EXISTS (SELECT ...)): not under OR, "
+                            "not in a projection, not under an outer join")
         raise BindError(f"cannot bind {type(node).__name__}")
 
-    def _col(self, ref: P.ColRef, refs: Set[str]) -> Col:
+    def _resolve(self, ref: P.ColRef) -> Optional[Tuple[str, str]]:
+        """-> (the FROM item of THIS query that `ref` names, the column's
+        plan name), or None: not a name of this scope. A bare name that
+        two items could mean, and a qualified one its item lacks, raise."""
         if ref.qualifier is not None:
             key = ref.qualifier
             if key not in self._schemas:
-                raise BindError(f"unknown table/alias {key!r}")
-            if ref.name not in self._schemas[key].names():
+                return None
+            name = self._renames.get(key, {}).get(ref.name, ref.name)
+            if name not in self._schemas[key].names():
                 raise BindError(f"column {ref.name!r} not in {key!r}")
-            refs.add(key)
-            return Col(ref.name)
-        key = self._col_to_rel.get(ref.name)
-        if key is None:
-            raise BindError(f"unknown column {ref.name!r}")
-        refs.add(key)
-        return Col(ref.name)
+            return key, name
+        keys = self._by_source.get(ref.name, ())
+        if len(keys) > 1:
+            raise BindError(f"ambiguous column {ref.name!r} (in "
+                            f"{' and '.join(keys)}): qualify it")
+        if not keys:
+            return None
+        return keys[0], self._renames.get(keys[0], {}).get(ref.name,
+                                                           ref.name)
+
+    def _col(self, ref: P.ColRef, refs: Set[str]) -> Col:
+        hit = self._resolve(ref)
+        if hit is not None:
+            refs.add(hit[0])
+            return Col(hit[1])
+        up = self.outer._resolve(ref) if self.outer is not None else None
+        if up is not None:
+            # an outer reference: a column of the enclosing query's row
+            refs.add(_OUTER)
+            self.outer._sub_refs.update((up[1], ref.name))
+            return Col(up[1])
+        if ref.qualifier is not None:
+            raise BindError(f"unknown table/alias {ref.qualifier!r}")
+        raise BindError(f"unknown column {ref.name!r}")
 
     def _flatten(self, node: P.Binary, op: str) -> List[P.Node]:
         out: List[P.Node] = []
@@ -677,6 +796,8 @@ class Binder:
         rb: Set[str] = set()
         a = self._col(ast.left, ra)
         b = self._col(ast.right, rb)
+        if _OUTER in ra | rb:
+            return None
         return (next(iter(ra)), a.name), (next(iter(rb)), b.name)
 
     @staticmethod
@@ -689,6 +810,118 @@ class Binder:
                     e.pairs.append((cb, ca))
                 return
         edges.append(_Edge(ra, rb, [(ca, cb)]))
+
+    # -------------------------------------------- correlated subqueries --
+
+    def _sub_binder(self, outer: Optional["Binder"] = None) -> "Binder":
+        """The binder of a subquery of this statement. One statement, one
+        list of slots: a `$n` inside the subquery is an argument of the
+        same program, and its join orderer counts on the same page."""
+        sub = Binder(self.catalog, params=self.params, outer=outer)
+        sub.param_slots = self.param_slots
+        sub.join_ranks = self.join_ranks
+        return sub
+
+    def _bind_exists(self, query: P.SelectStmt, negate: bool) -> Apply:
+        """`[NOT] EXISTS (query)`, a top-level WHERE conjunct -> the Apply
+        that filters this query's rows by it (its `input` is filled in
+        once the join tree stands)."""
+        sub_binder = self._sub_binder(outer=self)
+        sub, correlation, residual = sub_binder._bind_correlated(query)
+        self._renamed_any |= sub_binder._renamed_any
+        self._open_params += sub_binder._open_params
+        return Apply(None, sub, correlation,
+                     "not_exists" if negate else "exists", None, residual)
+
+    def _bind_correlated(self, stmt: P.SelectStmt):
+        """An EXISTS subquery, in the sub-binder -> (its plan, projected
+        to the columns the Apply reads; the correlation, (outer column,
+        inner column) an `inner = outer` conjunct; the residual
+        `Cmp(op, Col(inner), Col(outer))` or None). Its select list
+        decides nothing. Conjuncts over its own FROM items alone bind as
+        any query's; one that names the outer query is a comparison of
+        ONE inner column with ONE outer column, an equality or at most
+        one other."""
+        stmt = _merge_derived(stmt)
+        if (stmt.group_by or stmt.having is not None
+                or stmt.limit is not None or stmt.offset
+                or any(isinstance(n, P.WindowCall)
+                       or (isinstance(n, P.FuncCall)
+                           and n.name in _AGG_FUNCS)
+                       for ast, _alias in stmt.items
+                       for n in _ast_nodes(ast))):
+            raise BindError("EXISTS over a subquery with GROUP BY, HAVING, "
+                            "aggregates, window functions, LIMIT or OFFSET "
+                            "is not supported")
+        if any(t.how != "inner" for t in stmt.tables):
+            raise BindError("an outer join inside an EXISTS subquery is "
+                            "not supported")
+        rels = self._resolve_from(stmt)
+        edges: List[_Edge] = []
+        post_filters: List[Expr] = []
+        correlation: List[Tuple[str, str]] = []
+        residual: Optional[Cmp] = None
+        for ast in (self._split_and(stmt.where) if stmt.where else []):
+            ast = _fold_dates(ast)
+            if any(isinstance(n, (P.InSubquery, P.ExistsAst))
+                   for n in _ast_nodes(ast)):
+                raise BindError("a subquery nested in an EXISTS subquery "
+                                "is not supported")
+            if self._place_conjunct(ast, rels, edges, post_filters):
+                continue
+            cmp_ = self._correlated_cmp(ast)
+            if cmp_.op == "==":
+                correlation.append((cmp_.right.name, cmp_.left.name))
+            elif residual is None:
+                residual = cmp_
+            else:
+                raise BindError("a correlated subquery with more than one "
+                                "comparison beside its equalities is not "
+                                "supported")
+        if not correlation:
+            raise BindError("an EXISTS subquery needs an equality between "
+                            "one of its columns and one of the outer "
+                            "query's (an uncorrelated EXISTS, or one "
+                            "correlated by an inequality alone, is not "
+                            "supported)")
+        if residual is not None:
+            for side in (residual.left, residual.right):
+                if side.type(self._global).kind not in _RESIDUAL_KINDS:
+                    raise BindError(
+                        f"a correlated {residual.op!r} comparison over "
+                        f"{side.name!r}: only numbers and dates order as "
+                        "their stored values do")
+        reads = [inner for _outer, inner in correlation]
+        if residual is not None:
+            reads.append(residual.left.name)
+        reads = list(dict.fromkeys(reads))
+        self._sub_refs.update(reads)  # kept above this subquery's joins
+        plan = self._join_tree(rels, edges, stmt, post_filters)
+        for f in post_filters:
+            plan = Filter(plan, f)
+        if _plan_columns(plan, self.catalog) != reads:
+            plan = Project(plan, tuple((n, Col(n)) for n in reads))
+        return plan, tuple(correlation), residual
+
+    def _correlated_cmp(self, ast: P.Node) -> Cmp:
+        """A conjunct of a subquery that names the outer query ->
+        `Cmp(op, Col(inner), Col(outer))`, the comparison read with the
+        subquery's column on the left; anything else raises."""
+        if (isinstance(ast, P.Binary) and ast.op in _CORRELATED_OPS
+                and isinstance(ast.left, P.ColRef)
+                and isinstance(ast.right, P.ColRef)):
+            la: Set[str] = set()
+            lb: Set[str] = set()
+            a, b = self._col(ast.left, la), self._col(ast.right, lb)
+            op = _CORRELATED_OPS[ast.op]
+            if _OUTER in lb and _OUTER not in la:
+                return Cmp(op, a, b)
+            if _OUTER in la and _OUTER not in lb:
+                return Cmp(_MIRRORED[op], b, a)
+        raise BindError("a correlated predicate must compare ONE column "
+                        "of the subquery with ONE column of the outer "
+                        "query (=, <>, <, <=, >, >=) as a conjunct of the "
+                        "subquery's WHERE")
 
     # ------------------------------------------------------- join tree --
 
@@ -747,6 +980,7 @@ class Binder:
             self._collect_cols(ast, needed)
         for e in post_filters:
             self._ir_cols(e, needed)
+        needed |= self._sub_refs  # what the correlated subqueries read
 
         # cost-ranked estimates: ANALYZE stats give per-conjunct
         # selectivities (histograms + distinct counts, sql/stats.py);
@@ -899,9 +1133,16 @@ class Binder:
             self._collect_cols(stmt.having, used)
         for ast, _d in stmt.order_by:
             self._collect_cols(ast, used)
-        schema = self._schemas[rel.key]
-        cols = tuple(n for n in schema.names() if n in used)
+        used |= self._sub_refs
+        # the scan reads the table's own names; an item under
+        # alias-qualified names renames them straight above it
+        source = self.catalog.table_schema(rel.table)
+        cols = tuple(n for n in source.names() if n in used)
         plan: Plan = Scan(rel.table, cols or None)
+        ren = self._renames.get(rel.key)
+        if ren is not None:
+            plan = Project(plan, tuple((ren[n], Col(n))
+                                       for n in cols or source.names()))
         for f in rel.filters:
             plan = Filter(plan, f)
         return plan
@@ -909,6 +1150,10 @@ class Binder:
     def _collect_cols(self, ast: P.Node, out: Set[str]):
         if isinstance(ast, P.ColRef):
             out.add(ast.name)
+            if ast.qualifier in self._renames:
+                # ... and the plan name, where the item has another
+                out.add(self._renames[ast.qualifier].get(ast.name,
+                                                         ast.name))
             return
         if isinstance(ast, P.SelectStmt):
             return  # subquery scope is separate
@@ -1188,7 +1433,8 @@ class Binder:
 
     def _default_name(self, ast: P.Node, e: Expr, idx: int) -> str:
         if isinstance(e, Col):
-            return e.name
+            # `n1.n_name` is sent back as `n_name`, as SQL names it
+            return ast.name if isinstance(ast, P.ColRef) else e.name
         if isinstance(ast, P.FuncCall):
             return ast.name
         return f"col{idx}"
@@ -1288,6 +1534,17 @@ class Binder:
                 return ast.name
             raise BindError(f"ORDER BY column {ast.name!r} is not in the "
                             f"output (have {out_cols})")
+        if isinstance(ast, P.ColRef):
+            # `n1.n_name`: the column under its plan name, or the select
+            # item that sends it back under another
+            hit = self._resolve(ast)
+            if hit is not None:
+                if hit[1] in out_cols:
+                    return hit[1]
+                for n, e in getattr(self, "_select_items", []):
+                    if isinstance(e, Col) and e.name == hit[1] \
+                            and n in out_cols:
+                        return n
         if isinstance(ast, P.FuncCall) and ast.name in _AGG_FUNCS:
             # match the aggregate structurally against the collected specs
             collector = getattr(self, "_last_collector", None)
@@ -1458,6 +1715,44 @@ class _AggCollector:
             fields.append(Field(
                 spec.out, FLOAT if spec.func == "avg" else in_ty))
         return Schema(fields)
+
+
+def _as_exists(ast: P.Node) -> Optional[Tuple[P.SelectStmt, bool]]:
+    """`EXISTS (q)` -> (q, False), `NOT EXISTS (q)` -> (q, True), else
+    None."""
+    if isinstance(ast, P.ExistsAst):
+        return ast.query, ast.negate
+    if (isinstance(ast, P.Unary) and ast.op == "not"
+            and isinstance(ast.arg, P.ExistsAst)):
+        return ast.arg.query, not ast.arg.negate
+    return None
+
+
+def _share_scan_columns(plan: Plan) -> Plan:
+    """A table that several aliases scan is read ONCE where it can be:
+    a Scan straight under an alias's renaming Project, which names what
+    it reads, takes the columns of the table's WIDEST scan in the plan
+    if those include its own; plan.build() then makes value-equal Scans
+    one operator over one device image (Q21: three aliases of lineitem,
+    72 MB, not three images), and the program never unpacks a column
+    nothing reads."""
+    widest: Dict[str, Tuple[str, ...]] = {}
+    for node in _walk_plan(plan):
+        if isinstance(node, Scan) and node.columns:
+            if len(node.columns) > len(widest.get(node.table, ())):
+                widest[node.table] = node.columns
+
+    def rec(node: Plan) -> Plan:
+        if (isinstance(node, Project) and isinstance(node.input, Scan)
+                and node.input.columns):
+            wide = widest[node.input.table]
+            if set(node.input.columns) < set(wide):
+                return Project(Scan(node.input.table, wide), node.outputs)
+            return node
+        kids = tuple(rec(k) for k in node.inputs())
+        return _rebuild(node, kids) if kids else node
+
+    return rec(plan)
 
 
 # ------------------------------------------------------- derived tables

@@ -63,7 +63,11 @@ def render_plan(p: Plan, catalog: Catalog) -> List[str]:
             # what the join orderer ranked its build by (sql/bind.py) and
             # what sizes a Shrink above it
             keeps = join_keeps(node, catalog)
-            return f"{node.how} join on {keys}" + (
+            # a decorrelated EXISTS / NOT EXISTS keeps what its subquery
+            # compared beside the equality (sql/plan.Join.residual)
+            residual = ("" if node.residual is None
+                        else f" residual {node.residual!r}")
+            return f"{node.how} join on {keys}{residual}" + (
                 "" if keeps is None else f" (keeps ~{100 * keeps:.1f}%)")
         if isinstance(node, Aggregate):
             aggs = ", ".join(f"{a.func}({a.col or '*'}) as {a.out}"

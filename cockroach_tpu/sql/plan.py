@@ -303,6 +303,14 @@ class Join(Plan):
     left_on: Tuple[str, ...]
     right_on: Tuple[str, ...]
     how: str = "inner"
+    # a semi or anti join's predicate beside the key equality, over the
+    # left's columns and the right's: a left row has a match iff a right
+    # row of its key makes it TRUE (NULL is no match). Only where the
+    # right is UNIQUE on `right_on` (decorrelate() puts an Aggregate on
+    # the key there): build() lowers the join as an inner (semi) or left
+    # (anti) join, a filter and a projection back to the left's columns,
+    # which answers a duplicate key with a duplicate row.
+    residual: Optional[Expr] = None
 
     def inputs(self):
         return (self.left, self.right)
@@ -369,6 +377,14 @@ class Apply(Plan):
 
     - kind="exists"     -> semi  Join(input, sub) on the correlation
     - kind="not_exists" -> anti  Join(input, sub) on the correlation
+    - either with a `residual`, ONE comparison `Cmp(op, Col(inner x),
+      Col(outer y))` beside the equalities (`b.x <> a.y`: TPC-H Q21) ->
+      Aggregate(sub, group_by=inner correlation cols, min x and/or max x)
+      and the semi / anti join against that unique build with the
+      comparison rewritten over the extremes: some `b.x <> a.y` holds iff
+      `min(b.x) <> a.y OR max(b.x) <> a.y`; `<` and `<=` ask the minimum
+      alone, `>` and `>=` the maximum. min and max skip NULLs and a NULL
+      `a.y` compares to NULL, so three-valued logic is kept
     - kind="scalar"     -> Aggregate(sub, group_by=inner correlation
       cols, (scalar,)) + LEFT Join — empty groups surface as NULL
       (SQL's empty-scalar-subquery semantics) through the left join's
@@ -382,6 +398,7 @@ class Apply(Plan):
     correlation: Tuple[Tuple[str, str], ...]  # (outer col, inner col)
     kind: str = "exists"        # "exists" | "not_exists" | "scalar"
     scalar: Optional[AggSpec] = None   # kind="scalar": the aggregate
+    residual: Optional[Cmp] = None     # exists / not_exists: see above
 
     def inputs(self):
         return (self.input, self.sub)
@@ -498,7 +515,8 @@ def push_filters(p: Plan, catalog: Catalog) -> Plan:
     if isinstance(p, Project):
         return Project(kids[0], p.outputs)
     if isinstance(p, Join):
-        return Join(kids[0], kids[1], p.left_on, p.right_on, p.how)
+        return Join(kids[0], kids[1], p.left_on, p.right_on, p.how,
+                    p.residual)
     if isinstance(p, Aggregate):
         return Aggregate(kids[0], p.group_by, p.aggs)
     if isinstance(p, OrderBy):
@@ -546,12 +564,12 @@ def _try_push(conj: Expr, node: Plan, catalog: Catalog) -> Tuple[bool, Plan]:
             ok, pushed = _try_push(conj, node.left, catalog)
             child = pushed if ok else Filter(node.left, conj)
             return True, Join(child, node.right, node.left_on,
-                              node.right_on, node.how)
+                              node.right_on, node.how, node.residual)
         if refs <= right_cols and node.how in ("inner", "right"):
             ok, pushed = _try_push(conj, node.right, catalog)
             child = pushed if ok else Filter(node.right, conj)
             return True, Join(node.left, child, node.left_on,
-                              node.right_on, node.how)
+                              node.right_on, node.how, node.residual)
         return False, node
     if isinstance(node, Scan):
         # land just above the scan (MapOp fuses it into the scan program)
@@ -643,7 +661,8 @@ def _rebuild(p: Plan, kids) -> Plan:
     if isinstance(p, Project):
         return Project(kids[0], p.outputs)
     if isinstance(p, Join):
-        return Join(kids[0], kids[1], p.left_on, p.right_on, p.how)
+        return Join(kids[0], kids[1], p.left_on, p.right_on, p.how,
+                    p.residual)
     if isinstance(p, Aggregate):
         return Aggregate(kids[0], p.group_by, p.aggs)
     if isinstance(p, OrderBy):
@@ -658,7 +677,8 @@ def _rebuild(p: Plan, kids) -> Plan:
         return VectorTopK(kids[0], p.column, p.query, p.metric, p.k,
                           p.ann, p.nprobe)
     if isinstance(p, Apply):
-        return Apply(kids[0], kids[1], p.correlation, p.kind, p.scalar)
+        return Apply(kids[0], kids[1], p.correlation, p.kind, p.scalar,
+                     p.residual)
     return p
 
 
@@ -705,6 +725,10 @@ def join_keeps(p: Join, catalog: Catalog) -> Optional[float]:
     whole."""
     frac = keep_share(estimate_cardinality(p.right, catalog),
                       _base_rows(p.right, catalog))
+    if p.residual is not None:
+        # a key's rows mostly differ somewhere (<>); an ordering holds of
+        # half the pairs. No statistics reach a correlated comparison.
+        frac *= 0.5 if isinstance(p.residual, Cmp) else 0.9
     if p.how in ("inner", "semi"):
         return frac
     if p.how == "anti":
@@ -812,20 +836,117 @@ def _shrink_rec(p: Plan, catalog: Optional[Catalog]):
     return out, False
 
 
+# comparison -> the extremes of the inner column that decide whether SOME
+# inner value satisfies it against an outer value
+_RESIDUAL_EXTREMES = {"!=": ("min", "max"), "<": ("min",), "<=": ("min",),
+                      ">": ("max",), ">=": ("max",)}
+
+
+def _column_distinct(p: Plan, name: str, catalog: Catalog) -> Optional[int]:
+    """The distinct values of output column `name` of `p`, at most, by the
+    statistics of the table it is scanned from, or None: followed through
+    what keeps a column's values a subset of the table's (as
+    _column_range) and through projections that rename it."""
+    if isinstance(p, (Scan, IndexScan)):
+        st = catalog.table_stats(p.table)
+        cs = st.columns.get(name) if st is not None else None
+        if name not in (p.columns or catalog.table_schema(p.table).names()):
+            return None
+        return int(cs.distinct) if cs is not None else None
+    if isinstance(p, Project):
+        e = dict(p.outputs).get(name)
+        return (_column_distinct(p.input, e.name, catalog)
+                if isinstance(e, Col) else None)
+    if isinstance(p, (Filter, Shrink, OrderBy, Limit, Distinct)):
+        return _column_distinct(p.input, name, catalog)
+    if isinstance(p, Aggregate):
+        return (_column_distinct(p.input, name, catalog)
+                if name in p.group_by else None)
+    if isinstance(p, Join):
+        sides = (p.left,) if p.how in ("semi", "anti") else (p.left, p.right)
+        for side in sides:
+            if name in _plan_columns(side, catalog):
+                return _column_distinct(side, name, catalog)
+    return None
+
+
+def _residual_join(outer: Plan, sub: Plan, p: "Apply", n: int,
+                   catalog: Catalog) -> Join:
+    """Apply `p` (exists / not_exists with a residual, the `n`th of its
+    plan) over its decorrelated inputs -> the semi / anti join against
+    the per-key extremes (see Apply's docstring). The build's columns are
+    renamed `__apply<n>_*`: hand-built plans scan the same table on both
+    sides under the same names. Where the statistics count the keys'
+    distinct values, and they are fewer than the rows the subquery scans,
+    the build is SHRUNK to them (a fact table's aggregate comes out at the
+    table's lanes, one live lane a group: lineitem's 8.4M lanes for 1.5M
+    orders, and the join's sorts would run at those lanes); a count that
+    is too low takes the Shrink's restart."""
+    r = p.residual
+    if not (isinstance(r, Cmp) and r.op in _RESIDUAL_EXTREMES
+            and isinstance(r.left, Col) and isinstance(r.right, Col)
+            and p.correlation):
+        raise TypeError(
+            "an Apply's residual is ONE comparison (!=, <, <=, >, >=) of "
+            "an inner column with an outer one, beside an equality "
+            f"correlation: {r!r}")
+    inner_on = tuple(b for _, b in p.correlation)
+    ext = {f: f"__apply{n}_{f}" for f in _RESIDUAL_EXTREMES[r.op]}
+    agg = Aggregate(sub, inner_on, tuple(AggSpec(f, r.left.name, out)
+                                         for f, out in ext.items()))
+    keys = tuple(f"__apply{n}_k{i}" for i in range(len(inner_on)))
+    build = Project(agg, tuple(zip(keys, map(Col, inner_on)))
+                    + tuple((out, Col(out)) for out in ext.values()))
+    distinct = [_column_distinct(sub, k, catalog) for k in inner_on]
+    if None not in distinct:
+        import math
+
+        cap = max(_pow2_at_least(int(math.prod(distinct) * 1.25) + 1),
+                  1 << 12)
+        # against the subquery's LANES, which its filters do not cut
+        if cap < _base_rows(sub, catalog):
+            build = Shrink(build, start_capacity=cap)
+    parts = [Cmp(r.op, Col(out), r.right) for out in ext.values()]
+    return Join(outer, build, tuple(a for a, _ in p.correlation), keys,
+                "semi" if p.kind == "exists" else "anti",
+                parts[0] if len(parts) == 1 else BoolOp("or", tuple(parts)))
+
+
 def decorrelate(p: Plan, catalog: Catalog) -> Plan:
     """Rewrite every Apply (correlated subquery) into join+aggregate form
     (see Apply's docstring). Runs FIRST in normalize(): the later passes
     (pushdown, index selection, shrink placement) and the builder only
     ever see ordinary relational nodes — compiled and host walks execute
     the same decorrelated plan, so the rewrite can never diverge the two
-    paths."""
-    kids = tuple(decorrelate(k, catalog) for k in p.inputs())
+    paths. A plan that held an Apply counts stage `sql.decorrelate` (one
+    event, `rows` = the Applies rewritten) and
+    `sql_apply_decorrelated_total`."""
+    rewritten: List[Apply] = []
+    out = _decorrelate(p, catalog, rewritten)
+    if rewritten:
+        from cockroach_tpu.exec import stats
+        from cockroach_tpu.util.metric import default_registry
+
+        stats.add("sql.decorrelate", rows=len(rewritten))
+        default_registry().counter(
+            "sql_apply_decorrelated_total",
+            "correlated subqueries (Apply nodes) rewritten into joins "
+            "and aggregates").inc(len(rewritten))
+    return out
+
+
+def _decorrelate(p: Plan, catalog: Catalog, rewritten: List["Apply"]) -> Plan:
+    kids = tuple(_decorrelate(k, catalog, rewritten) for k in p.inputs())
     if not isinstance(p, Apply):
         return _rebuild(p, kids) if kids else p
     outer, sub = kids
+    rewritten.append(p)
     outer_on = tuple(a for a, _ in p.correlation)
     inner_on = tuple(b for _, b in p.correlation)
     if p.kind in ("exists", "not_exists"):
+        if p.residual is not None:
+            return _residual_join(outer, sub, p, len(rewritten) - 1,
+                                  catalog)
         how = "semi" if p.kind == "exists" else "anti"
         return Join(outer, sub, outer_on, inner_on, how)
     if p.kind != "scalar" or p.scalar is None:
@@ -911,6 +1032,29 @@ def build(p: Plan, catalog: Catalog, capacity: int = 1 << 17,
             node_map[id(node)] = op
         return op
 
+    def residual_join(node: Join) -> Operator:
+        """A semi / anti join with a residual over a unique build: the
+        inner (left) join that brings the build's columns to the probe's
+        lanes, the residual as a filter (for the anti join: whatever it
+        does NOT make TRUE, an unmatched lane's NULLs among that), and
+        the probe's columns alone again. Every lowering of a join and of
+        a filter serves it as it is, on one chip and on a mesh; the
+        JoinOp keeps `residual_of` for EXPLAIN and the lanes' count."""
+        if node.how not in ("semi", "anti"):
+            raise TypeError(f"a residual on a {node.how} join")
+        from cockroach_tpu.ops.expr import Not, ScalarFunc
+
+        join = JoinOp(rec(node.left), rec(node.right),
+                      list(node.left_on), list(node.right_on),
+                      how="inner" if node.how == "semi" else "left")
+        join.residual_of = node.how
+        keep = (node.residual if node.how == "semi"
+                else Not(ScalarFunc("coalesce",
+                                    (node.residual, Lit(False)))))
+        cols = _plan_columns(node.left, catalog)
+        return MapOp(join, [("filter", keep),
+                            ("project", [(n, Col(n)) for n in cols])])
+
     def _rec(node: Plan) -> Operator:
         if isinstance(node, Scan):
             schema = catalog.table_schema(node.table)
@@ -981,6 +1125,8 @@ def build(p: Plan, catalog: Catalog, capacity: int = 1 << 17,
                 return RowMapOp(child_op, list(node.outputs))
             return MapOp(child_op, [("project", list(node.outputs))])
         if isinstance(node, Join):
+            if node.residual is not None:
+                return residual_join(node)
             return JoinOp(rec(node.left), rec(node.right),
                           list(node.left_on), list(node.right_on),
                           how=node.how)

@@ -288,7 +288,8 @@ def _describe(node: Plan) -> str:
     if isinstance(node, (Scan, IndexScan)):
         return node.table
     if isinstance(node, Join):
-        return node.how + " " + ",".join(node.left_on)
+        return node.how + " " + ",".join(node.left_on) + (
+            " +residual" if node.residual is not None else "")
     if isinstance(node, Aggregate):
         return ",".join(node.group_by) if node.group_by else "scalar"
     if isinstance(node, (OrderBy,)):

@@ -2284,9 +2284,7 @@ class Session:
                 raise BindError("cannot UPDATE the primary key")
         binder = Binder(cat)
         schema = desc.schema()
-        binder._schemas = {ast.table: schema}
-        binder._col_to_rel = {n: ast.table for n in schema.names()}
-        binder._global = schema
+        binder.scope_one_table(ast.table, schema)
         where = (binder._bind_scalar(ast.where)[0]
                  if ast.where is not None else None)
         sets = [(c, binder._bind_scalar(e)[0]) for c, e in ast.sets]
@@ -2330,9 +2328,7 @@ class Session:
         desc = cat.desc(ast.table)
         binder = Binder(cat)
         schema = desc.schema()
-        binder._schemas = {ast.table: schema}
-        binder._col_to_rel = {n: ast.table for n in schema.names()}
-        binder._global = schema
+        binder.scope_one_table(ast.table, schema)
         where = (binder._bind_scalar(ast.where)[0]
                  if ast.where is not None else None)
         n = 0

@@ -71,9 +71,12 @@ def sample_stats(chunks, schema, sample_rows: int = SAMPLE_ROWS
     via a DistSQL sampler processor), but integer BOUNDS are exact over
     every row — lo/hi feed planner decisions (direct-address aggregation
     ranges, index spans) where a prefix-biased bound would be wrong, not
-    just imprecise."""
+    just imprecise — and so is the count of runs that caps an integer
+    column's distinct count (it sizes the Shrink of a decorrelated
+    subquery's build, sql/plan._residual_join)."""
     cols: Dict[str, List[np.ndarray]] = {}
     bounds: Dict[str, Tuple[int, int]] = {}
+    runs: Dict[str, int] = {}   # runs of one value, over every row
     sampled = 0
     total = 0
     for c in chunks:
@@ -91,6 +94,8 @@ def sample_stats(chunks, schema, sample_rows: int = SAMPLE_ROWS
                     bounds[name] = (min(plo, lo), max(phi, hi))
                 else:
                     bounds[name] = (lo, hi)
+                runs[name] = (runs.get(name, 0) + 1
+                              + int(np.count_nonzero(a[1:] != a[:-1])))
             if take:
                 stride = max(1, n // take)
                 cols.setdefault(name, []).append(a[::stride][:take])
@@ -108,6 +113,12 @@ def sample_stats(chunks, schema, sample_rows: int = SAMPLE_ROWS
             distinct = int(distinct_sample * scale)
         else:
             distinct = distinct_sample
+        # a value's rows form at least one run, so the runs counted over
+        # every row bound the distinct values from above: a CLUSTERED
+        # foreign key (lineitem's order key: 4 rows an order, side by
+        # side) looks unique to a strided sample and was scaled to the
+        # row count
+        distinct = min(distinct, runs.get(name, distinct))
         cs = ColumnStats(max(distinct, 1), 0.0)
         if name in bounds:
             cs.lo, cs.hi = bounds[name]

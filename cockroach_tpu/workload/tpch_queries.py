@@ -412,6 +412,20 @@ def q2_oracle(gen: TPCH):
 
 Q4_LO, Q4_HI = _days(1993, 7, 1), _days(1993, 10, 1)
 
+# the specification's text (clause 2.4.4, validation DATE): sql/bind.py
+# binds it to the plan q4_plan() builds by hand (tests/test_q21.py)
+Q4_SQL = """
+select o_orderpriority, count(*) as order_count
+from orders
+where o_orderdate >= date '1993-07-01'
+  and o_orderdate < date '1993-07-01' + interval '3' month
+  and exists (select * from lineitem
+              where l_orderkey = o_orderkey
+                and l_commitdate < l_receiptdate)
+group by o_orderpriority
+order by o_orderpriority
+"""
+
 
 def q4_plan():
     orders = Filter(
@@ -424,6 +438,7 @@ def q4_plan():
                Cmp("<", Col("l_commitdate"), Col("l_receiptdate"))),
         (("l_orderkey", Col("l_orderkey")),))
     ap = Apply(orders, late, (("o_orderkey", "l_orderkey"),), kind="exists")
+    ap = Project(ap, (("o_orderpriority", Col("o_orderpriority")),))
     agg = Aggregate(ap, ("o_orderpriority",),
                     (AggSpec("count_star", None, "order_count"),))
     # priority dict pool is ordered 1-URGENT..5-LOW: code order == text order
@@ -917,8 +932,107 @@ def q19_oracle(gen: TPCH) -> int:
                 * (100 - l["l_discount"][keep].astype(np.int64))).sum())
 
 
+# ------------------------------------------------------------------ Q21 ---
+# Suppliers who kept orders waiting: the fact table three times, twice in
+# a correlated subquery whose predicate is no equality (`<>` on the
+# supplier beside the order key). Two Apply nodes with a residual;
+# decorrelate() turns each into the min / max of l_suppkey by order and a
+# semi (anti) join against that unique build.
+
+Q21_NATION = "SAUDI ARABIA"   # the validation substitution (2.4.21.3)
+
+# the specification's text, NATION a parameter (the cell's statement:
+# benchmark/workloads/q21_qgen.txt)
+Q21_SQL = """
+select s_name, count(*) as numwait
+from supplier, lineitem l1, orders, nation
+where s_suppkey = l1.l_suppkey
+  and o_orderkey = l1.l_orderkey
+  and o_orderstatus = 'F'
+  and l1.l_receiptdate > l1.l_commitdate
+  and exists (select * from lineitem l2
+              where l2.l_orderkey = l1.l_orderkey
+                and l2.l_suppkey <> l1.l_suppkey)
+  and not exists (select * from lineitem l3
+                  where l3.l_orderkey = l1.l_orderkey
+                    and l3.l_suppkey <> l1.l_suppkey
+                    and l3.l_receiptdate > l3.l_commitdate)
+  and s_nationkey = n_nationkey
+  and n_name = $1
+group by s_name
+order by numwait desc, s_name
+limit 100
+"""
+
+
+def q21_plan(nation: str = Q21_NATION):
+    line = ("l_orderkey", "l_suppkey", "l_commitdate", "l_receiptdate")
+    late = Cmp(">", Col("l_receiptdate"), Col("l_commitdate"))
+    other = Cmp("!=", Col("l_suppkey"), Col("l_suppkey"))  # inner, outer
+    on_order = (("l_orderkey", "l_orderkey"),)
+    pair = (("l_orderkey", Col("l_orderkey")), ("l_suppkey", Col("l_suppkey")))
+    home = Project(Filter(Scan("nation", ("n_nationkey", "n_name")),
+                          Cmp("==", Col("n_name"), Lit(nation))),
+                   (("n_nationkey", Col("n_nationkey")),))
+    supp = Join(Scan("supplier", ("s_suppkey", "s_name", "s_nationkey")),
+                home, ("s_nationkey",), ("n_nationkey",), how="semi")
+    l1 = Join(Filter(Scan("lineitem", line), late), supp,
+              ("l_suppkey",), ("s_suppkey",))
+    done = Project(Filter(Scan("orders", ("o_orderkey", "o_orderstatus")),
+                          Cmp("==", Col("o_orderstatus"), Lit("F"))),
+                   (("o_orderkey", Col("o_orderkey")),))
+    l1 = Join(l1, done, ("l_orderkey",), ("o_orderkey",), how="semi")
+    ap = Apply(l1, Project(Scan("lineitem", line), pair), on_order,
+               "exists", None, other)
+    ap = Apply(ap, Project(Filter(Scan("lineitem", line), late), pair),
+               on_order, "not_exists", None, other)
+    agg = Aggregate(Project(ap, (("s_name", Col("s_name")),)), ("s_name",),
+                    (AggSpec("count_star", None, "numwait"),))
+    return Limit(OrderBy(agg, (SortKey("numwait", descending=True),
+                               SortKey("s_name"))), 100)
+
+
+def q21(gen: TPCH, capacity: int = 1 << 17, catalog=None,
+        nation: str = Q21_NATION) -> Operator:
+    return _build(gen, q21_plan(nation), capacity, catalog)
+
+
+def q21_oracle(gen: TPCH, nation: str = Q21_NATION):
+    """[(s_name code, numwait)] by (numwait desc, the name's text): row by
+    row over each order's lines, as the text reads."""
+    s, l, o = gen.table("supplier"), gen.table("lineitem"), gen.table("orders")
+    nat = _code(gen, "nation", "n_name", nation)
+    nkey = int(gen.table("nation")["n_nationkey"][
+        gen.table("nation")["n_name"] == nat][0])
+    f = _code(gen, "orders", "o_orderstatus", "F")
+    done = set(o["o_orderkey"][o["o_orderstatus"] == f].tolist())
+    lines: Dict[int, list] = {}
+    for ok, sk, cd, rd in zip(l["l_orderkey"].tolist(),
+                              l["l_suppkey"].tolist(),
+                              l["l_commitdate"].tolist(),
+                              l["l_receiptdate"].tolist()):
+        lines.setdefault(ok, []).append((sk, rd > cd))
+    home = {int(k): int(n) for k, n, nk in
+            zip(s["s_suppkey"].tolist(), s["s_name"].tolist(),
+                s["s_nationkey"].tolist()) if nk == nkey}
+    waits: Dict[int, int] = {}
+    for ok, rows in lines.items():
+        if ok not in done:
+            continue
+        for sk, is_late in rows:
+            if (is_late and sk in home
+                    and any(sk2 != sk for sk2, _late in rows)
+                    and not any(sk3 != sk and late3 for sk3, late3 in rows)):
+                waits[sk] = waits.get(sk, 0) + 1
+    names = gen.schema("supplier").dicts["s_name"]
+    out = sorted(((home[sk], n) for sk, n in waits.items()),
+                 key=lambda r: (-r[1], str(names[r[0]])))
+    return out[:100]
+
+
 QUERIES = {1: q1, 2: q2, 3: q3, 4: q4, 5: q5, 6: q6, 9: q9, 10: q10,
-           12: q12, 14: q14, 15: q15, 16: q16, 17: q17, 18: q18, 19: q19}
+           12: q12, 14: q14, 15: q15, 16: q16, 17: q17, 18: q18, 19: q19,
+           21: q21}
 
 # logical-plan constructors (uniform gen -> Plan signature) — what the
 # placement pass compiles directly
@@ -938,6 +1052,7 @@ PLANS = {
     17: lambda gen: q17_plan(),
     18: lambda gen: q18_plan(),
     19: lambda gen: q19_plan(),
+    21: lambda gen: q21_plan(),
 }
 
 

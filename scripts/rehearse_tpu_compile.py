@@ -4,7 +4,7 @@ on-chip-measurement guide. What the chip's compiler refuses it refuses
 here, at no chip time; each compile's seconds are the smoke's cold budget.
 
     JAX_PLATFORMS=cpu python scripts/rehearse_tpu_compile.py [--sf 1] \
-        [--only kernel,q1,q6,q3,q3_dist4,q9_dist4,q18_dist4]
+        [--only kernel,q1,q6,q3,q21,q3_dist4,q9_dist4,q18_dist4]
 
 Plans are built from SF1 data (TPCH.mvcc_load, so the planner sees SF1's
 statistics and every inner capacity is SF1's), lowered exactly as
@@ -120,6 +120,11 @@ def main():
                                      sharding=one_chip))
             report(f"limb_kernel({rows},{limbs},{lanes})", lowered, tl)
 
+    if "q21" in only:
+        from benchmark.loaders import tpch_sname
+
+        prepared_fused(one_chip, args, "q21_fused",
+                       "tpch-sf1-q21.q21-1stream", tpch_sname, ("FRANCE",))
     if "q9_dist4" in only:
         q9_dist4(topo, args)
     if "q18_dist4" in only:
@@ -222,14 +227,12 @@ def q18_dist4(topo, args):
     assert kinds == ["_AggRoute", "_Gather", "_Gather"], kinds
 
 
-def prepared_dist4(topo, args, name, cell, loader, values) -> dict:
-    """Lower and compile `cell`'s one prepared statement as
-    DistFusedRunner does for four described chips, from `loader`'s SF
-    tables with `values` bound; -> _classify's placements."""
+def _prepared_plan(args, cell, loader, values):
+    """`cell`'s one prepared statement over `loader`'s SF tables with
+    `values` bound -> (its bound arguments, the compiled plan, its scans,
+    scan_shapes' generator: the row counts the benchmark's loader gave)."""
     from benchmark import manifest
     from cockroach_tpu.exec.operators import ScanOp, walk_operators
-    from cockroach_tpu.ops.expr import bound_args
-    from cockroach_tpu.parallel import dist_flow, ingest
     from cockroach_tpu.sql import params as _params
     from cockroach_tpu.sql import parser
     from cockroach_tpu.sql.bind import Binder
@@ -251,11 +254,41 @@ def prepared_dist4(topo, args, name, cell, loader, values) -> dict:
     bound = _params.evaluate(binder.param_slots, values)
     cp = compile_plan(plan, catalog, chip_smoke.CAPACITY, sql=stmt["sql"],
                       setting="tpu")
+    scans = [n for n in walk_operators(cp.op) if isinstance(n, ScanOp)]
+    gen = SimpleNamespace(num_rows=loaded["rows"].__getitem__)
+    return bound, cp, scans, gen
+
+
+def prepared_fused(one_chip, args, name, cell, loader, values) -> None:
+    """Lower and compile `cell`'s one prepared statement as FusedRunner
+    does for one described chip (not in the default list: Q21's takes
+    minutes)."""
+    bound, cp, scans, gen = _prepared_plan(args, cell, loader, values)
+    assert cp.runner is not None, f"{name} is outside the fusion grammar"
+    prog, box = cp.runner._make_prog([id(s) for s in scans])
+    sds = scan_shapes(scans, gen, lambda sc, n: (_pow2(n), one_chip))
+    sds += tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                 for a in bound)
+    lowered, tl = timed_lower(prog, *sds)
+    report(name, lowered, tl,
+           chunks={sc.table: int(a[0].shape[0])
+                   for sc, a in zip(scans, sds)},
+           sort_lanes=box["sort_lanes"],
+           join_residual_lanes=box.get("join_residual_lanes"),
+           params=[list(a.shape) for a in bound])
+
+
+def prepared_dist4(topo, args, name, cell, loader, values) -> dict:
+    """Lower and compile `cell`'s one prepared statement as
+    DistFusedRunner does for four described chips, from `loader`'s SF
+    tables with `values` bound; -> _classify's placements."""
+    from cockroach_tpu.exec.operators import walk_operators
+    from cockroach_tpu.ops.expr import bound_args
+    from cockroach_tpu.parallel import dist_flow, ingest
+
+    bound, cp, scans, gen = _prepared_plan(args, cell, loader, values)
     mesh = Mesh(np.array(topo.devices[:4]), ("x",))
     runner = dist_flow.DistFusedRunner(cp.op, mesh, "x")
-    scans = [n for n in walk_operators(cp.op) if isinstance(n, ScanOp)]
-    # scan_shapes' generator: the row counts the benchmark's loader gave
-    gen = SimpleNamespace(num_rows=loaded["rows"].__getitem__)
     chunks = {id(sc): -(-gen.num_rows(sc.table) // sc.capacity)
               for sc in scans}
     sharded, repart = runner._classify(chunks)

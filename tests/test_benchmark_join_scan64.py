@@ -21,15 +21,18 @@ from benchmark.test_judged_metrics import (
     test_the_cells_last_lines_carry_what_the_manifest_says as _q6_lines,
 )
 
+# the four of ISSUE 49, and the Q21 cell that ISSUE 50 appended
 JOIN_CELLS = ["tpch-sf1-q18.q18-1stream", "tpch-sf1.q3-1stream",
-              "tpch-sf1-qgen.q3-1stream", "tpch-sf1-q9.q9-1stream"]
+              "tpch-sf1-qgen.q3-1stream", "tpch-sf1-q9.q9-1stream",
+              "tpch-sf1-q21.q21-1stream"]
 CELLS = [w["name"] for w in manifest.benchmark()["workloads"]]
 
 
-def test_the_manifest_is_valid_and_the_metric_is_its_last_entry():
+def test_the_manifest_is_valid_and_the_metric_is_its_entry():
     bench = manifest.benchmark()
     assert manifest.validate(bench) == []
-    assert bench["per_layer"][-1] == {
+    # the last entry until ISSUE 50 put `join_residual_lanes_m` after it
+    assert bench["per_layer"][-2] == {
         "name": "join_scan64_lanes_m", "unit": "Mlanes", "better": "lower",
         "source": "program_counter", "layer": "fused runner",
         "moves": "stmt_p50_ms", "workloads": JOIN_CELLS}
@@ -39,7 +42,7 @@ def test_the_manifest_is_valid_and_the_metric_is_its_last_entry():
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_the_four_one_chip_join_cells_report_it_and_no_other(cell):
+def test_the_one_chip_join_cells_report_it_and_no_other(cell):
     bench = manifest.benchmark()
     layer = {m["name"] for m in manifest.metrics_for(bench, cell,
                                                      "per_layer")}

@@ -743,9 +743,14 @@ _LOWERINGS = {
     "int_key_run_ends_view": (      # over 2^18 lanes: left to the reader
         lambda: HashAggOp(_keyed_scan(1000, 1 << 19), ["k"], _SUMS),
         "int_key", 0),
-    "int_key_off_for_a_min": (      # the kernel sums and counts only
+    "int_key_with_a_min_and_a_max": (   # extremes ride the sort too (PR 50)
         lambda: HashAggOp(_keyed_scan(), ["k"],
-                          _SUMS + [AggSpec("min", "v", "lo")]),
+                          _SUMS + [AggSpec("min", "v", "lo"),
+                                   AggSpec("max", "v", "hi")]),
+        "int_key", 256),
+    "int_key_off_for_a_first_value": (  # sums, counts and extremes only
+        lambda: HashAggOp(_keyed_scan(), ["k"],
+                          _SUMS + [AggSpec("any_not_null", "v", "a")]),
         "materialized", None),
     "by_slot_dictionary_and_bool": (
         lambda: HashAggOp(_coded_scan(), ["mode", "f"], _SUMS),

@@ -44,6 +44,25 @@ def test_sample_stats_histogram_and_distinct():
     assert len(st.columns["a"].histogram) == 16
 
 
+def test_a_clustered_keys_distinct_count_is_capped_by_its_runs():
+    """A foreign key stored side by side (lineitem's order key: 1 to 7
+    rows an order) looks unique to the strided sample, whose count was
+    scaled to the rows; the runs of one value, counted over EVERY row,
+    bound the distinct values from above (PR 50: the bound sizes the
+    Shrink of a decorrelated subquery's build)."""
+    rng = np.random.default_rng(5)
+    orders = np.arange(1, 200_001, dtype=np.int64) * 4     # sparse keys
+    key = np.repeat(orders, rng.integers(1, 8, len(orders)))
+    # one chunk a table, as the bulk loaders hand it over: the sample is
+    # every twelfth row, so no order key comes twice
+    chunk = {"k": key, "u": np.arange(len(key), dtype=np.int64) % 97}
+    st = sample_stats([chunk], Schema([Field("k", INT), Field("u", INT)]))
+    assert st.row_count == len(key) > 4 * 150_000
+    assert st.columns["k"].distinct == len(orders)   # exact; was the rows
+    # a column without clustering keeps its sampled count
+    assert st.columns["u"].distinct == 97
+
+
 def test_selectivity_eq_and_range():
     cs = ColumnStats(distinct=100, null_frac=0.0, lo=0, hi=999,
                      histogram=list(range(62, 1000, 62))[:16])
